@@ -1,7 +1,7 @@
 """Bench-baseline drift gate (ISSUE 15 satellite).
 
 Every bench in this repo prints ONE JSON line; until now those lines
-lived in ad-hoc BENCH_r*.json artifacts and prose in docs/BENCHMARKS.md
+lived in ad-hoc per-round JSON artifacts and prose in docs/BENCHMARKS.md
 — nothing machine-readable tracked the trajectory, so a silent 2×
 regression between PRs would only surface if a human re-read the docs.
 This tool normalizes a bench's JSON line into `docs/baselines/
@@ -136,9 +136,9 @@ def compare(baseline: dict, current: dict,
 
 def _read_record(args) -> dict:
     raw = (open(args.file).read() if args.file else sys.stdin.read())
-    # A whole-file JSON document first (the BENCH_r*.json artifact
-    # shape); else benches may emit warnings before their JSON line —
-    # take the LAST line that parses as a JSON object.
+    # A whole-file JSON document first (a driver artifact that wraps
+    # the line under "parsed"); else benches may emit warnings before
+    # their JSON line — take the LAST line that parses as a JSON object.
     try:
         rec = json.loads(raw)
         if isinstance(rec, dict):
@@ -153,7 +153,7 @@ def _read_record(args) -> dict:
             last_err = e
             continue
         if isinstance(rec, dict):
-            # BENCH_r* artifacts wrap the line under "parsed".
+            # Driver artifacts wrap the line under "parsed".
             return rec.get("parsed", rec) if "parsed" in rec else rec
     raise SystemExit(f"no JSON object line found in input ({last_err})")
 

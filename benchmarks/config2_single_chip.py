@@ -46,7 +46,7 @@ TRIALS = int(os.environ.get("CONFIG2_TRIALS", 3))
 MN = "legal winner thank year wave sausage worth useful legal winner thank yellow"
 
 
-def build_messages(n=N, seed=2):
+def build_messages(n=N, seed=2, rows=5000):
     rng = random.Random(seed)
     tables = [("todo", ("title", "isCompleted", "categoryId")),
               ("todoCategory", ("name",)),
@@ -58,7 +58,7 @@ def build_messages(n=N, seed=2):
         table, cols = rng.choice(tables)
         out.append(CrdtMessage(
             timestamp_to_string(Timestamp(base + i // 4, i % 4, rng.choice(nodes))),
-            table, f"row{rng.randrange(5000)}", rng.choice(cols), f"v{i}",
+            table, f"row{rng.randrange(rows)}", rng.choice(cols), f"v{i}",
         ))
     return out
 

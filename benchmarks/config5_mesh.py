@@ -29,7 +29,7 @@ INNER_ITERS = 2
 
 def main():
     import jax.numpy as jnp
-    from evolu_tpu.ops import shard_map
+    from jax import shard_map
     from jax.sharding import PartitionSpec as P
 
     sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))

@@ -73,7 +73,7 @@ def ledger_sentinel_sequence():
     """The ledger + sentinel work ONE config-2 engine pass performs
     (32 requests / 32 owners / 1M rows): per-request relay ingress
     counts, the pass's pending-entry terminal classification, the
-    recompile-sentinel gauge refresh, and the tunnel-pull wave
+    recompile-sentinel gauge refresh, and the device→host pull wave
     instrumentation. Deliberately a superset (real passes skip
     zero-count stations for free)."""
     for o in _OWNERS:
@@ -86,7 +86,7 @@ def ledger_sentinel_sequence():
     # Recompile sentinel: two cache gauges + the flat-diff bookkeeping.
     metrics.set_gauge("evolu_jit_cache_size", 7, cache="merkle")
     metrics.set_gauge("evolu_jit_cache_size", 0, cache="mesh")
-    # Tunnel-bandwidth plane: one output wave of the merkle kernel.
+    # Pull-bandwidth plane: one output wave of the merkle kernel.
     metrics.inc("evolu_pull_bytes_total", 48_000_000)
     metrics.inc("evolu_pull_seconds_total", 3.0)
     metrics.observe("evolu_pull_wave_bytes", 48_000_000,
@@ -164,7 +164,7 @@ def main():
     logger.clear()
     instr_ms = measure_instrumentation_ms()
     ledger_ms = measure_ledger_sentinel_ms()
-    anatomy.set_platform("tpu")  # priced floors = the expensive path
+    anatomy.set_device_kind(anatomy.V5E)  # priced floors = the expensive path
     anatomy_ms = measure_anatomy_ms()
     batch_ms = measure_reconcile_batch_ms()
     print(json.dumps({

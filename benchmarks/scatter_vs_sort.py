@@ -35,7 +35,7 @@ ITERS_LO, ITERS_HI = 2, 8
 
 
 def make_loop(mesh, iters, kernel, cell_bits):
-    from evolu_tpu.ops import shard_map
+    from jax import shard_map
     from jax.sharding import PartitionSpec as P
 
     spec = P("owners")
@@ -116,7 +116,7 @@ def main():
             # row orders per kernel — so cross-kernel equality is
             # asserted on the digest; full mask/delta parity is pinned
             # in tests/test_scatter_merge.py).
-            from evolu_tpu.ops import shard_map
+            from jax import shard_map
             from jax.sharding import PartitionSpec as P
 
             dig = jax.jit(shard_map(

@@ -46,11 +46,12 @@ sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax import shard_map
 from jax.sharding import PartitionSpec as P
 
 import bench
 from evolu_tpu.obs import anatomy
-from evolu_tpu.ops import shard_map, to_host_many
+from evolu_tpu.ops import to_host_many
 from evolu_tpu.ops.encode import timestamp_hashes, unpack_ts_keys
 from evolu_tpu.ops.merge import masks_from_sorted_flags, winner_flags
 from evolu_tpu.ops.merkle_ops import owner_minute_segments
@@ -388,28 +389,30 @@ def run(n, owners, iters_pair, reps, wave_pair, liveness_n=512):
         # 3. Pull wave (outside the fused loop).
         pull = measure_pull_wave(mesh, cols, wave_pair, reps)
 
-    platform = jax.devices()[0].platform
+    device = jax.devices()[0]
+    kind = device.device_kind  # a device without laws raises: no silent 0
     full = slopes[DEVICE_STAGES[-1]]
     stages = {}
     for name in DEVICE_STAGES:
         marginal = marginals[name]
-        floor = anatomy.floor_ms(name, rows=n, platform=platform)
+        floor = anatomy.floor_ms(name, rows=n, device_kind=kind)
         stages[name] = {
             "slope_ms": round(slopes[name], 4),
             "marginal_ms": round(marginal, 4),
             "share": round(max(marginal, 0.0) / full, 4) if full > 0 else 0.0,
-            "floor_ms": round(floor, 4),
+            "floor_ms": None if floor is None else round(floor, 4),
             "floor_ratio": (
-                round(max(marginal, 0.0) / floor, 3) if floor > 0 else None
+                round(max(marginal, 0.0) / floor, 3) if floor else None
             ),
         }
-    pull["floor_ms"] = round(
-        anatomy.floor_ms("pull_wave", nbytes=int(pull["wave_mb"] * 1e6),
-                         platform=platform), 4)
+    pull_floor = anatomy.floor_ms(
+        "pull_wave", nbytes=int(pull["wave_mb"] * 1e6), device_kind=kind)
+    pull["floor_ms"] = None if pull_floor is None else round(pull_floor, 4)
 
     return {
         "metric": "stage_anatomy",
-        "platform": platform,
+        "platform": device.platform,
+        "device_kind": kind,
         "batch": n,
         "owners": owners,
         "devices": n_dev,

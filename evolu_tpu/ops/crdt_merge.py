@@ -68,7 +68,7 @@ def segmented_sum_scan(flags, vals):
     with a big-enough batch the single-pass Pallas kernel takes over
     (same routing rule as the lex-max scan)."""
     n = flags.shape[0]
-    if n >= (1 << 15) and _use_pallas_scan():
+    if _use_pallas_scan(n):
         from evolu_tpu.ops.pallas_scan import segmented_sum_scan_pallas
 
         return segmented_sum_scan_pallas(flags, vals)

@@ -83,24 +83,27 @@ _SCAN_BLOCK = 256
 _PALLAS_SCAN_MIN = 1 << 15  # one pallas grid tile; below this, padding waste wins
 
 
-def _use_pallas_scan() -> bool:
-    """Trace-time routing: the single-pass Pallas scan wins on TPU
-    silicon (0.42 vs 0.60 ms per fwd+rev pair at 1M, slope-measured);
-    everywhere else (CPU tests, exotic builds) the blocked XLA form
-    runs. Overridable via EVOLU_PALLAS_SCAN=0/1."""
+def _use_pallas_scan(n: int) -> bool:
+    """Trace-time routing of the three segmented scans (lex-max here,
+    XOR in merkle_ops, sum in crdt_merge): the single-pass Pallas
+    kernel on a TPU backend from one grid tile up, the blocked XLA form
+    everywhere else (the CPU tests; EVOLU_PALLAS_SCAN=0 pins it on the
+    chip). Below one tile there is no choice and nothing is counted;
+    from one tile up the route is counted per TRACE
+    (`evolu_merge_scan_total{path}` — a jit-cache hit re-runs no
+    Python), so the counter says which form each compiled program
+    holds. chip_smoke.py fails on any `path="xla"` count."""
     import os
 
-    override = os.environ.get("EVOLU_PALLAS_SCAN", "").lower()
-    if override in ("0", "false", "off"):
+    if n < _PALLAS_SCAN_MIN:
         return False
-    try:
-        from evolu_tpu.ops.pallas_scan import PALLAS_AVAILABLE
-    except Exception:  # pragma: no cover
-        return False
-    # "1" only FORCES where the kernel can actually run — the
-    # availability and TPU-backend guards always hold (a CPU build
-    # would crash mid-jit in non-interpret mode).
-    return PALLAS_AVAILABLE and jax.default_backend() == "tpu"
+    pallas = (
+        jax.default_backend() == "tpu"
+        and os.environ.get("EVOLU_PALLAS_SCAN", "").lower()
+        not in ("0", "false", "off")
+    )
+    metrics.inc("evolu_merge_scan_total", path="pallas" if pallas else "xla")
+    return pallas
 
 
 def _segmented_max_scan(flags, k1, k2, reverse: bool = False):
@@ -113,15 +116,15 @@ def _segmented_max_scan(flags, k1, k2, reverse: bool = False):
 
     On TPU with a big-enough batch the single-pass Pallas kernel
     (ops/pallas_scan.py) takes over — one HBM pass with the carry in
-    SMEM across the sequential grid, measured another ~30% off the
-    scan pair on v5e silicon, bit-identical (tests/test_pallas.py).
+    SMEM across the sequential grid, bit-identical
+    (tests/test_pallas.py).
 
     Identical results to `_segmented_max_scan_reference` (property
     pinned in tests/test_ops.py). Production batches are padded to
     power-of-two buckets so L always tiles; other lengths fall back.
     """
     n = flags.shape[0]
-    if n >= _PALLAS_SCAN_MIN and _use_pallas_scan():
+    if _use_pallas_scan(n):
         from evolu_tpu.ops.pallas_scan import segmented_max_scan_pallas
 
         return segmented_max_scan_pallas(flags, k1, k2, reverse=reverse)

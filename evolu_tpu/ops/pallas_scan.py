@@ -28,16 +28,8 @@ import functools
 
 import jax
 import jax.numpy as jnp
-
-from evolu_tpu.core.types import UnknownError
-
-try:  # pallas is part of jax, but guard exotic builds
-    from jax.experimental import pallas as pl
-    from jax.experimental.pallas import tpu as pltpu
-
-    PALLAS_AVAILABLE = True
-except Exception:  # pragma: no cover
-    PALLAS_AVAILABLE = False
+from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 _LANES = 128
 _BLOCK_ROWS = 256  # rows per grid step: 256*128 = 32768 elements
@@ -194,8 +186,6 @@ def segmented_xor_scan_pallas(flags, values_u32, interpret: bool = False):
     """(N,) bool flags (segment starts) + (N,) uint32 → inclusive
     segmented XOR scan. At each segment's last row the value is the
     segment's total XOR — the only positions the Merkle decode reads."""
-    if not PALLAS_AVAILABLE:
-        raise UnknownError("pallas is unavailable in this jax build")
     n = flags.shape[0]
     tile = _BLOCK_ROWS * _LANES
     padded = -(-max(n, 1) // tile) * tile
@@ -213,8 +203,6 @@ def segmented_sum_scan_pallas(flags, values_u64, interpret: bool = False):
     (segment starts) + uint64 values → inclusive segmented sum, one HBM
     pass. The u64⇄u32 limb split runs in XLA around the kernel; exact
     for the PN-counter fold's non-negative partial sums (< 2^55)."""
-    if not PALLAS_AVAILABLE:
-        raise UnknownError("pallas is unavailable in this jax build")
     n = flags.shape[0]
     tile = _BLOCK_ROWS * _LANES
     padded = -(-max(n, 1) // tile) * tile
@@ -236,8 +224,6 @@ def segmented_max_scan_pallas(flags, k1, k2, reverse: bool = False,
     """Drop-in for `merge._segmented_max_scan`: (N,) bool flags + uint64
     keys → inclusive segmented lex-max (m1, m2) uint64. Traceable; the
     u64⇄u32 limb split and padding run in XLA around the kernel."""
-    if not PALLAS_AVAILABLE:
-        raise UnknownError("pallas is unavailable in this jax build")
     if reverse:
         o1, o2 = segmented_max_scan_pallas(
             flags[::-1], k1[::-1], k2[::-1], interpret=interpret

@@ -1,7 +1,8 @@
 """Sort-free scatter-argmax LWW merge plan (ISSUE 4 tentpole).
 
-BENCH_r05's anatomy put 65% of the merge pipeline in one `lax.sort`
-(2.30 of 3.53 ms per 1M-message pass on v5e), yet LWW resolution needs
+The round-5 ablation put 65% of the merge pipeline in one `lax.sort`
+(measured before PR 1; not re-measured on the attached chip), yet LWW
+resolution needs
 a per-cell MAX, not a total order (reference applyMessages.ts:34-40) —
 the commutative per-key reduction Merkle-CRDTs exploit to make merge
 order-free (arxiv 2004.00107). This module is the dense formulation:
@@ -49,12 +50,12 @@ compiled kernels, bit-identical plans wherever both can run
 
 Cost model notes (why this is config-selectable, not the default):
 three scatters + three gathers against table rows vs ONE sort. The
-recorded v5e pricing (docs/BENCHMARKS.md r2: 1M-row u64 gathers ~4× a
-sort; XLA lowers scatters to serialized updates on TPU, ~100ms+/1M)
-predicts a heavy loss on TPU silicon; on the CPU backend (this
-environment's production default) the same formulation measures ~13×
-FASTER than the 1M single-device sort+scan plan. `merge_plan_path()`
-therefore routes "auto" by backend. Numbers: docs/BENCHMARKS.md r6.
+pre-PR-1 v5e pricing (1M-row u64 gathers ~4× a sort; XLA lowers
+scatters to serialized updates on TPU) predicts a heavy loss on TPU
+silicon — on the attached chip: not measured; on the CPU backend the
+same formulation measures ~13× FASTER than the 1M single-device
+sort+scan plan. `merge_plan_path()` therefore routes "auto" by
+backend.
 """
 
 from __future__ import annotations

@@ -21,7 +21,7 @@ from typing import Dict, Sequence, Tuple
 import jax
 import jax.numpy as jnp
 import numpy as np
-from evolu_tpu.ops import shard_map
+from jax import shard_map
 from jax.sharding import Mesh, PartitionSpec as P
 
 import functools

@@ -24,17 +24,13 @@ def create_mesh(n_devices: Optional[int] = None, devices=None) -> Mesh:
         devices = jax.devices()
     if n_devices is not None:
         devices = devices[:n_devices]
-    # Push the platform into the jax-free stage-anatomy plane (ISSUE
-    # 16): obs/anatomy.py prices roofline floors from COST_LAWS keyed
-    # by platform but must never import jax itself, so the one place
-    # that already holds a device tells it. Best-effort — an exotic
-    # device object without .platform must not break mesh creation.
-    try:
-        from evolu_tpu.obs import anatomy
+    # Push the device kind into the jax-free stage-anatomy plane
+    # (ISSUE 16): obs/anatomy.py prices floors from COST_LAWS keyed by
+    # `device_kind` but must never import jax itself, so the one place
+    # that already holds a device tells it.
+    from evolu_tpu.obs import anatomy
 
-        anatomy.set_platform(devices[0].platform)
-    except Exception:  # noqa: BLE001 - telemetry must never gate compute
-        pass
+    anatomy.set_device_kind(devices[0].device_kind)
     return Mesh(np.array(devices), (OWNERS_AXIS,))
 
 

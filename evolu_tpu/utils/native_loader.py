@@ -6,17 +6,24 @@ contract: build the specific make target on first use (g++ and the
 versioned system sonames are baked into the image), load via ctypes,
 run the module's `configure` (argtypes + optional runtime probe), and
 cache the result — including failure, so an unbuildable environment
-costs one attempt, not one per call. Failure always means "caller
-falls back to its pure-Python path", never an exception.
+costs one attempt, not one per call. Failure means "caller falls back
+to its pure-Python path" (the test oracle), never an exception — but
+never silently: every failed build, load or probe is logged and
+counted in `evolu_native_load_failures_total{lib, reason}`, and
+chip_smoke.py fails on any count.
 """
 
 from __future__ import annotations
 
 import ctypes
+import fcntl
 import os
 import subprocess
 import threading
 from typing import Callable, Dict, Optional
+
+from evolu_tpu.obs import metrics
+from evolu_tpu.utils.log import log
 
 NATIVE_DIR = os.path.join(
     os.path.dirname(os.path.dirname(os.path.dirname(os.path.abspath(__file__)))),
@@ -44,21 +51,41 @@ def load_native_library(
         # rebuild returns the already-loaded stale handle — so the
         # rebuild must happen BEFORE the first load.
         try:
-            subprocess.run(
-                ["make", "-s", so_name], cwd=NATIVE_DIR,
-                check=True, capture_output=True, timeout=120,
-            )
-        except Exception:
+            # One build at a time across PROCESSES (pytest-xdist
+            # workers, relay workers starting together): a concurrent
+            # make races the linker — a sibling finds the target "up to
+            # date" while ld is still writing it and dlopens a partial
+            # file, which used to read as "native unavailable" on
+            # whichever worker lost (9 silent skips under `-n 6`).
+            with open(os.path.join(NATIVE_DIR, "Makefile")) as build_lock:
+                fcntl.flock(build_lock, fcntl.LOCK_EX)
+                subprocess.run(
+                    ["make", "-s", so_name], cwd=NATIVE_DIR,
+                    check=True, capture_output=True, timeout=120,
+                )
+        except (OSError, subprocess.SubprocessError) as e:
+            _note_failure(so_name, "build", e)
             if not os.path.exists(path):
                 _cache[so_name] = None
                 return None
             # make unavailable but a binary exists: try it as-is.
         try:
             lib = configure(ctypes.CDLL(path))
-        except (OSError, AttributeError):
+            if lib is None:
+                _note_failure(so_name, "probe", "configure vetoed the library")
+        except (OSError, AttributeError) as e:
             # AttributeError = a symbol this build of the bindings
             # needs is missing (stale binary + no toolchain): fall
             # back to the pure-Python paths instead of crashing.
+            _note_failure(so_name, "load", e)
             lib = None
         _cache[so_name] = lib
         return lib
+
+
+def _note_failure(so_name: str, reason: str, error: object) -> None:
+    stderr = getattr(error, "stderr", None)
+    detail = stderr.decode("utf-8", "replace")[-400:] if stderr else repr(error)
+    metrics.inc("evolu_native_load_failures_total", lib=so_name, reason=reason)
+    log("dev", "native library unavailable: pure-Python fallback",
+        lib=so_name, reason=reason, error=detail)

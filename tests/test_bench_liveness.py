@@ -160,6 +160,11 @@ def test_ledger_does_not_touch_the_bench_graph(tiny_setup):
 
     mesh, args = tiny_setup
     loop = bench.make_loop(mesh, 1)
+    # The ledger is a process singleton: under `--dist loadfile` earlier
+    # files on this worker leave their (legitimately unbalanced,
+    # mid-stream) counts in it, and the audit below must see only this
+    # test's own.
+    ledger.reset()
     with jax.enable_x64(True):
         ledger.set_enabled(False)
         try:
@@ -230,7 +235,7 @@ def test_every_truncated_variant_output_is_live(tiny_setup, stage):
 
 def test_anatomy_does_not_touch_the_bench_graph(tiny_setup):
     """ISSUE 16's twin of the metrics/tracing/ledger fences: with the
-    stage-anatomy accountant HOT (platform set, stage records posting
+    stage-anatomy accountant HOT (device kind set, stage records posting
     around and between loop invocations — the engine seams call it per
     batch), the bench checksum must stay bit-identical and the jit
     cache-miss count flat. Stage accounting is host-side float/dict
@@ -239,14 +244,14 @@ def test_anatomy_does_not_touch_the_bench_graph(tiny_setup):
 
     mesh, args = tiny_setup
     loop = bench.make_loop(mesh, 1)
-    prev_platform = anatomy.get_platform()
+    prev_kind = anatomy.get_device_kind()
     with jax.enable_x64(True):
         metrics.set_enabled(False)
         try:
             base = int(loop(*args))
             cache_size = loop._cache_size()
             metrics.set_enabled(True)
-            anatomy.set_platform("tpu")
+            anatomy.set_device_kind(anatomy.V5E)
             anatomy.record_stage("device_dispatch", 0.105, rows=512)
             with_anatomy = int(loop(*args))
             anatomy.record_stage("host_apply", 0.002, rows=512)
@@ -254,7 +259,7 @@ def test_anatomy_does_not_touch_the_bench_graph(tiny_setup):
             cache_size_after = loop._cache_size()
         finally:
             metrics.set_enabled(True)
-            anatomy.set_platform(prev_platform)
+            anatomy.set_device_kind(prev_kind)
             anatomy.reset()
     assert with_anatomy == base, "stage accounting changed the bench checksum"
     assert cache_size_after == cache_size, (

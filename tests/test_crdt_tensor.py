@@ -341,10 +341,8 @@ def test_tensor_flat_layout_pallas_interpret_parity(n):
 
     from evolu_tpu.ops.crdt_merge import segmented_sum_scan
     from evolu_tpu.ops.pallas_scan import (
-        PALLAS_AVAILABLE, segmented_max_scan_pallas, segmented_sum_scan_pallas)
+        segmented_max_scan_pallas, segmented_sum_scan_pallas)
 
-    if not PALLAS_AVAILABLE:
-        pytest.skip("pallas unavailable")
     width = 3
     rng = np.random.default_rng(n)
     c_s = np.sort(rng.integers(0, 37, n)).astype(np.int32)

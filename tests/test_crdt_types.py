@@ -254,9 +254,9 @@ def test_segmented_sum_scan_formulations_agree(n):
             np.asarray(flags), np.asarray(vals)))
         blocked = np.asarray(cm.segmented_sum_scan(np.asarray(flags), np.asarray(vals)))
     assert np.array_equal(ref, blocked)
-    from evolu_tpu.ops.pallas_scan import PALLAS_AVAILABLE, segmented_sum_scan_pallas
+    from evolu_tpu.ops.pallas_scan import segmented_sum_scan_pallas
 
-    if PALLAS_AVAILABLE and n <= 8192:  # interpret mode is slow; bound it
+    if n <= 8192:  # interpret mode is slow; bound it
         with jax.enable_x64(True):
             pal = np.asarray(segmented_sum_scan_pallas(
                 np.asarray(flags), np.asarray(vals), interpret=True))

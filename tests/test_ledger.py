@@ -518,7 +518,7 @@ def test_recompile_sentinel_counts_growth_and_flight_event():
     eng_mod._JIT_SENTINEL_SIZES.clear()
 
 
-# --- tunnel-bandwidth plane (satellite) ---
+# --- pull-bandwidth plane (satellite) ---
 
 
 def test_pull_instrumentation_counts_waves():

@@ -1366,8 +1366,7 @@ def test_mesh_sharded_multi_relay_scheduler_episode(seed=90210):
         b = create_evolu(SCHEMA, config=cfg(r2.url), mnemonic=a.owner.mnemonic)
         replicas = [a, b]
         try:
-            for r in replicas:
-                connect(r)
+            transports = [connect(r) for r in replicas]
             assert type(a.worker._planner.cache) is MeshShardedWinnerCache
             row_ids: list = []
             for step in range(24):
@@ -1434,6 +1433,18 @@ def test_mesh_sharded_multi_relay_scheduler_episode(seed=90210):
             except urllib.error.HTTPError as e:
                 assert e.code == 500
             _converge(replicas)
+            # Quiesce the clients BEFORE the quarantined oracle replay:
+            # `ledger.quarantine()` switches the process-global ledger
+            # off, and `_converge` returns on equal logs with
+            # anti-entropy rounds possibly still in flight — a round
+            # counted at ingress before the quarantine whose terminal
+            # fell inside it read as a lost delivery (the flake under
+            # the driver's six loaded workers). `stop()` joins each
+            # transport's in-flight round; the workers stay up for the
+            # winner-cache audit below.
+            for t, r in zip(transports, replicas):
+                t.stop()
+                r.worker.flush()
             # Write-behind drain barrier, then the authoritative dump
             # (ONE shared parity-dump helper — tests/conftest.py).
             wb.flush()

@@ -1,17 +1,15 @@
 """Pallas timestamp-hash kernel: bit-exact vs oracle and XLA path.
 
-Runs the kernel in interpreter mode (CPU test env); the driver's TPU
-bench exercises the compiled path.
+Runs the kernel in interpreter mode (CPU test env); the compiled form
+is compiled for the chip in tests/test_tpu_compile.py and run on it by
+chip_smoke.py.
 """
 
 import numpy as np
-import pytest
 
 from evolu_tpu.core.timestamp import Timestamp, timestamp_to_hash
 from evolu_tpu.ops.encode import timestamp_hashes
-from evolu_tpu.ops.pallas_hash import PALLAS_AVAILABLE, timestamp_hashes_pallas
-
-pytestmark = pytest.mark.skipif(not PALLAS_AVAILABLE, reason="pallas unavailable")
+from evolu_tpu.ops.pallas_hash import timestamp_hashes_pallas
 
 
 def _batch(n=300, seed=3):

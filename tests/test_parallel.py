@@ -618,7 +618,7 @@ def test_packed_owner_kernel_matches_wide_kernel():
         shard_kernel_for,
     )
 
-    from evolu_tpu.ops import shard_map
+    from jax import shard_map
     from jax.sharding import PartitionSpec as P
 
     rng = np.random.default_rng(23)

@@ -1,7 +1,8 @@
 """Stage-anatomy plane (evolu_tpu/obs/anatomy.py + the ablation
 harness benchmarks/stage_anatomy.py) — registry shape and digest
-stability, roofline floor pricing against the recorded v5e laws,
-unknown-platform unpriced behavior, the evolu_stage_* metrics family
+stability, floor pricing against the recorded v5e laws, unmeasured laws
+reading "not measured", an unknown device asked for by name raising,
+the evolu_stage_* metrics family
 (histograms/counters/gauges, over-floor flagging past warmup, the
 decayed slope/fixed fit recovering a synthetic cost law, runtime share
 gauges), kernel-span folding through utils.log.span, the /stats
@@ -24,9 +25,9 @@ sys.path.insert(0, os.path.join(os.path.dirname(os.path.dirname(
 @pytest.fixture(autouse=True)
 def _clean_slate():
     logger.clear()  # resets metrics registry + anatomy accumulators
-    prev = anatomy.get_platform()
+    prev = anatomy.get_device_kind()
     yield
-    anatomy.set_platform(prev)
+    anatomy.set_device_kind(prev)
     logger.configure(False)
     logger.clear()
 
@@ -48,69 +49,103 @@ def test_registry_shape():
     for s in device:
         assert set(s.inputs) <= produced, (s.name, s.inputs)
         produced |= set(s.outputs)
-    # Every price term names a real law key in EVERY platform's laws
-    # (cpu and tpu rows must stay key-compatible).
-    for s in anatomy.STAGES:
-        for law_key, unit in s.price:
-            if unit == "device_pipeline":
-                continue
-            for plat, laws in anatomy.COST_LAWS.items():
-                assert law_key in laws, (s.name, law_key, plat)
+    # Every price term names a law key some device has measured (a
+    # typo would silently unprice the stage everywhere), and every law
+    # a device records prices some stage.
+    price_keys = {
+        law_key for s in anatomy.STAGES for law_key, unit in s.price
+        if unit != "device_pipeline"
+    }
+    measured = set().union(*anatomy.COST_LAWS.values())
+    assert price_keys == measured
 
 
 def test_registry_digest_is_stable_and_law_sensitive():
     d1 = anatomy.registry_digest()
     assert d1 == anatomy.registry_digest()
     assert len(d1) == 8 and int(d1, 16) >= 0
-    old = anatomy.COST_LAWS["tpu"]["sort_key_ms_per_1m"]
+    old = anatomy.COST_LAWS[anatomy.V5E]["sort_key_ms_per_1m"]
     try:
-        anatomy.COST_LAWS["tpu"]["sort_key_ms_per_1m"] = old * 2
+        anatomy.COST_LAWS[anatomy.V5E]["sort_key_ms_per_1m"] = old * 2
         assert anatomy.registry_digest() != d1  # re-pricing moves the gate
     finally:
-        anatomy.COST_LAWS["tpu"]["sort_key_ms_per_1m"] = old
+        anatomy.COST_LAWS[anatomy.V5E]["sort_key_ms_per_1m"] = old
 
 
 # --- floor pricing ---
 
 
 def test_floor_prices_v5e_laws_exactly():
+    v5e = anatomy.V5E
     # key_sort at 1M rows = 1.5 (key) + 2 × 0.75 (payloads) = 3.0 ms.
     assert anatomy.floor_ms("key_sort", rows=1_000_000,
-                            platform="tpu") == pytest.approx(3.0)
-    # pull_wave is bandwidth-priced: 17 MB at 17 MB/s = 1000 ms.
-    assert anatomy.floor_ms("pull_wave", nbytes=17_000_000,
-                            platform="tpu") == pytest.approx(1000.0)
+                            device_kind=v5e) == pytest.approx(3.0)
     # host_apply is throughput-priced: 720k rows at 720k rows/s = 1 s.
     assert anatomy.floor_ms("host_apply", rows=720_000,
-                            platform="tpu") == pytest.approx(1000.0)
-    # device_dispatch = fixed RTT + the whole device pipeline at size.
-    dev_sum = sum(
-        anatomy.floor_ms(s.name, rows=1_000_000, platform="tpu")
-        for s in anatomy.STAGES if s.kind == "device"
-    )
-    assert anatomy.floor_ms("device_dispatch", rows=1_000_000,
-                            platform="tpu") == pytest.approx(101.0 + dev_sum)
+                            device_kind=v5e) == pytest.approx(1000.0)
     # Span targets price as the sum of their mapped stages.
     merkle = sum(
-        anatomy.floor_ms(s, rows=1_000_000, platform="tpu")
+        anatomy.floor_ms(s, rows=1_000_000, device_kind=v5e)
         for s in ("hash_render", "minute_fold", "delta_encode")
     )
     assert anatomy.floor_ms("kernel:merkle", rows=1_000_000,
-                            platform="tpu") == pytest.approx(merkle)
+                            device_kind=v5e) == pytest.approx(merkle)
 
 
-def test_unknown_platform_and_stage_are_unpriced():
-    assert anatomy.floor_ms("key_sort", rows=1 << 20, platform="riscv") == 0.0
-    assert anatomy.floor_ms("no_such_stage", rows=1 << 20, platform="tpu") == 0.0
-    anatomy.set_platform("riscv")
-    assert anatomy.floor_ms("key_sort", rows=1 << 20) == 0.0
+def test_floor_prices_bandwidth_and_fixed_terms_where_measured():
+    # pull_wave is bandwidth-priced: 7650 MB at 7650 MB/s = 1000 ms.
+    assert anatomy.floor_ms("pull_wave", nbytes=7_650_000_000,
+                            device_kind="cpu") == pytest.approx(1000.0)
+    # device_dispatch = fixed intercept + the whole device pipeline.
+    dev_sum = sum(
+        anatomy.floor_ms(s.name, rows=1_000_000, device_kind="cpu")
+        for s in anatomy.STAGES if s.kind == "device"
+    )
+    assert anatomy.floor_ms("device_dispatch", rows=1_000_000,
+                            device_kind="cpu") == pytest.approx(261.0 + dev_sum)
+
+
+def test_unmeasured_v5e_laws_read_not_measured():
+    """No dispatch intercept and no pull bandwidth has been measured on
+    the attached chip: the stages priced by them are unpriced there —
+    None, never a number carried over from another attachment — and so
+    is everything that sums them."""
+    laws = anatomy.COST_LAWS[anatomy.V5E]
+    assert "fixed_dispatch_ms" not in laws and "pull_mb_per_s" not in laws
+    assert anatomy.floor_ms("pull_wave", nbytes=1 << 20,
+                            device_kind=anatomy.V5E) is None
+    assert anatomy.floor_ms("device_dispatch", rows=1 << 20,
+                            device_kind=anatomy.V5E) is None
+    assert anatomy.floor_ms("kernel:reconcile", rows=1 << 20,
+                            device_kind=anatomy.V5E) is None
+    anatomy.set_device_kind(anatomy.V5E)
+    for _ in range(4):  # past warmup: unpriced is never flagged
+        anatomy.record_stage("pull_wave", 10.0, nbytes=1)
+    assert metrics.get_counter("evolu_stage_over_floor_total",
+                               stage="pull_wave") == 0
+    assert metrics.registry.get_gauge("evolu_stage_floor_ms",
+                                      stage="pull_wave") is None
+    assert anatomy.stages_payload()["stages"]["pull_wave"]["floor_ms"] is None
+
+
+def test_unknown_device_by_name_raises_and_unknown_stage_is_unpriced():
+    with pytest.raises(KeyError):
+        anatomy.floor_ms("key_sort", rows=1 << 20, device_kind="riscv")
+    assert anatomy.floor_ms("no_such_stage", rows=1 << 20,
+                            device_kind=anatomy.V5E) is None
+    # The runtime accountant names no device: whatever the process came
+    # up on is recorded unpriced, and compute is never gated.
+    anatomy.set_device_kind("riscv")
+    assert anatomy.floor_ms("key_sort", rows=1 << 20) is None
+    anatomy.record_stage("key_sort", 0.5, rows=1 << 20)
+    assert anatomy.stages_payload()["stages"]["key_sort"]["floor_ms"] is None
 
 
 # --- the evolu_stage_* family ---
 
 
 def test_record_stage_emits_family():
-    anatomy.set_platform("tpu")
+    anatomy.set_device_kind(anatomy.V5E)
     anatomy.record_stage("host_apply", 0.010, rows=7200)  # floor = 10 ms
     assert metrics.get_counter("evolu_stage_seconds_total",
                                stage="host_apply") == pytest.approx(0.010)
@@ -127,7 +162,7 @@ def test_record_stage_emits_family():
 
 
 def test_over_floor_flags_only_past_warmup():
-    anatomy.set_platform("tpu")
+    anatomy.set_device_kind(anatomy.V5E)
     # floor = 10 ms; 100 ms is 10× over FLOOR_FACTOR=4.
     for _ in range(2):  # warmup records never flag (compile time)
         anatomy.record_stage("host_apply", 0.100, rows=7200)
@@ -144,7 +179,7 @@ def test_over_floor_flags_only_past_warmup():
 def test_slope_fit_recovers_synthetic_law():
     # Synthetic stage law: 5 ms fixed + 2 µs/row. The decayed online
     # fit must separate intercept from slope (the wall/count trap).
-    anatomy.set_platform("unknown-bench")
+    anatomy.set_device_kind("unknown-bench")
     for rows in (1000, 4000, 16000, 2000, 8000, 32000):
         anatomy.record_stage("device_dispatch", (5.0 + 0.002 * rows) / 1e3,
                              rows=rows)
@@ -157,7 +192,7 @@ def test_slope_fit_recovers_synthetic_law():
 
 
 def test_runtime_share_gauges():
-    anatomy.set_platform("unknown-bench")
+    anatomy.set_device_kind("unknown-bench")
     anatomy.record_stage("device_dispatch", 0.030, rows=100)
     anatomy.record_stage("pull_wave", 0.010, nbytes=1000)
     anatomy.record_stage("host_apply", 0.060, rows=100)
@@ -183,7 +218,7 @@ def test_disabled_registry_records_nothing():
 
 
 def test_kernel_span_folds_into_family():
-    anatomy.set_platform("tpu")
+    anatomy.set_device_kind(anatomy.V5E)
     with span("kernel:merkle", "t", n=1000):
         pass
     with span("host:apply", "t"):  # non-kernel spans stay out
@@ -195,14 +230,14 @@ def test_kernel_span_folds_into_family():
                                stage="kernel:merkle") == 1000
     # The span target priced via its mapped stages.
     assert payload["stages"]["kernel:merkle"]["floor_ms"] == pytest.approx(
-        anatomy.floor_ms("kernel:merkle", rows=1000, platform="tpu"))
+        anatomy.floor_ms("kernel:merkle", rows=1000, device_kind=anatomy.V5E))
 
 
 def test_stages_payload_shape_and_reset():
-    anatomy.set_platform("tpu")
+    anatomy.set_device_kind(anatomy.V5E)
     anatomy.record_stage("host_apply", 0.010, rows=7200)
     p = anatomy.stages_payload()
-    assert p["platform"] == "tpu"
+    assert p["device_kind"] == anatomy.V5E
     assert p["registry_digest"] == anatomy.registry_digest()
     assert p["floor_factor"] == anatomy.FLOOR_FACTOR
     st = p["stages"]["host_apply"]
@@ -211,7 +246,7 @@ def test_stages_payload_shape_and_reset():
     json.dumps(p)  # must be JSON-clean for GET /stats
     logger.clear()
     assert anatomy.stages_payload()["stages"] == {}
-    assert anatomy.get_platform() == "tpu"  # platform survives clear
+    assert anatomy.get_device_kind() == anatomy.V5E  # survives clear
 
 
 # --- registry ↔ ablation-harness agreement ---
