@@ -24,8 +24,12 @@ Here the model becomes data:
   measured", which is actionable, where "above DRAM bandwidth ideal"
   never is. A law a device has no measurement for is ABSENT, and
   every stage priced by it is unpriced ("not measured") there.
-- `record_stage` / `record_span` — the runtime accountant feeding the
-  `evolu_stage_*` metrics family: per-stage histograms + totals, an
+- `stage` / `record_stage` / `record_span` — the runtime accountant
+  feeding the `evolu_stage_*` metrics family (`stage` is the ONE
+  primitive the served path's seams use: it times an interval where
+  the work happens, records it here, and mirrors it into the
+  profiler's trace and the distributed trace): per-stage histograms +
+  totals, an
   online (decayed) least-squares fit per stage separating the fixed
   per-dispatch intercept from the per-row slope, per-batch
   device-dispatch / pull-wave / host-apply share gauges (EWMA over
@@ -45,11 +49,13 @@ from __future__ import annotations
 import json
 import os
 import threading
+import time
 import zlib
 from dataclasses import dataclass
 from typing import Dict, Optional, Tuple
 
-from evolu_tpu.obs import metrics
+from evolu_tpu.obs import metrics, trace
+from evolu_tpu.utils import log as _log  # jax-free at import (annotations load lazily)
 
 # --------------------------------------------------------------------
 # Cost laws, keyed by jax `device_kind`: ms per 1M rows (per_1m_rows),
@@ -458,10 +464,89 @@ def get_device_kind() -> str:
 
 def record_stage(stage: str, seconds: float, rows: int = 0,
                  nbytes: int = 0, shard: Optional[int] = None) -> None:
-    """Record one execution of a stage (runtime seams call this
-    directly: engine.start_batch/finish_batch, ops.to_host_many, the
-    write-behind drain workers with their shard index)."""
+    """Record one execution of a stage whose interval is only known
+    afterwards (the write-behind drain workers, with their shard
+    index); a seam that can bracket its work uses `stage`."""
     _acct.record(stage, seconds, rows=rows, nbytes=nbytes, shard=shard)
+
+
+class stage:
+    """The ONE stage primitive: time an interval on the thread that
+    does the work and record it where `record_stage` does.
+
+        with anatomy.stage("pull_wave") as wave:
+            ...
+            wave.nbytes = n          # known only inside the interval
+
+    `time.perf_counter` at both edges feeds `record_stage` (so the
+    `evolu_stage_*` family always sees it); with `utils.log`'s trace
+    annotations enabled the same interval is also a
+    `jax.profiler.TraceAnnotation` named ``evolu/<name>`` — in the
+    `.xplane.pb` on the profiler's own clock, beside the device's
+    `XLA Ops`, no calibration between clocks; and under an ambient
+    sampled `obs.trace` context (read at `start`) it lands in the
+    distributed trace too, as `log.span` does, so `GET /trace/<id>`
+    shows the same names. Annotations off: one `is None` test beyond
+    `record_stage`; registry disabled: `record_stage` is one attribute
+    read.
+
+    `then(name)` is a SEAM: one clock read closes the running stage and
+    opens the next under the new name, so consecutive stages tile their
+    parent with no gap and no overlap (the engine pass's `pass_*`
+    children). `start`/`stop` are the explicit edges for an interval
+    that opens in one function and closes in another on the SAME
+    thread (`pass_respond`: engine → scheduler); `stop` on a stage that
+    is not running is a no-op, so a `finally` may always call it.
+    Runtime seam names passed here are NOT `STAGES` entries (that is
+    the ablation registry the baseline digest pins); an unregistered
+    name is unpriced and never flagged."""
+
+    __slots__ = ("name", "rows", "nbytes", "seconds", "_t0",
+                 "_annotation", "_ctx")
+
+    def __init__(self, name: str, rows: int = 0, nbytes: int = 0):
+        self.name = name
+        self.rows = rows
+        self.nbytes = nbytes
+        self.seconds = 0.0  # of the last closed interval
+        self._t0: Optional[float] = None
+
+    def start(self) -> "stage":
+        self._annotation = _log.open_annotation(self.name, "evolu/")
+        self._ctx = trace.current()
+        self._t0 = time.perf_counter()
+        return self
+
+    def stop(self) -> None:
+        if self._t0 is not None:
+            self._close(time.perf_counter())
+            self._t0 = None
+
+    def then(self, name: str, rows: int = 0, nbytes: int = 0) -> None:
+        now = time.perf_counter()
+        if self._t0 is not None:
+            self._close(now)
+        self.name, self.rows, self.nbytes = name, rows, nbytes
+        self.start()
+        self._t0 = now  # the seam is ONE instant: no gap between tiles
+
+    def _close(self, now: float) -> None:
+        self.seconds = now - self._t0
+        _log.close_annotation(self._annotation)
+        self._record(self.seconds)
+
+    def _record(self, seconds: float) -> None:
+        """Where a closed interval goes. A subclass that fires per
+        request overrides this with a plain histogram observation."""
+        _acct.record(self.name, seconds, rows=self.rows, nbytes=self.nbytes)
+        if self._ctx is not None:
+            trace.record_span(self.name, self._ctx, time.time() - seconds,
+                              seconds * 1e3)
+
+    __enter__ = start
+
+    def __exit__(self, *_exc) -> None:
+        self.stop()
 
 
 def record_span(target: str, ms: float, rows: object = 0) -> None:

@@ -179,22 +179,41 @@ class MetricsRegistry:
             return
         key = _label_key(labels)
         with self._lock:
-            edges = self._buckets.get(name)
-            if edges is None:
-                edges = self._buckets[name] = tuple(
-                    buckets if buckets is not None else LATENCY_MS_BUCKETS
-                )
-            fam = self._hists.setdefault(name, {})
-            key = self._admit(fam, name, key)
-            h = fam.get(key)
-            if h is None:
-                h = fam[key] = _Hist(len(edges))
-            i = _bisect(edges, value)
-            h.counts[i] += 1
-            h.sum += value
-            h.count += 1
-            if exemplar is not None:
-                h.exemplar = (exemplar, float(value), time.time())
+            self._observe_locked(name, value, buckets, exemplar, key)
+
+    def observe_many(self, items) -> None:
+        """`observe(name, value, **labels)` for each (name, value,
+        labels-dict) of `items`, under ONE acquisition of the registry
+        lock. For a hot path that closes several intervals at once (a
+        handler thread at the end of a round, the dispatcher at a batch
+        close): with the interpreter lock contended, a thread that is
+        switched out while it holds this lock stalls every other
+        thread's next metric, so the number of acquisitions on such a
+        path costs more than their microseconds."""
+        if not self.enabled:
+            return
+        keyed = [(name, value, _label_key(labels)) for name, value, labels in items]
+        with self._lock:
+            for name, value, key in keyed:
+                self._observe_locked(name, value, None, None, key)
+
+    def _observe_locked(self, name, value, buckets, exemplar, key) -> None:
+        edges = self._buckets.get(name)
+        if edges is None:
+            edges = self._buckets[name] = tuple(
+                buckets if buckets is not None else LATENCY_MS_BUCKETS
+            )
+        fam = self._hists.setdefault(name, {})
+        key = self._admit(fam, name, key)
+        h = fam.get(key)
+        if h is None:
+            h = fam[key] = _Hist(len(edges))
+        i = _bisect(edges, value)
+        h.counts[i] += 1
+        h.sum += value
+        h.count += 1
+        if exemplar is not None:
+            h.exemplar = (exemplar, float(value), time.time())
 
     def describe(self, name: str, help_: str) -> None:
         with self._lock:
@@ -434,6 +453,7 @@ registry = MetricsRegistry()
 
 inc = registry.inc
 observe = registry.observe
+observe_many = registry.observe_many
 set_gauge = registry.set_gauge
 get_counter = registry.get_counter
 get_gauge = registry.get_gauge

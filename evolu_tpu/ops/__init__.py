@@ -93,25 +93,22 @@ def to_host_many(*xs):
     arrays, never touches the traced graph, and costs a few dict ops
     per WAVE (checksum + jit-cache invariance pinned by
     tests/test_bench_liveness.py)."""
-    import time as _time
-
     from evolu_tpu.obs import anatomy as _anatomy
     from evolu_tpu.obs import metrics as _metrics
 
-    t0 = _time.perf_counter()
-    out = tuple(to_host(x) for x in start_host_transfer(*xs))
+    # Stage-anatomy fold (ISSUE 16): every wave is one pull_wave stage
+    # record (and, with annotations on, one `evolu/pull_wave` event on
+    # the pulling thread's line of the profiler trace); where the
+    # device has a recorded pull bandwidth law, the over-floor flag
+    # fires when a wave runs slower than FLOOR_FACTOR× it.
+    with _anatomy.stage("pull_wave") as wave:
+        out = tuple(to_host(x) for x in start_host_transfer(*xs))
+        wave.nbytes = sum(int(getattr(a, "nbytes", 0)) for a in out)
     if _metrics.registry.enabled:
-        dt = _time.perf_counter() - t0
-        wave_bytes = sum(int(getattr(a, "nbytes", 0)) for a in out)
-        _metrics.inc("evolu_pull_bytes_total", wave_bytes)
-        _metrics.inc("evolu_pull_seconds_total", dt)
-        _metrics.observe("evolu_pull_wave_bytes", wave_bytes,
+        _metrics.inc("evolu_pull_bytes_total", wave.nbytes)
+        _metrics.inc("evolu_pull_seconds_total", wave.seconds)
+        _metrics.observe("evolu_pull_wave_bytes", wave.nbytes,
                          buckets=_metrics.SIZE_BUCKETS)
-        # Stage-anatomy fold (ISSUE 16): every wave is one pull_wave
-        # stage record; where the device has a recorded pull
-        # bandwidth law, the over-floor flag fires when a wave runs
-        # slower than FLOOR_FACTOR× it.
-        _anatomy.record_stage("pull_wave", dt, nbytes=wave_bytes)
     return out
 
 

@@ -23,7 +23,6 @@ from __future__ import annotations
 
 import functools
 import os
-import time
 from contextlib import contextmanager
 from typing import Dict, List, Optional, Sequence, Tuple
 
@@ -296,12 +295,24 @@ def deltas_dispatch(
     case_ok: np.ndarray,
     ts_strings: Sequence[str],
     ctx=None,
+    pull_pool=None,
+    tile=None,
 ):
     """First half of `deltas_from_columns` — host packing, device
     dispatch, async transfer START. Returns an opaque state for
     `deltas_finish`. Between the two calls the device computes and the
     outputs copy back, so a pipelining caller can run batch k's SQLite
-    work while batch k+1 is in flight here.
+    work while batch k+1 is in flight here. With a `pull_pool` (a
+    one-thread executor) the blocking pull starts there NOW: its wait
+    releases the GIL, so the transfer overlaps the caller's host leg.
+
+    Two stage records tile the call (obs.anatomy): `pass_layout`, the
+    host's numpy work up to the last array built, and
+    `pass_device_call`, what ONE device call costs the host on this
+    chip — uploads, the jit call, the async transfer start and the
+    hand-off to the pull thread. A caller that tiles a parent stage
+    passes its running `tile`: the two continue it seam to seam and it
+    comes back running as `pass_device_call`, the caller's to stop.
 
     With a `ctx` (parallel.mesh.MeshContext — the PR-12 sharded-engine
     path), the layout uses STABLE owner→device placement
@@ -312,6 +323,53 @@ def deltas_dispatch(
     the sharded path is byte-identical by construction (parity-pinned
     in tests/test_mesh_engine.py anyway)."""
     require_single_process("engine.deltas_from_columns")
+    if tile is None:
+        tile = anatomy.stage("pass_layout")
+        try:
+            return deltas_dispatch(mesh, owner_index, all_m, all_c, all_n,
+                                   case_ok, ts_strings, ctx, pull_pool, tile)
+        finally:
+            tile.stop()
+    tile.then("pass_layout")
+    deltas, digest, good, layout = _deltas_layout(
+        mesh, owner_index, all_m, all_c, all_n, case_ok, ts_strings, ctx
+    )
+    if layout is None:
+        return (deltas, digest, good, None, None)
+    k1, node, oix, cap, upload, tile.rows = layout
+    tile.then("pass_device_call", rows=tile.rows)
+    shd = sharding(mesh)
+    if upload is not None:
+        dmillis, ownctr, base = upload
+        metrics.inc("evolu_engine_compact_upload_bytes_total",
+                    16 * len(oix), variant="delta")
+        args = [put_sharded(a, shd) for a in (dmillis, ownctr, node)]
+        base_arr = jax.device_put(
+            np.array([base], np.int64),
+            jax.sharding.NamedSharding(mesh, P()),
+        )
+        outs = start_host_transfer(
+            *_compiled_merkle_kernel_compact_delta(mesh, cap)(*args, base_arr)
+        )
+    else:
+        metrics.inc("evolu_engine_compact_upload_bytes_total",
+                    20 * len(oix), variant="full")
+        args = [put_sharded(a, shd) for a in (k1, node, oix)]
+        outs = start_host_transfer(*_compiled_merkle_kernel_compact(mesh, cap)(*args))
+    if pull_pool is not None:
+        outs = pull_pool.submit(to_host_many, *outs)
+    return (deltas, digest, good, outs, (k1, node, oix, mesh, cap))
+
+
+def _deltas_layout(mesh, owner_index, all_m, all_c, all_n, case_ok,
+                   ts_strings, ctx):
+    """The host half of `deltas_dispatch`: case check (non-canonical
+    owners fold on the host here), owner units, shard assignment,
+    bucket padding, key packing. → (deltas, digest, good, layout);
+    `layout` is None where no owner is left for the device, else
+    (k1, node, oix, cap, upload, rows) with `upload` = (dmillis,
+    ownctr, base) for the 16 B/row delta kernel or None for the
+    full-key one, and `rows` the real (unpadded) row count."""
     owners = list(owner_index)
     deltas: Dict[str, Dict[str, int]] = {o: {} for o in owners}
     digest = 0
@@ -331,7 +389,7 @@ def deltas_dispatch(
     sizes = {o: len(owner_index[o]) for o in owners}
     good = [o for o in owners if o not in quarantined and sizes[o]]
     if not good:
-        return (deltas, digest, good, None, None)
+        return deltas, digest, good, None
 
     owner_ix = {o: i for i, o in enumerate(good)}
     # Hot-owner split: hashing needs no cell locality, and the decoder
@@ -392,7 +450,6 @@ def deltas_dispatch(
         pos_by_shard[si] = pos + n
 
     cap = bucket_size(max(shard_size // 8, 64))
-    shd = sharding(mesh)
     real = oix >= 0
     millis = (k1 >> np.uint64(16)).astype(np.int64)
     real_millis = millis[real]
@@ -416,6 +473,7 @@ def deltas_dispatch(
         and max_real < (1 << 47)  # wrapped pre-1970 lands near 2^48
         and len(good) < _DELTA_PAD_OWNER
     )
+    upload = None
     if use_delta:
         dmillis = np.where(real, millis - base, 0).astype(np.uint32)
         ownctr = np.where(
@@ -424,41 +482,45 @@ def deltas_dispatch(
             | (k1 & np.uint64(0xFFFF)).astype(np.uint32),
             np.uint32(_DELTA_PAD_OWNER << 16),
         )
-        metrics.inc("evolu_engine_compact_upload_bytes_total",
-                    16 * total, variant="delta")
-        args = [put_sharded(a, shd) for a in (dmillis, ownctr, node)]
-        base_arr = jax.device_put(
-            np.array([base], np.int64),
-            jax.sharding.NamedSharding(mesh, P()),
-        )
-        outs = start_host_transfer(
-            *_compiled_merkle_kernel_compact_delta(mesh, cap)(*args, base_arr)
-        )
-    else:
-        metrics.inc("evolu_engine_compact_upload_bytes_total",
-                    20 * total, variant="full")
-        args = [put_sharded(a, shd) for a in (k1, node, oix)]
-        outs = start_host_transfer(*_compiled_merkle_kernel_compact(mesh, cap)(*args))
-    return (deltas, digest, good, outs, (k1, node, oix, mesh, cap))
+        upload = (dmillis, ownctr, base)
+    return deltas, digest, good, (k1, node, oix, cap, upload, n_good_rows)
+
+
+def deltas_finish(state) -> Tuple[Dict[str, Dict[str, int]], int]:
+    """Second half: wait for the (mostly arrived) compact outputs, then
+    decode them. The wait alone is the `pass_pull_wait` stage: the
+    device and transfer time the caller's host work did not hide, the
+    host-clock reading of "the chip made the host wait". A caller that
+    tiles its own stages (`finish_batch`) calls the two halves itself."""
+    with anatomy.stage("pass_pull_wait"):
+        pulled = deltas_pull(state)
+    return deltas_decode(state, pulled)
+
+
+def deltas_pull(state):
+    """Block until the dispatch's outputs are host arrays (None where
+    nothing went to the device)."""
+    outs = state[3]
+    if outs is None:
+        return None
+    if hasattr(outs, "result"):
+        # A background-thread pull started at dispatch time
+        # (deltas_dispatch parks the blocking pull on the pull thread
+        # so the transfer overlaps the caller's host work).
+        return outs.result()
+    return to_host_many(*outs)
 
 
 @with_x64
-def deltas_finish(state) -> Tuple[Dict[str, Dict[str, int]], int]:
-    """Second half: materialize the (mostly arrived) compact outputs
-    and decode the per-(owner, minute) deltas. If any shard produced
-    more segments than the compaction cap, re-run the full-width
-    kernel and decode every row (rare: means distinct (owner, minute)
-    pairs exceed an eighth of the shard's rows)."""
-    deltas, digest, good, outs, extra = state
-    if outs is None:
+def deltas_decode(state, pulled) -> Tuple[Dict[str, Dict[str, int]], int]:
+    """Decode the pulled per-(owner, minute) deltas. If any shard
+    produced more segments than the compaction cap, re-run the
+    full-width kernel and decode every row (rare: means distinct
+    (owner, minute) pairs exceed an eighth of the shard's rows)."""
+    deltas, digest, good, _outs, extra = state
+    if pulled is None:
         return deltas, digest
-    if hasattr(outs, "result"):
-        # A background-thread pull started at dispatch time
-        # (start_batch parks the blocking pull on the pull thread so
-        # the transfer overlaps the caller's host work).
-        packed, xors, counts, dev_digest = outs.result()
-    else:
-        packed, xors, counts, dev_digest = to_host_many(*outs)
+    packed, xors, counts, dev_digest = pulled
     k1, node, oix, mesh, cap = extra
     counts = np.asarray(counts)
     if (counts > cap).any():
@@ -859,8 +921,46 @@ class BatchReconciler:
         per-dispatch cost separates from the per-row slope in the stage
         fit, and where the device has a priced pipeline floor a
         dispatch above FLOOR_FACTOR× it flags
-        evolu_stage_over_floor_total."""
-        t0_dispatch = time.perf_counter()
+        evolu_stage_over_floor_total. Four children tile it seam to
+        seam, each recorded once per pass: `pass_pack` (`_pack_batch`),
+        `pass_parse` (`parse_packed_timestamps` of every live shard),
+        and `deltas_dispatch`'s `pass_layout` + `pass_device_call`."""
+        with anatomy.stage("device_dispatch") as whole, \
+                anatomy.stage("pass_pack") as tile:
+            live, shard_data, packed, shard_offsets, merged, off = \
+                self._pack_batch(requests)
+            whole.rows = tile.rows = off
+            # Parse AFTER every shard packed (it needs only the packed
+            # buffer), so each stage is one interval a pass.
+            tile.then("pass_parse", rows=off)
+            col_parts = ([], [], [], [])
+            for si in live:
+                _gu, gc, ts_packed, _cp, _lens = shard_data[si]
+                cols = parse_packed_timestamps(ts_packed, sum(gc), with_case=True)
+                for part, c in zip(col_parts, cols):
+                    part.append(c)
+            dev_state = None
+            if merged:
+                all_m, all_c, all_n, case_ok = (
+                    (p[0] if len(p) == 1 else np.concatenate(p)) for p in col_parts
+                )
+                # The blocking pull starts on the pull thread inside the
+                # dispatch: the device/host overlap of the pipelined path.
+                dev_state = deltas_dispatch(
+                    self.mesh, merged, all_m, all_c, all_n, case_ok, packed,
+                    ctx=self.mesh_ctx, pull_pool=self._pull_executor(), tile=tile,
+                )
+        return {
+            "requests": requests, "live": live, "shard_data": shard_data,
+            "dev": dev_state, "packed": packed, "n_total": off,
+            "shard_offsets": shard_offsets,
+        }
+
+    def _pack_batch(self, requests):
+        """The `pass_pack` leg of `start_batch`: shard grouping, in-batch
+        dedup, list building, `_pack_rows`, owner index arrays. →
+        (live shard ids, per-shard packed data, `_PackedRows`, shard row
+        offsets, owner → row indices, row count)."""
         stores, shard_index = self._shards()
         per_shard: List[List[protocol.SyncRequest]] = [[] for _ in stores]
         for r in requests:
@@ -870,7 +970,6 @@ class BatchReconciler:
         shard_data: Dict[int, tuple] = {}
         buffers: List[bytes] = []
         offsets: List[int] = []
-        col_parts = ([], [], [], [])
         owner_rows: Dict[str, List[np.ndarray]] = {}
         live: List[int] = []
         off = 0
@@ -900,7 +999,6 @@ class BatchReconciler:
                 continue
             live.append(si)
             ts_packed, content_packed, lens = _pack_rows(ts_list, contents)
-            cols = parse_packed_timestamps(ts_packed, n, with_case=True)
             pos = 0
             for u, k in zip(gu, gc):
                 if k:
@@ -908,49 +1006,26 @@ class BatchReconciler:
                 pos += k
             buffers.append(ts_packed)
             offsets.append(off)
-            for part, c in zip(col_parts, cols):
-                part.append(c)
             shard_data[si] = (gu, gc, ts_packed, content_packed, lens)
             off += n
-
-        packed = _PackedRows(buffers, offsets)
-        shard_offsets = dict(zip(live, offsets))
-        dev_state = None
-        if owner_rows:
-            merged = {
-                u: (v[0] if len(v) == 1 else np.concatenate(v))
-                for u, v in owner_rows.items()
-            }
-            all_m, all_c, all_n, case_ok = (
-                (p[0] if len(p) == 1 else np.concatenate(p)) for p in col_parts
-            )
-            dev_state = deltas_dispatch(
-                self.mesh, merged, all_m, all_c, all_n, case_ok, packed,
-                ctx=self.mesh_ctx,
-            )
-            if dev_state[3] is not None:
-                # Start the blocking pull NOW on the pull thread: its
-                # wait releases the GIL, so the transfer overlaps the
-                # host leg of the previous batch — the device/host
-                # overlap of the pipelined path.
-                fut = self._pull_executor().submit(to_host_many, *dev_state[3])
-                dev_state = (*dev_state[:3], fut, dev_state[4])
-        anatomy.record_stage("device_dispatch",
-                             time.perf_counter() - t0_dispatch, rows=off)
-        return {
-            "requests": requests, "live": live, "shard_data": shard_data,
-            "dev": dev_state, "packed": packed, "n_total": off,
-            "shard_offsets": shard_offsets,
+        merged = {
+            u: (v[0] if len(v) == 1 else np.concatenate(v))
+            for u, v in owner_rows.items()
         }
+        return (live, shard_data, _PackedRows(buffers, offsets),
+                dict(zip(live, offsets)), merged, off)
 
-    def finish_batch(self, st, wire: bool = False) -> List:
+    def finish_batch(self, st, wire: bool = False, respond_stage=None) -> List:
         """Land batch k: per-shard C inserts (parallel, GIL-free),
         duplicate-owner delta recompute, tree updates, one atomic
         commit per shard — while batch k+1 flies on the device.
         `wire=True` answers in BYTES mode (`_respond_wire`) for
         consumers that only forward protobuf — the live scheduler path,
         byte-identical to encoding the object responses (test-pinned
-        via `_respond_wire`'s own fence)."""
+        via `_respond_wire`'s own fence). A `respond_stage` (an
+        unstarted `anatomy.stage`, the scheduler's `pass_respond`) is
+        STARTED where the apply leg ends; the caller, on this thread,
+        stops it when its own respond work is done."""
         stores, shard_index = self._shards()
         metrics.inc("evolu_engine_store_passes_total", path="stream")
         respond = self._respond_wire if wire else self._respond
@@ -958,6 +1033,8 @@ class BatchReconciler:
         trees: Dict[str, dict] = {}
         strings: Dict[str, str] = {}
         if not live:
+            if respond_stage is not None:
+                respond_stage.start()
             return respond(st["requests"], trees, strings)
 
         def ingest_shard(si: int):
@@ -966,20 +1043,31 @@ class BatchReconciler:
                 gu, gc, ts_packed, content_packed, lens
             )
 
-        # host_apply stage record (obs.anatomy): the C inserts + delta
-        # decode + tree folds + commit block. The pull itself records
-        # under pull_wave from to_host_many (possibly on the pull
-        # thread) — shares are over summed stage walls, and the two
-        # legs can overlap (documented in docs/OBSERVABILITY.md).
-        t0_apply = time.perf_counter()
-        with span("kernel:merkle", "reconcile_stream_finish",
-                  owners=len({r.user_id for r in st["requests"]}),
-                  n=st["n_total"], shards=len(live)):
+        # host_apply stage record (obs.anatomy): the WHOLE finish leg up
+        # to the commit — C inserts, the blocked wait for the pull,
+        # delta decode, tree folds, commit. The `kernel:merkle` span
+        # below has the same extent under its historical name: it times
+        # this host leg, not a kernel. Three children tile it:
+        # pass_insert (BEGIN + the C inserts), pass_pull_wait (only the
+        # blocking wait for the device's outputs), pass_tree (decode,
+        # duplicate recompute, tree folds, merkleTree upsert, COMMIT).
+        # The pull itself records under pull_wave from to_host_many on
+        # the pull thread — shares are over summed stage walls, and the
+        # two legs overlap (docs/OBSERVABILITY.md).
+        rows = st["n_total"]
+        with anatomy.stage("host_apply", rows=rows), \
+                span("kernel:merkle", "reconcile_stream_finish",
+                     owners=len({r.user_id for r in st["requests"]}),
+                     n=rows, shards=len(live)), \
+                anatomy.stage("pass_insert", rows=rows) as tile:
             with self._shard_transactions(stores, live):
                 was_new_by_shard = dict(
                     self._map_shards(ingest_shard, live, len(stores))
                 )
-                deltas_by_owner, _digest = deltas_finish(st["dev"])
+                tile.then("pass_pull_wait")
+                pulled = deltas_pull(st["dev"])
+                tile.then("pass_tree", rows=rows)
+                deltas_by_owner, _digest = deltas_decode(st["dev"], pulled)
                 self._recompute_duplicate_owners(
                     st, was_new_by_shard, deltas_by_owner
                 )
@@ -1001,8 +1089,8 @@ class BatchReconciler:
                             "VALUES (?, ?)",
                             tree_rows[si],
                         )
-        anatomy.record_stage("host_apply", time.perf_counter() - t0_apply,
-                             rows=st["n_total"])
+        if respond_stage is not None:
+            respond_stage.start()
         # Ledger terminals AFTER the per-shard commits: per-owner
         # was-new sums classify inserted; the per-owner request totals
         # in _ledger_count_pass fold the in-batch-deduped rows into
@@ -1192,7 +1280,7 @@ class BatchReconciler:
         return responses
 
     def reconcile_wire(
-        self, requests: Sequence[protocol.SyncRequest]
+        self, requests: Sequence[protocol.SyncRequest], respond_stage=None
     ) -> List[bytes]:
         """`reconcile` with BYTES-mode responses: each entry is the
         fully encoded SyncResponse, the messages stream emitted
@@ -1206,9 +1294,12 @@ class BatchReconciler:
         per-request fallback to the object path + encoder where the C
         entry is missing or a stored row is non-canonical."""
         trees, strings = self._ingest(requests)
+        if respond_stage is not None:
+            respond_stage.start()
         return self._respond_wire(requests, trees, strings)
 
-    def run_batch_wire(self, requests: Sequence[protocol.SyncRequest]) -> List[bytes]:
+    def run_batch_wire(self, requests: Sequence[protocol.SyncRequest],
+                       respond_stage=None) -> List[bytes]:
         """ONE engine/store pass for a live micro-batch → wire bytes per
         request (the scheduler's entry point). With a write-behind
         queue attached, the pass defers SQLite entirely
@@ -1221,21 +1312,25 @@ class BatchReconciler:
         anything else routes through `reconcile_wire`, whose `_ingest`
         picks the store-appropriate batched path. Either way a failure
         rolls every shard transaction back before raising — the
-        scheduler's singleton retry depends on that."""
+        scheduler's singleton retry depends on that. `respond_stage`:
+        see `finish_batch`; every route starts it where its respond
+        leg begins."""
         stores, _ = self._shards()
         if self.write_behind is not None and hasattr(
             self.store, "get_merkle_tree_string"
         ):
-            return self._finish_batch_deferred(self.start_batch(requests))
+            return self._finish_batch_deferred(
+                self.start_batch(requests), respond_stage)
         if all(
             hasattr(getattr(s, "db", None), "relay_insert_packed") for s in stores
         ):
-            return self.finish_batch(self.start_batch(requests), wire=True)
-        return self.reconcile_wire(requests)
+            return self.finish_batch(
+                self.start_batch(requests), wire=True, respond_stage=respond_stage)
+        return self.reconcile_wire(requests, respond_stage)
 
     # -- write-behind serving (PR-11: device state is the truth) --
 
-    def _finish_batch_deferred(self, st) -> List[bytes]:
+    def _finish_batch_deferred(self, st, respond_stage=None) -> List[bytes]:
         """Land batch k WITHOUT touching the btree: fold the device
         deltas onto the queue's authoritative per-owner trees
         (optimistically — every in-batch-deduped row XORs; rows that
@@ -1255,6 +1350,8 @@ class BatchReconciler:
         strings: Dict[str, str] = {}
         metrics.inc("evolu_engine_store_passes_total", path="write_behind")
         if not live:
+            if respond_stage is not None:
+                respond_stage.start()
             return self._respond_deferred(requests, trees, strings)
         with span("kernel:merkle", "reconcile_deferred",
                   owners=len({r.user_id for r in requests}),
@@ -1309,6 +1406,8 @@ class BatchReconciler:
             for o, total in totals.items():
                 ledger.count(ledger.STORE_DUPLICATE, total - kept.get(o, 0),
                              owner=o)
+        if respond_stage is not None:
+            respond_stage.start()
         return self._respond_deferred(requests, trees, strings)
 
     def _resolve_tree_deferred(self, user_id: str, trees, tree_strings):

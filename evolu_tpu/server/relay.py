@@ -630,6 +630,27 @@ def capture_live_profile(duration_ms: float) -> dict:
             "metadata": meta}
 
 
+class _round_leg(anatomy.stage):
+    """One handler-thread leg of the served round: the stage
+    primitive's clock and `evolu/<name>` profiler annotation, kept as a
+    plain `evolu_relay_stage_ms{stage=…}` observation in `closed` for
+    the handler to post in ONE `metrics.observe_many` at the end of the
+    round — these fire per request on 25 threads, so they skip the
+    stage accountant's fit and gauges (and the trace ring, which
+    already holds relay.sync/relay.respond) and take the registry lock
+    once a round."""
+
+    __slots__ = ("closed",)
+
+    def __init__(self, name: str):
+        super().__init__(name)
+        self.closed = []
+
+    def _record(self, seconds: float) -> None:
+        self.closed.append(
+            ("evolu_relay_stage_ms", seconds * 1e3, {"stage": self.name}))
+
+
 class _Handler(BaseHTTPRequestHandler):
     store: RelayStore  # injected by RelayServer
     scheduler = None  # SyncScheduler when continuous batching is on
@@ -1045,18 +1066,39 @@ class _Handler(BaseHTTPRequestHandler):
         if self.path.startswith("/fleet/"):
             self._do_fleet()
             return
+        # The round, tiled on this handler thread: `read_decode` from
+        # here to the call of _serve_request (body read, decode, ledger
+        # ingress, routing), then — inside the scheduler —
+        # evolu_sched_queue_wait_ms, the pass, evolu_sched_wake_ms, then
+        # `respond_write` from _serve_request returning to the socket
+        # write returning. evolu_relay_round_ms is the whole server
+        # side of a 200 round; the client's median minus it is what the
+        # client, the TCP connect and the accept queue cost.
         t0 = time.perf_counter()
+        leg = _round_leg("read_decode").start()
+        answered = False
+        try:
+            answered = self._sync_round(t0, leg)
+        finally:
+            leg.stop()
+            if answered:
+                leg.closed.append(("evolu_relay_round_ms",
+                                   (time.perf_counter() - t0) * 1e3, {}))
+            metrics.observe_many(leg.closed)
+
+    def _sync_round(self, t0: float, leg: _round_leg) -> bool:
+        """POST /: one sync round. → True once a 200 was written."""
         # Count the request BEFORE any reject so errors_total can never
         # exceed requests_total (error-rate = errors/requests must stay
         # a fraction).
         metrics.inc("evolu_relay_requests_total", endpoint="/")
         length = self._body_length()
         if length is None:
-            return
+            return False
         if length > MAX_BODY_BYTES:
             metrics.inc("evolu_relay_errors_total")
             self.send_error(413)
-            return
+            return False
         body = self.rfile.read(length)
         metrics.observe("evolu_relay_request_bytes", len(body),
                         buckets=metrics.SIZE_BUCKETS)
@@ -1084,16 +1126,18 @@ class _Handler(BaseHTTPRequestHandler):
             if self.fleet is not None:
                 if not self._route_fleet(request, body):
                     served = True  # egress/shed terminal counted there
-                    return  # answered: 307/forwarded/503-not-ready
+                    return False  # answered: 307/forwarded/503-not-ready
             shard = (
                 self.store.shard_index(request.user_id)
                 if hasattr(self.store, "shard_index") else 0
             )
             metrics.inc("evolu_relay_shard_requests_total", shard=str(shard))
+            leg.stop()
             out = self._serve_request(request)
+            leg.then("respond_write")
             served = True  # terminals counted (store path or 503 shed)
             if out is None:
-                return  # 503 backpressure already answered
+                return False  # 503 backpressure already answered
             # Ingest-mix counters AFTER routing AND a successful
             # serve: a 307'd/forwarded request never counts at a
             # relay whose store it skips, and a 503-shed or errored
@@ -1124,7 +1168,7 @@ class _Handler(BaseHTTPRequestHandler):
                              owner=request.user_id)
             log("dev", "relay sync request failed", error=repr(e))
             self.send_error(500, str(e))
-            return
+            return False
         finally:
             trace.deactivate(_tok)
             srv_span.end()
@@ -1154,6 +1198,7 @@ class _Handler(BaseHTTPRequestHandler):
         # kernel's, not ours to time).
         rspan.end()
         self._respond(200, out, "application/octet-stream")
+        return True
 
     def _do_replicate(self) -> None:
         """POST /replicate/{summary,pull,snapshot,snapshot/chunk} — the

@@ -60,7 +60,7 @@ import threading
 import time
 from typing import List, Optional
 
-from evolu_tpu.obs import ledger, metrics, trace
+from evolu_tpu.obs import anatomy, ledger, metrics, trace
 from evolu_tpu.sync import aead, protocol
 from evolu_tpu.utils.log import log
 
@@ -91,7 +91,7 @@ class _Pending:
     open; a handler-thread write acked mid-batch would be rolled back
     with a poisoned batch)."""
 
-    __slots__ = ("request", "single", "t_enqueue", "t_wall", "ctx",
+    __slots__ = ("request", "single", "t_enqueue", "t_wall", "t_done", "ctx",
                  "done", "response", "error")
 
     def __init__(self, request: protocol.SyncRequest, single: bool = False):
@@ -109,10 +109,12 @@ class _Pending:
 
     def resolve(self, response: bytes) -> None:
         self.response = response
+        self.t_done = time.monotonic()  # the wake's start (evolu_sched_wake_ms)
         self.done.set()
 
     def fail(self, error: BaseException) -> None:
         self.error = error
+        self.t_done = time.monotonic()
         self.done.set()
 
 
@@ -220,6 +222,10 @@ class SyncScheduler:
                 f"sync scheduler did not serve the request within "
                 f"{self.submit_timeout_s}s"
             )
+        # The hand-over of the interpreter from the dispatcher (which
+        # stamped t_done just before setting the event) to this handler.
+        metrics.observe("evolu_sched_wake_ms",
+                        (time.monotonic() - p.t_done) * 1e3)
         if p.error is not None:
             raise p.error
         return p.response  # type: ignore[return-value]
@@ -227,6 +233,15 @@ class SyncScheduler:
     # -- dispatch (one background thread) --
 
     def _dispatch_loop(self) -> None:
+        # evolu_sched_dispatcher_seconds_total{state}: `busy` is
+        # _close_batch + _run_batch, `cpu` this thread's own CPU seconds
+        # (time.thread_time) across the same extent, `idle` everything
+        # between two busy extents (the empty-queue wait and the
+        # max_wait_s coalescing wait), so idle + busy is the thread's
+        # wall time. busy/(busy+idle) near 1: the relay is one thread
+        # long; cpu/busy well under 1: the dispatcher waits — for the
+        # interpreter lock, SQLite's shard threads or the device.
+        t_idle = time.perf_counter()
         try:
             while True:
                 with self._cv:
@@ -247,6 +262,7 @@ class SyncScheduler:
                         if remaining <= 0:
                             break
                         self._cv.wait(remaining)
+                    t_busy, cpu_busy = time.perf_counter(), time.thread_time()
                     batch = self._close_batch()
                     metrics.set_gauge("evolu_sched_queue_depth", len(self._queue))
                 try:
@@ -256,6 +272,14 @@ class SyncScheduler:
                         if not p.done.is_set():
                             p.fail(RuntimeError("sync scheduler dispatcher exited"))
                     raise
+                now = time.perf_counter()
+                metrics.inc("evolu_sched_dispatcher_seconds_total",
+                            t_busy - t_idle, state="idle")
+                metrics.inc("evolu_sched_dispatcher_seconds_total",
+                            now - t_busy, state="busy")
+                metrics.inc("evolu_sched_dispatcher_seconds_total",
+                            time.thread_time() - cpu_busy, state="cpu")
+                t_idle = now
         finally:
             # If the loop died abnormally (BaseException out of
             # _run_batch — e.g. KeyboardInterrupt mid-pass), blocked
@@ -297,19 +321,19 @@ class SyncScheduler:
         self._queue = keep
         return batch
 
-    def _record_queue_waits(self, batch: List[_Pending]) -> float:
-        """Per-request queue-wait spans (enqueue → batch close), under
-        each request's own trace — one leg of the queue-wait /
-        engine-time / respond split the trace surfaces. Returns the
-        dispatch instant (monotonic) the waits were measured against."""
+    def _record_queue_waits(self, batch: List[_Pending]) -> None:
+        """Per-request queue wait (enqueue → the batch close that takes
+        it), measured against ONE dispatch instant: the
+        `evolu_sched_queue_wait_ms` histogram for every request, and a
+        `sched.queue` span under the request's own trace where it has
+        one — one leg of the queue-wait / engine-time / respond split."""
         t_dispatch = time.monotonic()
-        for p in batch:
+        waits = [(t_dispatch - p.t_enqueue) * 1e3 for p in batch]
+        metrics.observe_many(
+            [("evolu_sched_queue_wait_ms", w, {}) for w in waits])
+        for p, wait_ms in zip(batch, waits):
             if p.ctx is not None:
-                trace.record_span(
-                    "sched.queue", p.ctx, p.t_wall,
-                    (t_dispatch - p.t_enqueue) * 1e3,
-                )
-        return t_dispatch
+                trace.record_span("sched.queue", p.ctx, p.t_wall, wait_ms)
 
     def _run_batch(self, batch: List[_Pending]) -> None:
         if not batch:
@@ -354,10 +378,24 @@ class SyncScheduler:
                 "owners": len({p.request.user_id for p in batch}),
             },
         )
+        # pass_respond (obs.anatomy): the engine starts it where its
+        # apply leg ends, this thread stops it at the batch_ms
+        # observation — ledger terminals, the wire respond, resolving
+        # the futures, the recompile sentinel. With the engine's seven
+        # pass_* stages it tiles the pass.
+        respond = anatomy.stage("pass_respond")
+        try:
+            self._run_pass(batch, bspan, respond, t0)
+        finally:
+            respond.stop()  # a no-op unless something escaped _run_pass
+
+    def _run_pass(self, batch: List[_Pending], bspan, respond, t0: float) -> None:
+        """One engine pass and its answers: `_run_batch` from the batch
+        span's start to the batch_ms observation."""
         try:
             engine = self._ensure_engine()
             with trace.use(bspan.context):
-                outs = engine.run_batch_wire([p.request for p in batch])
+                outs = engine.run_batch_wire([p.request for p in batch], respond)
             bspan.end()
         except _write_behind_full_type() as e:
             # Write-behind admission backpressure: nothing was served
@@ -402,6 +440,7 @@ class SyncScheduler:
                     metrics.inc("evolu_sched_fallback_total", reason="poison_retry")
                     p.resolve(response)
             self._observe_jit_caches(batch)
+            respond.stop()
             metrics.observe("evolu_sched_batch_ms", (time.perf_counter() - t0) * 1e3,
                             exemplar=bspan.trace_id)
             return
@@ -417,6 +456,7 @@ class SyncScheduler:
         for p, out in zip(batch, outs):
             p.resolve(out)
         self._observe_jit_caches(batch)
+        respond.stop()
         metrics.observe("evolu_sched_batch_ms", (time.perf_counter() - t0) * 1e3,
                         exemplar=bspan.trace_id)
 

@@ -38,12 +38,14 @@ TARGETS = (
 
 # jax.profiler trace annotations keyed by the SAME span target names
 # (VERDICT #7): when enabled, every `span(target, message)` also opens
-# a `jax.profiler.TraceAnnotation("<target>|<message>")`, so a captured
-# trace (jax.profiler.trace / benchmarks/kernel_trace.py) shows the
-# host-side spans interleaved with the device timeline under the names
-# the log/metrics surfaces already use. OFF by default and lazily
-# imported — this module must never touch jax at import time (the obs
-# import-hygiene contract), and a disabled span stays allocation-free.
+# a `jax.profiler.TraceAnnotation("<target>|<message>")` and every
+# `obs.anatomy.stage(name)` one named "evolu/<name>", so a captured
+# trace (jax.profiler.trace / GET /profile / perf/run.py --trace 1)
+# shows the host-side spans and stages on the profiler's own clock,
+# beside the device timeline, under the names the log/metrics surfaces
+# already use. OFF by default and lazily imported — this module must
+# never touch jax at import time (the obs import-hygiene contract), and
+# a disabled span stays allocation-free.
 _trace_annotation_cls = None
 
 
@@ -61,6 +63,25 @@ def enable_trace_annotations(flag: bool = True) -> None:
 
 if os.environ.get("EVOLU_TRACE_ANNOTATIONS") == "1":
     enable_trace_annotations(True)
+
+
+def open_annotation(name: str, prefix: str = ""):
+    """→ an ENTERED profiler annotation named `prefix + name` on the
+    calling thread, or None with annotations off (one `is None` test,
+    no string built). The one helper behind `span` and
+    `obs.anatomy.stage`; close it on the same thread with
+    `close_annotation`."""
+    cls = _trace_annotation_cls
+    if cls is None:
+        return None
+    annotation = cls(prefix + name)
+    annotation.__enter__()
+    return annotation
+
+
+def close_annotation(annotation) -> None:
+    if annotation is not None:
+        annotation.__exit__(None, None, None)
 
 
 @dataclass
@@ -151,18 +172,14 @@ class Logger:
         jax.profiler.TraceAnnotation under "<target>|<message>" so a
         captured trace carries the same names the log/metrics surfaces
         use."""
-        annotation = None
-        if _trace_annotation_cls is not None:
-            annotation = _trace_annotation_cls(
-                f"{target}|{message}" if message else target
-            )
-            annotation.__enter__()
+        annotation = open_annotation(
+            f"{target}|{message}" if message else target
+        )
         t0 = time.perf_counter()
         try:
             yield
         finally:
-            if annotation is not None:
-                annotation.__exit__(None, None, None)
+            close_annotation(annotation)
             ms = (time.perf_counter() - t0) * 1e3
             ev = LogEvent(target=target, message=message, t=time.time(),
                           duration_ms=ms, fields=fields)

@@ -239,11 +239,11 @@ def test_scheduler_poison_retry_does_not_double_count(monkeypatch):
     orig = BatchReconciler.run_batch_wire
     state = {"fails": 0}
 
-    def flaky(self, requests):
+    def flaky(self, requests, *stage):
         if state["fails"] == 0:
             state["fails"] += 1
             raise RuntimeError("injected poison")
-        return orig(self, requests)
+        return orig(self, requests, *stage)
 
     monkeypatch.setattr(BatchReconciler, "run_batch_wire", flaky)
     server = RelayServer(RelayStore(), batching=True).start()

@@ -1189,11 +1189,11 @@ def _run_mixed_ledger_episode(tmp_path, seed):
     orig_rbw = BatchReconciler.run_batch_wire
     poison = {"armed": False, "fired": 0}
 
-    def flaky(self, requests):
+    def flaky(self, requests, *stage):
         if poison["armed"] and not poison["fired"]:
             poison["fired"] += 1
             raise RuntimeError("injected poisoned batch")
-        return orig_rbw(self, requests)
+        return orig_rbw(self, requests, *stage)
 
     BatchReconciler.run_batch_wire = flaky
     server = RelayServer(RelayStore(db_path), write_behind=True).start()

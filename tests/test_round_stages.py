@@ -1,0 +1,291 @@
+"""The served round and the engine pass, tiled into named host stages
+(ISSUE 26): `obs.anatomy.stage` at every seam of `scheduler._run_batch`
+→ `engine.start_batch`/`finish_batch`, the handler-thread legs of
+`relay.do_POST`, the scheduler's queue-wait and wake histograms, and the
+dispatcher thread's idle/busy/cpu seconds.
+
+What is pinned: the eight `pass_*` stages are recorded once per pass,
+never overlap and cover the pass (their seconds sum to 0.90-1.00 of
+`evolu_sched_batch_ms`); the dispatch and apply children stay inside
+their parents; the per-request families count EVERY request, traced or
+not; the dispatcher's idle + busy seconds are its wall time; with
+annotations on, every stage is one `evolu/<name>` profiler annotation on
+the thread that did the work; and none of it changes a byte of a
+response or of the store.
+"""
+
+import os
+import subprocess
+import sys
+import threading
+import time
+import urllib.request
+
+import pytest
+
+import evolu_tpu.utils.log as log_mod
+from conftest import relay_store_dump
+from evolu_tpu.core.timestamp import Timestamp, timestamp_to_string
+from evolu_tpu.obs import metrics, trace
+from evolu_tpu.server.relay import RelayServer, ShardedRelayStore
+from evolu_tpu.sync import protocol
+
+ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+BASE = 1_700_000_000_000
+PASS_STAGES = ("pass_pack", "pass_parse", "pass_layout", "pass_device_call",
+               "pass_insert", "pass_pull_wait", "pass_tree", "pass_respond")
+DISPATCH_CHILDREN, APPLY_CHILDREN = PASS_STAGES[:4], PASS_STAGES[4:7]
+DISPATCHER = "evolu_sched_dispatcher_seconds_total"
+
+
+def _body(owner: int, round_: int, n: int) -> bytes:
+    node = f"{owner + 1:016x}"
+    msgs = tuple(
+        protocol.EncryptedCrdtMessage(
+            timestamp_to_string(Timestamp(BASE + (round_ * n + i) * 1000, 0, node)),
+            b"ct-%d" % i)
+        for i in range(n))
+    return protocol.encode_sync_request(
+        protocol.SyncRequest(msgs, f"owner-{owner}", node, "{}"))
+
+
+def _post(url: str, body: bytes) -> bytes:
+    # No traceparent header: the families below must not depend on one.
+    with urllib.request.urlopen(
+            urllib.request.Request(url, data=body, method="POST"), timeout=120) as r:
+        return r.read()
+
+
+def _push_rounds(url: str, clients: int, rounds: range, n: int) -> None:
+    """`clients` closed-loop threads, each its own owner, one push a round."""
+    errors = []
+
+    def client(owner):
+        try:
+            for k in rounds:
+                _post(url, _body(owner, k, n))
+        except Exception as e:  # noqa: BLE001 - collected and re-raised
+            errors.append(e)
+
+    threads = [threading.Thread(target=client, args=(o,)) for o in range(clients)]
+    for t in threads:
+        t.start()
+    for t in threads:
+        t.join(timeout=120)
+    assert not any(t.is_alive() for t in threads), "a client hung"
+    assert not errors, errors
+
+
+def _hist(family: str, **labels):
+    h = metrics.registry.get_histogram(family, **labels)
+    return (h[2], h[3]) if h else (0.0, 0)  # (sum, count)
+
+
+def _stage_seconds(stage: str) -> float:
+    return metrics.get_counter("evolu_stage_seconds_total", stage=stage)
+
+
+def _wait_until(cond, what: str, timeout: float = 30.0) -> None:
+    deadline = time.monotonic() + timeout
+    while not cond():
+        assert time.monotonic() < deadline, f"timed out waiting for {what}"
+        time.sleep(0.001)
+
+
+def _tails():
+    """→ a wait for the tails a client's answer does not wait for: the
+    dispatcher observes batch_ms and its own seconds AFTER it resolved
+    the last future, and a handler closes `respond_write` and observes
+    round_ms AFTER the socket write. Every round here answers 200, so
+    both are settled when the counts since now agree."""
+    def counts():
+        return (metrics.get_counter("evolu_sched_batches_total"),
+                _hist("evolu_sched_batch_ms")[1],
+                metrics.get_counter("evolu_relay_requests_total", endpoint="/"),
+                _hist("evolu_relay_round_ms")[1])
+
+    b0, m0, q0, r0 = counts()
+
+    def settled():
+        def done():
+            b, m, q, r = counts()
+            return b - b0 == m - m0 and q - q0 == r - r0
+        _wait_until(done, "the dispatcher's and the handlers' tails")
+    return settled
+
+
+@pytest.fixture
+def server():
+    srv = RelayServer(ShardedRelayStore(shards=4), batching=True).start()
+    try:
+        srv.settled = _tails()
+        _push_rounds(srv.url, 4, range(0, 2), 200)  # compile outside every reading
+        srv.settled()
+        yield srv
+    finally:
+        srv.stop()
+
+
+def test_pass_stages_tile_the_pass(server):
+    def reading():
+        return ({s: (_stage_seconds(s), _hist("evolu_stage_ms", stage=s)[1])
+                 for s in PASS_STAGES + ("device_dispatch", "host_apply")},
+                metrics.get_counter("evolu_sched_batches_total"),
+                _hist("evolu_sched_batch_ms")[0])
+
+    stages0, batches0, batch_ms0 = reading()
+    _push_rounds(server.url, 8, range(2, 8), 200)
+    server.settled()
+    stages1, batches1, batch_ms1 = reading()
+    passes = batches1 - batches0
+    assert passes >= 6
+    seconds = {s: stages1[s][0] - stages0[s][0] for s in stages1}
+    for s in PASS_STAGES:  # once per pass, every one of them
+        assert stages1[s][1] - stages0[s][1] == passes, s
+    # They tile: never more than the pass (no overlap), and what is
+    # outside every tile (queue-wait records, span open/close, the few
+    # lines between the lumps) is a small share of it.
+    share = sum(seconds[s] for s in PASS_STAGES) / ((batch_ms1 - batch_ms0) / 1e3)
+    assert 0.90 <= share <= 1.00, share
+    assert sum(seconds[s] for s in DISPATCH_CHILDREN) <= seconds["device_dispatch"]
+    assert sum(seconds[s] for s in APPLY_CHILDREN) <= seconds["host_apply"]
+
+
+@pytest.mark.parametrize("traced", [True, False], ids=["trace-on", "trace-off"])
+def test_round_families_count_every_request(server, traced):
+    families = [("evolu_sched_queue_wait_ms", {}), ("evolu_sched_wake_ms", {}),
+                ("evolu_relay_round_ms", {}),
+                ("evolu_relay_stage_ms", {"stage": "read_decode"}),
+                ("evolu_relay_stage_ms", {"stage": "respond_write"})]
+    before = [_hist(f, **labels) for f, labels in families]
+    trace.set_enabled(traced)
+    try:
+        _push_rounds(server.url, 5, range(2, 6), 20)
+    finally:
+        trace.set_enabled(True)
+    sent = 5 * 4
+    server.settled()
+    after = [_hist(f, **labels) for f, labels in families]
+    for (family, labels), b, a in zip(families, before, after):
+        assert a[1] - b[1] == sent, (family, labels)
+    (decode, queue, wake, respond, round_) = (
+        after[i][0] - before[i][0] for i in (3, 0, 1, 4, 2))
+    assert decode + queue + wake + respond <= round_  # legs of one round
+
+
+def test_dispatcher_idle_plus_busy_is_wall_time(server):
+    def reading():
+        # `cpu` is the last of the three the dispatcher posts for a pass.
+        return {s: metrics.get_counter(DISPATCHER, state=s)
+                for s in ("cpu", "idle", "busy")}, time.perf_counter()
+
+    def one_round(k):
+        cpu = metrics.get_counter(DISPATCHER, state="cpu")
+        _post(server.url, _body(0, k, 50))
+        _wait_until(lambda: metrics.get_counter(DISPATCHER, state="cpu") != cpu,
+                    "the dispatcher's counters")
+
+    one_round(2)
+    r0, t0 = reading()
+    time.sleep(0.5)  # idle
+    for k in range(3, 8):
+        one_round(k)
+    r1, t1 = reading()
+    idle, busy, cpu = (r1[s] - r0[s] for s in ("idle", "busy", "cpu"))
+    assert busy > 0 and idle > 0.4
+    assert idle + busy == pytest.approx(t1 - t0, rel=0.05)
+    assert cpu <= busy
+
+
+def test_every_stage_is_one_annotation_on_its_thread(server):
+    events, built = [], []
+
+    class Recording:
+        def __init__(self, name):
+            built.append(name)
+            self.name = name
+
+        def __enter__(self):
+            events.append(("open", self.name, threading.get_ident()))
+            return self
+
+        def __exit__(self, *exc):
+            events.append(("close", self.name, threading.get_ident()))
+
+    batches0 = metrics.get_counter("evolu_sched_batches_total")
+    orig = log_mod._trace_annotation_cls
+    log_mod._trace_annotation_cls = Recording
+    try:
+        _push_rounds(server.url, 4, range(2, 5), 50)
+        server.settled()
+    finally:
+        log_mod._trace_annotation_cls = orig
+    passes = int(metrics.get_counter("evolu_sched_batches_total") - batches0)
+    assert passes >= 3
+    dispatcher = server.scheduler._thread.ident
+    by_name = {}
+    for kind, name, tid in events:
+        by_name.setdefault(name, {"open": [], "close": []})[kind].append(tid)
+    for s in PASS_STAGES + ("device_dispatch", "host_apply"):
+        rec = by_name["evolu/" + s]
+        assert rec["open"] == rec["close"] == [dispatcher] * passes, s
+    pulls = by_name["evolu/pull_wave"]
+    assert len(pulls["open"]) == passes and pulls["open"] == pulls["close"]
+    assert dispatcher not in pulls["open"]  # the pull thread's line
+    for leg in ("read_decode", "respond_write"):  # handler threads, per request
+        rec = by_name["evolu/" + leg]
+        assert len(rec["open"]) == 4 * 3 and sorted(rec["open"]) == sorted(rec["close"])
+        assert dispatcher not in rec["open"]
+    # No pass_* opens while another pass_* of that thread is open.
+    open_pass = {}
+    for kind, name, tid in events:
+        if name.startswith("evolu/pass_"):
+            if kind == "open":
+                assert open_pass.get(tid) is None, (name, open_pass[tid])
+                open_pass[tid] = name
+            else:
+                assert open_pass.pop(tid) == name
+    assert not open_pass
+    # Annotations off: the stand-in (or any class) is never constructed.
+    count = len(built)
+    _push_rounds(server.url, 2, range(5, 6), 50)
+    server.settled()
+    assert len(built) == count
+
+
+def _serve_script(enabled: bool):
+    """One deterministic, sequential script of pushes and pulls against a
+    fresh batching relay → (every response's bytes, the store's dump)."""
+    metrics.set_enabled(enabled)
+    srv = RelayServer(ShardedRelayStore(shards=2), batching=True).start()
+    try:
+        out = [_post(srv.url, _body(owner, k, 30))
+               for k in range(3) for owner in range(3)]
+        for owner in range(3):  # another device of each owner pulls it all
+            out.append(_post(srv.url, protocol.encode_sync_request(
+                protocol.SyncRequest((), f"owner-{owner}", "f" * 16, "{}"))))
+        return out, relay_store_dump(srv.store)
+    finally:
+        srv.stop()
+        metrics.set_enabled(True)
+
+
+def test_responses_and_store_identical_with_metrics_disabled():
+    on, off = _serve_script(True), _serve_script(False)
+    assert on[0] == off[0] and len(on[0]) == 12 and all(on[0])
+    assert on[1] == off[1]
+    assert sum(len(messages) for messages, _trees in on[1]) == 3 * 3 * 30
+
+
+def test_perf_selfcheck_reads_every_new_layer_file():
+    """`perf/selfcheck.py` holds every `perf/layers/*.json` against
+    `BENCHMARK.json`; the 15 stage metrics are data it must accept."""
+    env = {k: v for k, v in os.environ.items() if k != "JAX_PLATFORMS"}
+    done = subprocess.run([sys.executable, os.path.join(ROOT, "perf", "selfcheck.py")],
+                          capture_output=True, text=True, timeout=300, env=env)
+    assert done.returncode == 0, done.stderr[-2000:]
+    listed = set(os.listdir(os.path.join(ROOT, "perf", "layers")))
+    assert {f"{s}_ms.json" for s in PASS_STAGES} <= listed
+    assert {"req_decode_ms.json", "sched_queue_wait_ms.json", "sched_wake_ms.json",
+            "req_respond_ms.json", "relay_round_ms.json",
+            "dispatcher_busy_share.json", "pass_cpu_share.json"} <= listed

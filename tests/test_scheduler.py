@@ -242,9 +242,9 @@ def test_backpressure_and_client_backoff_recover_without_data_loss():
     eng = BatchReconciler(store)
     orig = eng.run_batch_wire
 
-    def slow_run(reqs):
+    def slow_run(reqs, *stage):
         time.sleep(0.05)
-        return orig(reqs)
+        return orig(reqs, *stage)
 
     eng.run_batch_wire = slow_run
     sched = SyncScheduler(store, engine=eng, max_batch=8, max_queue=2,
@@ -303,11 +303,11 @@ def test_poisoned_batch_retried_as_singletons_spares_batchmates():
     orig = eng.run_batch_wire
     state = {"boom": 1}
 
-    def poisoned(reqs):
+    def poisoned(reqs, *stage):
         if state["boom"]:
             state["boom"] -= 1
             raise RuntimeError("injected device failure")
-        return orig(reqs)
+        return orig(reqs, *stage)
 
     eng.run_batch_wire = poisoned
     sched = SyncScheduler(store, engine=eng, max_batch=8, max_wait_s=0.2)
@@ -443,9 +443,9 @@ def test_stop_drains_inflight_batches():
     eng = BatchReconciler(store)
     orig = eng.run_batch_wire
 
-    def slow_run(reqs):
+    def slow_run(reqs, *stage):
         time.sleep(0.08)
-        return orig(reqs)
+        return orig(reqs, *stage)
 
     eng.run_batch_wire = slow_run
     sched = SyncScheduler(store, engine=eng, max_batch=2, max_wait_s=0.0)
@@ -499,11 +499,11 @@ def test_singleton_fallback_never_overlaps_an_open_engine_pass(monkeypatch):
     orig = eng.run_batch_wire
     in_pass = threading.Event()
 
-    def slow(reqs):
+    def slow(reqs, *stage):
         in_pass.set()
         try:
             time.sleep(0.15)
-            return orig(reqs)
+            return orig(reqs, *stage)
         finally:
             in_pass.clear()
 

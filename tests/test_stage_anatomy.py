@@ -217,6 +217,55 @@ def test_disabled_registry_records_nothing():
     assert anatomy.stages_payload()["stages"] == {}
 
 
+def test_stage_primitive_tiles_its_parent_at_the_seams():
+    """`stage` is `record_stage` at both edges of an interval; `then`
+    is a seam (one clock read closes a tile and opens the next), so the
+    children of a parent tile it with no gap and no overlap; `stop` on
+    a stage that is not running, or was never started, is a no-op."""
+    with anatomy.stage("device_dispatch", rows=7) as whole, \
+            anatomy.stage("pass_pack", rows=7) as tile:
+        tile.then("pass_parse")
+        tile.nbytes = 64  # known only inside the interval
+        tile.then("pass_layout")
+    tile.stop()
+    anatomy.stage("pass_respond").stop()  # never started
+    stages = anatomy.stages_payload()["stages"]
+    assert [stages[s]["count"] for s in (
+        "device_dispatch", "pass_pack", "pass_parse", "pass_layout")] == [1, 1, 1, 1]
+    assert "pass_respond" not in stages
+    assert stages["pass_pack"]["floor_ms"] is None  # runtime seams are unpriced
+    children = sum(metrics.get_counter("evolu_stage_seconds_total", stage=s)
+                   for s in ("pass_pack", "pass_parse", "pass_layout"))
+    assert 0 < children <= whole.seconds
+    assert children == pytest.approx(whole.seconds, abs=2e-4)
+    assert metrics.get_counter("evolu_stage_rows_total", stage="pass_pack") == 7
+    assert metrics.get_counter("evolu_stage_bytes_total", stage="pass_parse") == 64
+    # The seam names are runtime names, not ablation-registry entries.
+    assert not {"pass_pack", "pass_parse", "pass_layout"} & {
+        s.name for s in anatomy.STAGES}
+
+
+def test_stage_lands_in_the_ambient_trace_and_costs_nothing_disabled():
+    from evolu_tpu.obs import trace
+
+    root = trace.start_span("engine.batch")
+    with trace.use(root.context), anatomy.stage("pass_tree"):
+        pass
+    root.end()
+    (span_,) = [s for s in trace.spans_for(root.trace_id) if s.name == "pass_tree"]
+    assert span_.parent_id == root.context.span_id and span_.duration_ms >= 0
+    with anatomy.stage("pass_tree"):  # no ambient context: registry only
+        pass
+    assert len([s for s in trace.recorder.dump() if s.name == "pass_tree"]) == 1
+    metrics.set_enabled(False)
+    try:
+        with anatomy.stage("pass_insert"):
+            pass
+    finally:
+        metrics.set_enabled(True)
+    assert "pass_insert" not in anatomy.stages_payload()["stages"]
+
+
 def test_kernel_span_folds_into_family():
     anatomy.set_device_kind(anatomy.V5E)
     with span("kernel:merkle", "t", n=1000):
