@@ -32,7 +32,11 @@ import numpy as np
 from jax import shard_map
 from jax.sharding import Mesh, PartitionSpec as P
 
-from evolu_tpu.core.merkle import apply_prefix_xors, merkle_tree_to_string
+from evolu_tpu.core.merkle import (
+    apply_prefix_xors,
+    merkle_tree_from_string,
+    merkle_tree_to_string,
+)
 from evolu_tpu.ops import bucket_size, start_host_transfer, to_host_many, with_x64
 from evolu_tpu.ops.encode import timestamp_hashes
 from evolu_tpu.ops.host_parse import parse_packed_timestamps, parse_timestamp_strings
@@ -48,6 +52,7 @@ from evolu_tpu.parallel.mesh import (
 from evolu_tpu.obs import anatomy, flight, ledger, metrics
 from evolu_tpu.parallel.reconcile import xor_allreduce
 from evolu_tpu.server.relay import RelayStore
+from evolu_tpu.storage.native import relay_commit_shards, relay_insert_packed_shards
 from evolu_tpu.utils.log import log, span
 from evolu_tpu.sync import protocol
 
@@ -588,6 +593,19 @@ def _ledger_count_pass(requests, inserted_by_owner) -> None:
         ledger.count(ledger.STORE_DUPLICATE, total - ins, owner=o)
 
 
+def _fold_trees(deltas_by_owner, stored, tree_rows, shard_index, trees, strings) -> None:
+    """Fold each owner's pass deltas onto its stored tree TEXT
+    (`_store_pass`'s `stored`): the folded tree into `trees`, its dump
+    into `strings` and, as the (owner, TEXT) row `_store_pass` upserts,
+    into the owner's shard's list of `tree_rows`."""
+    for o, deltas in deltas_by_owner.items():
+        if not deltas:
+            continue
+        trees[o] = apply_prefix_xors(merkle_tree_from_string(stored[o]), deltas)
+        strings[o] = merkle_tree_to_string(trees[o])
+        tree_rows[shard_index(o)].append((o, strings[o]))
+
+
 def _pack_rows(ts_list, contents):
     """Pack one shard's rows into flat buffers. Per-string width check
     BEFORE packing: a total-length check alone would accept
@@ -649,7 +667,6 @@ class BatchReconciler:
             mesh = mesh_ctx.mesh
         self.mesh = mesh or create_mesh()
         self.write_behind = write_behind
-        self._executor = None
         self._pull_pool = None
 
     def _new_messages(
@@ -731,15 +748,6 @@ class BatchReconciler:
             return self.store.shards, self.store.shard_index
         return [self.store], (lambda _u: 0)
 
-    def _pool(self, n: int):
-        """One worker per storage shard (sized to the store, not to the
-        current batch, so a small first batch can't cap later ones)."""
-        if self._executor is None and n > 1:
-            from concurrent.futures import ThreadPoolExecutor
-
-            self._executor = ThreadPoolExecutor(max_workers=n, thread_name_prefix="evolu-ingest")
-        return self._executor
-
     def _pull_executor(self):
         if self._pull_pool is None:
             from concurrent.futures import ThreadPoolExecutor
@@ -747,32 +755,38 @@ class BatchReconciler:
             self._pull_pool = ThreadPoolExecutor(max_workers=1, thread_name_prefix="evolu-pull")
         return self._pull_pool
 
-    def _map_shards(self, fn, live, n_stores):
-        """Run fn(si) per live shard — parallel when a pool exists.
-        Waits for EVERY worker before raising: a rollback while a
-        worker is still running would let its insert land in autocommit
-        mode — committed rows outside any tree."""
-        pool = self._pool(n_stores)
-        if pool is not None and len(live) > 1:
-            futures = [pool.submit(fn, si) for si in live]
-            results, first_err = [], None
-            for f in futures:
-                try:
-                    results.append(f.result())
-                except BaseException as e:  # noqa: BLE001
-                    first_err = first_err or e
-            if first_err is not None:
-                raise first_err
-            return results
-        return [fn(si) for si in live]
+    @contextmanager
+    def _store_pass(self, stores, live, batches):
+        """The storage leg of ONE pass over the live shards, in two
+        native calls (`storage/native.py`, the shard-set calls) and no
+        thread of this process's but the caller's. On entry: BEGIN +
+        the packed INSERT OR IGNORE of `batches[i]` on shard `live[i]`
+        + the stored trees of every group user, one call. Yields
+        (was-new flags by shard id, {owner: stored tree TEXT}, per-shard
+        lists for the caller to fill with (owner, tree TEXT) rows). On
+        exit: those rows upserted and every shard committed, one call.
+        Insert and upsert of a shard share a transaction, so rows never
+        outrun their tree; an exception anywhere, in the body too,
+        rolls back EVERY live shard before it propagates."""
+        dbs = [stores[si].db for si in live]
+        flags, stored = relay_insert_packed_shards(dbs, batches)
+        metrics.inc("evolu_engine_store_calls_total", op="insert")
+        tree_rows: List[List[Tuple[str, str]]] = [[] for _ in stores]
+        try:
+            yield dict(zip(live, flags)), stored, tree_rows
+            relay_commit_shards(dbs, [tree_rows[si] for si in live])
+        except BaseException:
+            for db in dbs:
+                db.rollback()
+            raise
+        metrics.inc("evolu_engine_store_calls_total", op="commit")
 
     @contextmanager
     def _shard_transactions(self, stores, live):
         """One open transaction per live shard, rolled back together on
-        error, committed together on exit (first commit error wins).
-        Short-lock begin/commit (not the lock-holding context manager)
-        so worker threads can execute inside them; each shard has
-        exactly one logical writer (its worker)."""
+        error, committed together on exit (first commit error wins):
+        `_store_pass` for stores the shard-set calls cannot drive (the
+        stdlib backend under `reconcile_pod`)."""
         begun: List[int] = []
         try:
             for si in live:
@@ -793,25 +807,21 @@ class BatchReconciler:
             raise commit_err
 
     def close(self) -> None:
-        if self._executor is not None:
-            self._executor.shutdown(wait=True)
-            self._executor = None
         if self._pull_pool is not None:
             self._pull_pool.shutdown(wait=True)
             self._pull_pool = None
 
     def _ingest_packed(self, requests, tree_strings=None) -> Dict[str, dict]:
         """The packed columnar ingest. Per storage shard: pack the
-        shard's timestamps and ciphertexts into flat buffers and INSERT
-        OR IGNORE them in ONE native call (the PK dedups, including
-        in-batch duplicates, with per-row was-new flags —
-        index.ts:153-158 semantics), then parse the packed buffer
-        natively. Shards ingest in parallel threads (the C calls drop
-        the GIL). The new rows of every shard ride ONE device dispatch
-        for the per-(owner, minute) hashes, and each shard's inserts +
-        tree updates commit in one transaction, so rows can never
-        outrun their tree. A failure anywhere rolls every uncommitted
-        shard back."""
+        shard's timestamps and ciphertexts into flat buffers; ONE
+        native call over all live shards INSERTs OR IGNOREs them (the
+        PK dedups, including in-batch duplicates, with per-row was-new
+        flags — index.ts:153-158 semantics) and reads the owners'
+        stored trees. The new rows of every shard ride ONE device
+        dispatch for the per-(owner, minute) hashes, and each shard's
+        inserts + tree updates commit in one transaction
+        (`_store_pass`), so rows can never outrun their tree. A failure
+        anywhere rolls every shard back."""
         stores, shard_index = self._shards()
         per_shard: List[List[protocol.SyncRequest]] = [[] for _ in stores]
         for r in requests:
@@ -821,82 +831,60 @@ class BatchReconciler:
         if not live:
             return trees
         n_total = sum(len(r.messages) for r in requests)
-
-        def ingest_shard(si: int):
-            db = stores[si].db
-            reqs = per_shard[si]
-            gu = [r.user_id for r in reqs]
-            gc = [len(r.messages) for r in reqs]
-            n = sum(gc)
-            # One flat pass over the shard's messages; everything below
-            # is C-speed (map/join/fromiter) — per-message Python
-            # generators here cost ~2.5s/1M (profiled).
-            ts_list = [m.timestamp for r in reqs for m in r.messages]
-            contents = [m.content for r in reqs for m in r.messages]
-            ts_packed, content_packed, lens = _pack_rows(ts_list, contents)
-            was_new = db.relay_insert_packed(gu, gc, ts_packed, content_packed, lens)
-            cols = parse_packed_timestamps(ts_packed, n, with_case=True)
-            return gu, gc, ts_packed, was_new, cols
-
-        def ingest_all():
-            results = self._map_shards(ingest_shard, live, len(stores))
-
-            # Merge shard results into one flat column space.
-            owner_index: Dict[str, List[np.ndarray]] = {}
-            buffers, offsets = [], []
-            col_parts = ([], [], [], [])
-            off = 0
-            for (gu, gc, ts_packed, was_new, cols) in results:
-                pos = 0
-                for u, k in zip(gu, gc):
-                    ix = np.nonzero(was_new[pos : pos + k])[0] + (pos + off)
-                    if len(ix):
-                        owner_index.setdefault(u, []).append(ix)
-                    pos += k
-                buffers.append(ts_packed)
-                offsets.append(off)
-                for part, c in zip(col_parts, cols):
-                    part.append(c)
-                off += len(was_new)
-            merged = {
-                u: (v[0] if len(v) == 1 else np.concatenate(v))
-                for u, v in owner_index.items()
-            }
-            inserted_by_owner.update((u, len(v)) for u, v in merged.items())
-            all_m, all_c, all_n, case_ok = (
-                (p[0] if len(p) == 1 else np.concatenate(p)) for p in col_parts
-            )
-            deltas_by_owner, _digest = deltas_from_columns(
-                self.mesh, merged, all_m, all_c, all_n, case_ok,
-                _PackedRows(buffers, offsets), ctx=self.mesh_ctx,
-            )
-            tree_rows: List[List[Tuple[str, str]]] = [[] for _ in stores]
-            for o, deltas in deltas_by_owner.items():
-                if not deltas:
-                    continue
-                si = shard_index(o)
-                tree = apply_prefix_xors(stores[si].get_merkle_tree(o), deltas)
-                trees[o] = tree
-                s = merkle_tree_to_string(tree)
-                if tree_strings is not None:
-                    tree_strings[o] = s  # respond reuses the upsert's dump
-                tree_rows[si].append((o, s))
-            for si in live:
-                if tree_rows[si]:
-                    stores[si].db.run_many(
-                        'INSERT OR REPLACE INTO "merkleTree" ("userId", "merkleTree") '
-                        "VALUES (?, ?)",
-                        tree_rows[si],
-                    )
-
         inserted_by_owner: Dict[str, int] = {}
         with span("kernel:merkle", "reconcile_ingest",
                   owners=len({r.user_id for r in requests}), n=n_total,
                   shards=len(live)):
+            batches = []
+            for si in live:
+                reqs = per_shard[si]
+                # One flat pass over the shard's messages; everything
+                # below is C-speed (map/join/fromiter) — per-message
+                # Python generators here cost ~2.5s/1M (profiled).
+                ts_list = [m.timestamp for r in reqs for m in r.messages]
+                contents = [m.content for r in reqs for m in r.messages]
+                batches.append((
+                    [r.user_id for r in reqs], [len(r.messages) for r in reqs],
+                    *_pack_rows(ts_list, contents),
+                ))
             # Transactions held across the device dispatch so inserts +
             # trees commit atomically.
-            with self._shard_transactions(stores, live):
-                ingest_all()
+            with self._store_pass(stores, live, batches) as (
+                    was_new_by_shard, stored, tree_rows):
+                # Merge shard results into one flat column space.
+                owner_index: Dict[str, List[np.ndarray]] = {}
+                buffers, offsets = [], []
+                col_parts = ([], [], [], [])
+                off = 0
+                for si, (gu, gc, ts_packed, _cp, _lens) in zip(live, batches):
+                    was_new = was_new_by_shard[si]
+                    pos = 0
+                    for u, k in zip(gu, gc):
+                        ix = np.nonzero(was_new[pos : pos + k])[0] + (pos + off)
+                        if len(ix):
+                            owner_index.setdefault(u, []).append(ix)
+                        pos += k
+                    buffers.append(ts_packed)
+                    offsets.append(off)
+                    cols = parse_packed_timestamps(ts_packed, len(was_new), with_case=True)
+                    for part, c in zip(col_parts, cols):
+                        part.append(c)
+                    off += len(was_new)
+                merged = {
+                    u: (v[0] if len(v) == 1 else np.concatenate(v))
+                    for u, v in owner_index.items()
+                }
+                inserted_by_owner.update((u, len(v)) for u, v in merged.items())
+                all_m, all_c, all_n, case_ok = (
+                    (p[0] if len(p) == 1 else np.concatenate(p)) for p in col_parts
+                )
+                deltas_by_owner, _digest = deltas_from_columns(
+                    self.mesh, merged, all_m, all_c, all_n, case_ok,
+                    _PackedRows(buffers, offsets), ctx=self.mesh_ctx,
+                )
+                # `tree_strings`: respond reuses the upsert's dump.
+                _fold_trees(deltas_by_owner, stored, tree_rows, shard_index, trees,
+                            {} if tree_strings is None else tree_strings)
         _ledger_count_pass(requests, inserted_by_owner)
         return trees
 
@@ -1016,9 +1004,10 @@ class BatchReconciler:
                 dict(zip(live, offsets)), merged, off)
 
     def finish_batch(self, st, wire: bool = False, respond_stage=None) -> List:
-        """Land batch k: per-shard C inserts (parallel, GIL-free),
-        duplicate-owner delta recompute, tree updates, one atomic
-        commit per shard — while batch k+1 flies on the device.
+        """Land batch k: the C inserts of every live shard in one
+        native call, duplicate-owner delta recompute, tree updates, one
+        atomic commit per shard in a second (`_store_pass`) — while
+        batch k+1 flies on the device.
         `wire=True` answers in BYTES mode (`_respond_wire`) for
         consumers that only forward protobuf — the live scheduler path,
         byte-identical to encoding the object responses (test-pinned
@@ -1037,33 +1026,26 @@ class BatchReconciler:
                 respond_stage.start()
             return respond(st["requests"], trees, strings)
 
-        def ingest_shard(si: int):
-            gu, gc, ts_packed, content_packed, lens = shard_data[si]
-            return si, stores[si].db.relay_insert_packed(
-                gu, gc, ts_packed, content_packed, lens
-            )
-
         # host_apply stage record (obs.anatomy): the WHOLE finish leg up
         # to the commit — C inserts, the blocked wait for the pull,
         # delta decode, tree folds, commit. The `kernel:merkle` span
         # below has the same extent under its historical name: it times
         # this host leg, not a kernel. Three children tile it:
-        # pass_insert (BEGIN + the C inserts), pass_pull_wait (only the
-        # blocking wait for the device's outputs), pass_tree (decode,
-        # duplicate recompute, tree folds, merkleTree upsert, COMMIT).
-        # The pull itself records under pull_wave from to_host_many on
-        # the pull thread — shares are over summed stage walls, and the
-        # two legs overlap (docs/OBSERVABILITY.md).
+        # pass_insert (`_store_pass`'s first native call: BEGIN, the C
+        # inserts, the stored trees), pass_pull_wait (only the blocking
+        # wait for the device's outputs), pass_tree (decode, duplicate
+        # recompute, tree folds, and the second native call: merkleTree
+        # upsert + COMMIT). The pull itself records under pull_wave from
+        # to_host_many on the pull thread — shares are over summed stage
+        # walls, and the two legs overlap (docs/OBSERVABILITY.md).
         rows = st["n_total"]
         with anatomy.stage("host_apply", rows=rows), \
                 span("kernel:merkle", "reconcile_stream_finish",
                      owners=len({r.user_id for r in st["requests"]}),
                      n=rows, shards=len(live)), \
                 anatomy.stage("pass_insert", rows=rows) as tile:
-            with self._shard_transactions(stores, live):
-                was_new_by_shard = dict(
-                    self._map_shards(ingest_shard, live, len(stores))
-                )
+            with self._store_pass(stores, live, [shard_data[si] for si in live]) as (
+                    was_new_by_shard, stored, tree_rows):
                 tile.then("pass_pull_wait")
                 pulled = deltas_pull(st["dev"])
                 tile.then("pass_tree", rows=rows)
@@ -1071,24 +1053,7 @@ class BatchReconciler:
                 self._recompute_duplicate_owners(
                     st, was_new_by_shard, deltas_by_owner
                 )
-
-                tree_rows: List[List[Tuple[str, str]]] = [[] for _ in stores]
-                for o, deltas in deltas_by_owner.items():
-                    if not deltas:
-                        continue
-                    si = shard_index(o)
-                    tree = apply_prefix_xors(stores[si].get_merkle_tree(o), deltas)
-                    trees[o] = tree
-                    s = merkle_tree_to_string(tree)
-                    strings[o] = s
-                    tree_rows[si].append((o, s))
-                for si in live:
-                    if tree_rows[si]:
-                        stores[si].db.run_many(
-                            'INSERT OR REPLACE INTO "merkleTree" ("userId", "merkleTree") '
-                            "VALUES (?, ?)",
-                            tree_rows[si],
-                        )
+                _fold_trees(deltas_by_owner, stored, tree_rows, shard_index, trees, strings)
         if respond_stage is not None:
             respond_stage.start()
         # Ledger terminals AFTER the per-shard commits: per-owner
@@ -1237,7 +1202,6 @@ class BatchReconciler:
         respond wall at 1k divergent owners — docs/BENCHMARKS.md r4).
         Mutates both caches; ONE copy shared by `_respond` and
         `_respond_wire`."""
-        from evolu_tpu.core.merkle import merkle_tree_from_string
 
         tree = trees.get(user_id)
         if tree is None:
@@ -1259,7 +1223,6 @@ class BatchReconciler:
         tree_strings: Optional[Dict[str, str]] = None,
     ) -> List[protocol.SyncResponse]:
         """Standard diff per request against the updated trees."""
-        from evolu_tpu.core.merkle import merkle_tree_from_string
 
         responses = []
         tree_strings = dict(tree_strings or {})
@@ -1340,7 +1303,6 @@ class BatchReconciler:
         respond from the in-memory trees. Nothing is installed if the
         append raises (backpressure or log failure) — the serving
         state stays consistent for the retry."""
-        from evolu_tpu.core.merkle import merkle_tree_from_string
         from evolu_tpu.storage.write_behind import IngestRecord
 
         wb = self.write_behind
@@ -1415,7 +1377,6 @@ class BatchReconciler:
         freshly folded tree, else the queue's serving cache (the owner
         has undrained history), else the stored string (SQLite is
         current for fully drained owners)."""
-        from evolu_tpu.core.merkle import merkle_tree_from_string
 
         tree = trees.get(user_id)
         if tree is not None:
@@ -1451,7 +1412,7 @@ class BatchReconciler:
         test_write_behind.py::test_duplicate_retry_response_tree_is_exact).
         Shards that cannot C-serve degrade to the batched object
         respond, also post-flush."""
-        from evolu_tpu.core.merkle import diff_merkle_trees, merkle_tree_from_string
+        from evolu_tpu.core.merkle import diff_merkle_trees
         from evolu_tpu.core.types import NonCanonicalStoreError
         from evolu_tpu.server.relay import fetch_response_stream
 
@@ -1527,7 +1488,6 @@ class BatchReconciler:
         byte-identical. Requests a shard cannot C-serve (python
         backend, malformed stored row) degrade to ONE batched
         object-path respond at their original positions."""
-        from evolu_tpu.core.merkle import merkle_tree_from_string
         from evolu_tpu.core.types import NonCanonicalStoreError
         from evolu_tpu.server.relay import fetch_response_stream
 
@@ -1710,10 +1670,10 @@ def reconcile_pod(
         )
         digest = int(dev_digest)
 
-    # 4) Storage leg — my owners only. Inserts run one worker per
-    # storage shard like `_ingest_packed` (the C calls drop the GIL);
-    # tree math + upserts follow per shard inside the same atomic
-    # transaction window.
+    # 4) Storage leg — my owners only, like `_ingest_packed`: the
+    # inserts of every live shard in one native call, tree math per
+    # shard, upserts + commits in a second, inside the same atomic
+    # transaction window (`_store_pass`).
     local = [o for o in good if proc_of[o] == pid]
     local += [o for o in host_only if owner_process(o, nproc) == pid]
     eng = BatchReconciler(store, mesh)  # storage/respond helpers only
@@ -1724,63 +1684,73 @@ def reconcile_pod(
     live = sorted(per_shard)
     trees: Dict[str, dict] = {}
     tree_strings: Dict[str, str] = {}
-    packed_capable = all(hasattr(stores[si].db, "relay_insert_packed") for si in live)
-
-    def insert_shard(si: int):
-        sh_owners = per_shard[si]
-        gu, gc = sh_owners, [len(kept[o]) for o in sh_owners]
-        if packed_capable:
-            ts_list = [m.timestamp for o in sh_owners for m in kept[o]]
-            contents = [m.content for o in sh_owners for m in kept[o]]
-            ts_packed, content_packed, lens = _pack_rows(ts_list, contents)
-            was_new = stores[si].db.relay_insert_packed(
-                gu, gc, ts_packed, content_packed, lens
-            )
-        else:  # stdlib backend: per-row changes==1 flags
-            was_new = np.array([
-                stores[si].db.run(
-                    'INSERT OR IGNORE INTO "message" '
-                    '("timestamp", "userId", "content") VALUES (?, ?, ?)',
-                    (m.timestamp, o, m.content),
-                ) == 1
-                for o in sh_owners
-                for m in kept[o]
-            ], bool)
-        return si, gu, gc, was_new
-
     pod_ins: Dict[str, int] = {}
+
+    def fold_shard(si: int, was_new, stored_tree, upsert) -> None:
+        """Fold one shard's owners from their was-new flags:
+        `stored_tree(o)` → the owner's tree before this pass,
+        `upsert(o, text)` stores the folded one."""
+        pos = 0
+        for o in per_shard[si]:
+            flags = was_new[pos : pos + len(kept[o])]
+            pos += len(kept[o])
+            pod_ins[o] = pod_ins.get(o, 0) + int(np.asarray(flags).sum())
+            if o in good_ix and bool(flags.all()):
+                deltas = by_ix.get(good_ix[o], {})
+            else:
+                # Duplicates or non-canonical: the exact host
+                # fold over this owner's NEW rows only.
+                deltas, _d = minute_deltas_host(
+                    m.timestamp for m, f in zip(kept[o], flags) if bool(f)
+                )
+            if not deltas:
+                continue
+            tree = apply_prefix_xors(stored_tree(o), deltas)
+            trees[o] = tree
+            tree_strings[o] = merkle_tree_to_string(tree)
+            upsert(o, tree_strings[o])
+
     with span("kernel:merkle", "reconcile_pod",
               owners=len(owners), local_owners=len(local),
               n=len(flat_ts), nproc=nproc):
-        with eng._shard_transactions(stores, live):
-            for si, gu, gc, was_new in eng._map_shards(
-                insert_shard, live, len(stores)
-            ):
-                pos = 0
-                for o, k in zip(gu, gc):
-                    flags = was_new[pos : pos + k]
-                    pos += k
-                    pod_ins[o] = pod_ins.get(o, 0) + int(np.asarray(flags).sum())
-                    if o in good_ix and bool(flags.all()):
-                        deltas = by_ix.get(good_ix[o], {})
-                    else:
-                        # Duplicates or non-canonical: the exact host
-                        # fold over this owner's NEW rows only.
-                        deltas, _d = minute_deltas_host(
-                            m.timestamp
-                            for m, f in zip(kept[o], flags)
-                            if bool(f)
-                        )
-                    if not deltas:
-                        continue
-                    tree = apply_prefix_xors(stores[si].get_merkle_tree(o), deltas)
-                    trees[o] = tree
-                    s = merkle_tree_to_string(tree)
-                    tree_strings[o] = s
-                    stores[si].db.run(
-                        'INSERT OR REPLACE INTO "merkleTree" ("userId", "merkleTree") '
-                        "VALUES (?, ?)",
-                        (o, s),
+        if live and all(hasattr(stores[si].db, "relay_insert_packed") for si in live):
+            batches = []
+            for si in live:
+                sh_owners = per_shard[si]
+                ts_list = [m.timestamp for o in sh_owners for m in kept[o]]
+                contents = [m.content for o in sh_owners for m in kept[o]]
+                batches.append((
+                    sh_owners, [len(kept[o]) for o in sh_owners],
+                    *_pack_rows(ts_list, contents),
+                ))
+            with eng._store_pass(stores, live, batches) as (
+                    was_new_by_shard, stored, tree_rows):
+                for si in live:
+                    fold_shard(
+                        si, was_new_by_shard[si],
+                        lambda o: merkle_tree_from_string(stored[o]),
+                        lambda o, text, rows=tree_rows[si]: rows.append((o, text)),
+                    )
+        elif live:  # stdlib backend: per-row changes==1 flags, per-shard calls
+            with eng._shard_transactions(stores, live):
+                for si in live:
+                    db = stores[si].db
+                    was_new = np.array([
+                        db.run(
+                            'INSERT OR IGNORE INTO "message" '
+                            '("timestamp", "userId", "content") VALUES (?, ?, ?)',
+                            (m.timestamp, o, m.content),
+                        ) == 1
+                        for o in per_shard[si]
+                        for m in kept[o]
+                    ], bool)
+                    fold_shard(
+                        si, was_new, stores[si].get_merkle_tree,
+                        lambda o, text, db=db: db.run(
+                            'INSERT OR REPLACE INTO "merkleTree" ("userId", "merkleTree") '
+                            "VALUES (?, ?)",
+                            (o, text),
+                        ),
                     )
     eng.close()
     # Ledger, per process: the broadcast batch ingresses HERE only for

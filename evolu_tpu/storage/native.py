@@ -23,7 +23,7 @@ import struct
 import threading
 
 import numpy as np
-from contextlib import contextmanager
+from contextlib import ExitStack, contextmanager
 from typing import Iterable, List, Optional, Sequence, Tuple
 
 from evolu_tpu.core.types import NonCanonicalStoreError, UnknownError
@@ -83,6 +83,13 @@ def _configure(lib: ctypes.CDLL) -> ctypes.CDLL:
     ]
     lib.eh_relay_insert.argtypes = [p, i64, sp, sp, sp, i32p, u8p]
     lib.eh_relay_insert_packed.argtypes = [p, i64, sp, i64p, s, s, i32p, u8p]
+    pp = c.POINTER(p)
+    lib.eh_relay_insert_packed_shards.restype = i64
+    lib.eh_relay_insert_packed_shards.argtypes = [
+        i64, pp, sp, i64p, sp, i32p, i64p, sp, sp, i32p, u8p, pp, i64p, s, c.c_int32,
+    ]
+    lib.eh_relay_commit_shards.restype = i64
+    lib.eh_relay_commit_shards.argtypes = [i64, pp, i64p, sp, i32p, sp, i32p, s, c.c_int32]
     lib.eh_parse_timestamps.argtypes = [s, i64, i64p, i32p, c.POINTER(c.c_uint64), u8p]
     lib.eh_run_many_tb.argtypes = [p, s, i64, c.c_int32, sp, i32p, i32p]
     lib.eh_get_messages.argtypes = [
@@ -529,13 +536,14 @@ class CppSqliteDatabase:
             self._check_open()
             return self._lib.eh_total_changes(self._db)
 
-    # Explicit transaction control for the shard-parallel relay ingest:
-    # unlike the `transaction()` context manager (which holds this
-    # db's lock across its body — correct for the single-writer
-    # runtime), these toggle the transaction in one short locked call
-    # each, so OTHER threads can run statements inside the open
-    # transaction. The caller owns exclusivity: exactly one logical
-    # writer per database (the engine assigns one worker per shard).
+    # Explicit transaction control: unlike the `transaction()` context
+    # manager (which holds this db's lock across its body — correct for
+    # the single-writer runtime), these toggle the transaction in one
+    # short locked call each, so statements can run inside the open
+    # transaction from other calls. The caller owns exclusivity:
+    # exactly one logical writer per database. The engine's passes
+    # open and close theirs through the shard-set calls below, which
+    # keep the same `_in_txn` flag, and roll back through `rollback()`.
 
     def begin(self) -> None:
         with self._lock:
@@ -909,6 +917,145 @@ class CppSqliteDatabase:
         if rc != 0:
             raise self._err()
         return [bool(x) for x in out]
+
+
+# -- shard-set calls: the storage leg of an engine pass, two C calls --
+#
+# `BatchReconciler` lands one pass on the live shards of a store
+# through these two functions and nothing else: the first opens a
+# transaction on every shard, inserts, and reads the owners' stored
+# trees; the second upserts the folded trees and commits. ctypes drops
+# the interpreter lock once per call, for all shards; the C side runs
+# them one after the other on the calling thread (native/evolu_host.cpp
+# says why). Both hold every shard's lock for the length of the call,
+# taken in the order given — callers pass shards in ascending order, so
+# two shard-set callers cannot deadlock. Between the two calls the
+# handles are inside a transaction (`_in_txn` true) and unlocked, like
+# after `begin()`.
+
+_I32P = ctypes.POINTER(ctypes.c_int32)
+_I64P = ctypes.POINTER(ctypes.c_int64)
+_ERR_CAP = 512
+
+
+def _text_array(items):
+    """→ (c_char_p array, int32 byte lengths) of utf-8 encoded strings."""
+    enc = [x.encode("utf-8") for x in items]
+    return (ctypes.c_char_p * len(enc))(*enc), np.fromiter(map(len, enc), np.int32, len(enc))
+
+
+def _handles(dbs):
+    return (ctypes.c_void_p * len(dbs))(*[db._db for db in dbs])
+
+
+def relay_insert_packed_shards(dbs, batches):
+    """BEGIN + `relay_insert_packed` + the stored trees of the group
+    users, on every shard, in ONE native call. `batches[i]` is the
+    argument tuple of `dbs[i].relay_insert_packed`: (group_users,
+    group_counts, ts_packed, content_packed, content_lens). →
+    (per-shard was-new bool arrays, {owner: stored merkleTree TEXT} over
+    all shards, "{}" for an owner with no stored tree). Every shard is
+    left INSIDE its transaction; finish with `relay_commit_shards` or
+    `rollback()` each. On any failure no shard holds a row or a
+    transaction of this call, and it raises."""
+    k = len(dbs)
+    lib = dbs[0]._lib
+    users: List[str] = []
+    counts: List[int] = []
+    n_groups = np.zeros(k, np.int64)
+    rows = np.zeros(k + 1, np.int64)
+    lens_parts = []
+    for i, (gu, gc, ts_packed, content_packed, content_lens) in enumerate(batches):
+        lens = np.ascontiguousarray(content_lens, dtype=np.int32)
+        if len(lens) * 46 != len(ts_packed) or sum(gc) != len(lens) or len(gu) != len(gc):
+            raise UnknownError("relay_insert_packed_shards: timestamp buffer size mismatch")
+        if int(lens.sum()) != len(content_packed):
+            raise UnknownError("relay_insert_packed_shards: content buffer size mismatch")
+        users.extend(gu)
+        counts.extend(gc)
+        n_groups[i] = len(gu)
+        rows[i + 1] = rows[i] + len(lens)
+        lens_parts.append(lens)
+    user_arr, user_lens = _text_array(users)
+    counts_np = np.asarray(counts, np.int64)
+    lens_np = np.concatenate(lens_parts) if lens_parts else np.zeros(0, np.int32)
+    out_new = np.zeros(int(rows[k]), np.uint8)
+    out_trees = ctypes.c_void_p()
+    out_trees_len = ctypes.c_int64()
+    err = ctypes.create_string_buffer(_ERR_CAP)
+    with ExitStack() as locks:
+        for db in dbs:
+            locks.enter_context(db._lock)
+        for db in dbs:
+            db._check_open()
+            if db._in_txn:
+                raise UnknownError("begin inside an open transaction")
+        failed = lib.eh_relay_insert_packed_shards(
+            k, _handles(dbs), (ctypes.c_char_p * k)(*[db._begin_sql for db in dbs]),
+            n_groups.ctypes.data_as(_I64P), user_arr, user_lens.ctypes.data_as(_I32P),
+            counts_np.ctypes.data_as(_I64P),
+            (ctypes.c_char_p * k)(*[b[2] for b in batches]),
+            (ctypes.c_char_p * k)(*[b[3] for b in batches]),
+            lens_np.ctypes.data_as(_I32P),
+            out_new.ctypes.data_as(ctypes.POINTER(ctypes.c_uint8)),
+            ctypes.byref(out_trees), ctypes.byref(out_trees_len), err, _ERR_CAP,
+        )
+        if failed >= 0:  # the C side rolled back whatever it began
+            raise UnknownError(
+                f"shard {failed} of {k}: " + err.value.decode("utf-8", "replace"))
+        for db in dbs:
+            db._in_txn = True
+    try:
+        raw = ctypes.string_at(out_trees.value, out_trees_len.value)
+    finally:
+        lib.eh_free(out_trees)
+    stored = {}
+    pos = 0
+    for u in users:
+        (n,) = _PACK_I32.unpack_from(raw, pos)
+        pos += 4
+        if n < 0:
+            stored[u] = "{}"
+        else:
+            stored[u] = raw[pos : pos + n].decode("utf-8")
+            pos += n
+    flags = out_new.view(bool)
+    return [flags[rows[i] : rows[i + 1]] for i in range(k)], stored
+
+
+def relay_commit_shards(dbs, tree_rows) -> None:
+    """INSERT OR REPLACE `tree_rows[i]` — (userId, merkleTree TEXT)
+    pairs — into `dbs[i]`'s "merkleTree", then COMMIT, on every shard,
+    in ONE native call (all upserts before the first COMMIT). Closes the
+    transactions `relay_insert_packed_shards` opened: whether it returns
+    or raises, no shard is inside a transaction afterwards."""
+    k = len(dbs)
+    user_arr, user_lens = _text_array([u for rows in tree_rows for u, _t in rows])
+    tree_arr, tree_lens = _text_array([t for rows in tree_rows for _u, t in rows])
+    n_trees = np.fromiter(map(len, tree_rows), np.int64, k)
+    err = ctypes.create_string_buffer(_ERR_CAP)
+    with ExitStack() as locks:
+        for db in dbs:
+            locks.enter_context(db._lock)
+        try:
+            for db in dbs:
+                db._check_open()
+                if not db._in_txn:
+                    raise UnknownError("commit without an open transaction")
+        except BaseException:
+            for db in dbs:
+                db.rollback()
+            raise
+        for db in dbs:
+            db._in_txn = False
+        failed = dbs[0]._lib.eh_relay_commit_shards(
+            k, _handles(dbs), n_trees.ctypes.data_as(_I64P),
+            user_arr, user_lens.ctypes.data_as(_I32P),
+            tree_arr, tree_lens.ctypes.data_as(_I32P), err, _ERR_CAP,
+        )
+    if failed >= 0:
+        raise UnknownError(
+            f"shard {failed} of {k}: " + err.value.decode("utf-8", "replace"))
 
 
 def open_database(path: str = ":memory:", backend: str = "auto"):

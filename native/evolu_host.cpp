@@ -654,6 +654,205 @@ int eh_relay_insert(sqlite3 *db, int64_t n, const char *const *timestamps,
 
 }  // extern "C"
 
+// --- shard-set calls: the storage leg of an engine pass in two C calls ---
+//
+// `BatchReconciler` lands one pass on every live shard of a
+// ShardedRelayStore: BEGIN + the packed insert + the owners' stored
+// trees per shard (eh_relay_insert_packed_shards), then, after the host
+// has folded the pass's Merkle deltas, the merkleTree upserts + COMMIT
+// per shard (eh_relay_commit_shards). Driven shard by shard from Python
+// that was ~45 ctypes calls and a thread pool a pass; here ctypes drops
+// the interpreter lock once per call. Statements and their order on
+// each handle are exactly the per-shard path's.
+//
+// The shards run one after the other on the calling thread, at every
+// size. On the chip's host, one storage pass through one std::thread
+// per shard took 4.6 to 7.7 times as long as through this loop at every
+// size from 256 to 250,000 rows a call (1,250 rows over 8 shards: 20.4
+// ms against 2.65 ms; 250,000: 3,036 ms against 633 ms; PERF.md §6, PR
+// 27), which is also what the Python thread pool this replaced had
+// cost. Should a host turn up where parallel handles win, run_shards is
+// the one place to start threads; the threading contract above
+// eh_relay_insert_packed holds either way: only the given handles and
+// caller-owned buffers are touched, no globals, no Python API.
+
+namespace {
+
+struct ShardRun {
+  bool begun = false;  // this call's BEGIN succeeded on the handle
+  std::string err;     // sqlite3_errmsg at the point of failure
+};
+
+// fn(s) for every shard s, all of them whatever fails (a failed set is
+// rolled back whole by the caller); returns the first shard whose fn
+// returned non-zero, or -1.
+template <class F>
+int64_t run_shards(int64_t k, F fn) {
+  int64_t failed = -1;
+  for (int64_t s = 0; s < k; ++s) {
+    int rc;
+    try {
+      rc = fn(s);
+    } catch (...) {  // bad_alloc must not cross the C ABI
+      rc = 1;
+    }
+    if (rc != 0 && failed < 0) failed = s;
+  }
+  return failed;
+}
+
+int fail(ShardRun &run, sqlite3 *db) {
+  if (run.err.empty()) run.err = sqlite3_errmsg(db);
+  return 1;
+}
+
+void report(const std::string &msg, char *err, int32_t err_cap) {
+  if (err_cap <= 0) return;
+  size_t n = msg.size() < size_t(err_cap - 1) ? msg.size() : size_t(err_cap - 1);
+  memcpy(err, msg.data(), n);
+  err[n] = 0;
+}
+
+}  // namespace
+
+extern "C" {
+
+// Per shard s of k, on dbs[s]: begin_sql[s], then eh_relay_insert_packed
+// with the shard's slice of the arguments, then the stored "merkleTree"
+// TEXT of each of the shard's group users, read inside the transaction.
+// The per-group and per-row arrays are FLAT over the shards in order
+// (n_groups[s] groups each; a shard's rows are the sum of its
+// group_counts); ts_packed and content_packed are one buffer per shard.
+// out_new receives the was-new flag of every row. *out_trees (free with
+// eh_free) holds, per group in flat order, an int32 byte length (-1: no
+// stored tree) followed by the text. Returns -1 with every shard's
+// transaction OPEN; on failure rolls back every transaction it began,
+// writes the failing handle's message to err, and returns the failing
+// shard's index (k: out of memory outside any shard).
+int64_t eh_relay_insert_packed_shards(
+    int64_t k, sqlite3 *const *dbs, const char *const *begin_sql,
+    const int64_t *n_groups, const char *const *group_users,
+    const int32_t *group_user_lens, const int64_t *group_counts,
+    const char *const *ts_packed, const unsigned char *const *content_packed,
+    const int32_t *content_lens, uint8_t *out_new, unsigned char **out_trees,
+    int64_t *out_trees_len, char *err, int32_t err_cap) {
+  *out_trees = nullptr;
+  *out_trees_len = 0;
+  std::vector<int64_t> group_off(k + 1, 0), row_off(k + 1, 0);
+  for (int64_t s = 0; s < k; ++s) {
+    group_off[s + 1] = group_off[s] + n_groups[s];
+    int64_t rows = 0;
+    for (int64_t g = group_off[s]; g < group_off[s + 1]; ++g) rows += group_counts[g];
+    row_off[s + 1] = row_off[s] + rows;
+  }
+  std::vector<ShardRun> runs(k);
+  std::string trees;  // framed stored trees, one per group, flat order
+  auto shard = [&](int64_t s) -> int {
+    sqlite3 *db = dbs[s];
+    ShardRun &run = runs[s];
+    if (sqlite3_exec(db, begin_sql[s], nullptr, nullptr, nullptr) != SQLITE_OK)
+      return fail(run, db);
+    run.begun = true;
+    const int64_t g0 = group_off[s];
+    if (eh_relay_insert_packed(db, n_groups[s], group_users + g0, group_counts + g0,
+                               ts_packed[s], content_packed[s],
+                               content_lens + row_off[s], out_new + row_off[s]) != 0)
+      return fail(run, db);
+    sqlite3_stmt *st = nullptr;
+    if (sqlite3_prepare_v2(db, "SELECT \"merkleTree\" FROM \"merkleTree\" WHERE \"userId\" = ?",
+                           -1, &st, nullptr) != SQLITE_OK)
+      return fail(run, db);
+    for (int64_t g = g0; g < group_off[s + 1]; ++g) {
+      sqlite3_bind_text(st, 1, group_users[g], group_user_lens[g], SQLITE_STATIC);
+      int rc = sqlite3_step(st);
+      if (rc != SQLITE_ROW && rc != SQLITE_DONE) {
+        sqlite3_finalize(st);
+        return fail(run, db);
+      }
+      const unsigned char *text = rc == SQLITE_ROW ? sqlite3_column_text(st, 0) : nullptr;
+      int32_t n = rc == SQLITE_ROW ? sqlite3_column_bytes(st, 0) : -1;
+      trees.append(reinterpret_cast<const char *>(&n), sizeof n);
+      if (n > 0) trees.append(reinterpret_cast<const char *>(text), size_t(n));
+      sqlite3_reset(st);
+    }
+    sqlite3_finalize(st);
+    return 0;
+  };
+  int64_t failed = run_shards(k, shard);
+  if (failed < 0) {
+    unsigned char *buf = static_cast<unsigned char *>(malloc(trees.size() + 1));
+    if (buf) {
+      memcpy(buf, trees.data(), trees.size());
+      *out_trees = buf;
+      *out_trees_len = int64_t(trees.size());
+      return -1;
+    }
+    failed = k;
+  }
+  for (int64_t s = 0; s < k; ++s)
+    if (runs[s].begun) sqlite3_exec(dbs[s], "ROLLBACK", nullptr, nullptr, nullptr);
+  report(failed < k ? runs[failed].err : "out of memory", err, err_cap);
+  return failed;
+}
+
+// Per shard s of k, on dbs[s] (each inside the transaction the insert
+// call opened): INSERT OR REPLACE the shard's n_trees[s] (userId,
+// merkleTree) rows, flat over the shards in order, and once EVERY
+// shard's upserts are done, COMMIT each. Returns -1, or the first
+// failing shard's index with its message in err. A failed upsert rolls
+// every shard back; a failed COMMIT rolls that shard back while the
+// others commit (the per-shard path's contract: first commit error
+// wins). Either way no handle is left inside a transaction.
+int64_t eh_relay_commit_shards(
+    int64_t k, sqlite3 *const *dbs, const int64_t *n_trees,
+    const char *const *users, const int32_t *user_lens,
+    const char *const *trees, const int32_t *tree_lens, char *err,
+    int32_t err_cap) {
+  std::vector<int64_t> off(k + 1, 0);
+  for (int64_t s = 0; s < k; ++s) off[s + 1] = off[s] + n_trees[s];
+  std::vector<ShardRun> runs(k);
+  auto upsert = [&](int64_t s) -> int {
+    if (off[s] == off[s + 1]) return 0;
+    sqlite3 *db = dbs[s];
+    sqlite3_stmt *st = nullptr;
+    if (sqlite3_prepare_v2(db,
+                           "INSERT OR REPLACE INTO \"merkleTree\" (\"userId\", \"merkleTree\") "
+                           "VALUES (?, ?)",
+                           -1, &st, nullptr) != SQLITE_OK)
+      return fail(runs[s], db);
+    for (int64_t i = off[s]; i < off[s + 1]; ++i) {
+      sqlite3_bind_text(st, 1, users[i], user_lens[i], SQLITE_STATIC);
+      sqlite3_bind_text(st, 2, trees[i], tree_lens[i], SQLITE_STATIC);
+      int rc = sqlite3_step(st);
+      sqlite3_reset(st);
+      if (rc != SQLITE_DONE) {
+        sqlite3_finalize(st);
+        return fail(runs[s], db);
+      }
+    }
+    sqlite3_finalize(st);
+    return 0;
+  };
+  int64_t failed = run_shards(k, upsert);
+  if (failed >= 0) {
+    for (int64_t s = 0; s < k; ++s)
+      sqlite3_exec(dbs[s], "ROLLBACK", nullptr, nullptr, nullptr);
+    report(runs[failed].err, err, err_cap);
+    return failed;
+  }
+  auto commit = [&](int64_t s) -> int {
+    if (sqlite3_exec(dbs[s], "COMMIT", nullptr, nullptr, nullptr) == SQLITE_OK) return 0;
+    fail(runs[s], dbs[s]);
+    sqlite3_exec(dbs[s], "ROLLBACK", nullptr, nullptr, nullptr);
+    return 1;
+  };
+  failed = run_shards(k, commit);
+  if (failed >= 0) report(runs[failed].err, err, err_cap);
+  return failed;
+}
+
+}  // extern "C"
+
 extern "C" {
 
 // --- generic bulk insert for text/blob/null rows ---
