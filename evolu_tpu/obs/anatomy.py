@@ -549,6 +549,76 @@ class stage:
         self.stop()
 
 
+class batched_stage(stage):
+    """`stage` for a hot path that closes several intervals a unit of
+    work: its clock and `evolu/<name>` annotation, but each closed
+    interval is kept in `closed` as a plain `family{stage=<name>}`
+    histogram observation, for the owner to post in ONE
+    `metrics.observe_many` when the unit ends. It skips the
+    accountant's totals, fit and gauges and the trace ring, and takes
+    the registry lock once a unit, not five times an interval."""
+
+    __slots__ = ("closed",)
+    family = "evolu_stage_ms"
+
+    def __init__(self, name: str, closed: Optional[list] = None):
+        super().__init__(name)
+        self.closed = [] if closed is None else closed
+
+    def _record(self, seconds: float) -> None:
+        self.closed.append((self.family, seconds * 1e3, {"stage": self.name}))
+
+
+_tiled = threading.local()  # .tiles: the `tiles` open on this thread
+
+
+class tiles:
+    """One command on ONE thread, tiled by stages whose seams lie in
+    other modules:
+
+        with anatomy.tiles("recv_", whole="handle", first="clock"):
+            ...                          # any depth, any module:
+            anatomy.seam("plan_host")    # recv_clock ends, recv_plan_host begins
+
+    `<prefix><whole>` brackets the block; `<prefix><first>` opens with
+    it and every `seam(name)` reached on this thread inside the block
+    is a `stage.then`: one clock read closes the running tile and opens
+    `<prefix><name>`, so the tiles never overlap and sum to the whole
+    less its first and last instants. All of them are `batched_stage`s
+    posted in one `metrics.observe_many` at the exit. Outside such a
+    block `seam` is one thread-local read, so code shared with other
+    commands (the planners a Send runs too) names its seams
+    unconditionally."""
+
+    __slots__ = ("prefix", "_whole", "_tile", "_outer")
+
+    def __init__(self, prefix: str, whole: str, first: str):
+        self.prefix = prefix
+        self._whole = batched_stage(prefix + whole)
+        self._tile = batched_stage(prefix + first, self._whole.closed)
+
+    def __enter__(self) -> "tiles":
+        self._outer = getattr(_tiled, "tiles", None)
+        _tiled.tiles = self
+        self._whole.start()
+        self._tile.start()
+        return self
+
+    def __exit__(self, *_exc) -> None:
+        self._tile.stop()
+        self._whole.stop()
+        _tiled.tiles = self._outer
+        metrics.observe_many(self._whole.closed)
+
+
+def seam(name: str) -> None:
+    """Close this thread's running tile and open `<prefix><name>`; a
+    no-op where no `tiles` block is open."""
+    open_tiles = getattr(_tiled, "tiles", None)
+    if open_tiles is not None:
+        open_tiles._tile.then(open_tiles.prefix + name)
+
+
 def record_span(target: str, ms: float, rows: object = 0) -> None:
     """Fold a kernel:* log span into the family (utils/log.py span
     close). Stage label = the span target; rows from the span's n=

@@ -38,8 +38,8 @@ import operator
 
 from evolu_tpu.core.timestamp import timestamp_from_string
 from evolu_tpu.core.types import CrdtMessage
-from evolu_tpu.obs import metrics
-from evolu_tpu.ops import bucket_size, to_host_many, with_x64
+from evolu_tpu.obs import anatomy, metrics
+from evolu_tpu.ops import bucket_size, start_host_transfer, to_host_many, with_x64
 from evolu_tpu.ops.encode import node_hex_to_u64, pack_ts_key_host
 from evolu_tpu.utils.log import span
 
@@ -689,6 +689,21 @@ def plan_packed_streamed(db, pb, millis, counter, node, cells, touched_ids):
     )
 
 
+def pull_plan_outputs(outs):
+    """Pull a plan kernel's outputs to the host in one wave. ONE copy
+    of the seams every plan route gives a tiled Receive
+    (`obs.anatomy.tiles`; no-ops in any other command): the caller's
+    `device_call` tile, opened just before its first `device_put`, ends
+    once the copies are started, `pull` is only the blocking wait for
+    them, and everything after it up to the worker's commit is `apply`
+    (mask unpermute, delta decode, the SQLite apply, the tree fold)."""
+    start_host_transfer(*outs)
+    anatomy.seam("pull")
+    pulled = to_host_many(*outs)
+    anatomy.seam("apply")
+    return pulled
+
+
 def _run_full_plan(cell_ids, k1, k2, ex_k1, ex_k2, n: int):
     """ONE copy of the full-plan dispatch sequence (pad →
     `_plan_full_kernel` → one-wave pull → unpermute → delta decode),
@@ -706,6 +721,7 @@ def _run_full_plan(cell_ids, k1, k2, ex_k1, ex_k2, n: int):
     (cell_ids, k1, k2, ex_k1, ex_k2), size = pad_columns(
         [cell_ids, k1, k2, ex_k1, ex_k2], n
     )
+    anatomy.seam("device_call")
     if table_size is not None:
         metrics.inc("evolu_merge_plan_total", path="scatter")
         outs = _plan_full_kernel_scatter(
@@ -718,7 +734,7 @@ def _run_full_plan(cell_ids, k1, k2, ex_k1, ex_k2, n: int):
             jnp.asarray(cell_ids), jnp.asarray(k1), jnp.asarray(k2),
             jnp.asarray(ex_k1), jnp.asarray(ex_k2),
         )
-    xor_s, upsert_s, i_s, minute_sorted, seg_end, seg_xor, valid = to_host_many(*outs)
+    xor_s, upsert_s, i_s, minute_sorted, seg_end, seg_xor, valid = pull_plan_outputs(outs)
     xor_mask, upsert_mask = unpermute_masks(xor_s, upsert_s, i_s)
     deltas = decode_owner_minute_deltas(
         np.zeros(size, np.int32), minute_sorted, seg_end, seg_xor, valid

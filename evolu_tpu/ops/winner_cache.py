@@ -67,10 +67,11 @@ from evolu_tpu.ops.merge import (
     _PAD_CELL,
     PlannedBatch,
     plan_merge_sorted_core,
+    pull_plan_outputs,
     select_messages,
     unpermute_masks,
 )
-from evolu_tpu.obs import metrics
+from evolu_tpu.obs import anatomy, metrics
 from evolu_tpu.ops.merkle_ops import decode_owner_minute_deltas, owner_minute_segments
 from evolu_tpu.utils.log import span
 
@@ -585,12 +586,13 @@ class DeviceWinnerCache:
         k1_p = np.concatenate([k1, np.zeros(pad, np.uint64)])
         k2_p = np.concatenate([node, np.zeros(pad, np.uint64)])
 
+        anatomy.seam("device_call")  # of a tiled Receive; a no-op elsewhere
         self._w1, self._w2, *outs = _cached_plan_kernel(
             self._w1, self._w2, jnp.asarray(slots_p),
             jnp.asarray(cell_p), jnp.asarray(k1_p), jnp.asarray(k2_p),
         )
         xor_s, upsert_s, i_s, minute_sorted, seg_end, seg_xor, valid = (
-            to_host_many(*outs)
+            pull_plan_outputs(outs)
         )
         xor_mask, upsert_mask = unpermute_masks(xor_s, upsert_s, i_s)
         deltas = decode_owner_minute_deltas(
@@ -953,13 +955,14 @@ class MeshShardedWinnerCache(DeviceWinnerCache):
         self.ctx.record_occupancy(counts.tolist(), size)
         self.ctx.record_xdev_reduce("winner_minute_partials")
         shd1 = self._sharding1()
+        anatomy.seam("device_call")
         self._w1, self._w2, *outs = _sharded_plan_kernel(self.ctx.mesh)(
             self._w1, self._w2,
             jax.device_put(slots_p, shd1), jax.device_put(cell_p, shd1),
             jax.device_put(k1_p, shd1), jax.device_put(k2_p, shd1),
         )
         xor_s, upsert_s, i_s, minute_sorted, seg_end, seg_xor, valid = (
-            to_host_many(*outs)
+            pull_plan_outputs(outs)
         )
         xor_flat, upsert_flat = unpermute_masks(
             xor_s, upsert_s, i_s, block_size=size

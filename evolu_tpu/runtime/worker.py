@@ -33,7 +33,7 @@ from evolu_tpu.core.timestamp import (
     timestamp_to_string,
 )
 from evolu_tpu.core.types import CrdtClock, CrdtMessage, Owner, SyncError
-from evolu_tpu.obs import flight, metrics, trace
+from evolu_tpu.obs import anatomy, flight, metrics, trace
 from evolu_tpu.runtime import messages as msg
 from evolu_tpu.runtime.jsonpatch import create_patch
 from evolu_tpu.runtime.synclock import SyncLock, get_sync_lock
@@ -325,7 +325,19 @@ class DbWorker:
 
     def handle(self, command: object) -> None:
         """Dispatch one command inside one transaction; errors roll back
-        and surface as OnError (db.worker.ts:57-73)."""
+        and surface as OnError (db.worker.ts:57-73). A Receive is tiled
+        into `evolu_stage_ms{stage=recv_*}` (docs/OBSERVABILITY.md):
+        `recv_clock` opens here, the seams to `recv_plan_host` and
+        `recv_commit` are in `_receive`, and those of the device call,
+        the pull and the apply are in the planners (`ops/winner_cache.py`,
+        `ops/merge.py`)."""
+        if isinstance(command, msg.Receive):
+            with anatomy.tiles("recv_", whole="handle", first="clock"):
+                self._handle(command)
+        else:
+            self._handle(command)
+
+    def _handle(self, command: object) -> None:
         t0 = time.perf_counter()
         self._staged_effects = []
         self._staged_cache: Dict[str, List[dict]] = {}
@@ -647,6 +659,7 @@ class DbWorker:
                     t = receive_timestamp(
                         t, timestamp_from_string(s), now, self.config.max_drift
                     )
+            anatomy.seam("plan_host")
             messages = command.messages if packed else list(command.messages)
             deferred: List[CrdtMessage] = []
             scope = getattr(self.config, "sync_scope", None)
@@ -695,6 +708,7 @@ class DbWorker:
                 )
                 # persist() already wrote the final clock with this tree
                 # and staged the OnReceive.
+                anatomy.seam("commit")
                 if deferred:
                     tree = self._apply_deferred(tree, deferred)
                     update_clock(self.db, CrdtClock(t, tree))
@@ -704,6 +718,7 @@ class DbWorker:
                     self.db, clock.merkle_tree, messages,
                     planner=self._planner, changes=self._staged_changes_or_none(),
                 )
+                anatomy.seam("commit")
                 if deferred:
                     tree = self._apply_deferred(tree, deferred)
                 clock = CrdtClock(t, tree)

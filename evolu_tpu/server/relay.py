@@ -632,25 +632,17 @@ def capture_live_profile(duration_ms: float) -> dict:
             "metadata": meta}
 
 
-class _round_leg(anatomy.stage):
-    """One handler-thread leg of the served round: the stage
-    primitive's clock and `evolu/<name>` profiler annotation, kept as a
-    plain `evolu_relay_stage_ms{stage=…}` observation in `closed` for
-    the handler to post in ONE `metrics.observe_many` at the end of the
+class _round_leg(anatomy.batched_stage):
+    """One handler-thread leg of the served round, kept as a plain
+    `evolu_relay_stage_ms{stage=…}` observation in `closed` for the
+    handler to post in ONE `metrics.observe_many` at the end of the
     round — these fire per request on 25 threads, so they skip the
     stage accountant's fit and gauges (and the trace ring, which
     already holds relay.sync/relay.respond) and take the registry lock
     once a round."""
 
-    __slots__ = ("closed",)
-
-    def __init__(self, name: str):
-        super().__init__(name)
-        self.closed = []
-
-    def _record(self, seconds: float) -> None:
-        self.closed.append(
-            ("evolu_relay_stage_ms", seconds * 1e3, {"stage": self.name}))
+    __slots__ = ()
+    family = "evolu_relay_stage_ms"
 
 
 class _Handler(BaseHTTPRequestHandler):

@@ -30,6 +30,7 @@ from array import array
 from typing import List, Optional, Sequence, Tuple
 
 from evolu_tpu.core.types import CrdtMessage
+from evolu_tpu.obs import anatomy
 from evolu_tpu.sync import protocol
 from evolu_tpu.sync.aead import decrypt_content
 from evolu_tpu.utils.native_loader import load_native_library
@@ -538,25 +539,30 @@ def decrypt_response_columns(response_bytes: bytes, password: str):
     lib = load_library()
     if lib is None:
         return None
-    pw = password.encode("utf-8")
-    out_p = ctypes.c_void_p()
-    out_len = ctypes.c_int64()
-    rc = lib.ehc_decrypt_response_columns(
-        response_bytes, len(response_bytes), pw, len(pw),
-        ctypes.byref(out_p), ctypes.byref(out_len),
-    )
-    if rc != 0:
-        return None
-    try:
-        raw = ctypes.string_at(out_p.value, out_len.value)
-    finally:
-        lib.ehc_free(out_p)
     from evolu_tpu.core.packed import PackedReceive
 
-    try:
-        return PackedReceive.from_blob(raw)
-    except UnicodeDecodeError:  # defense in depth: C validated UTF-8
-        return None
+    # `recv_decrypt`, on the caller's thread: wire bytes to the
+    # PackedReceive; its rows are the messages of a decoded response.
+    with anatomy.stage("recv_decrypt") as decrypt:
+        pw = password.encode("utf-8")
+        out_p = ctypes.c_void_p()
+        out_len = ctypes.c_int64()
+        rc = lib.ehc_decrypt_response_columns(
+            response_bytes, len(response_bytes), pw, len(pw),
+            ctypes.byref(out_p), ctypes.byref(out_len),
+        )
+        if rc != 0:
+            return None
+        try:
+            raw = ctypes.string_at(out_p.value, out_len.value)
+        finally:
+            lib.ehc_free(out_p)
+        try:
+            out = PackedReceive.from_blob(raw)
+        except UnicodeDecodeError:  # defense in depth: C validated UTF-8
+            return None
+        decrypt.rows = len(out[0])
+        return out
 
 
 def _pure_one(m, password: str) -> CrdtMessage:

@@ -1,0 +1,239 @@
+"""A client's `Receive`, tiled into named host stages (ISSUE 29):
+`obs.anatomy.tiles` in `DbWorker.handle` and `anatomy.seam` in
+`_receive` and the planners (`ops/winner_cache.py`, `ops/merge.py`), and
+`recv_decrypt` around `decrypt_response_columns` on the caller's thread.
+
+What is pinned: every `Receive` records each of `recv_clock`,
+`recv_plan_host`, `recv_device_call`, `recv_pull`, `recv_apply`,
+`recv_commit` and `recv_handle` once, on the worker's thread, on the
+streamed route and on the cached one; the six never overlap and sum to
+`recv_handle` within 5 %; the seven are posted in ONE acquisition of the
+registry's lock; with annotations on each is one `evolu/<name>` profiler
+annotation and with them off none is constructed; a Send, which runs
+the same planner, records none of them; and none of it changes a byte
+of the end state.
+"""
+
+import itertools
+import threading
+
+import pytest
+
+import evolu_tpu.utils.log as log_mod
+from evolu_tpu.core.types import TableDefinition
+from evolu_tpu.obs import anatomy, metrics
+from evolu_tpu.runtime import messages as rmsg
+from evolu_tpu.runtime.worker import DbWorker
+from evolu_tpu.storage import native
+from evolu_tpu.sync import native_crypto
+from evolu_tpu.utils.config import Config
+from perf import gen_client, load_module
+
+driver = load_module("drivers", "client")
+
+pytestmark = pytest.mark.skipif(
+    not (native.native_available() and native_crypto.native_available()),
+    reason="the packed receive needs both native libraries")
+
+TILES = ("recv_clock", "recv_plan_host", "recv_device_call", "recv_pull",
+         "recv_apply", "recv_commit")
+ON_WORKER = TILES + ("recv_handle",)
+NOW = 1_700_010_000_000
+
+
+@pytest.fixture(scope="module")
+def wires():
+    # 4 x 1,000 messages over <= 250 cells: streamed, streamed, seeded, hit.
+    messages = gen_client.build_messages(4000, 29, 50, 8)
+    return gen_client.build_responses(messages, 4, gen_client.MNEMONIC)
+
+
+class Device:
+    """One restoring device: a fresh database and worker."""
+
+    def __init__(self):
+        self.outputs = []
+        self.db = native.open_database(backend="native")
+        self.worker = DbWorker(self.db, Config(backend="tpu"),
+                               on_output=self.outputs.append,
+                               now=itertools.count(NOW, 1000).__next__)
+        self.worker.start(gen_client.MNEMONIC)
+        self.worker.post(rmsg.UpdateDbSchema(tuple(
+            TableDefinition.of(t, cols) for t, cols in gen_client.TABLES)))
+        self.worker.flush()
+
+    def receive(self, wire) -> None:
+        packed, tree = native_crypto.decrypt_response_columns(wire, gen_client.MNEMONIC)
+        self.worker.post(rmsg.Receive(packed, tree, None))
+        self.worker.flush()
+
+    def restore(self, wires) -> dict:
+        for wire in wires:
+            self.receive(wire)
+        assert not [o for o in self.outputs if isinstance(o, rmsg.OnError)]
+        return driver.dump(self.db)
+
+    def close(self) -> None:
+        self.worker.stop()
+        self.db.close()
+
+
+@pytest.fixture
+def device(wires):
+    Device().restore(wires)  # compile every program outside every reading
+    dev = Device()
+    yield dev
+    dev.close()
+
+
+def _hist(stage: str):
+    h = metrics.registry.get_histogram("evolu_stage_ms", stage=stage)
+    return (h[2], h[3]) if h else (0.0, 0)  # (sum of ms, count)
+
+
+def test_each_stage_once_a_receive_and_the_six_tile_the_handle(device, wires):
+    streamed0 = metrics.get_counter("evolu_winner_cache_streamed_cells_total")
+    hits0 = metrics.get_counter("evolu_winner_cache_hits_total")
+    for k, wire in enumerate(wires):
+        before = {s: _hist(s) for s in ON_WORKER + ("recv_decrypt",)}
+        rows0 = metrics.get_counter("evolu_stage_rows_total", stage="recv_decrypt")
+        device.receive(wire)
+        ms = {}
+        for s, (sum0, count0) in before.items():
+            sum1, count1 = _hist(s)
+            assert count1 - count0 == 1, (k, s)
+            ms[s] = sum1 - sum0
+        assert metrics.get_counter("evolu_stage_rows_total", stage="recv_decrypt") \
+            - rows0 == 1000
+        tiled = sum(ms[s] for s in TILES)
+        assert tiled <= ms["recv_handle"], (k, ms)  # no overlap
+        assert tiled >= 0.95 * ms["recv_handle"], (k, ms)
+        assert all(ms[s] > 0 for s in ON_WORKER)
+    # Both plan routes were walked: winners streamed from SQLite, then from HBM.
+    assert metrics.get_counter("evolu_winner_cache_streamed_cells_total") > streamed0
+    assert metrics.get_counter("evolu_winner_cache_hits_total") > hits0
+
+
+def test_one_registry_acquisition_a_receive(device, wires, monkeypatch):
+    """The seven worker-thread stages go to the registry in one
+    `observe_many`; no other write names a `recv_*` stage there."""
+    calls = []  # (writer, thread, stages named)
+    real = {w: getattr(metrics, w) for w in ("observe_many", "observe", "inc")}
+
+    def spy_many(items):
+        items = list(items)
+        calls.append(("observe_many", threading.get_ident(),
+                      [labels.get("stage") for _f, _v, labels in items]))
+        real["observe_many"](items)
+
+    def spy(writer):
+        def write(name, *args, **labels):
+            if str(labels.get("stage", "")).startswith("recv_"):
+                calls.append((writer, threading.get_ident(), [labels["stage"]]))
+            real[writer](name, *args, **labels)
+        return write
+
+    monkeypatch.setattr(metrics, "observe_many", spy_many)
+    monkeypatch.setattr(metrics, "observe", spy("observe"))
+    monkeypatch.setattr(metrics, "inc", spy("inc"))
+    for wire in wires:
+        del calls[:]
+        device.receive(wire)
+        worker = device.worker._thread.ident
+        on_worker = [c for c in calls if c[1] == worker]
+        assert [c[0] for c in on_worker] == ["observe_many"], on_worker
+        assert sorted(on_worker[0][2]) == sorted(ON_WORKER)
+        decrypt = [c for c in calls if c[1] != worker]
+        assert decrypt and all(c[2] == ["recv_decrypt"] for c in decrypt)
+        assert all(c[1] == threading.get_ident() for c in decrypt)
+
+
+def test_every_stage_is_one_annotation_on_its_thread(device, wires):
+    events, built = [], []
+
+    class Recording:
+        def __init__(self, name):
+            built.append(name)
+            self.name = name
+
+        def __enter__(self):
+            events.append(("open", self.name, threading.get_ident()))
+            return self
+
+        def __exit__(self, *exc):
+            events.append(("close", self.name, threading.get_ident()))
+
+    orig = log_mod._trace_annotation_cls
+    log_mod._trace_annotation_cls = Recording
+    try:
+        for wire in wires:
+            device.receive(wire)
+    finally:
+        log_mod._trace_annotation_cls = orig
+    worker, caller = device.worker._thread.ident, threading.get_ident()
+    by_name = {}
+    for kind, name, tid in events:
+        by_name.setdefault(name, {"open": [], "close": []})[kind].append(tid)
+    for s in ON_WORKER:
+        rec = by_name["evolu/" + s]
+        assert rec["open"] == rec["close"] == [worker] * len(wires), s
+    rec = by_name["evolu/recv_decrypt"]
+    assert rec["open"] == rec["close"] == [caller] * len(wires)
+    # The tiles in order inside the handle, one open at a time; the pull
+    # wave lies inside recv_pull.
+    order = [(kind, name[len("evolu/"):]) for kind, name, tid in events
+             if tid == worker and name.startswith("evolu/")]
+    one = [("open", "recv_handle")]
+    for s in TILES:
+        one += [("open", s)] + ([("open", "pull_wave"), ("close", "pull_wave")]
+                                if s == "recv_pull" else []) + [("close", s)]
+    one += [("close", "recv_handle")]
+    assert order == one * len(wires)
+    # Annotations off: the stand-in (or any class) is never constructed.
+    count = len(built)
+    device.receive(wires[0])
+    assert len(built) == count
+
+
+def test_a_send_runs_the_planner_and_records_no_recv_stage(device):
+    before = {s: _hist(s)[1] for s in ON_WORKER}
+    device.worker.post(rmsg.Send(tuple(
+        rmsg.NewCrdtMessage("todo", f"row{i}", "title", f"mine {i}") for i in range(64))))
+    device.worker.flush()
+    assert not [o for o in device.outputs if isinstance(o, rmsg.OnError)]
+    assert {s: _hist(s)[1] for s in ON_WORKER} == before
+    anatomy.seam("plan_host")  # outside a tiled command: nothing, no error
+
+
+def test_end_state_identical_with_metrics_disabled(wires):
+    def restored(enabled: bool) -> dict:
+        metrics.set_enabled(enabled)
+        dev = Device()
+        try:
+            return dev.restore(wires)
+        finally:
+            dev.close()
+            metrics.set_enabled(True)
+
+    counts = {s: _hist(s)[1] for s in ON_WORKER}
+    off = restored(False)
+    assert {s: _hist(s)[1] for s in ON_WORKER} == counts  # nothing recorded
+    on = restored(True)
+    assert on == off and len(on["__message"]) == 4000
+    assert {s: _hist(s)[1] - counts[s] for s in ON_WORKER} == dict.fromkeys(ON_WORKER, 4)
+
+
+def test_perf_selfcheck_reads_every_client_layer_file():
+    import os
+    import subprocess
+    import sys
+
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    env = {k: v for k, v in os.environ.items() if k != "JAX_PLATFORMS"}
+    done = subprocess.run([sys.executable, os.path.join(root, "perf", "selfcheck.py")],
+                          capture_output=True, text=True, timeout=300, env=env)
+    assert done.returncode == 0, done.stderr[-2000:]
+    listed = set(os.listdir(os.path.join(root, "perf", "layers")))
+    assert {f"{s}_ms.json" for s in ON_WORKER} | {
+        "decrypt_us_msg.json", "winner_cache_hit_share.json",
+        "window_compiles.client.json"} <= listed
