@@ -153,9 +153,24 @@ class MetricsRegistry:
             return
         key = _label_key(labels)
         with self._lock:
-            fam = self._counters.setdefault(name, {})
-            key = self._admit(fam, name, key)
-            fam[key] = fam.get(key, 0) + value
+            self._inc_locked(name, value, key)
+
+    def inc_many(self, items) -> None:
+        """`inc(name, value, **labels)` for each (name, value,
+        labels-dict) of `items`, under ONE acquisition of the registry
+        lock: `observe_many`'s reason, for counters that one event
+        moves together (a storage call and what it reserved)."""
+        if not self.enabled:
+            return
+        keyed = [(name, value, _label_key(labels)) for name, value, labels in items if value]
+        with self._lock:
+            for name, value, key in keyed:
+                self._inc_locked(name, value, key)
+
+    def _inc_locked(self, name, value, key) -> None:
+        fam = self._counters.setdefault(name, {})
+        key = self._admit(fam, name, key)
+        fam[key] = fam.get(key, 0) + value
 
     def set_gauge(self, name: str, value: float, **labels) -> None:
         if not self.enabled:
@@ -452,6 +467,7 @@ def _bisect(edges: Sequence[float], value: float) -> int:
 registry = MetricsRegistry()
 
 inc = registry.inc
+inc_many = registry.inc_many
 observe = registry.observe
 observe_many = registry.observe_many
 set_gauge = registry.set_gauge

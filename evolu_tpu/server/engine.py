@@ -769,8 +769,9 @@ class BatchReconciler:
         outrun their tree; an exception anywhere, in the body too,
         rolls back EVERY live shard before it propagates."""
         dbs = [stores[si].db for si in live]
-        flags, stored = relay_insert_packed_shards(dbs, batches)
-        metrics.inc("evolu_engine_store_calls_total", op="insert")
+        flags, stored = relay_insert_packed_shards(
+            dbs, batches,
+            also_count=(("evolu_engine_store_calls_total", 1, {"op": "insert"}),))
         tree_rows: List[List[Tuple[str, str]]] = [[] for _ in stores]
         try:
             yield dict(zip(live, flags)), stored, tree_rows

@@ -27,6 +27,7 @@ from contextlib import ExitStack, contextmanager
 from typing import Iterable, List, Optional, Sequence, Tuple
 
 from evolu_tpu.core.types import NonCanonicalStoreError, UnknownError
+from evolu_tpu.obs import metrics
 from evolu_tpu.utils.native_loader import load_native_library
 
 _SQLITE_ROW = 100
@@ -74,29 +75,34 @@ def _configure(lib: ctypes.CDLL) -> ctypes.CDLL:
     lib.eh_column_blob.argtypes = [p, i]
     lib.eh_column_bytes.argtypes = [p, i]
     lib.eh_fetch_winners.argtypes = [p, i64, sp, sp, sp, c.c_char_p, i64]
-    lib.eh_apply_sequential.argtypes = [p, i64, sp, sp, sp, sp, i32p, i64p, dp, sp, i32p, u8p]
+    # The batched calls end with `out_reserved` (int64*): what the
+    # call's heap reservation held (native/evolu_host.cpp, reserve_heap).
+    lib.eh_apply_sequential.argtypes = [
+        p, i64, sp, sp, sp, sp, i32p, i64p, dp, sp, i32p, u8p, i64p]
     lib.eh_apply_planned_packed.argtypes = [
-        p, i64, s, i32p, s, i32p, s, i32p, s, i32p, i32p, i64p, dp, s, i32p, u8p,
+        p, i64, s, i32p, s, i32p, s, i32p, s, i32p, i32p, i64p, dp, s, i32p, u8p, i64p,
     ]
     lib.eh_apply_planned_cells.argtypes = [
-        p, i64, s, i64, s, i32p, i32p, u8p, i64p, dp, s, i32p, u8p,
+        p, i64, s, i64, s, i32p, i32p, u8p, i64p, dp, s, i32p, u8p, i64p,
     ]
-    lib.eh_relay_insert.argtypes = [p, i64, sp, sp, sp, i32p, u8p]
-    lib.eh_relay_insert_packed.argtypes = [p, i64, sp, i64p, s, s, i32p, u8p]
+    lib.eh_relay_insert.argtypes = [p, i64, sp, sp, sp, i32p, u8p, i64p]
+    lib.eh_relay_insert_packed.argtypes = [p, i64, sp, i64p, s, s, i32p, u8p, i64p]
     pp = c.POINTER(p)
     lib.eh_relay_insert_packed_shards.restype = i64
     lib.eh_relay_insert_packed_shards.argtypes = [
-        i64, pp, sp, i64p, sp, i32p, i64p, sp, sp, i32p, u8p, pp, i64p, s, c.c_int32,
+        i64, pp, sp, i64p, sp, i32p, i64p, sp, sp, i32p, u8p, pp, i64p, s, c.c_int32, i64p,
     ]
     lib.eh_relay_commit_shards.restype = i64
     lib.eh_relay_commit_shards.argtypes = [i64, pp, i64p, sp, i32p, sp, i32p, s, c.c_int32]
     lib.eh_parse_timestamps.argtypes = [s, i64, i64p, i32p, c.POINTER(c.c_uint64), u8p]
-    lib.eh_run_many_tb.argtypes = [p, s, i64, c.c_int32, sp, i32p, i32p]
+    lib.eh_run_many_tb.argtypes = [p, s, i64, c.c_int32, sp, i32p, i32p, i64p]
     lib.eh_get_messages.argtypes = [
         p, s, c.c_int32, s, s, c.c_int32,
         c.POINTER(c.c_char_p), c.POINTER(p), c.POINTER(i32p), c.POINTER(i64),
     ]
     lib.eh_free.argtypes = [p]
+    lib.eh_reserve_heap.restype = i64
+    lib.eh_reserve_heap.argtypes = [i64, i64]
     lib.eh_exec_packed.argtypes = [p, c.POINTER(p), i64p, i64p, c.POINTER(i64p)]
     lib.eh_get_messages_wire.argtypes = [
         p, s, c.c_int32, s, s, c.c_int32, c.POINTER(p), i64p, i64p,
@@ -278,6 +284,21 @@ def _columnar_values(values) -> Tuple:
         k, iv, dv, sv, bl = _encode_value(v)
         kinds[j], ivals[j], dvals[j], svals[j], blens[j] = k, iv, dv, sv, bl
     return kinds, ivals, dvals, svals, blens
+
+
+def _count_reserved(reserved: ctypes.c_int64, also=()) -> None:
+    """Post what one native batch call's heap reservation held
+    (`reserve_heap` in native/evolu_host.cpp, through the call's
+    `out_reserved`): the bytes and one call, only where it held any, so
+    a batch under one block (a client's own mutation) writes nothing.
+    `also` are counter items of the caller's that ride the same
+    acquisition of the registry's lock, whatever was reserved."""
+    items = list(also)
+    if reserved.value:
+        items.append(("evolu_native_heap_reserved_bytes_total", reserved.value, {}))
+        items.append(("evolu_native_heap_reserve_calls_total", 1, {}))
+    if items:
+        metrics.inc_many(items)
 
 
 def _str_array(items: Sequence[str]):
@@ -521,15 +542,19 @@ class CppSqliteDatabase:
                     b = v.encode("utf-8")
                     vals[i], lens[i], kinds[i] = b, len(b), 3
                 i += 1
+        reserved = ctypes.c_int64()
         with self._lock:
             self._check_open()
             before = lib.eh_total_changes(self._db)
             rc = lib.eh_run_many_tb(
-                self._db, sql.encode("utf-8"), nrows, ncols, vals, lens, kinds
+                self._db, sql.encode("utf-8"), nrows, ncols, vals, lens, kinds,
+                ctypes.byref(reserved),
             )
             if rc != 0:
                 raise self._err()
-            return lib.eh_total_changes(self._db) - before
+            changed = lib.eh_total_changes(self._db) - before
+        _count_reserved(reserved)
+        return changed
 
     def changes(self) -> int:
         with self._lock:
@@ -640,6 +665,7 @@ class CppSqliteDatabase:
             return []
         kinds, ivals, dvals, svals, blens = _columnar_values([m.value for m in messages])
         out = (ctypes.c_uint8 * n)()
+        reserved = ctypes.c_int64()
         with self._lock:
             self._check_open()
             rc = self._lib.eh_apply_sequential(
@@ -648,8 +674,9 @@ class CppSqliteDatabase:
                 _str_array([m.table for m in messages]),
                 _str_array([m.row for m in messages]),
                 _str_array([m.column for m in messages]),
-                kinds, ivals, dvals, svals, blens, out,
+                kinds, ivals, dvals, svals, blens, out, ctypes.byref(reserved),
             )
+        _count_reserved(reserved)
         if rc != 0:
             raise self._err()
         return [bool(x) for x in out]
@@ -686,6 +713,7 @@ class CppSqliteDatabase:
         mask_np = np.ascontiguousarray(np.asarray(upsert_mask, dtype=np.uint8))
         if len(mask_np) != n:  # C reads n bytes; a short buffer would be OOB
             raise ValueError(f"upsert_mask length {len(mask_np)} != messages {n}")
+        reserved = ctypes.c_int64()
         with self._lock:
             self._check_open()
             rc = self._lib.eh_apply_planned_packed(
@@ -697,7 +725,9 @@ class CppSqliteDatabase:
                 dvals.ctypes.data_as(ctypes.POINTER(ctypes.c_double)),
                 val_buf, vlens.ctypes.data_as(i32p),
                 mask_np.ctypes.data_as(ctypes.POINTER(ctypes.c_uint8)),
+                ctypes.byref(reserved),
             )
+        _count_reserved(reserved)
         if rc == 3:
             raise UnknownError("identifier contains NUL")
         if rc != 0:
@@ -728,6 +758,7 @@ class CppSqliteDatabase:
         mask_np = np.ascontiguousarray(np.asarray(upsert_mask, dtype=np.uint8))
         if len(mask_np) != n:  # C reads n bytes; a short buffer would be OOB
             raise ValueError(f"upsert_mask length {len(mask_np)} != rows {n}")
+        reserved = ctypes.c_int64()
         with self._lock:
             self._check_open()
             rc = self._lib.eh_apply_planned_cells(
@@ -738,8 +769,9 @@ class CppSqliteDatabase:
                 ivals.ctypes.data_as(ctypes.POINTER(ctypes.c_int64)),
                 dvals.ctypes.data_as(ctypes.POINTER(ctypes.c_double)),
                 vblob, vlens.ctypes.data_as(i32p),
-                mask_np.ctypes.data_as(u8p),
+                mask_np.ctypes.data_as(u8p), ctypes.byref(reserved),
             )
+        _count_reserved(reserved)
         if rc == 3:
             raise UnknownError("identifier contains NUL")
         if rc == 2:
@@ -880,6 +912,7 @@ class CppSqliteDatabase:
             raise UnknownError("relay_insert_packed: content buffer size mismatch")
         counts = np.ascontiguousarray(group_counts, dtype=np.int64)
         out = (ctypes.c_uint8 * n)()
+        reserved = ctypes.c_int64()
         with self._lock:
             self._check_open()
             rc = self._lib.eh_relay_insert_packed(
@@ -888,8 +921,9 @@ class CppSqliteDatabase:
                 counts.ctypes.data_as(ctypes.POINTER(ctypes.c_int64)),
                 ts_packed, content_packed,
                 lens.ctypes.data_as(ctypes.POINTER(ctypes.c_int32)),
-                out,
+                out, ctypes.byref(reserved),
             )
+        _count_reserved(reserved)
         if rc != 0:
             raise self._err()
         return np.frombuffer(out, np.uint8).astype(bool)
@@ -906,14 +940,16 @@ class CppSqliteDatabase:
             contents[j] = content
             lens[j] = len(content)
         out = (ctypes.c_uint8 * n)()
+        reserved = ctypes.c_int64()
         with self._lock:
             self._check_open()
             rc = self._lib.eh_relay_insert(
                 self._db, n,
                 _str_array([r[0] for r in rows]),
                 _str_array([r[1] for r in rows]),
-                contents, lens, out,
+                contents, lens, out, ctypes.byref(reserved),
             )
+        _count_reserved(reserved)
         if rc != 0:
             raise self._err()
         return [bool(x) for x in out]
@@ -948,11 +984,15 @@ def _handles(dbs):
     return (ctypes.c_void_p * len(dbs))(*[db._db for db in dbs])
 
 
-def relay_insert_packed_shards(dbs, batches):
+def relay_insert_packed_shards(dbs, batches, also_count=()):
     """BEGIN + `relay_insert_packed` + the stored trees of the group
-    users, on every shard, in ONE native call. `batches[i]` is the
+    users, on every shard, in ONE native call (which first reserves the
+    calling thread's heap once for all of them). `batches[i]` is the
     argument tuple of `dbs[i].relay_insert_packed`: (group_users,
-    group_counts, ts_packed, content_packed, content_lens). →
+    group_counts, ts_packed, content_packed, content_lens).
+    `also_count`: counter items of the caller's, posted with the
+    reservation's in one acquisition of the registry's lock once the
+    call has succeeded. →
     (per-shard was-new bool arrays, {owner: stored merkleTree TEXT} over
     all shards, "{}" for an owner with no stored tree). Every shard is
     left INSIDE its transaction; finish with `relay_commit_shards` or
@@ -983,6 +1023,7 @@ def relay_insert_packed_shards(dbs, batches):
     out_trees = ctypes.c_void_p()
     out_trees_len = ctypes.c_int64()
     err = ctypes.create_string_buffer(_ERR_CAP)
+    reserved = ctypes.c_int64()
     with ExitStack() as locks:
         for db in dbs:
             locks.enter_context(db._lock)
@@ -999,12 +1040,15 @@ def relay_insert_packed_shards(dbs, batches):
             lens_np.ctypes.data_as(_I32P),
             out_new.ctypes.data_as(ctypes.POINTER(ctypes.c_uint8)),
             ctypes.byref(out_trees), ctypes.byref(out_trees_len), err, _ERR_CAP,
+            ctypes.byref(reserved),
         )
         if failed >= 0:  # the C side rolled back whatever it began
+            _count_reserved(reserved)
             raise UnknownError(
                 f"shard {failed} of {k}: " + err.value.decode("utf-8", "replace"))
         for db in dbs:
             db._in_txn = True
+    _count_reserved(reserved, also_count)
     try:
         raw = ctypes.string_at(out_trees.value, out_trees_len.value)
     finally:

@@ -174,9 +174,97 @@ int step_done(sqlite3_stmt *st) {
   return rc == SQLITE_DONE || rc == SQLITE_ROW ? SQLITE_OK : rc;
 }
 
+// --- heap reservation per native batch ---
+//
+// glibc gives every thread but the main one an arena whose heaps are
+// 64 MiB mappings (HEAP_MAX_SIZE), PROT_NONE beyond what has been used,
+// and `sysmalloc` makes them writable through `grow_heap` by exactly
+// what the failing request lacks: no top pad applies there, so a batch
+// that stores pages of 4 KiB and journal chunks of 1 KiB pays one
+// `mprotect` for every page of heap it gains. Under the chip machine's
+// sandboxed kernel that call costs 112-136 us whatever it covers (3 us
+// in the CPU sandbox), and four fifths of a restoring client's
+// `eh_apply_planned_cells` was that (PERF.md §6, PR 29 and PR 30).
+// `grow_heap` calls `mprotect` only beyond the heap's high-water mark
+// (`mprotect_size`), and `shrink_heap` gives pages back with `madvise`
+// and leaves the mark where it was. So before a batch steps its first
+// statement, reserve_heap takes blocks under the default mmap threshold
+// (128 KiB) from malloc until they cover what the batch is about to
+// store, one `mprotect` a block, and frees them again: the pages the
+// statements then ask for lie under the mark.
+//
+// The blocks are freed in the order they were taken. Each but the last
+// has a live block between it and the top of the heap, so it only
+// coalesces with the ones freed before it; the last joins the top, and
+// the allocator trims once (`heap_trim`: one `madvise`), not once a
+// block. Nothing is kept: no block outlives the call, the blocks are
+// never written to, and what the trim leaves writable is address space
+// whose pages the kernel has taken back. On an allocator that is not
+// glibc's, and in glibc's main arena (`brk`, which already grows by a
+// pad), this is a few hundred malloc/free pairs and no gain.
+//
+// The estimate is the batch's own input bytes times a constant measured
+// once (CHANGES.md, PR 30: `sqlite3_memory_used()` and the heap's
+// extent in /proc/self/maps over one call). A reservation that is too
+// large costs one `mprotect` a block once, since the mark stays for the
+// next batch on the thread; one that is too small leaves the remainder
+// at one a page. Nothing here touches SQLite, and nothing is global:
+// the threading contract above eh_relay_insert_packed holds.
+
+// A block is three quarters of the default M_MMAP_THRESHOLD (128 KiB; at
+// or above it malloc maps the block by itself and the heap gains
+// nothing); the call's cost does not grow with it, so fewer and larger
+// is cheaper. The cap is the whole blocks in half of one heap.
+constexpr int64_t kReserveBlock = 96 * 1024;
+constexpr int64_t kReserveCap = 32 * 1024 * 1024 / kReserveBlock * kReserveBlock;
+// Heap bytes a stored input byte asks for inside one transaction:
+// `__message` is a row, its primary key's index and the covering index
+// (3.3 bytes of pages a byte) plus the in-memory journal of every page
+// the batch rewrites (up to 3.8 more once the table holds 75,000 rows).
+constexpr int64_t kHeapPerByteIndexed = 8;
+// One B-tree and no second copy of the row: the relay's `message`
+// (WITHOUT ROWID), a temp table, an app table.
+constexpr int64_t kHeapPerByteRow = 4;
+
+// Makes `input_bytes * per_byte` of the calling thread's heap writable
+// (at most kReserveCap, whole blocks only: under one block, nothing);
+// returns the bytes it held, all freed again. Every batched entry point
+// ends its argument list with a nullable `out_reserved` that receives
+// them too.
+int64_t reserve_heap(int64_t input_bytes, int64_t per_byte, int64_t *out_reserved = nullptr) {
+  if (out_reserved) *out_reserved = 0;
+  if (input_bytes <= 0) return 0;
+  int64_t want = input_bytes > kReserveCap / per_byte ? kReserveCap : input_bytes * per_byte;
+  size_t blocks = size_t(want / kReserveBlock), got = 0;
+  void *held[kReserveCap / kReserveBlock];
+  while (got < blocks && (held[got] = malloc(size_t(kReserveBlock))) != nullptr) ++got;
+  for (size_t i = 0; i < got; ++i) free(held[i]);
+  int64_t reserved = int64_t(got) * kReserveBlock;
+  if (out_reserved) *out_reserved = reserved;
+  return reserved;
+}
+
+int64_t text_bytes(const char *const *items, int64_t n) {
+  int64_t total = 0;
+  for (int64_t i = 0; i < n; ++i) total += int64_t(strlen(items[i]));
+  return total;
+}
+
+template <class T>
+int64_t sum_of(const T *items, int64_t n) {
+  int64_t total = 0;
+  for (int64_t i = 0; i < n; ++i) total += items[i];
+  return total;
+}
+
 }  // namespace
 
 extern "C" {
+
+// reserve_heap itself, for the tests.
+int64_t eh_reserve_heap(int64_t input_bytes, int64_t per_byte) {
+  return per_byte > 0 ? reserve_heap(input_bytes, per_byte) : 0;
+}
 
 sqlite3 *eh_open(const char *path) {
   sqlite3 *db = nullptr;
@@ -309,7 +397,10 @@ int eh_apply_sequential(sqlite3 *db, int64_t n, const char *const *timestamps,
                         const char *const *cols, const int32_t *kinds,
                         const int64_t *ivals, const double *dvals,
                         const char *const *svals, const int32_t *blob_lens,
-                        uint8_t *out_xor) {
+                        uint8_t *out_xor, int64_t *out_reserved) {
+  reserve_heap(text_bytes(timestamps, n) + text_bytes(tables, n) + text_bytes(rows, n) +
+                   text_bytes(cols, n) + sum_of(blob_lens, n),
+               kHeapPerByteIndexed, out_reserved);
   StmtCache cache(db);
   sqlite3_stmt *sel = cache.get(kSelectWinner);
   sqlite3_stmt *ins = cache.get(kInsertMessage);
@@ -377,7 +468,10 @@ int eh_apply_planned_packed(sqlite3 *db, int64_t n,
                             const int32_t *kinds, const int64_t *ivals,
                             const double *dvals, const char *val_buf,
                             const int32_t *val_lens,
-                            const uint8_t *upsert_mask) {
+                            const uint8_t *upsert_mask, int64_t *out_reserved) {
+  reserve_heap(sum_of(ts_lens, n) + sum_of(tbl_lens, n) + sum_of(row_lens, n) +
+                   sum_of(col_lens, n) + sum_of(val_lens, n),
+               kHeapPerByteIndexed, out_reserved);
   StmtCache cache(db);
   sqlite3_stmt *ins = cache.get(kInsertMessage);
   if (!ins) return 1;
@@ -431,10 +525,7 @@ int eh_apply_planned_cells(sqlite3 *db, int64_t n, const char *ts_slab,
                            const uint8_t *kinds, const int64_t *ivals,
                            const double *dvals, const char *val_blob,
                            const int32_t *val_lens,
-                           const uint8_t *upsert_mask) {
-  StmtCache cache(db);
-  sqlite3_stmt *ins = cache.get(kInsertMessage);
-  if (!ins) return 1;
+                           const uint8_t *upsert_mask, int64_t *out_reserved) {
   // Per-cell field offsets into cell_blob (k is small: unique cells).
   std::vector<int64_t> coff(size_t(k) * 3 + 1);
   int64_t o = 0;
@@ -443,6 +534,15 @@ int eh_apply_planned_cells(sqlite3 *db, int64_t n, const char *ts_slab,
     o += cell_lens[j];
   }
   coff[size_t(k) * 3] = o;
+  int64_t input_bytes = n * 46 + sum_of(val_lens, n);
+  for (int64_t i = 0; i < n; ++i) {
+    int64_t cid = cell_ids[i];
+    if (cid >= 0 && cid < k) input_bytes += coff[size_t(cid) * 3 + 3] - coff[size_t(cid) * 3];
+  }
+  reserve_heap(input_bytes, kHeapPerByteIndexed, out_reserved);
+  StmtCache cache(db);
+  sqlite3_stmt *ins = cache.get(kInsertMessage);
+  if (!ins) return 1;
   // Upsert statements resolved once per cell, not per row (lazy: most
   // cells in a steady-state batch never win).
   std::vector<sqlite3_stmt *> up_stmt(size_t(k), nullptr);
@@ -593,12 +693,12 @@ int eh_parse_timestamps(const char *ts_packed, int64_t n, int64_t *out_millis,
 // across the workers; serialization is per shard via the shard lock).
 // Keep it that way: any global/static state added here would race the
 // parallel drain.
-int eh_relay_insert_packed(sqlite3 *db, int64_t n_groups,
-                           const char *const *group_users,
-                           const int64_t *group_counts,
-                           const char *ts_packed,
-                           const unsigned char *content_packed,
-                           const int32_t *content_lens, uint8_t *out_new) {
+static int relay_insert_packed_rows(sqlite3 *db, int64_t n_groups,
+                                    const char *const *group_users,
+                                    const int64_t *group_counts,
+                                    const char *ts_packed,
+                                    const unsigned char *content_packed,
+                                    const int32_t *content_lens, uint8_t *out_new) {
   sqlite3_stmt *st = nullptr;
   const char *sql =
       "INSERT OR IGNORE INTO \"message\" (\"timestamp\", \"userId\", \"content\") "
@@ -627,9 +727,27 @@ int eh_relay_insert_packed(sqlite3 *db, int64_t n_groups,
   return 0;
 }
 
+int eh_relay_insert_packed(sqlite3 *db, int64_t n_groups,
+                           const char *const *group_users,
+                           const int64_t *group_counts,
+                           const char *ts_packed,
+                           const unsigned char *content_packed,
+                           const int32_t *content_lens, uint8_t *out_new,
+                           int64_t *out_reserved) {
+  int64_t n = sum_of(group_counts, n_groups), input_bytes = n * 46 + sum_of(content_lens, n);
+  for (int64_t g = 0; g < n_groups; ++g)
+    input_bytes += group_counts[g] * int64_t(strlen(group_users[g]));
+  reserve_heap(input_bytes, kHeapPerByteRow, out_reserved);
+  return relay_insert_packed_rows(db, n_groups, group_users, group_counts, ts_packed,
+                                  content_packed, content_lens, out_new);
+}
+
 int eh_relay_insert(sqlite3 *db, int64_t n, const char *const *timestamps,
                     const char *const *user_ids, const char *const *contents,
-                    const int32_t *content_lens, uint8_t *out_new) {
+                    const int32_t *content_lens, uint8_t *out_new,
+                    int64_t *out_reserved) {
+  reserve_heap(text_bytes(timestamps, n) + text_bytes(user_ids, n) + sum_of(content_lens, n),
+               kHeapPerByteRow, out_reserved);
   sqlite3_stmt *st = nullptr;
   const char *sql =
       "INSERT OR IGNORE INTO \"message\" (\"timestamp\", \"userId\", \"content\") "
@@ -717,8 +835,10 @@ void report(const std::string &msg, char *err, int32_t err_cap) {
 
 extern "C" {
 
-// Per shard s of k, on dbs[s]: begin_sql[s], then eh_relay_insert_packed
-// with the shard's slice of the arguments, then the stored "merkleTree"
+// One reserve_heap for the rows of every shard, before the first BEGIN
+// (the shards' pages all come from the calling thread's heap). Then,
+// per shard s of k, on dbs[s]: begin_sql[s], then eh_relay_insert_packed's
+// rows with the shard's slice of the arguments, then the stored "merkleTree"
 // TEXT of each of the shard's group users, read inside the transaction.
 // The per-group and per-row arrays are FLAT over the shards in order
 // (n_groups[s] groups each; a shard's rows are the sum of its
@@ -735,16 +855,22 @@ int64_t eh_relay_insert_packed_shards(
     const int32_t *group_user_lens, const int64_t *group_counts,
     const char *const *ts_packed, const unsigned char *const *content_packed,
     const int32_t *content_lens, uint8_t *out_new, unsigned char **out_trees,
-    int64_t *out_trees_len, char *err, int32_t err_cap) {
+    int64_t *out_trees_len, char *err, int32_t err_cap, int64_t *out_reserved) {
   *out_trees = nullptr;
   *out_trees_len = 0;
   std::vector<int64_t> group_off(k + 1, 0), row_off(k + 1, 0);
+  int64_t input_bytes = 0;
   for (int64_t s = 0; s < k; ++s) {
     group_off[s + 1] = group_off[s] + n_groups[s];
     int64_t rows = 0;
-    for (int64_t g = group_off[s]; g < group_off[s + 1]; ++g) rows += group_counts[g];
+    for (int64_t g = group_off[s]; g < group_off[s + 1]; ++g) {
+      rows += group_counts[g];
+      input_bytes += group_counts[g] * (46 + group_user_lens[g]);
+    }
     row_off[s + 1] = row_off[s] + rows;
   }
+  input_bytes += sum_of(content_lens, row_off[k]);
+  reserve_heap(input_bytes, kHeapPerByteRow, out_reserved);
   std::vector<ShardRun> runs(k);
   std::string trees;  // framed stored trees, one per group, flat order
   auto shard = [&](int64_t s) -> int {
@@ -754,9 +880,9 @@ int64_t eh_relay_insert_packed_shards(
       return fail(run, db);
     run.begun = true;
     const int64_t g0 = group_off[s];
-    if (eh_relay_insert_packed(db, n_groups[s], group_users + g0, group_counts + g0,
-                               ts_packed[s], content_packed[s],
-                               content_lens + row_off[s], out_new + row_off[s]) != 0)
+    if (relay_insert_packed_rows(db, n_groups[s], group_users + g0, group_counts + g0,
+                                 ts_packed[s], content_packed[s],
+                                 content_lens + row_off[s], out_new + row_off[s]) != 0)
       return fail(run, db);
     sqlite3_stmt *st = nullptr;
     if (sqlite3_prepare_v2(db, "SELECT \"merkleTree\" FROM \"merkleTree\" WHERE \"userId\" = ?",
@@ -863,7 +989,8 @@ extern "C" {
 // (the ctypes per-bind path costs ~3us/bind; this is one call).
 int eh_run_many_tb(sqlite3 *db, const char *sql, int64_t nrows, int32_t ncols,
                    const char *const *vals, const int32_t *lens,
-                   const int32_t *kinds) {
+                   const int32_t *kinds, int64_t *out_reserved) {
+  reserve_heap(sum_of(lens, nrows * ncols), kHeapPerByteRow, out_reserved);
   sqlite3_stmt *st = nullptr;
   if (sqlite3_prepare_v2(db, sql, -1, &st, nullptr) != SQLITE_OK) return 1;
   for (int64_t r = 0; r < nrows; ++r) {

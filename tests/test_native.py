@@ -2,14 +2,26 @@
 state vs the Python backend (SURVEY.md §2.14 "real SQLite via the C API
 behind a C++ host layer" + the byte-identical north star)."""
 
+import ctypes
+import json
+import os
 import random
+import subprocess
+import sys
+import threading
 
+import numpy as np
 import pytest
 
 from evolu_tpu.core.ids import create_node_id
 from evolu_tpu.core.merkle import merkle_tree_to_string
 from evolu_tpu.core.timestamp import Timestamp, timestamp_to_string
 from evolu_tpu.core.types import CrdtMessage
+from evolu_tpu.obs import metrics
+from evolu_tpu.server import engine as engine_mod
+from evolu_tpu.server.engine import BatchReconciler
+from evolu_tpu.server.relay import ShardedRelayStore
+from evolu_tpu.storage import native
 from evolu_tpu.storage.apply import apply_messages, apply_messages_sequential
 from evolu_tpu.storage.native import (
     CppSqliteDatabase,
@@ -18,6 +30,7 @@ from evolu_tpu.storage.native import (
 )
 from evolu_tpu.storage.schema import init_db_model
 from evolu_tpu.storage.sqlite import PySqliteDatabase
+from evolu_tpu.sync import protocol
 
 pytestmark = pytest.mark.skipif(
     not native_available(), reason="native host library unavailable"
@@ -394,3 +407,428 @@ def test_unpack_changed_rows_matches_full_unpack():
         assert got == unpack_packed_rows(raw), trial
         prev_raw, prev_offs, prev_rows = raw, offs, got
     db.close()
+
+
+# --- heap reservation per native batch (ISSUE 30) ---
+#
+# `reserve_heap` in native/evolu_host.cpp: before a batched call steps
+# its first statement it takes 96 KiB blocks from malloc until they
+# cover the batch's input bytes times a constant (8 for `__message` and
+# its two indexes, 4 for a single B-tree), at most 32 MiB, and frees
+# them in the order taken. What is pinned: the arithmetic (whole blocks,
+# nothing under one, exactly the cap above it); on a fresh thread the
+# heap's writable extent afterwards, and that a batch under the estimate
+# no longer moves it; the end state of every reserving call against the
+# Python backend on both sides of one block and of the cap; and the two
+# counters, once a call, in the acquisition the call already made.
+
+BLOCK = 96 * 1024
+CAP = 32 * 1024 * 1024 // BLOCK * BLOCK  # the whole blocks in half of one glibc heap
+INDEXED, ROW = 8, 4  # kHeapPerByteIndexed, kHeapPerByteRow
+RESERVED = "evolu_native_heap_reserved_bytes_total"
+RESERVE_CALLS = "evolu_native_heap_reserve_calls_total"
+
+
+def _reserved():
+    return metrics.get_counter(RESERVED), metrics.get_counter(RESERVE_CALLS)
+
+
+def _expected(input_bytes: int, per_byte: int) -> int:
+    return min(input_bytes * per_byte, CAP) // BLOCK * BLOCK
+
+
+@pytest.mark.parametrize("per_byte", [INDEXED, ROW])
+def test_reserve_heap_holds_whole_blocks_up_to_the_cap(per_byte):
+    lib = native.load_library()
+    one = BLOCK // per_byte  # input bytes that ask for exactly one block
+    assert lib.eh_reserve_heap(0, per_byte) == 0
+    assert lib.eh_reserve_heap(-7, per_byte) == 0
+    assert lib.eh_reserve_heap(one - 1, per_byte) == 0
+    assert lib.eh_reserve_heap(one, per_byte) == BLOCK
+    assert lib.eh_reserve_heap(3 * one + one // 2, per_byte) == 3 * BLOCK
+    assert lib.eh_reserve_heap(CAP // per_byte - 1, per_byte) == CAP - BLOCK
+    assert lib.eh_reserve_heap(CAP // per_byte, per_byte) == CAP
+    assert lib.eh_reserve_heap(1 << 40, per_byte) == CAP
+    assert lib.eh_reserve_heap(1 << 62, per_byte) == CAP  # no overflow on the way to the cap
+
+
+_HEAP_EXTENT_SCRIPT = r"""
+import ctypes, json, sys, threading
+import numpy as np
+from evolu_tpu.core.packed import PackedReceive
+from evolu_tpu.storage.native import CppSqliteDatabase, load_library
+from evolu_tpu.storage.schema import init_db_model
+
+HEAP = 64 << 20  # glibc's HEAP_MAX_SIZE: a thread arena's heaps are aligned to it
+libc = ctypes.CDLL(None)
+libc.malloc.restype = ctypes.c_void_p
+libc.malloc.argtypes = [ctypes.c_size_t]
+libc.free.argtypes = [ctypes.c_void_p]
+lib = load_library()
+
+
+def extent():
+    # (heap base, bytes of it that are rw-p) around a pointer malloc just returned
+    p = libc.malloc(48)
+    libc.free(p)
+    base = p & ~(HEAP - 1)
+    with open("/proc/self/maps") as maps:
+        for line in maps:
+            span, perm = line.split()[:2]
+            lo, hi = (int(x, 16) for x in span.split("-"))
+            if lo <= p < hi:
+                assert perm.startswith("rw"), line
+                return base, hi - max(lo, base), "[heap]" in line
+
+
+def batch(n, value_bytes):
+    # n messages of 100 cells, each value `value_bytes` of text
+    cells = [("todo", "row%04d" % c, "title") for c in range(100)]
+    enc = [x.encode() for cell in cells for x in cell]
+    ts = b"".join(b"2023-11-14T22:13:20.%03dZ-%04X-a1b2c3d4e5f60718" % (i % 1000, i // 1000)
+                  for i in range(n))
+    vlens = np.full(n, value_bytes, np.int32)
+    return PackedReceive(
+        n, ts, cells, (np.arange(n) % 100).astype(np.int32), np.full(n, 3, np.uint8),
+        np.zeros(n, np.int64), np.zeros(n, np.float64), vlens,
+        np.arange(n, dtype=np.int64) * value_bytes, b"v" * (n * value_bytes),
+        b"".join(enc), np.fromiter(map(len, enc), np.int32, len(enc)))
+
+
+out = {}
+
+
+def on_a_fresh_thread():
+    db = CppSqliteDatabase()
+    init_db_model(db, mnemonic=None)
+    db.exec('CREATE TABLE "todo" ("id" TEXT PRIMARY KEY, "title" BLOB)')
+    pb = batch(2000, 200)  # 0.5 MB of input: an estimate of 4 MB
+    out["before"] = extent()
+    out["reserved"] = lib.eh_reserve_heap(16 << 20, 1)
+    out["after_reserve"] = extent()
+    with db.transaction():
+        db.apply_planned_cells(pb, np.ones(pb.n, np.uint8))
+    out["after_apply"] = extent()
+    out["rows"] = db.exec('SELECT COUNT(*) FROM "__message"')[0][0]
+    db.close()
+
+
+t = threading.Thread(target=on_a_fresh_thread)
+t.start()
+t.join()
+print(json.dumps(out))
+"""
+
+
+def test_reserved_heap_is_writable_and_the_batch_no_longer_extends_it():
+    """The scratch check of ISSUE 30, kept as a test: a process of its
+    own (no other thread shares the arena), a fresh thread, and the
+    address space read from /proc/self/maps."""
+    if not os.path.exists("/proc/self/maps"):
+        pytest.skip("/proc/self/maps is absent: no view of the heap's extent")
+    if not hasattr(ctypes.CDLL(None), "gnu_get_libc_version"):
+        pytest.skip("not glibc (no gnu_get_libc_version): other allocators grow otherwise")
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    env = {k: v for k, v in os.environ.items()
+           if not k.startswith(("MALLOC_", "GLIBC_TUNABLES"))}
+    proc = subprocess.run([sys.executable, "-c", _HEAP_EXTENT_SCRIPT], cwd=root, env=env,
+                          capture_output=True, text=True, timeout=120)
+    assert proc.returncode == 0, proc.stderr[-2000:]
+    out = json.loads(proc.stdout.strip().splitlines()[-1])
+    base, writable, main_arena = out["after_reserve"]
+    assert not main_arena, "a fresh thread drew the main arena"
+    assert out["reserved"] == (16 << 20) // BLOCK * BLOCK
+    assert writable >= out["reserved"], out
+    assert out["before"][1] < 4 << 20, out  # the reservation is what grew it
+    assert out["rows"] == 2000
+    base2, writable2, _ = out["after_apply"]
+    assert base2 == base
+    # 2,000 messages of 0.5 MB asked malloc for several MB of pages and
+    # journal; the extent moved by less than one block.
+    assert 0 <= writable2 - writable < BLOCK, out
+
+
+def _client_db(backend):
+    db = open_database(backend=backend)
+    bootstrap(db)
+    return db
+
+
+def _client_dump(db):
+    return {t: db.exec_sql_query(f'SELECT * FROM "{t}" ORDER BY 1, 2')
+            for t in ("todo", "todoCategory", "__message")}
+
+
+def _sized_messages(n, value_bytes, seed=30):
+    """n messages over 40 cells; `value_bytes` of text each (0: the
+    mixed small values of `make_messages`)."""
+    rng = random.Random(seed)
+    base = make_messages(n, seed)
+    if not value_bytes:
+        return base
+    return [CrdtMessage(m.timestamp, m.table, m.row, m.column,
+                        ("%06d" % rng.randrange(10**6)) * (value_bytes // 6))
+            for m in base]
+
+
+def _packed_input_bytes(pb):
+    cell_bytes = np.add.reduceat(np.asarray(pb.cell_lens, np.int64),
+                                 np.arange(0, len(pb.cell_lens), 3))
+    return pb.n * 46 + int(cell_bytes[pb.cell_id].sum()) + int(pb.vlens.sum())
+
+
+# (messages, value bytes): input under one block's worth (8 KiB at 8 a
+# byte), a few blocks, just under the cap (4 MiB of input), over it.
+CLIENT_SIZES = {"sub_block": (60, 0), "blocks": (400, 0),
+                "under_cap": (3600, 1000), "over_cap": (4400, 1000)}
+
+
+@pytest.mark.parametrize("size", sorted(CLIENT_SIZES))
+def test_apply_planned_cells_end_state_on_both_sides_of_a_block_and_the_cap(size):
+    from evolu_tpu.runtime.worker import select_planner
+    from evolu_tpu.sync import native_crypto
+    from evolu_tpu.sync.client import encrypt_messages
+    from evolu_tpu.utils.config import Config
+
+    if not native_crypto.native_available():
+        pytest.skip("the PackedReceive comes from the native decrypt")
+    mnemonic = "legal winner thank year wave sausage worth useful legal winner thank yellow"
+    msgs = _sized_messages(*CLIENT_SIZES[size])
+    wire = protocol.encode_sync_response(
+        protocol.SyncResponse(tuple(encrypt_messages(msgs, mnemonic)), "{}"))
+    pb, _tree = native_crypto.decrypt_response_columns(wire, mnemonic)
+    want = _expected(_packed_input_bytes(pb), INDEXED)
+    assert {"sub_block": want == 0, "blocks": 0 < want < CAP // 4,
+            "under_cap": CAP - 64 * BLOCK < want < CAP, "over_cap": want == CAP}[size], want
+
+    cpp, py = _client_db("native"), _client_db("python")
+    packed0 = metrics.get_counter("evolu_apply_batches_total", route="packed")
+    bytes0, calls0 = _reserved()
+    tree_c = apply_messages(cpp, {}, pb, planner=select_planner(Config(backend="tpu"), cpp))
+    bytes1, calls1 = _reserved()
+    tree_p = apply_messages(py, {}, msgs)
+    assert metrics.get_counter("evolu_apply_batches_total", route="packed") == packed0 + 1
+    assert _client_dump(cpp) == _client_dump(py)
+    assert merkle_tree_to_string(tree_c) == merkle_tree_to_string(tree_p)
+    # The planner's own reads (a temp table of cells) may reserve too.
+    assert bytes1 - bytes0 >= want and (calls1 - calls0 >= 1) == (bytes1 > bytes0)
+    cpp.close(), py.close()
+
+
+SHARDS = 4
+# (rows a shard, content bytes) over 3 live shards of 4: input under one
+# block's worth (16 KiB at 4 a byte), a few blocks, under the cap
+# (8 MiB of input), over it.
+RELAY_SIZES = {"sub_block": (24, 20), "blocks": (600, 120),
+               "under_cap": (600, 4100), "over_cap": (600, 5000)}
+INSERT_MESSAGE = ('INSERT OR IGNORE INTO "message" ("timestamp", "userId", "content") '
+                  'VALUES (?, ?, ?)')
+UPSERT_TREE = 'INSERT OR REPLACE INTO "merkleTree" ("userId", "merkleTree") VALUES (?, ?)'
+
+
+def _relay_pass(rows_per_shard, content_bytes, round_=0):
+    """→ (live shards, per-shard [(owner, [(ts, content)])]): two owners
+    a shard, the second pushing its first five rows twice."""
+    import zlib
+
+    live, groups = (0, 2, 3), []
+    rng = random.Random(rows_per_shard * 31 + round_)
+    for si in live:
+        owners, i = [], 0
+        while len(owners) < 2:
+            u = f"owner-{si}-{i}"
+            if zlib.crc32(u.encode()) % SHARDS == si:
+                owners.append(u)
+            i += 1
+        shard_groups = []
+        for o, n in zip(owners, (rows_per_shard // 2, rows_per_shard - rows_per_shard // 2)):
+            rows = [(ts(1_700_000_000_000 + (round_ * 10_000 + j) * 977, j % 4,
+                        "%016x" % zlib.crc32(o.encode())),
+                     bytes(rng.randrange(256) for _ in range(8)) * (content_bytes // 8))
+                    for j in range(n)]
+            shard_groups.append((o, rows))
+        shard_groups.append((owners[1], shard_groups[1][1][:5]))
+        groups.append(shard_groups)
+    return live, groups
+
+
+def _native_batch(shard_groups):
+    rows = [r for _o, rs in shard_groups for r in rs]
+    return ([o for o, _rs in shard_groups], [len(rs) for _o, rs in shard_groups],
+            *engine_mod._pack_rows([t for t, _c in rows], [c for _t, c in rows]))
+
+
+def _relay_input_bytes(groups):
+    return sum(46 + len(o.encode()) + len(c)
+               for shard_groups in groups for o, rs in shard_groups for _t, c in rs)
+
+
+def _fold(stored: str, new_ts) -> str:
+    from evolu_tpu.core.merkle import (
+        apply_prefix_xors, merkle_tree_from_string, minute_deltas_host)
+
+    deltas, _ = minute_deltas_host(iter(new_ts))
+    return merkle_tree_to_string(apply_prefix_xors(merkle_tree_from_string(stored), deltas))
+
+
+@pytest.mark.parametrize("size", sorted(RELAY_SIZES))
+def test_shard_set_end_state_on_both_sides_of_a_block_and_the_cap(size):
+    from conftest import relay_store_dump
+
+    live, groups = _relay_pass(*RELAY_SIZES[size])
+    want = _expected(_relay_input_bytes(groups), ROW)
+    assert {"sub_block": want == 0, "blocks": 0 < want < CAP // 4,
+            "under_cap": CAP - 64 * BLOCK < want < CAP, "over_cap": want == CAP}[size], want
+    cpp = ShardedRelayStore(":memory:", "native", shards=SHARDS)
+    py = ShardedRelayStore(":memory:", "python", shards=SHARDS)
+    try:
+        for round_ in range(2):  # the second on top of stored rows and trees
+            if round_:
+                live, groups = _relay_pass(*RELAY_SIZES[size], round_=1)
+            dbs = [cpp.shards[si].db for si in live]
+            bytes0, calls0 = _reserved()
+            flags, stored = native.relay_insert_packed_shards(
+                dbs, [_native_batch(g) for g in groups])
+            assert _reserved() == (bytes0 + want, calls0 + (1 if want else 0))
+            tree_rows = []
+            for shard_groups, was_new in zip(groups, flags):
+                new_ts, pos = {}, 0
+                for o, rs in shard_groups:
+                    new_ts.setdefault(o, []).extend(
+                        t for (t, _c), f in zip(rs, was_new[pos:pos + len(rs)]) if f)
+                    pos += len(rs)
+                tree_rows.append([(o, _fold(stored[o], tss)) for o, tss in new_ts.items()])
+            native.relay_commit_shards(dbs, tree_rows)
+            assert _reserved() == (bytes0 + want, calls0 + (1 if want else 0))
+            # The Python backend, statement by statement.
+            for si, shard_groups in zip(live, groups):
+                db = py.shards[si].db
+                with db.transaction():
+                    new_ts = {}
+                    for o, rs in shard_groups:
+                        for t, c in rs:
+                            if db.run(INSERT_MESSAGE, (t, o, c)) == 1:
+                                new_ts.setdefault(o, []).append(t)
+                    for o, tss in new_ts.items():
+                        db.run(UPSERT_TREE, (o, _fold(py.shards[si].get_merkle_tree_string(o), tss)))
+            assert relay_store_dump(cpp) == relay_store_dump(py)
+        assert sum(s.stats()[0]["messages"] for s in cpp.shards) == \
+            2 * sum(len(rs) for g in groups[:] for _o, rs in g[:2])
+    finally:
+        cpp.close(), py.close()
+
+
+# (rows, bytes a text cell): 3 cells a row, one of them NULL.
+RUN_MANY_SIZES = {"sub_block": (100, 40), "blocks": (2000, 40),
+                  "under_cap": (2000, 2900), "over_cap": (2000, 3400)}
+
+
+@pytest.mark.parametrize("size", sorted(RUN_MANY_SIZES))
+def test_run_many_end_state_on_both_sides_of_a_block_and_the_cap(size):
+    n, width = RUN_MANY_SIZES[size]
+    rng = random.Random(n + width)
+    rows = [(f"k{i:06d}", ("%08d" % rng.randrange(10**8)) * (width // 8),
+             None if i % 3 else bytes([i % 256]) * width) for i in range(n)]
+    rows += rows[:7]  # OR IGNORE on the primary key
+    want = _expected(sum(len(v) for r in rows for v in r if v is not None), ROW)
+    assert {"sub_block": want == 0, "blocks": 0 < want < CAP // 4,
+            "under_cap": CAP - 64 * BLOCK < want < CAP, "over_cap": want == CAP}[size], want
+    cpp, py = CppSqliteDatabase(), PySqliteDatabase()
+    bytes0, calls0 = _reserved()
+    for db in (cpp, py):
+        db.exec('CREATE TABLE "t" ("k" TEXT PRIMARY KEY, "a" TEXT, "b" BLOB)')
+        with db.transaction():
+            assert db.run_many('INSERT OR IGNORE INTO "t" VALUES (?, ?, ?)', rows) == n
+    assert _reserved() == (bytes0 + want, calls0 + (1 if want else 0))
+    assert cpp.exec('SELECT * FROM "t" ORDER BY "k"') == py.exec('SELECT * FROM "t" ORDER BY "k"')
+    cpp.close(), py.close()
+
+
+def test_every_reserving_call_posts_its_bytes_once():
+    """Each batched entry point: the two counters move by what
+    `out_reserved` said, once a call, and not at all under one block or
+    for an empty batch."""
+    db = CppSqliteDatabase()
+    bootstrap(db)
+    db.exec('CREATE TABLE "message" ("timestamp" TEXT, "userId" TEXT, "content" BLOB, '
+            'PRIMARY KEY ("userId", "timestamp")) WITHOUT ROWID')
+    big = "v" * 500
+    msgs = [CrdtMessage(ts(1_700_000_000_000 + i), "todo", f"row{i % 9}", "title", big)
+            for i in range(300)]
+    msg_bytes = sum(46 + 4 + len(m.row) + 5 + 500 for m in msgs)
+    relay_rows = [(ts(1_700_000_000_000 + i), "owner-a", b"c" * 300) for i in range(200)]
+    relay_bytes = sum(46 + 7 + 300 for _ in relay_rows)
+    calls = [
+        (lambda: db.apply_sequential(msgs), _expected(msg_bytes, INDEXED)),
+        (lambda: db.apply_planned(msgs, [True] * len(msgs)), _expected(msg_bytes, INDEXED)),
+        (lambda: db.relay_insert(relay_rows), _expected(relay_bytes, ROW)),
+        (lambda: db.relay_insert_packed(
+            ["owner-b"], [200], *engine_mod._pack_rows(
+                [t for t, _u, _c in relay_rows], [c for _t, _u, c in relay_rows])),
+         _expected(relay_bytes, ROW)),
+        (lambda: db.run_many('INSERT INTO "todoCategory" ("id", "title") VALUES (?, ?)',
+                             [(f"id{i}", big) for i in range(100)]),
+         _expected(100 * 505 - 10 - 90, ROW)),
+        # Under one block, and empty: nothing is written to the registry.
+        (lambda: db.apply_sequential(msgs[:3]), 0),
+        (lambda: db.apply_planned(msgs[:3], [True] * 3), 0),
+        (lambda: db.relay_insert(relay_rows[:5]), 0),
+        (lambda: db.run_many('INSERT INTO "todo" ("id", "title") VALUES (?, ?)', [("a", "b")]), 0),
+        (lambda: db.apply_sequential([]), 0),
+        (lambda: db.relay_insert([]), 0),
+    ]
+    with db.transaction():
+        for call, want in calls:
+            bytes0, calls0 = _reserved()
+            call()
+            assert _reserved() == (bytes0 + want, calls0 + (1 if want else 0)), want
+    assert [want for _c, want in calls[:5]] == sorted(
+        [want for _c, want in calls[:5]], reverse=True) and calls[4][1] > 0
+    db.close()
+
+
+class _CountingLock:
+    """The registry's lock, counting the acquisitions of one thread."""
+
+    def __init__(self, lock):
+        self.lock, self.count, self.thread = lock, 0, threading.get_ident()
+
+    def __enter__(self):
+        if threading.get_ident() == self.thread:
+            self.count += 1
+        return self.lock.__enter__()
+
+    def __exit__(self, *exc):
+        return self.lock.__exit__(*exc)
+
+
+def test_a_pass_storage_leg_still_takes_the_registry_lock_once_a_native_call(monkeypatch):
+    """`_store_pass`: BEGIN + insert + stored trees, then upsert +
+    COMMIT. Each native call posted one counter before this issue and
+    posts one `inc_many` now, the reservation's counters riding with
+    `evolu_engine_store_calls_total`."""
+    live, groups = _relay_pass(*RELAY_SIZES["blocks"])
+    want = _expected(_relay_input_bytes(groups), ROW)
+    store = ShardedRelayStore(":memory:", "native", shards=SHARDS)
+    engine = BatchReconciler(store)
+    lock = _CountingLock(metrics.registry._lock)
+    try:
+        before = {op: metrics.get_counter("evolu_engine_store_calls_total", op=op)
+                  for op in ("insert", "commit")}
+        bytes0, calls0 = _reserved()
+        monkeypatch.setattr(metrics.registry, "_lock", lock)
+        with engine._store_pass(store.shards, list(live),
+                                [_native_batch(g) for g in groups]) as (flags, stored, tree_rows):
+            assert lock.count == 1
+            for si, shard_groups in zip(live, groups):
+                tree_rows[si].extend((o, "{}") for o in dict(shard_groups))
+        assert lock.count == 2
+        monkeypatch.undo()
+        assert _reserved() == (bytes0 + want, calls0 + 1) and want > 0
+        for op in ("insert", "commit"):
+            assert metrics.get_counter("evolu_engine_store_calls_total", op=op) == before[op] + 1
+        assert sum(s.stats()[0]["messages"] for s in store.shards) == \
+            sum(len(rs) for g in groups for _o, rs in g[:2])
+    finally:
+        engine.close()
+        store.close()
