@@ -22,7 +22,6 @@ ciphertext blobs — the LWW cell merge happens client-side.
 from __future__ import annotations
 
 import functools
-import os
 from contextlib import contextmanager
 from typing import Dict, List, Optional, Sequence, Tuple
 
@@ -51,7 +50,7 @@ from evolu_tpu.parallel.mesh import (
 )
 from evolu_tpu.obs import anatomy, flight, ledger, metrics
 from evolu_tpu.parallel.reconcile import xor_allreduce
-from evolu_tpu.server.relay import RelayStore
+from evolu_tpu.server.store import ShardedRelayStore, fetch_response_stream
 from evolu_tpu.storage.native import relay_commit_shards, relay_insert_packed_shards
 from evolu_tpu.utils.log import log, span
 from evolu_tpu.sync import protocol
@@ -469,12 +468,9 @@ def _deltas_layout(mesh, owner_index, all_m, all_c, all_n, case_ok,
     # `base >= 0` guard would be dead code); both kernels treat the
     # wrapped value identically, but wrapped batches keep the full-key
     # kernel so admission stays a statement about true timestamps.
-    # EVOLU_COMPACT_DELTA=0 pins the 20 B/row kernel (the before/after
-    # bytes measurement).
     max_real = base + millis_span
     use_delta = (
-        os.environ.get("EVOLU_COMPACT_DELTA", "1") != "0"
-        and millis_span < (1 << 32)
+        millis_span < (1 << 32)
         and max_real < (1 << 47)  # wrapped pre-1970 lands near 2^48
         and len(good) < _DELTA_PAD_OWNER
     )
@@ -496,7 +492,7 @@ def deltas_finish(state) -> Tuple[Dict[str, Dict[str, int]], int]:
     decode them. The wait alone is the `pass_pull_wait` stage: the
     device and transfer time the caller's host work did not hide, the
     host-clock reading of "the chip made the host wait". A caller that
-    tiles its own stages (`finish_batch`) calls the two halves itself."""
+    tiles its own stages (`_land`) calls the two halves itself."""
     with anatomy.stage("pass_pull_wait"):
         pulled = deltas_pull(state)
     return deltas_decode(state, pulled)
@@ -710,40 +706,48 @@ class BatchReconciler:
         trees, strings = self._ingest(requests)
         return self._respond(requests, trees, strings)
 
-    def _ingest(self, requests):
-        """The batched ingest, routed by store shape → (trees, strings).
-        ONE copy shared by `reconcile` and `reconcile_wire`."""
-        from evolu_tpu.server.relay import ShardedRelayStore
+    def _route(self, live: bool) -> str:
+        """Where a pass lands: the ONE route decision, read by `_ingest`
+        and `run_batch_wire`, and the `path` label of
+        `evolu_engine_store_passes_total`. "write_behind": a queue is
+        attached and the pass is `live` (the scheduler's; the offline
+        entry points stay synchronous). "stream": the store says it is
+        packed-capable (`start_batch` + `_land`; a stand-in store
+        without the attribute answers no). "generic": everything else,
+        the python-backend routes under `_ingest`."""
+        if live and self.write_behind is not None and hasattr(
+            self.store, "get_merkle_tree_string"
+        ):
+            return "write_behind"
+        if getattr(self.store, "packed", False):
+            return "stream"
+        return "generic"
 
-        metrics.inc("evolu_engine_store_passes_total", path="oneshot")
+    def _ingest(self, requests, respond_stage=None):
+        """The synchronous batched ingest → (trees, strings). ONE copy
+        shared by `reconcile` and `reconcile_wire`. `respond_stage`: see
+        `finish_batch`."""
+        if self._route(live=False) == "stream":
+            return self._land(self.start_batch(requests), respond_stage)
+        metrics.inc("evolu_engine_store_passes_total", path="generic")
         strings: Dict[str, str] = {}
-        db = getattr(self.store, "db", None)
-        if isinstance(self.store, ShardedRelayStore):
-            if all(hasattr(s.db, "relay_insert_packed") for s in self.store.shards):
-                trees = self._ingest_packed(requests, strings)
-            else:
-                # Sharded python-backend: per-request per-shard path.
-                trees = {
-                    r.user_id: self.store.add_messages(r.user_id, r.messages)
-                    for r in requests
-                }
-        elif db is not None and hasattr(db, "relay_insert_packed"):
-            trees = self._ingest_packed(requests, strings)
-        elif db is not None:
+        if (not isinstance(self.store, ShardedRelayStore)
+                and getattr(self.store, "db", None) is not None):
             trees = self._ingest_generic(requests, strings)
         else:
-            # Generic store (RelayStore surface, no `.db` SQL handle):
-            # per-request ingest; the respond side degrades likewise
-            # (`_respond_wire`'s object fallback).
+            # Sharded python-backend, or a generic store (RelayStore
+            # surface, no `.db` SQL handle): per-request ingest; the
+            # respond side degrades likewise (`_respond_wire`'s object
+            # fallback).
             trees = {
                 r.user_id: self.store.add_messages(r.user_id, r.messages)
                 for r in requests
             }
+        if respond_stage is not None:
+            respond_stage.start()
         return trees, strings
 
     def _shards(self):
-        from evolu_tpu.server.relay import ShardedRelayStore
-
         if isinstance(self.store, ShardedRelayStore):
             return self.store.shards, self.store.shard_index
         return [self.store], (lambda _u: 0)
@@ -812,95 +816,15 @@ class BatchReconciler:
             self._pull_pool.shutdown(wait=True)
             self._pull_pool = None
 
-    def _ingest_packed(self, requests, tree_strings=None) -> Dict[str, dict]:
-        """The packed columnar ingest. Per storage shard: pack the
-        shard's timestamps and ciphertexts into flat buffers; ONE
-        native call over all live shards INSERTs OR IGNOREs them (the
-        PK dedups, including in-batch duplicates, with per-row was-new
-        flags — index.ts:153-158 semantics) and reads the owners'
-        stored trees. The new rows of every shard ride ONE device
-        dispatch for the per-(owner, minute) hashes, and each shard's
-        inserts + tree updates commit in one transaction
-        (`_store_pass`), so rows can never outrun their tree. A failure
-        anywhere rolls every shard back."""
-        stores, shard_index = self._shards()
-        per_shard: List[List[protocol.SyncRequest]] = [[] for _ in stores]
-        for r in requests:
-            per_shard[shard_index(r.user_id)].append(r)
-        live = [si for si, reqs in enumerate(per_shard) if any(len(r.messages) for r in reqs)]
-        trees: Dict[str, dict] = {}
-        if not live:
-            return trees
-        n_total = sum(len(r.messages) for r in requests)
-        inserted_by_owner: Dict[str, int] = {}
-        with span("kernel:merkle", "reconcile_ingest",
-                  owners=len({r.user_id for r in requests}), n=n_total,
-                  shards=len(live)):
-            batches = []
-            for si in live:
-                reqs = per_shard[si]
-                # One flat pass over the shard's messages; everything
-                # below is C-speed (map/join/fromiter) — per-message
-                # Python generators here cost ~2.5s/1M (profiled).
-                ts_list = [m.timestamp for r in reqs for m in r.messages]
-                contents = [m.content for r in reqs for m in r.messages]
-                batches.append((
-                    [r.user_id for r in reqs], [len(r.messages) for r in reqs],
-                    *_pack_rows(ts_list, contents),
-                ))
-            # Transactions held across the device dispatch so inserts +
-            # trees commit atomically.
-            with self._store_pass(stores, live, batches) as (
-                    was_new_by_shard, stored, tree_rows):
-                # Merge shard results into one flat column space.
-                owner_index: Dict[str, List[np.ndarray]] = {}
-                buffers, offsets = [], []
-                col_parts = ([], [], [], [])
-                off = 0
-                for si, (gu, gc, ts_packed, _cp, _lens) in zip(live, batches):
-                    was_new = was_new_by_shard[si]
-                    pos = 0
-                    for u, k in zip(gu, gc):
-                        ix = np.nonzero(was_new[pos : pos + k])[0] + (pos + off)
-                        if len(ix):
-                            owner_index.setdefault(u, []).append(ix)
-                        pos += k
-                    buffers.append(ts_packed)
-                    offsets.append(off)
-                    cols = parse_packed_timestamps(ts_packed, len(was_new), with_case=True)
-                    for part, c in zip(col_parts, cols):
-                        part.append(c)
-                    off += len(was_new)
-                merged = {
-                    u: (v[0] if len(v) == 1 else np.concatenate(v))
-                    for u, v in owner_index.items()
-                }
-                inserted_by_owner.update((u, len(v)) for u, v in merged.items())
-                all_m, all_c, all_n, case_ok = (
-                    (p[0] if len(p) == 1 else np.concatenate(p)) for p in col_parts
-                )
-                deltas_by_owner, _digest = deltas_from_columns(
-                    self.mesh, merged, all_m, all_c, all_n, case_ok,
-                    _PackedRows(buffers, offsets), ctx=self.mesh_ctx,
-                )
-                # `tree_strings`: respond reuses the upsert's dump.
-                _fold_trees(deltas_by_owner, stored, tree_rows, shard_index, trees,
-                            {} if tree_strings is None else tree_strings)
-        _ledger_count_pass(requests, inserted_by_owner)
-        return trees
-
-    # -- pipelined streaming reconcile (VERDICT r2 #1) --
+    # -- the packed ingest: start_batch, then _land --
     #
-    # `reconcile` holds each shard transaction open ACROSS the device
-    # dispatch, so host and device strictly alternate. The streaming
-    # path breaks the dependency: the device hashes the WHOLE batch
-    # optimistically (newness is unknown until the insert), and owners
-    # that turn out to contain duplicate rows get their deltas
-    # recomputed host-side from the new rows only — bit-identical to
-    # the fold the one-shot path does. Since the device leg then needs
-    # nothing from the database, batch k+1's transfer + compute run on
-    # the device while batch k's C inserts/trees/commit run on the
-    # host (the C calls drop the GIL).
+    # The device leg needs nothing from the database: it hashes the
+    # WHOLE batch optimistically (newness is unknown until the insert),
+    # and owners that turn out to contain duplicate rows get their
+    # deltas recomputed host-side from the new rows only. So the insert
+    # runs while the device computes, and in `reconcile_stream` batch
+    # k+1's transfer + compute run on the device while batch k's C
+    # inserts/trees/commit run on the host (the C calls drop the GIL).
 
     def start_batch(self, requests: Sequence[protocol.SyncRequest]):
         """Stage batch k+1: pack per-shard buffers, parse natively,
@@ -968,11 +892,10 @@ class BatchReconciler:
             ts_list: List[str] = []
             contents: List[bytes] = []
             for r in reqs:
-                # In-batch dedup up front (the one-shot path leaves it
-                # to the PK): correction logic needs was_new==False to
-                # mean exactly "already in the store". Same-user rows
-                # stay in request order, so the kept occurrence matches
-                # the row the PK would have kept.
+                # In-batch dedup up front: correction logic needs
+                # was_new==False to mean exactly "already in the
+                # store". Same-user rows stay in request order, so the
+                # kept occurrence matches the row the PK would keep.
                 kept = [
                     m for m in r.messages
                     if (m.timestamp, r.user_id) not in seen
@@ -1005,30 +928,36 @@ class BatchReconciler:
                 dict(zip(live, offsets)), merged, off)
 
     def finish_batch(self, st, wire: bool = False, respond_stage=None) -> List:
-        """Land batch k: the C inserts of every live shard in one
-        native call, duplicate-owner delta recompute, tree updates, one
-        atomic commit per shard in a second (`_store_pass`) — while
-        batch k+1 flies on the device.
-        `wire=True` answers in BYTES mode (`_respond_wire`) for
-        consumers that only forward protobuf — the live scheduler path,
+        """Land batch k (`_land`) and answer it — while batch k+1 flies
+        on the device. `wire=True` answers in BYTES mode
+        (`_respond_wire`) for consumers that only forward protobuf,
         byte-identical to encoding the object responses (test-pinned
         via `_respond_wire`'s own fence). A `respond_stage` (an
         unstarted `anatomy.stage`, the scheduler's `pass_respond`) is
         STARTED where the apply leg ends; the caller, on this thread,
         stops it when its own respond work is done."""
+        trees, strings = self._land(st, respond_stage)
+        respond = self._respond_wire if wire else self._respond
+        return respond(st["requests"], trees, strings)
+
+    def _land(self, st, respond_stage=None):
+        """The landing half of a packed pass → (trees, strings): the C
+        inserts of every live shard in one native call,
+        duplicate-owner delta recompute, tree updates, one atomic
+        commit per shard in a second (`_store_pass`), then the ledger
+        count. `respond_stage`: see `finish_batch`."""
         stores, shard_index = self._shards()
         metrics.inc("evolu_engine_store_passes_total", path="stream")
-        respond = self._respond_wire if wire else self._respond
         live, shard_data = st["live"], st["shard_data"]
         trees: Dict[str, dict] = {}
         strings: Dict[str, str] = {}
         if not live:
             if respond_stage is not None:
                 respond_stage.start()
-            return respond(st["requests"], trees, strings)
+            return trees, strings
 
-        # host_apply stage record (obs.anatomy): the WHOLE finish leg up
-        # to the commit — C inserts, the blocked wait for the pull,
+        # host_apply stage record (obs.anatomy): the WHOLE landing leg
+        # up to the commit — C inserts, the blocked wait for the pull,
         # delta decode, tree folds, commit. The `kernel:merkle` span
         # below has the same extent under its historical name: it times
         # this host leg, not a kernel. Three children tile it:
@@ -1072,12 +1001,12 @@ class BatchReconciler:
                 )
                 pos += k
         _ledger_count_pass(st["requests"], ins_by_owner)
-        return respond(st["requests"], trees, strings)
+        return trees, strings
 
     def _recompute_duplicate_owners(self, st, was_new_by_shard, deltas_by_owner) -> None:
         """The device hashed every row; owners where some rows were
         already stored get their delta dict recomputed from the NEW
-        rows only — the same fold the one-shot path runs, so minute-key
+        rows only — the fold `RelayStore.add_messages` runs, so minute-key
         presence semantics (a minute whose new hashes XOR to zero stays
         present; a minute with only duplicate rows disappears) are
         bit-identical. Steady state has no duplicates and skips this
@@ -1124,10 +1053,9 @@ class BatchReconciler:
         """Software-pipelined reconcile over a stream of request
         batches: batch k+1's device leg (upload, hash, output transfer)
         overlaps batch k's host leg (C inserts, trees, commit). End
-        state is identical to sequential `reconcile` calls. Requires a
-        packed-capable store; falls back to sequential otherwise."""
-        stores, _ = self._shards()
-        if not all(hasattr(s.db, "relay_insert_packed") for s in stores):
+        state is identical to sequential `reconcile` calls, which is
+        what any other route than the packed one runs."""
+        if self._route(live=False) != "stream":
             return [self.reconcile(b) for b in batches]
         out: List[List[protocol.SyncResponse]] = []
         prev = None
@@ -1257,9 +1185,7 @@ class BatchReconciler:
         `encode_sync_response(reconcile(...)[i])` (test-pinned);
         per-request fallback to the object path + encoder where the C
         entry is missing or a stored row is non-canonical."""
-        trees, strings = self._ingest(requests)
-        if respond_stage is not None:
-            respond_stage.start()
+        trees, strings = self._ingest(requests, respond_stage)
         return self._respond_wire(requests, trees, strings)
 
     def run_batch_wire(self, requests: Sequence[protocol.SyncRequest],
@@ -1270,26 +1196,18 @@ class BatchReconciler:
         (`_finish_batch_deferred`): serve from in-memory trees, ACK
         into the durable log, answer — a `WriteBehindFull` raised
         before the ACK leaves no state anywhere (the scheduler maps it
-        to 503 + Retry-After). Otherwise packed-capable stores take
-        `start_batch`/`finish_batch` (in-batch dedup in request order,
-        optimistic device hash, atomic per-shard insert+tree commit);
-        anything else routes through `reconcile_wire`, whose `_ingest`
-        picks the store-appropriate batched path. Either way a failure
+        to 503 + Retry-After). Otherwise it is `reconcile_wire`, whose
+        `_ingest` reads the same `_route`: `start_batch` + `_land` on a
+        packed-capable store (in-batch dedup in request order,
+        optimistic device hash, atomic per-shard insert+tree commit),
+        the python-backend routes elsewhere. Either way a failure
         rolls every shard transaction back before raising — the
         scheduler's singleton retry depends on that. `respond_stage`:
         see `finish_batch`; every route starts it where its respond
         leg begins."""
-        stores, _ = self._shards()
-        if self.write_behind is not None and hasattr(
-            self.store, "get_merkle_tree_string"
-        ):
+        if self._route(live=True) == "write_behind":
             return self._finish_batch_deferred(
                 self.start_batch(requests), respond_stage)
-        if all(
-            hasattr(getattr(s, "db", None), "relay_insert_packed") for s in stores
-        ):
-            return self.finish_batch(
-                self.start_batch(requests), wire=True, respond_stage=respond_stage)
         return self.reconcile_wire(requests, respond_stage)
 
     # -- write-behind serving (PR-11: device state is the truth) --
@@ -1415,7 +1333,6 @@ class BatchReconciler:
         respond, also post-flush."""
         from evolu_tpu.core.merkle import diff_merkle_trees
         from evolu_tpu.core.types import NonCanonicalStoreError
-        from evolu_tpu.server.relay import fetch_response_stream
 
         wb = self.write_behind
         shards, shard_ix = self._shards()
@@ -1483,14 +1400,13 @@ class BatchReconciler:
         tree_strings: Optional[Dict[str, str]] = None,
     ) -> List[bytes]:
         """Bytes-mode twin of `_respond`. The response composition is
-        `relay.fetch_response_stream` (ONE copy shared with
+        `store.fetch_response_stream` (ONE copy shared with
         `RelayStore.sync_wire`) plus the field-2 tree string — the SAME
         serialized tree `_respond` would carry, so encodings are
         byte-identical. Requests a shard cannot C-serve (python
         backend, malformed stored row) degrade to ONE batched
         object-path respond at their original positions."""
         from evolu_tpu.core.types import NonCanonicalStoreError
-        from evolu_tpu.server.relay import fetch_response_stream
 
         shards, shard_ix = self._shards()
         tree_strings = dict(tree_strings or {})
@@ -1671,7 +1587,7 @@ def reconcile_pod(
         )
         digest = int(dev_digest)
 
-    # 4) Storage leg — my owners only, like `_ingest_packed`: the
+    # 4) Storage leg — my owners only, like `_land`: the
     # inserts of every live shard in one native call, tree math per
     # shard, upserts + commits in a second, inside the same atomic
     # transaction window (`_store_pass`).
@@ -1714,7 +1630,7 @@ def reconcile_pod(
     with span("kernel:merkle", "reconcile_pod",
               owners=len(owners), local_owners=len(local),
               n=len(flat_ts), nproc=nproc):
-        if live and all(hasattr(stores[si].db, "relay_insert_packed") for si in live):
+        if live and getattr(store, "packed", False):
             batches = []
             for si in live:
                 sh_owners = per_shard[si]

@@ -78,6 +78,7 @@ import time
 from typing import Dict, List, Optional, Sequence, Tuple
 
 from evolu_tpu.obs import ledger, metrics
+from evolu_tpu.server.store import RelayStore
 from evolu_tpu.sync import protocol
 from evolu_tpu.utils.config import FleetConfig
 from evolu_tpu.utils.log import log
@@ -612,7 +613,8 @@ def _worker_main(argv: Optional[Sequence[str]] = None) -> None:
     import json
     import signal
 
-    from evolu_tpu.server.relay import RelayServer, RelayStore
+    # The relay sits above this module (it builds the FleetManager).
+    from evolu_tpu.server.relay import RelayServer
 
     ap = argparse.ArgumentParser(description="one evolu fleet relay process")
     ap.add_argument("--host", default="127.0.0.1")

@@ -67,6 +67,7 @@ from evolu_tpu.core.timestamp import (
 )
 from evolu_tpu.core.types import TimestampParseError
 from evolu_tpu.obs import ledger, metrics, trace
+from evolu_tpu.server.store import serve_single_request
 from evolu_tpu.sync import aead, protocol
 from evolu_tpu.sync.client import _accepts_headers
 from evolu_tpu.utils.log import log
@@ -932,8 +933,6 @@ class ReplicationManager:
             if first_err is not None:
                 raise first_err
             return
-        from evolu_tpu.server.relay import serve_single_request
-
         served = []
         try:
             for r in requests:

@@ -61,6 +61,7 @@ import time
 from typing import List, Optional
 
 from evolu_tpu.obs import anatomy, ledger, metrics, trace
+from evolu_tpu.server.store import serve_single_request
 from evolu_tpu.sync import aead, protocol
 from evolu_tpu.utils.log import log
 
@@ -513,8 +514,6 @@ class SyncScheduler:
         before any side effect). Only ever called on the dispatcher
         thread, so it can never interleave with an open engine
         transaction on the shared store connection."""
-        from evolu_tpu.server.relay import serve_single_request
-
         if self._write_behind is not None:
             # Direct store writes (the host-oracle / non-batchable
             # path) must observe and produce committed state: drain

@@ -480,7 +480,7 @@ class WriteBehindQueue:
             blockers = [
                 si for si, s in enumerate(stores)
                 if getattr(s.db, "path", None) in (None, ":memory:")
-                or hasattr(s.db, "relay_insert_packed")
+                or s.packed
             ]
             if blockers:
                 log("storage", "write-behind process drain unavailable; "

@@ -12,10 +12,13 @@ array / device lookup raises. A module that imports cleanly there is
 proven backend-free at import.
 """
 
+import ast
 import json
 import os
 import subprocess
 import sys
+
+import pytest
 
 _REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
@@ -121,3 +124,44 @@ def test_trace_module_never_imports_jax_and_never_touches_a_backend():
     )
     assert proc.returncode == 0, proc.stderr[-2000:]
     assert "CLEAN" in proc.stdout, "evolu_tpu.obs.trace transitively imported jax"
+
+
+# server/ in four boxes whose arrows point one way:
+# store <- engine <- scheduler <- relay (CLAUDE.md). Read from the
+# SOURCE, function bodies included: `evolu_tpu/server/__init__.py`
+# imports `relay` eagerly, so sys.modules cannot tell who asked for it.
+_LAYERS = {
+    "store": {"relay", "scheduler", "engine", "conn", "push", "fleet",
+              "replicate", "snapshot", "http.server", "jax"},
+    "engine": {"relay", "scheduler"},
+    "scheduler": {"relay"},
+}
+
+
+def _imported_names(path):
+    """Every module a file names in an `import` / `from ... import`, at
+    any depth, as dotted names (`from evolu_tpu.server import scope as
+    s` yields both `evolu_tpu.server` and `evolu_tpu.server.scope`)."""
+    with open(path, encoding="utf-8") as f:
+        tree = ast.parse(f.read(), path)
+    names = set()
+    for node in ast.walk(tree):
+        if isinstance(node, ast.Import):
+            names.update(a.name for a in node.names)
+        elif isinstance(node, ast.ImportFrom):
+            assert node.level == 0, f"{path}:{node.lineno}: relative import"
+            names.add(node.module)
+            names.update(f"{node.module}.{a.name}" for a in node.names)
+    return names
+
+
+@pytest.mark.parametrize("module", sorted(_LAYERS))
+def test_server_layers_import_only_what_is_below_them(module):
+    path = os.path.join(_REPO, "evolu_tpu", "server", module + ".py")
+    names = _imported_names(path)
+    assert any(n.startswith("evolu_tpu.") for n in names), names
+    bad = sorted(
+        n for n in names for b in _LAYERS[module]
+        if n in (b, "evolu_tpu.server." + b) or n.startswith(b + ".")
+    )
+    assert bad == [], f"server/{module}.py reaches above or beside itself: {bad}"

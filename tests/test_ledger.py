@@ -364,9 +364,9 @@ def test_miswired_route_is_caught_by_the_audit(monkeypatch):
     object store path) and the conservation audit must name the broken
     equation with a positive ingress-side delta — a ledger that cannot
     catch a mis-wired route is worse than none."""
-    from evolu_tpu.server import relay as relay_mod
+    from evolu_tpu.server import store as store_mod
 
-    monkeypatch.setattr(relay_mod, "_ledger_store_apply",
+    monkeypatch.setattr(store_mod, "_ledger_store_apply",
                         lambda *_a, **_kw: None)
     server = RelayServer(RelayStore()).start()
     try:

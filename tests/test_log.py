@@ -77,15 +77,15 @@ def test_span_trace_annotations_fire_under_the_target_name():
     orig = log_mod._trace_annotation_cls
     try:
         log_mod._trace_annotation_cls = FakeAnnotation
-        with lg.span("kernel:merkle", "reconcile_ingest", n=3):
+        with lg.span("kernel:merkle", "reconcile_stream_finish", n=3):
             pass
         with lg.span("kernel:merge"):
             pass
     finally:
         log_mod._trace_annotation_cls = orig
     assert entered == [
-        ("enter", "kernel:merkle|reconcile_ingest"),
-        ("exit", "kernel:merkle|reconcile_ingest"),
+        ("enter", "kernel:merkle|reconcile_stream_finish"),
+        ("exit", "kernel:merkle|reconcile_stream_finish"),
         ("enter", "kernel:merge"),
         ("exit", "kernel:merge"),
     ]

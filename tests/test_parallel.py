@@ -468,12 +468,13 @@ def test_dryrun_multichip_any_mesh_size(n):
 
 def test_reconcile_stream_matches_sequential_batches():
     """Pipelined streaming reconcile (device leg of batch k+1 in flight
-    while batch k commits) must end byte-identical to sequential
-    `reconcile` calls — across cross-batch duplicates, in-batch
+    while batch k commits) and sequential `reconcile` calls must both
+    end byte-identical to the sequential server (a per-request replay
+    on the python backend) — across cross-batch duplicates, in-batch
     duplicates, owners spanning batches, a non-canonical-hex owner, and
     an all-duplicate replay batch (VERDICT r2 #1)."""
     from evolu_tpu.server.engine import BatchReconciler
-    from evolu_tpu.server.relay import ShardedRelayStore
+    from evolu_tpu.server.relay import RelayStore, ShardedRelayStore
     from evolu_tpu.sync import protocol
 
     def enc(msgs):
@@ -505,16 +506,18 @@ def test_reconcile_stream_matches_sequential_batches():
     ]
 
     def dump(store):
-        out = []
-        for s in store.shards:
-            out += s.db.exec_sql_query(
-                'SELECT "timestamp","userId","content" FROM "message" '
-                'ORDER BY "userId","timestamp"'
-            )
-            out += s.db.exec_sql_query(
-                'SELECT "userId","merkleTree" FROM "merkleTree" ORDER BY "userId"'
-            )
-        return out
+        shards = getattr(store, "shards", [store])
+        return (
+            sorted(r for s in shards for r in s.db.exec(
+                'SELECT "timestamp","userId","content" FROM "message"')),
+            sorted(r for s in shards for r in s.db.exec(
+                'SELECT "userId","merkleTree" FROM "merkleTree"')),
+        )
+
+    # One request an owner a batch, so the sequential server answers
+    # each request as the batched pass does.
+    oracle = RelayStore(":memory:", "python")
+    want = [[oracle.sync(r) for r in batch] for batch in batches]
 
     seq_store = ShardedRelayStore(shards=4)
     seq_engine = BatchReconciler(seq_store, create_mesh())
@@ -524,10 +527,14 @@ def test_reconcile_stream_matches_sequential_batches():
     pipe_engine = BatchReconciler(pipe_store, create_mesh())
     pipe_responses = pipe_engine.reconcile_stream(batches)
 
-    assert dump(pipe_store) == dump(seq_store)
-    for br_seq, br_pipe in zip(seq_responses, pipe_responses):
-        assert [r.merkle_tree for r in br_seq] == [r.merkle_tree for r in br_pipe]
-        assert [len(r.messages) for r in br_seq] == [len(r.messages) for r in br_pipe]
+    try:
+        assert dump(seq_store) == dump(oracle)
+        assert dump(pipe_store) == dump(oracle)
+        assert seq_responses == want
+        assert pipe_responses == want
+    finally:
+        seq_engine.close(), pipe_engine.close()
+        seq_store.close(), pipe_store.close(), oracle.close()
 
 
 def test_compact_segment_overflow_falls_back_to_full_pull():
@@ -748,21 +755,38 @@ def test_run_batch_wire_on_generic_store_without_db_handle():
         ref_store.close(), gen_store.close()
 
 
-def test_delta_compact_transfer_matches_full_key_kernel(monkeypatch):
+def test_delta_compact_transfer_matches_full_key_kernel():
     """The 16 B/row delta-encoded compact upload (VERDICT #9) must
     produce identical deltas + digest to the 20 B/row packed-HLC-key
-    kernel, and batches outside its admission bounds (millis span
-    ≥ 2^32 ms) must silently keep the full-key kernel — same results
-    either way."""
+    kernel run on the same layout, and batches outside its admission
+    bounds (millis span ≥ 2^32 ms) must silently keep the full-key
+    kernel — same results either way, and the host fold's."""
     from evolu_tpu.core.merkle import minute_deltas_host
     from evolu_tpu.core.timestamp import Timestamp
-    from evolu_tpu.server.engine import deltas_from_columns
+    from evolu_tpu.obs import metrics
+    from evolu_tpu.ops import to_host_many, with_x64
     from evolu_tpu.ops.host_parse import parse_timestamp_strings
+    from evolu_tpu.parallel.mesh import put_sharded, sharding
+    from evolu_tpu.server import engine
 
     base = 1_700_000_000_000
     mesh = create_mesh()
 
-    def run(spread):
+    @with_x64
+    def full_key(cols):
+        """The full-key kernel on the layout `_deltas_layout` returns."""
+        deltas, digest, good, layout = engine._deltas_layout(mesh, *cols, None)
+        k1, node, oix, cap, upload, _rows = layout
+        outs = engine._compiled_merkle_kernel_compact(mesh, cap)(
+            *[put_sharded(a, sharding(mesh)) for a in (k1, node, oix)])
+        state = (deltas, digest, good, None, (k1, node, oix, mesh, cap))
+        return engine.deltas_decode(state, to_host_many(*outs)), upload is not None
+
+    def uploaded(variant):
+        return metrics.get_counter(
+            "evolu_engine_compact_upload_bytes_total", variant=variant)
+
+    def run(spread, admitted):
         owners, ts_all = {}, []
         for o in range(5):
             msgs = [
@@ -778,21 +802,21 @@ def test_delta_compact_transfer_matches_full_key_kernel(monkeypatch):
         for o, msgs in owners.items():
             owner_index[o] = np.arange(pos, pos + len(msgs))
             pos += len(msgs)
-        out = {}
-        for flag in ("1", "0"):
-            monkeypatch.setenv("EVOLU_COMPACT_DELTA", flag)
-            out[flag] = deltas_from_columns(
-                mesh, owner_index, all_m, all_c, all_n, case_ok, ts_all
-            )
-        monkeypatch.delenv("EVOLU_COMPACT_DELTA")
+        cols = (owner_index, all_m, all_c, all_n, case_ok, ts_all)
+        before = uploaded("delta"), uploaded("full")
+        routed = engine.deltas_from_columns(mesh, *cols)
+        grew = uploaded("delta") > before[0], uploaded("full") > before[1]
+        assert grew == (admitted, not admitted)
+        full, layout_admits = full_key(cols)
+        assert layout_admits == admitted
         # Host oracle cross-check, not just self-consistency.
         expect_digest = 0
         for o, msgs in owners.items():
             exp, d = minute_deltas_host(msgs)
-            assert out["1"][0][o] == exp, o
+            assert routed[0][o] == exp, o
             expect_digest ^= d
-        assert out["1"] == out["0"]
-        assert out["1"][1] == expect_digest
+        assert routed == full
+        assert routed[1] == expect_digest
 
-    run(spread=977)            # in-bounds: the delta kernel serves it
-    run(spread=120_000_000_00)  # 1.2e10 ms × 40 rows ≫ 2^32: full-key fallback
+    run(spread=977, admitted=True)  # in-bounds: the delta kernel serves it
+    run(spread=120_000_000_00, admitted=False)  # 1.2e10 ms × 40 rows ≫ 2^32: full-key

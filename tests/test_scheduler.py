@@ -491,7 +491,7 @@ def test_singleton_fallback_never_overlaps_an_open_engine_pass(monkeypatch):
     transaction on the shared connection, so a handler-thread fallback
     write acked mid-batch would be silently rolled back if the batch
     later poisoned (review finding)."""
-    import evolu_tpu.server.relay as relay_mod
+    import evolu_tpu.server.scheduler as sched_mod
     from evolu_tpu.server.engine import BatchReconciler
 
     store = ShardedRelayStore(shards=2)
@@ -508,14 +508,14 @@ def test_singleton_fallback_never_overlaps_an_open_engine_pass(monkeypatch):
             in_pass.clear()
 
     eng.run_batch_wire = slow
-    orig_serve = relay_mod.serve_single_request
+    orig_serve = sched_mod.serve_single_request
     overlap = []
 
     def spying_serve(store_, request):
         overlap.append(in_pass.is_set())
         return orig_serve(store_, request)
 
-    monkeypatch.setattr(relay_mod, "serve_single_request", spying_serve)
+    monkeypatch.setattr(sched_mod, "serve_single_request", spying_serve)
     sched = SyncScheduler(store, engine=eng, max_batch=4, max_wait_s=0.0)
     bad = protocol.SyncRequest(
         (protocol.EncryptedCrdtMessage("short", b"x"),), "ser-bad", "6" * 16, "{}"

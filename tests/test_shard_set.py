@@ -198,14 +198,29 @@ def _requests(live, rows, scenario, round_):
     return reqs
 
 
+def _replay_per_request(reqs):
+    """→ (python store, its answers): the sequential server's replay."""
+    oracle = RelayStore(":memory:", "python")
+    with ledger_mod.quarantine():
+        return oracle, [oracle.sync(r) for r in reqs]
+
+
+def _assert_equals_replay(store, oracle):
+    dump = relay_store_dump(store)
+    assert sorted(r for msgs, _t in dump for r in msgs) == oracle.db.exec(
+        'SELECT * FROM "message" ORDER BY "timestamp", "userId"')
+    assert sorted(r for _m, ts in dump for r in ts) == oracle.db.exec(
+        'SELECT * FROM "merkleTree" ORDER BY "userId"')
+
+
 @pytest.mark.parametrize("size", sorted(ROWS_PER_SHARD))
 @pytest.mark.parametrize("entry", ("run_batch_wire", "reconcile_wire"))
 @pytest.mark.parametrize("k", sorted(SUBSETS))
 def test_engine_wire_bytes_match_the_python_replay(k, entry, size):
     """Every scenario as consecutive batches through the served entry
-    (`run_batch_wire`: start_batch/finish_batch) and the one-shot entry
-    (`reconcile_wire`: `_ingest_packed`) on the native sharded store,
-    against the same batches through the python backend."""
+    (`run_batch_wire`) and the offline one (`reconcile_wire`) on the
+    native sharded store, both `start_batch` + `_land`, against the
+    same batches through the python backend (`_ingest_generic`)."""
     live = SUBSETS[k]
     rows = ROWS_PER_SHARD[size]
     store = ShardedRelayStore(":memory:", "native", shards=SHARDS)
@@ -224,13 +239,111 @@ def test_engine_wire_bytes_match_the_python_replay(k, entry, size):
                 n += 1
         assert metrics.get_counter(
             "evolu_engine_store_calls_total", op="insert") == calls0 + n
-        merged = sorted(r for msgs, _t in relay_store_dump(store) for r in msgs)
-        assert merged == oracle.db.exec(
-            'SELECT * FROM "message" ORDER BY "timestamp", "userId"')
-        trees = sorted(r for _m, ts in relay_store_dump(store) for r in ts)
-        assert trees == oracle.db.exec('SELECT * FROM "merkleTree" ORDER BY "userId"')
+        _assert_equals_replay(store, oracle)
     finally:
         eng.close(), oracle_eng.close(), store.close(), oracle.close()
+
+
+def test_reconcile_lands_through_the_stream_route_in_two_store_calls():
+    """`reconcile` on a packed-capable store is the scheduler's route:
+    one pass counted under `path="stream"`, two native store calls, the
+    `pass_*` stages of a served pass; answers, rows and trees are the
+    per-request replay's on the python backend."""
+    store = ShardedRelayStore(":memory:", "native", shards=SHARDS)
+    eng = BatchReconciler(store)
+    # One request an owner a batch (a batched pass answers every request
+    # of an owner from the tree after all of them); the second batch
+    # re-sends half of the first and repeats rows inside one request.
+    first = _requests(SUBSETS[8], 24, "already_stored", 0)
+    reqs = _requests(SUBSETS[8], 24, "already_stored", 1)
+    reqs[0] = protocol.SyncRequest(
+        reqs[0].messages + reqs[0].messages[:3], reqs[0].user_id, "f" * 16, "{}")
+    oracle, want = _replay_per_request(first + reqs)
+    try:
+        with ledger_mod.quarantine():
+            assert eng.reconcile(first) == want[:len(first)]
+        passes0 = {p: metrics.get_counter("evolu_engine_store_passes_total", path=p)
+                   for p in ("stream", "generic", "write_behind", "oneshot")}
+        calls0 = [metrics.get_counter("evolu_engine_store_calls_total", op=op)
+                  for op in ("insert", "commit")]
+        tiles0 = metrics.registry.get_histogram("evolu_stage_ms", stage="pass_insert")[3]
+        with ledger_mod.quarantine():
+            assert eng.reconcile(reqs) == want[len(first):]
+        passes = {p: metrics.get_counter("evolu_engine_store_passes_total", path=p)
+                  for p in passes0}
+        assert passes == {**passes0, "stream": passes0["stream"] + 1}
+        assert [metrics.get_counter("evolu_engine_store_calls_total", op=op)
+                for op in ("insert", "commit")] == [calls0[0] + 1, calls0[1] + 1]
+        assert metrics.registry.get_histogram(
+            "evolu_stage_ms", stage="pass_insert")[3] == tiles0 + 1
+        _assert_equals_replay(store, oracle)
+    finally:
+        eng.close(), store.close(), oracle.close()
+
+
+def test_a_batch_replayed_whole_inserts_nothing_and_counts_duplicates():
+    """Every row a duplicate: the device hashes them all, every owner is
+    recomputed on the host to an empty delta, no tree TEXT moves, and
+    the ledger files every row under store.duplicate."""
+    ledger_mod.reset()
+    ledger_mod.set_enabled(True)
+    store = ShardedRelayStore(":memory:", "native", shards=SHARDS)
+    eng = BatchReconciler(store)
+    reqs = _requests(SUBSETS[8], 24, "in_batch_duplicates", 0)
+    n = sum(len(r.messages) for r in reqs)
+    try:
+        first = eng.reconcile(reqs)
+        before = _dump(store)
+        t0 = ledger_mod.totals()
+        again = eng.reconcile(reqs)
+        assert _dump(store) == before
+        assert [r.merkle_tree for r in again] == [r.merkle_tree for r in first]
+        t1 = ledger_mod.totals()
+        assert t1[ledger_mod.STORE_INSERTED] == t0[ledger_mod.STORE_INSERTED]
+        assert t1[ledger_mod.STORE_DUPLICATE] == t0[ledger_mod.STORE_DUPLICATE] + n
+    finally:
+        eng.close(), store.close()
+        ledger_mod.reset()
+
+
+class _StandIn:
+    """The RelayStore surface over a real store, without `.db` and
+    without the store's own answer."""
+
+    def __init__(self, inner):
+        for name in ("add_messages", "get_messages", "get_merkle_tree",
+                     "get_merkle_tree_string", "sync"):
+            setattr(self, name, getattr(inner, name))
+
+
+@pytest.mark.parametrize("shape, packed, route", [
+    ("native", True, "stream"),
+    ("python", False, "generic"),
+    ("sharded-native", True, "stream"),
+    ("sharded-python", False, "generic"),
+    ("stand-in", False, "generic"),
+])
+def test_the_store_answers_packed_capable_and_the_engine_routes_by_it(shape, packed, route):
+    backend = "python" if shape.endswith("python") else "native"
+    inner = (ShardedRelayStore(":memory:", backend, shards=2) if shape.startswith("sharded")
+             else RelayStore(":memory:", backend))
+    store = _StandIn(inner) if shape == "stand-in" else inner
+    eng = BatchReconciler(store)
+    try:
+        assert getattr(store, "packed", False) is packed
+        for s in getattr(store, "shards", ()):
+            assert s.packed is packed
+        assert eng._route(live=False) == eng._route(live=True) == route
+        passes0 = metrics.get_counter("evolu_engine_store_passes_total", path=route)
+        reqs = _requests((0, 1), 12, "fresh", 0)
+        oracle, want = _replay_per_request(reqs)
+        with ledger_mod.quarantine():
+            assert eng.reconcile(reqs) == want
+        assert metrics.get_counter(
+            "evolu_engine_store_passes_total", path=route) == passes0 + 1
+        oracle.close()
+    finally:
+        eng.close(), inner.close()
 
 
 def _poison_closed(store, si):
