@@ -1,8 +1,9 @@
 """Batched-AEAD v2 sync payload — the `aead-batch-v1` capability.
 
 The reference wire (sync/crypto.py) pays a FRESH iterated+salted S2K —
-a 1KB SHA-256 — per message: ~3µs/msg of irreducible format cost that
-caps any implementation near 330k msgs/s/core while the in-kernel
+a 1KB SHA-256 — per message: format cost (~3µs/msg as measured then,
+~1.5 since PR 32, PERF.md §6) that caps any implementation at a few
+hundred thousand msgs/s/core while the in-kernel
 merge runs 282M msgs/s/chip (docs/BENCHMARKS.md; ROADMAP open item
 #2 records that "only protocol changes could beat it"). This module is
 that protocol change: the key is derived ONCE per (owner, session)

@@ -20,8 +20,10 @@ the shapes OpenPGP.js can emit for these small messages.
 Crypto is host-side work by design (SURVEY.md §5): the TPU kernels
 never see plaintext values, mirroring the E2EE-blind relay.
 
-The ~3µs/msg S2K here is the measured per-message floor of this wire
-format (docs/BENCHMARKS.md). `sync/aead.py` is the negotiated escape
+The per-message S2K (1 KB of SHA-256, then a key schedule) is this wire
+format's floor: ~1.5µs/msg in the native layer (PERF.md §6, PR 32; the
+~3µs docs/BENCHMARKS.md measured held a microsecond of OpenSSL 3's
+per-init algorithm lookup). `sync/aead.py` is the negotiated escape
 hatch — session-keyed AES-256-GCM records under the `aead-batch-v1`
 capability — and `aead.decrypt_content` is the dispatch that lets
 stored logs mix both formats; this module stays the reference-parity

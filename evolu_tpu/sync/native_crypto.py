@@ -30,7 +30,7 @@ from array import array
 from typing import List, Optional, Sequence, Tuple
 
 from evolu_tpu.core.types import CrdtMessage
-from evolu_tpu.obs import anatomy
+from evolu_tpu.obs import anatomy, metrics
 from evolu_tpu.sync import protocol
 from evolu_tpu.sync.aead import decrypt_content
 from evolu_tpu.utils.native_loader import load_native_library
@@ -69,7 +69,13 @@ def _configure(lib: ctypes.CDLL) -> Optional[ctypes.CDLL]:
     lib.ehc_decrypt_response_columns.restype = c.c_int
     lib.ehc_decrypt_response_columns.argtypes = [
         c.c_char_p, c.c_int64, c.c_char_p, c.c_int32,
-        c.POINTER(c.c_void_p), c.POINTER(c.c_int64),
+        c.POINTER(c.c_void_p), c.POINTER(c.c_int64), c.POINTER(c.c_int32),
+    ]
+    # The same with the lane count given: for the tests.
+    lib.ehc_decrypt_response_columns_lanes.restype = c.c_int
+    lib.ehc_decrypt_response_columns_lanes.argtypes = [
+        c.c_char_p, c.c_int64, c.c_char_p, c.c_int32, c.c_int32,
+        c.POINTER(c.c_void_p), c.POINTER(c.c_int64), c.POINTER(c.c_int32),
     ]
     lib.ehc_free.argtypes = [c.c_void_p]
     # aead-batch-v1 leg (ISSUE 8). Guarded: a stale binary without the
@@ -535,24 +541,35 @@ def decrypt_response_columns(response_bytes: bytes, password: str):
     non-canonical wire) — the caller then runs `decrypt_response` /
     the pure decoder, which own the exact error surface. Success here
     implies the object path would have produced the same batch
-    (pinned by tests), so behavior is identical either way."""
+    (pinned by tests), so behavior is identical either way.
+
+    The C call decrypts in lanes (native/evolu_crypto.cpp): one for
+    every 4,096 messages, at most 8 and at most the cores this process
+    may run on, threads started and joined inside the call; under 8,192
+    messages one lane, the calling thread. The blob does not depend on
+    the lane count. `evolu_recv_decrypt_lanes_total` /
+    `evolu_recv_decrypt_calls_total` say how widely and how often."""
     lib = load_library()
     if lib is None:
         return None
     from evolu_tpu.core.packed import PackedReceive
 
     # `recv_decrypt`, on the caller's thread: wire bytes to the
-    # PackedReceive; its rows are the messages of a decoded response.
+    # PackedReceive (walk, lanes, columnarize, `from_blob`); its rows
+    # are the messages of a decoded response.
     with anatomy.stage("recv_decrypt") as decrypt:
         pw = password.encode("utf-8")
         out_p = ctypes.c_void_p()
         out_len = ctypes.c_int64()
+        lanes = ctypes.c_int32()
         rc = lib.ehc_decrypt_response_columns(
             response_bytes, len(response_bytes), pw, len(pw),
-            ctypes.byref(out_p), ctypes.byref(out_len),
+            ctypes.byref(out_p), ctypes.byref(out_len), ctypes.byref(lanes),
         )
         if rc != 0:
             return None
+        metrics.inc_many((("evolu_recv_decrypt_lanes_total", lanes.value, {}),
+                          ("evolu_recv_decrypt_calls_total", 1, {})))
         try:
             raw = ctypes.string_at(out_p.value, out_len.value)
         finally:

@@ -22,13 +22,37 @@
 // OpenSSL: the image ships libcrypto.so.3 without dev headers, so the
 // needed EVP/RAND prototypes are declared here (stable ABI) and the
 // Makefile links the versioned soname directly, mirroring its
-// libsqlite3 pattern.
+// libsqlite3 pattern. The four functions that exist only in OpenSSL 3
+// (EVP_MD_fetch / EVP_MD_free / EVP_CIPHER_fetch / EVP_CIPHER_free) are
+// looked up with dlsym, so the same source links against 1.1 too.
+//
+// What a v1 message costs (ISSUE 32). Under OpenSSL 3 an `*_Init_ex` on
+// a legacy handle (`EVP_sha256()`, `EVP_aes_256_cfb128()`) fetches the
+// algorithm again through the library's global method store, under its
+// lock, and decrypt_one did three a message: a third of the "3 us of
+// S2K" that earlier notes called the format's floor was that lookup,
+// and it is why threads contended. `Ctxs` now resolves its algorithms
+// once a call and nothing per message touches shared OpenSSL state: not
+// the store, and not the reference count of a shared EVP_MD / EVP_CIPHER
+// either (SHA-256 and SHA-1 each keep their own EVP_MD_CTX; a cipher
+// context is told its cipher once). What is left of a message is the
+// format's: 1 KiB of SHA-256 (S2K count byte 0), an AES-256 key
+// schedule, ~120 bytes of CFB and ~100 of SHA-1.
 
+#include <dlfcn.h>
+#include <sched.h>
+
+#include <algorithm>
 #include <array>
 #include <cstdint>
 #include <cstdlib>
 #include <cstring>
+#include <functional>
+#include <memory>
+#include <new>
 #include <string>
+#include <system_error>
+#include <thread>
 #include <unordered_map>
 #include <vector>
 
@@ -102,12 +126,52 @@ inline uint8_t *put_pkt_hdr(uint8_t *p, int tag, size_t n) {
 // AEAD aliases share the GCM values).
 constexpr int CTRL_GCM_GET_TAG = 0x10, CTRL_GCM_SET_TAG = 0x11;
 
+// OpenSSL 3's explicit fetch, absent from 1.1 and therefore not
+// declared above: resolved once a process from the very libcrypto the
+// declared symbols bind to (all four or none). 1.1 has no implicit
+// fetch to avoid, so there the legacy handles stay.
+struct FetchApi {
+  EVP_MD *(*md_fetch)(void *, const char *, const char *) = nullptr;
+  void (*md_free)(EVP_MD *) = nullptr;
+  EVP_CIPHER *(*cipher_fetch)(void *, const char *, const char *) = nullptr;
+  void (*cipher_free)(EVP_CIPHER *) = nullptr;
+  FetchApi() {
+    Dl_info info;
+    if (!dladdr(reinterpret_cast<void *>(&EVP_sha256), &info) || !info.dli_fname) return;
+    // NOLOAD: a handle on the library that is already mapped, never a
+    // second copy; kept for the life of the process like the library.
+    void *lib = dlopen(info.dli_fname, RTLD_LAZY | RTLD_NOLOAD);
+    if (!lib) return;
+    auto mf = reinterpret_cast<decltype(md_fetch)>(dlsym(lib, "EVP_MD_fetch"));
+    auto mr = reinterpret_cast<decltype(md_free)>(dlsym(lib, "EVP_MD_free"));
+    auto cf = reinterpret_cast<decltype(cipher_fetch)>(dlsym(lib, "EVP_CIPHER_fetch"));
+    auto cr = reinterpret_cast<decltype(cipher_free)>(dlsym(lib, "EVP_CIPHER_free"));
+    if (mf && mr && cf && cr) { md_fetch = mf; md_free = mr; cipher_fetch = cf; cipher_free = cr; }
+  }
+};
+
+const FetchApi &fetch_api() {
+  static const FetchApi api;  // thread-safe once (C++11), read-only after
+  return api;
+}
+
 struct Ctxs {
   EVP_CIPHER_CTX *cipher = nullptr;
-  EVP_MD_CTX *md = nullptr;
+  // One digest context an algorithm: a context that alternates between
+  // two frees and reallocates its algorithm state and takes and drops a
+  // reference on the shared EVP_MD at every switch.
+  EVP_MD_CTX *md = nullptr;       // SHA-256: S2K, HKDF
+  EVP_MD_CTX *md_sha1 = nullptr;  // SHA-1: the MDC
   const EVP_CIPHER *aes = nullptr;
   const EVP_MD *sha256 = nullptr;
   const EVP_MD *sha1 = nullptr;
+  // What this Ctxs fetched and must free (null under OpenSSL 1.1).
+  EVP_MD *own_sha256 = nullptr, *own_sha1 = nullptr;
+  EVP_CIPHER *own_aes = nullptr, *own_gcm = nullptr;
+  // `cipher` has been told its cipher: inits pass nullptr (cfb_init).
+  bool cfb_named = false;
+  // salt ‖ password repeated, for s2k_iterated.
+  std::vector<uint8_t> s2k_tile;
   // aead-batch-v1 state: a dedicated GCM context so the CFB context's
   // reuse pattern is untouched. `gcm_keyed` tracks whether gcm_ctx
   // currently holds `gcm_key` with its AES key schedule expanded — a
@@ -127,22 +191,65 @@ struct Ctxs {
   uint8_t last_salt[16] = {0};
   uint8_t last_key[32] = {0};
   bool has_last_salt = false;
-  bool ok() const { return cipher && md && aes && sha256 && sha1 && gcm_ctx && gcm; }
+  bool ok() const {
+    return cipher && md && md_sha1 && aes && sha256 && sha1 && gcm_ctx && gcm;
+  }
   Ctxs() {
     cipher = EVP_CIPHER_CTX_new();
     md = EVP_MD_CTX_new();
-    aes = EVP_aes_256_cfb128();
-    sha256 = EVP_sha256();
-    sha1 = EVP_sha1();
+    md_sha1 = EVP_MD_CTX_new();
     gcm_ctx = EVP_CIPHER_CTX_new();
-    gcm = EVP_aes_256_gcm();
+    const FetchApi &api = fetch_api();
+    if (api.md_fetch) {
+      own_sha256 = api.md_fetch(nullptr, "SHA256", nullptr);
+      own_sha1 = api.md_fetch(nullptr, "SHA1", nullptr);
+      own_aes = api.cipher_fetch(nullptr, "AES-256-CFB", nullptr);
+      own_gcm = api.cipher_fetch(nullptr, "AES-256-GCM", nullptr);
+    }
+    // A provider that lacks one keeps the legacy handle's behaviour.
+    sha256 = own_sha256 ? own_sha256 : EVP_sha256();
+    sha1 = own_sha1 ? own_sha1 : EVP_sha1();
+    aes = own_aes ? own_aes : EVP_aes_256_cfb128();
+    gcm = own_gcm ? own_gcm : EVP_aes_256_gcm();
+    // Let each context allocate its algorithm state here, on the thread
+    // that builds the Ctxs, not at the first message on the thread that
+    // uses it (a lane's: see ehc_decrypt_response_columns). A failure
+    // shows again, and is handled, at the first message.
+    if (ok()) {
+      EVP_DigestInit_ex(md, sha256, nullptr);
+      EVP_DigestInit_ex(md_sha1, sha1, nullptr);
+      cfb_named = EVP_DecryptInit_ex(cipher, aes, nullptr, nullptr, nullptr) != 0;
+    }
   }
+  Ctxs(const Ctxs &) = delete;
+  Ctxs &operator=(const Ctxs &) = delete;
   ~Ctxs() {
+    // Contexts first: each holds a reference on what it was given.
     if (cipher) EVP_CIPHER_CTX_free(cipher);
     if (md) EVP_MD_CTX_free(md);
+    if (md_sha1) EVP_MD_CTX_free(md_sha1);
     if (gcm_ctx) EVP_CIPHER_CTX_free(gcm_ctx);
+    const FetchApi &api = fetch_api();
+    if (own_sha256) api.md_free(own_sha256);
+    if (own_sha1) api.md_free(own_sha1);
+    if (own_aes) api.cipher_free(own_aes);
+    if (own_gcm) api.cipher_free(own_gcm);
   }
 };
+
+// (Re)key the CFB context with the zero IV of SEIPD v1. The cipher is
+// named once a context (in Ctxs(), here only after a failure): an
+// `*_Init_ex` that names it again resets the context (frees and
+// reallocates its state, takes and drops a reference on the shared
+// EVP_CIPHER); one that passes nullptr keeps the state and runs the key
+// schedule alone.
+bool cfb_init(Ctxs &cx, const uint8_t key[32], bool enc) {
+  static const uint8_t zero_iv[16] = {0};
+  const EVP_CIPHER *named = cx.cfb_named ? nullptr : cx.aes;
+  cx.cfb_named = (enc ? EVP_EncryptInit_ex(cx.cipher, named, nullptr, key, zero_iv)
+                      : EVP_DecryptInit_ex(cx.cipher, named, nullptr, key, zero_iv)) != 0;
+  return cx.cfb_named;
+}
 
 // RFC 4880 §3.7.1.3 iterated+salted S2K (SHA-256 → exactly the 32-byte
 // AES-256 key, single context). Incremental so an adversarial wire
@@ -150,15 +257,26 @@ struct Ctxs {
 bool s2k_iterated(Ctxs &cx, const uint8_t *pw, size_t pw_len,
                   const uint8_t *salt, int count_byte, uint8_t key_out[32]) {
   uint64_t count = uint64_t(16 + (count_byte & 15)) << ((count_byte >> 4) + 6);
-  std::vector<uint8_t> data(8 + pw_len);
-  memcpy(data.data(), salt, 8);
-  memcpy(data.data() + 8, pw, pw_len);
-  uint64_t total = count > data.size() ? count : data.size();
+  // The hashed stream is salt ‖ password repeated to `total` bytes. It
+  // is fed from a tile of whole repetitions that lives in `cx` (one
+  // allocation a call, not one a message) and covers the usual count
+  // (1,024: count byte 0) in ONE DigestUpdate where a repetition a call
+  // made 14.
+  size_t unit = 8 + pw_len;
+  size_t reps = unit >= 1024 ? 1 : (1024 + unit - 1) / unit;
+  size_t tile = unit * reps;
+  if (cx.s2k_tile.size() != tile) cx.s2k_tile.resize(tile);
+  uint8_t *t = cx.s2k_tile.data();
+  memcpy(t, salt, 8);
+  memcpy(t + 8, pw, pw_len);
+  for (size_t r = 1; r < reps; r++) memcpy(t + r * unit, t, unit);
+  uint64_t total = count > unit ? count : unit;
   if (!EVP_DigestInit_ex(cx.md, cx.sha256, nullptr)) return false;
-  uint64_t full = total / data.size(), rem = total % data.size();
-  for (uint64_t i = 0; i < full; i++)
-    if (!EVP_DigestUpdate(cx.md, data.data(), data.size())) return false;
-  if (rem && !EVP_DigestUpdate(cx.md, data.data(), size_t(rem))) return false;
+  // A whole tile is a whole number of repetitions, so every update
+  // starts at phase 0 of the stream.
+  for (; total >= tile; total -= tile)
+    if (!EVP_DigestUpdate(cx.md, t, tile)) return false;
+  if (total && !EVP_DigestUpdate(cx.md, t, size_t(total))) return false;
   unsigned int out_len = 0;
   uint8_t digest[32];
   if (!EVP_DigestFinal_ex(cx.md, digest, &out_len) || out_len != 32) return false;
@@ -180,10 +298,10 @@ bool s2k_salted(Ctxs &cx, const uint8_t *pw, size_t pw_len,
 }
 
 bool sha1_oneshot(Ctxs &cx, const uint8_t *data, size_t n, uint8_t out[20]) {
-  if (!EVP_DigestInit_ex(cx.md, cx.sha1, nullptr)) return false;
-  if (!EVP_DigestUpdate(cx.md, data, n)) return false;
+  if (!EVP_DigestInit_ex(cx.md_sha1, cx.sha1, nullptr)) return false;
+  if (!EVP_DigestUpdate(cx.md_sha1, data, n)) return false;
   unsigned int out_len = 0;
-  if (!EVP_DigestFinal_ex(cx.md, out, &out_len) || out_len != 20) return false;
+  if (!EVP_DigestFinal_ex(cx.md_sha1, out, &out_len) || out_len != 20) return false;
   return true;
 }
 
@@ -291,12 +409,13 @@ bool gcm_ready(Ctxs &cx, const uint8_t key[32], const uint8_t *nonce, bool enc) 
              : EVP_DecryptInit_ex(cx.gcm_ctx, nullptr, nullptr, nullptr, nonce);
 }
 
-// Decrypt + verify ONE v2 record into `plain` (resized to the content
-// length). false = demote to the Python oracle (which owns the exact
-// PgpError surface for truncation/auth failure).
+// Decrypt + verify ONE v2 record into `plain` (room for `clen` bytes;
+// the content's length, clen − 47, comes back in `plain_len`). false =
+// demote to the Python oracle (which owns the exact PgpError surface
+// for truncation/auth failure).
 bool aead_open_record(Ctxs &cx, const uint8_t *msg, size_t clen,
                       const uint8_t *password, size_t pw_len,
-                      std::vector<uint8_t> &plain) {
+                      uint8_t *plain, size_t &plain_len) {
   if (clen < AEAD_OVERHEAD) return false;
   const uint8_t *salt = msg + 3, *nonce = msg + 3 + AEAD_SALT;
   const uint8_t *ct = msg + 3 + AEAD_SALT + AEAD_NONCE;
@@ -305,22 +424,20 @@ bool aead_open_record(Ctxs &cx, const uint8_t *msg, size_t clen,
   memcpy(tag, msg + clen - AEAD_TAG, AEAD_TAG);
   if (!aead_key_for(cx, password, pw_len, salt, key)) return false;
   if (!gcm_ready(cx, key, nonce, /*enc=*/false)) { cx.gcm_keyed = false; return false; }
-  plain.resize(ct_len ? ct_len : 1);
   int len = 0, fl = 0;
-  if (ct_len && !EVP_DecryptUpdate(cx.gcm_ctx, plain.data(), &len, ct,
-                                   int(ct_len))) {
+  if (ct_len && !EVP_DecryptUpdate(cx.gcm_ctx, plain, &len, ct, int(ct_len))) {
     cx.gcm_keyed = false;
     return false;
   }
   if (EVP_CIPHER_CTX_ctrl(cx.gcm_ctx, CTRL_GCM_SET_TAG, AEAD_TAG, tag) != 1 ||
-      EVP_DecryptFinal_ex(cx.gcm_ctx, plain.data() + len, &fl) != 1 ||
+      EVP_DecryptFinal_ex(cx.gcm_ctx, plain + len, &fl) != 1 ||
       size_t(len + fl) != ct_len) {
     // A failed final leaves ctx state undefined enough that the next
     // record must re-run the full keyed init.
     cx.gcm_keyed = false;
     return false;
   }
-  plain.resize(ct_len);
+  plain_len = ct_len;
   return true;
 }
 
@@ -454,7 +571,6 @@ bool emit_message(Ctxs &cx, const uint8_t *password, size_t pw_len,
                   const uint8_t *rnd24, const uint8_t *strs,
                   const int32_t L[4], int8_t vkind, int64_t ival, double dval,
                   size_t c, std::vector<uint8_t> &plainbuf, uint8_t *dst) {
-  static const uint8_t zero_iv[16] = {0};
   const uint8_t *salt = rnd24, *prefix = rnd24 + 8;
   uint8_t key[32];
   if (!s2k_iterated(cx, password, pw_len, salt, 0, key)) return false;
@@ -486,7 +602,7 @@ bool emit_message(Ctxs &cx, const uint8_t *password, size_t pw_len,
   q = put_pkt_hdr(q, 18, seipd_body);
   *q++ = 0x01;
   int enc_len = 0;
-  if (!EVP_EncryptInit_ex(cx.cipher, cx.aes, nullptr, key, zero_iv) ||
+  if (!cfb_init(cx, key, /*enc=*/true) ||
       !EVP_EncryptUpdate(cx.cipher, q, &enc_len, plainbuf.data(), int(plain)) ||
       size_t(enc_len) != plain)
     return false;
@@ -1091,23 +1207,39 @@ bool decode_content(const uint8_t *d, size_t n, Content &out) {
   return true;
 }
 
-// Decrypt ONE canonical SKESK‖SEIPD stream + decode its content.
-// false = demote this message to the Python oracle.
-bool decrypt_one(Ctxs &cx, const uint8_t *msg, size_t clen,
-                 const uint8_t *password, size_t pw_len,
-                 std::vector<uint8_t> &plain, std::vector<Pkt> &pkts,
-                 std::vector<Pkt> &inner, Content &c) {
+// What decrypt_one needs besides the message: the contexts and two
+// packet lists, reused from message to message. One a call, or one a
+// lane (ehc_decrypt_response_columns); nothing in it is shared.
+struct Scratch {
+  Ctxs cx;
+  std::vector<Pkt> pkts, inner;
+  Scratch() {
+    pkts.reserve(8);  // a canonical message has two packets and one inside
+    inner.reserve(8);
+  }
+};
+
+// Decrypt ONE canonical SKESK‖SEIPD stream (or one v2 record) into
+// `plain` and decode its content; `c` points into `plain`, which must
+// have room for `clen` bytes (a plaintext is no longer than its
+// ciphertext) and outlive `c`. false = demote this message to the
+// Python oracle.
+bool decrypt_one(Scratch &sc, const uint8_t *msg, size_t clen,
+                 const uint8_t *password, size_t pw_len, uint8_t *plain,
+                 Content &c) {
+  Ctxs &cx = sc.cx;
+  std::vector<Pkt> &pkts = sc.pkts, &inner = sc.inner;
   if (is_aead_record(msg, clen)) {
     // aead-batch-v1 record: session-keyed GCM instead of per-message
     // S2K. Every decrypt entry point (batch, fused response, fused
     // columns) gains v2 through this one dispatch; any failure —
     // truncation, bad tag — demotes to the Python oracle, which owns
     // the exact PgpError surface.
-    if (!aead_open_record(cx, msg, clen, password, pw_len, plain))
+    size_t plain_len = 0;
+    if (!aead_open_record(cx, msg, clen, password, pw_len, plain, plain_len))
       return false;
-    return decode_content(plain.data(), plain.size(), c);
+    return decode_content(plain, plain_len, c);
   }
-  static const uint8_t zero_iv[16] = {0};
   pkts.clear();
   if (!read_packets(msg, clen, pkts)) return false;
   const Pkt *skesk = nullptr, *seipd = nullptr;
@@ -1137,14 +1269,13 @@ bool decrypt_one(Ctxs &cx, const uint8_t *msg, size_t clen,
 
   if (seipd->len < 1 + 18 + 22 || seipd->body[0] != 1) return false;
   size_t blen = seipd->len - 1;
-  plain.resize(blen);
   int dec_len = 0;
-  if (!EVP_DecryptInit_ex(cx.cipher, cx.aes, nullptr, key, zero_iv) ||
-      !EVP_DecryptUpdate(cx.cipher, plain.data(), &dec_len, seipd->body + 1,
+  if (!cfb_init(cx, key, /*enc=*/false) ||
+      !EVP_DecryptUpdate(cx.cipher, plain, &dec_len, seipd->body + 1,
                          int(blen)) ||
       size_t(dec_len) != blen)
     return false;
-  const uint8_t *b = plain.data();
+  const uint8_t *b = plain;
   if (b[16] != b[14] || b[17] != b[15]) return false;  // wrong password → oracle
   if (b[blen - 22] != 0xD3 || b[blen - 21] != 0x14) return false;
   uint8_t mdc[20];
@@ -1197,12 +1328,11 @@ void append_content_record(std::string &out, const Content &c) {
 int ehc_decrypt_batch(int64_t n, const uint8_t *ct_blob, const int32_t *ct_lens,
                       const uint8_t *password, int32_t pw_len,
                       uint8_t *statuses, uint8_t **out_blob, int64_t *out_len) {
-  Ctxs cx;
-  if (!cx.ok() || n < 0 || pw_len < 0) return 1;
+  Scratch sc;
+  if (!sc.cx.ok() || n < 0 || pw_len < 0) return 1;
   std::string out;
   out.reserve(size_t(n) * 128);
   std::vector<uint8_t> plain;
-  std::vector<Pkt> pkts, inner;
   const uint8_t *ct = ct_blob;
 
   for (int64_t i = 0; i < n; i++) {
@@ -1210,8 +1340,8 @@ int ehc_decrypt_batch(int64_t n, const uint8_t *ct_blob, const int32_t *ct_lens,
     const uint8_t *msg = ct;
     ct += clen;
     Content c;
-    if (decrypt_one(cx, msg, clen, password, size_t(pw_len), plain, pkts,
-                    inner, c)) {
+    if (plain.size() < clen) plain.resize(clen);
+    if (decrypt_one(sc, msg, clen, password, size_t(pw_len), plain.data(), c)) {
       append_content_record(out, c);
       statuses[i] = 0;
     } else {
@@ -1243,15 +1373,14 @@ int ehc_decrypt_batch(int64_t n, const uint8_t *ct_blob, const int32_t *ct_lens,
 int ehc_decrypt_response(const uint8_t *resp, int64_t resp_len,
                          const uint8_t *password, int32_t pw_len,
                          uint8_t **out_blob, int64_t *out_len) {
-  Ctxs cx;
-  if (!cx.ok() || resp_len < 0 || pw_len < 0) return 1;
+  Scratch sc;
+  if (!sc.cx.ok() || resp_len < 0 || pw_len < 0) return 1;
   size_t n_ = size_t(resp_len);
   std::string out(12, '\0');  // n + tree_len placeholders
   int64_t n_msgs = 0;
   const uint8_t *tree = nullptr;
   size_t tree_len = 0;
   std::vector<uint8_t> plain;
-  std::vector<Pkt> pkts, inner;
 
   int n_caps = 0;
   size_t pos = 0;
@@ -1308,8 +1437,9 @@ int ehc_decrypt_response(const uint8_t *resp, int64_t resp_len,
     out.append(reinterpret_cast<const char *>(&tl32), 4);
     if (ts_len) out.append(reinterpret_cast<const char *>(ts), ts_len);
     Content c;
-    if (ct && decrypt_one(cx, ct, ct_len, password, size_t(pw_len), plain,
-                          pkts, inner, c)) {
+    if (plain.size() < ct_len) plain.resize(ct_len);
+    if (ct && decrypt_one(sc, ct, ct_len, password, size_t(pw_len),
+                          plain.data(), c)) {
       append_content_record(out, c);
     } else {
       out[status_at] = 1;
@@ -1335,104 +1465,210 @@ int ehc_decrypt_response(const uint8_t *resp, int64_t resp_len,
 // (utf8_ok lives in the anonymous namespace above, next to the
 // response walkers' shared capability validation.)
 
-// Columnar twin of ehc_decrypt_response for the fused receive→apply
-// path (reference sync.worker.ts:135-173 → receive.ts:144 →
-// applyMessages.ts:78 as ONE leg). Succeeds ONLY when every message
-// decrypts on the canonical fast path, every timestamp is exactly 46
-// ASCII bytes, and every string field (incl. the tree) is strict
-// UTF-8 — the Python side then feeds the batch straight into the
-// planner and the packed SQLite apply with ZERO per-row objects.
-// Cells (table,row,column) are interned in first-appearance order
-// (parity with host_parse.intern_cells) so only k unique triples ever
-// become Python strings.
-// Returns 0 ok; 2 non-canonical wire; 3 some row needs the object
-// path (the caller falls back to ehc_decrypt_response, whose per-row
-// oracle demotion owns the exact error surface); 1 internal.
-// Output blob layout (little-endian, naturally aligned):
-//   [i64 n][i64 k][i64 tree_len][i64 vblob_len][i64 cell_blob_len]
-//   ivals i64[n]; dvals f64[n];
-//   cell_id i32[n]; vlens i32[n]; cell_lens i32[3k];
-//   vkinds u8[n] (SQLite bind encoding: 0 null, 1 int, 2 double, 3 text)
-//   ts_slab u8[46*n]; vblob; cell_blob; tree
-int ehc_decrypt_response_columns(const uint8_t *resp, int64_t resp_len,
-                                 const uint8_t *password, int32_t pw_len,
-                                 uint8_t **out_blob, int64_t *out_len) {
-  Ctxs cx;
-  if (!cx.ok() || resp_len < 0 || pw_len < 0) return 1;
+// ehc_decrypt_response_columns in three phases (ISSUE 32; the entry and
+// its blob are described where it is defined, below). WALK, on the
+// caller's thread: the protobuf walk, which keeps for each message its
+// timestamp and its ciphertext slice. DECRYPT, in L lanes: lane j runs decrypt_one over a contiguous
+// range of the messages with a Scratch of its own and writes each
+// plaintext into its slice of one buffer (a plaintext is no longer than
+// its ciphertext, so the messages' summed ct_len bounds it), leaving a
+// Content a message that points there. COLUMNARIZE, on the caller's
+// thread, in wire order: interning, the value columns, the UTF-8
+// checks, the blob. Interning is by first appearance in wire order, so
+// the blob does not depend on L, and the return code is that of the
+// first message in wire order that fails, as one loop over the messages
+// gave it.
+//
+// L is what the call can observe: min(cores this process may run on,
+// kMaxLanes, n / kLaneMessages), at least 1; a live sync's small
+// response takes one lane and starts no thread. Lane 0 is the calling
+// thread, lanes 1..L-1 are std::threads started and joined inside the
+// call: no pool, no global, nothing outlives the call.
+//
+// **A lane's thread never grows a heap.** Everything a lane touches is
+// allocated on the caller's thread before the first thread starts: the
+// Scratches (OpenSSL's contexts with them), the plaintext buffer, the
+// Contents. A thread's first malloc draws an arena and grows it a page
+// an `mprotect`, which costs 112-136 us under the chip machine's kernel
+// (evolu_host.cpp, reserve_heap; the eight-thread insert of PERF.md §6,
+// PR 27, lost to exactly that). A v1 message allocates nothing at all
+// (Ctxs names every algorithm to its context when it is built); a v2
+// record allocates one small map node the first time its lane sees a
+// session salt (aead_key_for) and OpenSSL the GCM state once a lane.
+
+namespace {
+
+constexpr int64_t kLaneMessages = 4096;  // messages that pay for one more lane
+constexpr int kMaxLanes = 8;
+
+struct MsgRef { const uint8_t *ts, *ct; size_t ct_len; };
+
+struct Lane {
+  Scratch sc;
+  int64_t begin = 0, end = 0;  // its messages
+  size_t plain_off = 0;        // where their plaintexts start in the call's buffer
+  bool failed = false;         // one of them needs the object path
+};
+
+int cores_granted() {
+  cpu_set_t set;
+  if (sched_getaffinity(0, sizeof(set), &set) != 0) return 1;
+  int n = CPU_COUNT(&set);
+  return n > 0 ? n : 1;
+}
+
+// `contents` is raw storage: a lane constructs its messages' Contents,
+// so the pages are first touched where they are filled.
+void run_lane(Lane &lane, const MsgRef *refs, Content *contents, uint8_t *plain,
+              const uint8_t *password, size_t pw_len) {
+  plain += lane.plain_off;
+  for (int64_t i = lane.begin; i < lane.end; i++) {
+    const MsgRef &m = refs[i];
+    Content *c = new (contents + i) Content();
+    if (!m.ct || !decrypt_one(lane.sc, m.ct, m.ct_len, password, pw_len, plain, *c)) {
+      lane.failed = true;  // the whole batch takes the object path: stop here
+      return;
+    }
+    plain += m.ct_len;
+  }
+}
+
+// `lanes` > 0 sets L (the tests'); 0 lets the call choose it.
+int decrypt_response_columns(const uint8_t *resp, int64_t resp_len,
+                             const uint8_t *password, int32_t pw_len, int lanes,
+                             uint8_t **out_blob, int64_t *out_len,
+                             int32_t *out_lanes) {
+  if (out_lanes) *out_lanes = 0;
+  if (resp_len < 0 || pw_len < 0) return 1;
   size_t n_ = size_t(resp_len);
   const uint8_t *tree = nullptr;
   size_t tree_len = 0;
-  std::vector<uint8_t> plain;
-  std::vector<Pkt> pkts, inner;
 
+  // ---- walk ----
+  std::vector<MsgRef> refs;
+  refs.reserve(size_t(resp_len / 90) + 8);  // a v2 record is >= 90 wire bytes
+  int n_caps = 0;
+  size_t pos = 0;
+  auto walk = [&]() -> int {
+    while (pos < n_) {
+      uint64_t key;
+      if (!read_varint64(resp, n_, pos, key)) return 2;
+      uint64_t field = key >> 3;
+      int wt = int(key & 7);
+      if (wt != 2) return 2;  // canonical SyncResponse is all wt-2
+      uint64_t len;
+      if (!read_varint64(resp, n_, pos, len)) return 2;
+      if (len > n_ - pos) return 2;  // overflow-safe: pos <= n_
+      const uint8_t *body = resp + pos;
+      size_t blen = size_t(len);
+      pos += blen;
+      if (field == 2) {
+        tree = body;  // last wins, like the Python decoder
+        tree_len = blen;
+        continue;
+      }
+      if (field == 3) {
+        // Same capability validation as ehc_decrypt_response — the pure
+        // decoder raises on bad UTF-8 / >64 entries, so the fused path
+        // must never succeed on those shapes.
+        if (!capability_ok(body, blen, n_caps)) return 2;
+        continue;
+      }
+      if (field != 1) continue;  // unknown length-delimited field: skip
+
+      // EncryptedCrdtMessage { timestamp=1, content=2 } — last wins.
+      MsgRef m{nullptr, nullptr, 0};
+      size_t ts_len = 0;
+      size_t mp = 0;
+      while (mp < blen) {
+        uint64_t mkey;
+        if (!read_varint64(body, blen, mp, mkey)) return 2;
+        uint64_t mf = mkey >> 3;
+        int mwt = int(mkey & 7);
+        if (mwt != 2) return 2;
+        uint64_t mlen;
+        if (!read_varint64(body, blen, mp, mlen)) return 2;
+        if (mlen > blen - mp) return 2;  // overflow-safe: mp <= blen
+        if (mf == 1) { m.ts = body + mp; ts_len = size_t(mlen); }
+        else if (mf == 2) { m.ct = body + mp; m.ct_len = size_t(mlen); }
+        mp += size_t(mlen);
+      }
+      // The packed apply path assumes fixed-width canonical timestamps;
+      // ASCII also guarantees the (rare) later string materialization
+      // decodes losslessly.
+      if (ts_len != 46) return 3;
+      for (size_t j = 0; j < 46; j++)
+        if (m.ts[j] >= 0x80) return 3;
+      refs.push_back(m);
+    }
+    return 0;
+  };
+  int walk_rc = walk();
+  // Every message before a bad timestamp walked clean, so whichever of
+  // them fails first fails with 3 as well.
+  if (walk_rc == 3) return 3;
+  // Before a non-canonical shape (2) a message may still need the object
+  // path (3), and the first failure in wire order names the code: go on
+  // over the messages walked so far, answer 2 only if they all pass.
+  const bool wire_bad = walk_rc != 0;
+
+  // ---- decrypt, in lanes ----
+  int64_t n = int64_t(refs.size());
+  int64_t L = lanes > 0 ? lanes
+                        : std::min<int64_t>({cores_granted(), kMaxLanes, n / kLaneMessages});
+  L = std::max<int64_t>(1, std::min(L, n));  // never an empty lane
+  std::vector<Lane> lane(static_cast<size_t>(L));
+  size_t plain_total = 0;
+  for (int64_t j = 0; j < L; j++) {
+    Lane &ln = lane[size_t(j)];
+    if (!ln.sc.cx.ok()) return 1;
+    ln.begin = n * j / L;
+    ln.end = n * (j + 1) / L;
+    ln.plain_off = plain_total;
+    for (int64_t i = ln.begin; i < ln.end; i++) plain_total += refs[size_t(i)].ct_len;
+  }
+  // Uninitialised on purpose, both: the lanes write every byte that is
+  // read afterwards.
+  std::unique_ptr<uint8_t[]> plain(new (std::nothrow) uint8_t[plain_total ? plain_total : 1]);
+  std::unique_ptr<void, decltype(&free)> contents_mem(
+      malloc(n ? size_t(n) * sizeof(Content) : 1), &free);
+  Content *contents = static_cast<Content *>(contents_mem.get());
+  if (!plain || !contents) return 1;
+  std::vector<std::thread> threads;
+  threads.reserve(size_t(L - 1));
+  for (int64_t j = 1; j < L; j++) {
+    try {
+      threads.emplace_back(run_lane, std::ref(lane[size_t(j)]), refs.data(),
+                           contents, plain.get(), password, size_t(pw_len));
+    } catch (const std::system_error &) {
+      break;  // no thread to be had: the caller's thread takes the rest
+    }
+  }
+  int64_t started = int64_t(threads.size());
+  run_lane(lane[0], refs.data(), contents, plain.get(), password, size_t(pw_len));
+  for (int64_t j = started + 1; j < L; j++)
+    run_lane(lane[size_t(j)], refs.data(), contents, plain.get(), password, size_t(pw_len));
+  for (std::thread &t : threads) t.join();
+  if (out_lanes) *out_lanes = int32_t(started + 1);
+  for (const Lane &ln : lane)
+    if (ln.failed) return 3;  // any demoted row → whole batch takes the object path
+
+  // ---- columnarize, in wire order ----
   std::vector<int64_t> ivals;
   std::vector<double> dvals;
   std::vector<int32_t> cell_ids, vlens, cell_lens;
   std::string vkinds, ts_slab, vblob, cell_blob;
+  ivals.reserve(size_t(n)); dvals.reserve(size_t(n));
+  cell_ids.reserve(size_t(n)); vlens.reserve(size_t(n));
+  vkinds.reserve(size_t(n)); ts_slab.reserve(size_t(n) * 46);
   std::unordered_map<std::string, int32_t> intern;
-  // Cold syncs intern ~one cell per row: pre-size for the worst case
-  // (a v2 record is ≥90 wire bytes) so the map never rehashes
-  // mid-batch — rehash churn measured as a visible share of the
-  // unique-cell decode.
-  intern.reserve(size_t(resp_len / 90) + 8);
+  // Cold syncs intern ~one cell per row: pre-size for the worst case so
+  // the map never rehashes mid-batch — rehash churn measured as a
+  // visible share of the unique-cell decode.
+  intern.reserve(size_t(n) + 8);
   std::string keybuf;
-
-  int n_caps = 0;
-  size_t pos = 0;
-  while (pos < n_) {
-    uint64_t key;
-    if (!read_varint64(resp, n_, pos, key)) return 2;
-    uint64_t field = key >> 3;
-    int wt = int(key & 7);
-    if (wt != 2) return 2;  // canonical SyncResponse is all wt-2
-    uint64_t len;
-    if (!read_varint64(resp, n_, pos, len)) return 2;
-    if (len > n_ - pos) return 2;  // overflow-safe: pos <= n_
-    const uint8_t *body = resp + pos;
-    size_t blen = size_t(len);
-    pos += blen;
-    if (field == 2) {
-      tree = body;  // last wins, like the Python decoder
-      tree_len = blen;
-      continue;
-    }
-    if (field == 3) {
-      // Same capability validation as ehc_decrypt_response — the pure
-      // decoder raises on bad UTF-8 / >64 entries, so the fused path
-      // must never succeed on those shapes.
-      if (!capability_ok(body, blen, n_caps)) return 2;
-      continue;
-    }
-    if (field != 1) continue;  // unknown length-delimited field: skip
-
-    // EncryptedCrdtMessage { timestamp=1, content=2 } — last wins.
-    const uint8_t *ts = nullptr, *ct = nullptr;
-    size_t ts_len = 0, ct_len = 0;
-    size_t mp = 0;
-    while (mp < blen) {
-      uint64_t mkey;
-      if (!read_varint64(body, blen, mp, mkey)) return 2;
-      uint64_t mf = mkey >> 3;
-      int mwt = int(mkey & 7);
-      if (mwt != 2) return 2;
-      uint64_t mlen;
-      if (!read_varint64(body, blen, mp, mlen)) return 2;
-      if (mlen > blen - mp) return 2;  // overflow-safe: mp <= blen
-      if (mf == 1) { ts = body + mp; ts_len = size_t(mlen); }
-      else if (mf == 2) { ct = body + mp; ct_len = size_t(mlen); }
-      mp += size_t(mlen);
-    }
-    // The packed apply path assumes fixed-width canonical timestamps;
-    // ASCII also guarantees the (rare) later string materialization
-    // decodes losslessly.
-    if (ts_len != 46) return 3;
-    for (size_t j = 0; j < 46; j++)
-      if (ts[j] >= 0x80) return 3;
-    Content c;
-    if (!ct || !decrypt_one(cx, ct, ct_len, password, size_t(pw_len), plain,
-                            pkts, inner, c))
-      return 3;  // any demoted row → whole batch takes the object path
-
+  for (int64_t i = 0; i < n; i++) {
+    const Content &c = contents[i];
     // Intern the cell; validate UTF-8 once per unique triple.
     keybuf.clear();
     uint32_t tl32 = uint32_t(c.tl), rl32 = uint32_t(c.rl);
@@ -1456,7 +1692,7 @@ int ehc_decrypt_response_columns(const uint8_t *resp, int64_t resp_len,
       if (c.cl) cell_blob.append(reinterpret_cast<const char *>(c.c), c.cl);
     }
     cell_ids.push_back(cid);
-    ts_slab.append(reinterpret_cast<const char *>(ts), 46);
+    ts_slab.append(reinterpret_cast<const char *>(refs[size_t(i)].ts), 46);
     // Content vkind (0 none, 1 str, 2 int, 3 double) → the SQLite bind
     // encoding shared with eh_apply_planned_packed (0 null, 1 int,
     // 2 double, 3 text).
@@ -1474,9 +1710,9 @@ int ehc_decrypt_response_columns(const uint8_t *resp, int64_t resp_len,
     ivals.push_back(c.ival);
     dvals.push_back(c.dval);
   }
+  if (wire_bad) return 2;
   if (tree_len && !utf8_ok(tree, tree_len)) return 3;
 
-  int64_t n = int64_t(cell_ids.size());
   int64_t k = int64_t(intern.size());
   int64_t header[5] = {n, k, int64_t(tree_len), int64_t(vblob.size()),
                        int64_t(cell_blob.size())};
@@ -1504,6 +1740,48 @@ int ehc_decrypt_response_columns(const uint8_t *resp, int64_t resp_len,
   *out_blob = blob;
   *out_len = int64_t(total);
   return 0;
+}
+
+}  // namespace
+
+// Columnar twin of ehc_decrypt_response for the fused receive→apply
+// path (reference sync.worker.ts:135-173 → receive.ts:144 →
+// applyMessages.ts:78 as ONE leg). Succeeds ONLY when every message
+// decrypts on the canonical fast path, every timestamp is exactly 46
+// ASCII bytes, and every string field (incl. the tree) is strict
+// UTF-8 — the Python side then feeds the batch straight into the
+// planner and the packed SQLite apply with ZERO per-row objects.
+// Cells (table,row,column) are interned in first-appearance order
+// (parity with host_parse.intern_cells) so only k unique triples ever
+// become Python strings.
+// Returns 0 ok; 2 non-canonical wire; 3 some row needs the object
+// path (the caller falls back to ehc_decrypt_response, whose per-row
+// oracle demotion owns the exact error surface); 1 internal.
+// Output blob layout (little-endian, naturally aligned):
+//   [i64 n][i64 k][i64 tree_len][i64 vblob_len][i64 cell_blob_len]
+//   ivals i64[n]; dvals f64[n];
+//   cell_id i32[n]; vlens i32[n]; cell_lens i32[3k];
+//   vkinds u8[n] (SQLite bind encoding: 0 null, 1 int, 2 double, 3 text)
+//   ts_slab u8[46*n]; vblob; cell_blob; tree
+// `out_lanes` (nullable) receives the lanes that ran at once: 1 + the
+// threads the call started, 1 when it started none.
+int ehc_decrypt_response_columns(const uint8_t *resp, int64_t resp_len,
+                                 const uint8_t *password, int32_t pw_len,
+                                 uint8_t **out_blob, int64_t *out_len,
+                                 int32_t *out_lanes) {
+  return decrypt_response_columns(resp, resp_len, password, pw_len, /*lanes=*/0,
+                                  out_blob, out_len, out_lanes);
+}
+
+// The same with L given (1..64), for the tests: as eh_reserve_heap is
+// for the heap.
+int ehc_decrypt_response_columns_lanes(const uint8_t *resp, int64_t resp_len,
+                                       const uint8_t *password, int32_t pw_len,
+                                       int32_t lanes, uint8_t **out_blob,
+                                       int64_t *out_len, int32_t *out_lanes) {
+  if (lanes < 1 || lanes > 64) return 1;
+  return decrypt_response_columns(resp, resp_len, password, pw_len, lanes,
+                                  out_blob, out_len, out_lanes);
 }
 
 }  // extern "C"

@@ -59,18 +59,52 @@ def test_native_encrypt_decrypts_via_pure_oracle():
         assert content == protocol.encode_content(m.table, m.row, m.column, m.value)
 
 
-def test_pure_encrypt_decrypts_via_native_batch():
-    msgs = _msgs()
-    enc = tuple(
+def _pure_encrypted(msgs, password):
+    return tuple(
         protocol.EncryptedCrdtMessage(
             m.timestamp,
             encrypt_symmetric(
-                protocol.encode_content(m.table, m.row, m.column, m.value), MN
+                protocol.encode_content(m.table, m.row, m.column, m.value), password
             ),
         )
         for m in msgs
     )
+
+
+def test_pure_encrypt_decrypts_via_native_batch():
+    msgs = _msgs()
+    enc = _pure_encrypted(msgs, MN)
     assert native_crypto.decrypt_batch(enc, MN) == tuple(_canon(m) for m in msgs)
+
+
+# The S2K hashes salt ‖ password repeated to `count` bytes; the native
+# side feeds it from a tile of whole repetitions (ISSUE 32). Password
+# lengths on both sides of the tile's 1,024 bytes (a repetition of
+# exactly 1,024, longer than any count here, 8 + 1 bytes), counts that
+# are a whole number of tiles, a fraction of one, and 65,536.
+@pytest.mark.parametrize("count_byte", [0, 1, 15, 16, 96])
+@pytest.mark.parametrize("pw_len", [1, 70, 1016, 1500])
+def test_native_s2k_matches_pure_for_every_password_length_and_count(
+        monkeypatch, pw_len, count_byte):
+    from evolu_tpu.sync import crypto
+
+    password = ("correct horse " * 200)[:pw_len]  # ASCII: pw_len bytes
+    monkeypatch.setattr(crypto, "_S2K_COUNT_BYTE", count_byte)
+    msgs = _msgs(VALUES[:4])
+    enc = _pure_encrypted(msgs, password)
+    assert all(e.content[14] == count_byte for e in enc)  # the SKESK's count octet
+
+    def demoted(*_a):
+        raise AssertionError("the native S2K derived another key: message demoted to the oracle")
+
+    monkeypatch.setattr(native_crypto, "_pure_one", demoted)
+    monkeypatch.setattr(native_crypto, "_pure", demoted)
+    assert native_crypto.decrypt_batch(enc, password) == tuple(_canon(m) for m in msgs)
+    # And the native encrypt (count byte 0) under the same password.
+    monkeypatch.undo()
+    for m, e in zip(msgs, native_crypto.encrypt_batch(msgs, password)):
+        assert decrypt_symmetric(e.content, password) == protocol.encode_content(
+            m.table, m.row, m.column, m.value)
 
 
 def test_pipeline_roundtrip_via_public_entry_points():

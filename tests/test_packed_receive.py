@@ -526,3 +526,212 @@ def test_packed_tensor_cells_bounce_before_side_effects():
         results[mode] = (dump(db), tree)
         db.close()
     assert results["objects"] == results["packed"]
+
+
+# --- decrypt lanes (ISSUE 32) ---------------------------------------
+#
+# `ehc_decrypt_response_columns` walks the wire on the caller's thread,
+# runs decrypt_one in L lanes and columnarizes in wire order. L is the
+# call's own (cores granted, 8, messages / 4,096);
+# `ehc_decrypt_response_columns_lanes` takes it as an argument, for
+# these tests only. The contract: the blob does not depend on L, and
+# neither does the return code.
+
+import ctypes
+import functools
+import hashlib
+import os
+import threading
+
+# sha256 of the blob the PARENT commit's library (eeeb05a: one loop, one
+# thread) gave for `_response_bytes(_mk_msgs(n))`. The blob holds only
+# plaintext, so the random salts of a fresh encryption do not move it.
+# Never update: a digest that changes means the blob changed.
+PARENT_BLOB_SHA256 = {
+    0: "6aa018afa1b1b6dbaf7c42ece2e23558bda166a03579417005d8fad1cc49f6b9",
+    1: "161171e7d5e9181ebdabe55e03c779167574815e01819ba040f97e11b0f7ba8d",
+    4095: "afbcd3e65a76fc7898bc5f0a9234e7e83c8cbbe94db946a5e0100c8ea3bd2f76",
+    8192: "e22903b8d83d063db856ffb4c0b4fd10428e272ffd499f98acfa5d65d1e18b48",
+    25000: "b38a2b7a58efb7a4fbc668f81edfc75ae204c2c3b16119ec97a3a34080f4cda4",
+}
+
+
+def _columns_lanes(wire, lanes, password=MN):
+    """→ (rc, blob or None, lanes that ran) of the test-only entry."""
+    lib = native_crypto.load_library()
+    pw = password.encode("utf-8")
+    out_p, out_len, ran = ctypes.c_void_p(), ctypes.c_int64(), ctypes.c_int32(-1)
+    rc = lib.ehc_decrypt_response_columns_lanes(
+        wire, len(wire), pw, len(pw), lanes,
+        ctypes.byref(out_p), ctypes.byref(out_len), ctypes.byref(ran))
+    if rc != 0:
+        return rc, None, ran.value
+    try:
+        return rc, ctypes.string_at(out_p.value, out_len.value), ran.value
+    finally:
+        lib.ehc_free(out_p)
+
+
+@functools.lru_cache(maxsize=None)
+def _wire_of(n):
+    return _response_bytes(_mk_msgs(n))
+
+
+@pytest.mark.parametrize("n", sorted(PARENT_BLOB_SHA256))
+@pytest.mark.parametrize("lanes", [1, 2, 3, 8])
+def test_blob_is_the_parents_at_every_lane_count(lanes, n):
+    rc, blob, ran = _columns_lanes(_wire_of(n), lanes)
+    assert rc == 0
+    # More lanes than messages is fewer lanes, never an empty one.
+    assert ran == max(1, min(lanes, n))
+    assert hashlib.sha256(blob).hexdigest() == PARENT_BLOB_SHA256[n]
+    assert blob == _columns_lanes(_wire_of(n), 1)[1]
+
+
+@pytest.mark.parametrize("n", [0, 1, 4095, 8191, 8192, 25000])
+def test_the_call_chooses_its_lanes_from_the_batch_and_counts_them(n):
+    """Under 8,192 messages one lane and no thread (lanes that ran = 1 +
+    threads started); above, one for every 4,096, capped by the cores
+    this process may run on. Python counts lanes and calls together."""
+    from evolu_tpu.obs import metrics
+
+    def counted():
+        return (metrics.get_counter("evolu_recv_decrypt_lanes_total"),
+                metrics.get_counter("evolu_recv_decrypt_calls_total"))
+
+    want = 1 if n < 8192 else min(len(os.sched_getaffinity(0)), 8, n // 4096)
+    lanes0, calls0 = counted()
+    pb, tree = native_crypto.decrypt_response_columns(_wire_of(n), MN)
+    assert pb.n == n and tree == '{"m":1}'
+    assert counted() == (lanes0 + want, calls0 + 1)
+    if n:  # a call that gives no batch counts nothing
+        assert native_crypto.decrypt_response_columns(_wire_of(n), "wrong-pw") is None
+        assert counted() == (lanes0 + want, calls0 + 1)
+
+
+def test_lanes_entry_refuses_a_lane_count_out_of_range():
+    for lanes in (0, -1, 65):
+        assert _columns_lanes(_wire_of(1), lanes)[0] == 1
+
+
+def _mixed_records(n, seed):
+    """n messages, each sealed as v1 OpenPGP or as an aead-batch-v1
+    record under one of three session salts by `seed`, so both formats
+    and a change of session key fall on every lane boundary of 2, 3 and
+    8 lanes."""
+    from evolu_tpu.sync import aead
+    from evolu_tpu.sync.client import encrypt_messages_v2
+
+    msgs = _mk_msgs(n, seed=seed)
+    rng = random.Random(seed)
+    v1 = encrypt_messages(msgs, MN)
+    v2 = []
+    for _ in range(3):
+        aead.reset_sessions()  # a new session: a new salt, a new key
+        v2.append(encrypt_messages_v2(msgs, MN))
+    aead.reset_sessions()
+    picks = [rng.randrange(4) for _ in range(n)]
+    assert len(set(picks)) == 4
+    return msgs, tuple((v1, *v2)[k][i] for i, k in enumerate(picks))
+
+
+@pytest.mark.parametrize("lanes", [2, 3, 8])
+def test_mixed_v1_and_v2_records_across_lane_boundaries(lanes):
+    msgs, enc = _mixed_records(96, seed=lanes)
+    wire = protocol.encode_sync_response(protocol.SyncResponse(enc, "{}"))
+    rc1, one, _ = _columns_lanes(wire, 1)
+    rc, blob, ran = _columns_lanes(wire, lanes)
+    assert (rc1, rc, ran) == (0, 0, lanes) and blob == one
+    from evolu_tpu.core.packed import PackedReceive
+
+    pb, _tree = PackedReceive.from_blob(blob)
+    assert pb.to_messages() == tuple(msgs)
+
+
+def _bad_utf8_record(ts):
+    from evolu_tpu.sync.crypto import encrypt_symmetric
+
+    return protocol.EncryptedCrdtMessage(
+        ts, encrypt_symmetric(b"\x0a\x02t\xff" + b"\x12\x01r" + b"\x1a\x01c", MN))
+
+
+def _flip_last_byte(record):
+    return protocol.EncryptedCrdtMessage(
+        record.timestamp, record.content[:-1] + bytes([record.content[-1] ^ 1]))
+
+
+# 12 messages in 3 lanes of 4: the first, a middle and the last message
+# of a lane, and of the batch.
+@pytest.mark.parametrize("at", [0, 3, 4, 6, 7, 11])
+@pytest.mark.parametrize("fault", ["ciphertext", "timestamp", "utf8"])
+def test_a_bad_message_gives_the_one_lane_code_wherever_it_falls(fault, at):
+    enc = list(encrypt_messages(_mk_msgs(12), MN))
+    ts = enc[at].timestamp
+    enc[at] = {
+        "ciphertext": lambda: _flip_last_byte(enc[at]),  # the MDC no longer matches
+        "timestamp": lambda: protocol.EncryptedCrdtMessage(ts[:-1], enc[at].content),
+        "utf8": lambda: _bad_utf8_record(ts),
+    }[fault]()
+    wire = protocol.encode_sync_response(protocol.SyncResponse(tuple(enc), "{}"))
+    codes = {lanes: _columns_lanes(wire, lanes)[0] for lanes in (1, 2, 3, 8)}
+    assert set(codes.values()) == {3}, codes
+    assert native_crypto.decrypt_response_columns(wire, MN) is None
+
+
+def test_the_first_failure_in_wire_order_names_the_code():
+    """A non-canonical wire shape answers 2, a message that needs the
+    object path 3, and as in one loop over the messages the earlier of
+    the two decides: at every lane count."""
+    enc = list(encrypt_messages(_mk_msgs(12), MN))
+    good = protocol.encode_sync_response(protocol.SyncResponse(tuple(enc), "{}"))
+    noncanonical = b"\x08\x01"  # a top-level varint field: not wt-2
+    enc[9] = _flip_last_byte(enc[9])
+    bad = protocol.encode_sync_response(protocol.SyncResponse(tuple(enc), "{}"))
+    for lanes in (1, 2, 3, 8):
+        assert _columns_lanes(good + noncanonical, lanes)[0] == 2
+        assert _columns_lanes(noncanonical + bad, lanes)[0] == 2
+        assert _columns_lanes(bad + noncanonical, lanes)[0] == 3
+        assert _columns_lanes(good[:-1], lanes)[0] == 2  # truncated tree field
+
+
+def test_a_bad_message_on_a_real_lane_boundary_bounces_from_python():
+    """8,192 messages are two lanes of 4,096 where two cores are
+    granted: corrupt the last message of lane 0 or the first of lane 1
+    and the Python entry gives None; the object path still serves it."""
+    enc = list(encrypt_messages(_mk_msgs(8192), MN))
+    for at in (4095, 4096):
+        broken = list(enc)
+        broken[at] = protocol.EncryptedCrdtMessage("short-ts", enc[at].content)
+        wire = protocol.encode_sync_response(protocol.SyncResponse(tuple(broken), "{}"))
+        assert native_crypto.decrypt_response_columns(wire, MN) is None
+        assert native_crypto.decrypt_response(wire, MN) is not None
+
+
+def test_two_threads_decrypting_at_once_each_get_their_batch():
+    """Each call owns its lanes, contexts and buffers: two Python
+    threads in `decrypt_response_columns` at once (ctypes has released
+    the interpreter lock) both get their own batch, several times over."""
+    batches = {"a": _mk_msgs(8192, seed=5), "b": _mk_msgs(9000, seed=6)}
+    wires = {k: _response_bytes(v, tree='{"%s":1}' % k) for k, v in batches.items()}
+    want = {k: _columns_lanes(w, 1)[1] for k, w in wires.items()}
+    start, failures = threading.Barrier(2), []
+
+    from evolu_tpu.core.packed import PackedReceive
+
+    def worker(k):
+        ref, ref_tree = PackedReceive.from_blob(want[k])
+        start.wait(timeout=60)
+        for _ in range(6):
+            pb, tree = native_crypto.decrypt_response_columns(wires[k], MN)
+            if (tree, bytes(pb.ts_slab), pb.vblob, pb.cell_blob, pb.cell_id.tolist(),
+                    pb.ivals.tolist()) != (ref_tree, bytes(ref.ts_slab), ref.vblob,
+                                           ref.cell_blob, ref.cell_id.tolist(),
+                                           ref.ivals.tolist()):
+                failures.append(k)
+
+    threads = [threading.Thread(target=worker, args=(k,)) for k in wires]
+    for t in threads:
+        t.start()
+    for t in threads:
+        t.join(timeout=120)
+    assert not any(t.is_alive() for t in threads) and not failures
