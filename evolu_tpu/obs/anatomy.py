@@ -46,6 +46,7 @@ record call is one attribute read.
 
 from __future__ import annotations
 
+import contextlib
 import json
 import os
 import threading
@@ -569,7 +570,23 @@ class batched_stage(stage):
         self.closed.append((self.family, seconds * 1e3, {"stage": self.name}))
 
 
+class _part(batched_stage):
+    """A child of a running tile that may open and close several times
+    a unit of work (`part`, below): its intervals add up in `ms` and go
+    to the registry as ONE observation when the unit ends."""
+
+    __slots__ = ("ms",)
+
+    def __init__(self, name: str):
+        super().__init__(name)
+        self.ms = 0.0
+
+    def _record(self, seconds: float) -> None:
+        self.ms += seconds * 1e3
+
+
 _tiled = threading.local()  # .tiles: the `tiles` open on this thread
+_NO_PART = contextlib.nullcontext()
 
 
 class tiles:
@@ -588,14 +605,23 @@ class tiles:
     posted in one `metrics.observe_many` at the exit. Outside such a
     block `seam` is one thread-local read, so code shared with other
     commands (the planners a Send runs too) names its seams
-    unconditionally."""
+    unconditionally.
 
-    __slots__ = ("prefix", "_whole", "_tile", "_outer")
+    `part(name)` is a CHILD of whatever tile is running: a `with` block
+    (same thread, any module) whose time stays inside its tile, so the
+    tiles still sum to the whole. A part reached several times in one
+    command (the tree fold: the delta decode in the planner, then the
+    fold after the SQLite apply) adds its intervals up and is observed
+    once, in the same `observe_many`. Outside a `tiles` block it is a
+    shared no-op context."""
+
+    __slots__ = ("prefix", "_whole", "_tile", "_outer", "_parts")
 
     def __init__(self, prefix: str, whole: str, first: str):
         self.prefix = prefix
         self._whole = batched_stage(prefix + whole)
         self._tile = batched_stage(prefix + first, self._whole.closed)
+        self._parts = {}
 
     def __enter__(self) -> "tiles":
         self._outer = getattr(_tiled, "tiles", None)
@@ -608,7 +634,10 @@ class tiles:
         self._tile.stop()
         self._whole.stop()
         _tiled.tiles = self._outer
-        metrics.observe_many(self._whole.closed)
+        closed = self._whole.closed
+        closed.extend((batched_stage.family, p.ms, {"stage": p.name})
+                      for p in self._parts.values())
+        metrics.observe_many(closed)
 
 
 def seam(name: str) -> None:
@@ -617,6 +646,20 @@ def seam(name: str) -> None:
     open_tiles = getattr(_tiled, "tiles", None)
     if open_tiles is not None:
         open_tiles._tile.then(open_tiles.prefix + name)
+
+
+def part(name: str):
+    """→ a context manager: `<prefix><name>` as a child of this
+    thread's running tile (`tiles`), a shared no-op where none is
+    open."""
+    open_tiles = getattr(_tiled, "tiles", None)
+    if open_tiles is None:
+        return _NO_PART
+    name = open_tiles.prefix + name
+    child = open_tiles._parts.get(name)
+    if child is None:
+        child = open_tiles._parts[name] = _part(name)
+    return child
 
 
 def record_span(target: str, ms: float, rows: object = 0) -> None:

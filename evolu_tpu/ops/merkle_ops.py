@@ -29,6 +29,7 @@ import numpy as np
 
 from evolu_tpu.core.merkle import minutes_base3
 from evolu_tpu.core.murmur import to_int32
+from evolu_tpu.obs import anatomy
 from evolu_tpu.ops import to_host, with_x64
 from evolu_tpu.ops.encode import timestamp_hashes
 
@@ -208,16 +209,17 @@ def decode_owner_minute_deltas(
     never splits an owner so keys are unique there, but the hot-owner
     cell sharding produces one partial delta per shard per minute and
     relies on the XOR merge being exact (associative/commutative)."""
-    owner_sorted = to_host(owner_sorted)
-    minute_sorted = to_host(minute_sorted)
-    ends = to_host(seg_end) & to_host(valid_sorted)
-    xs = to_host(seg_xor)
-    out: Dict[int, Dict[str, int]] = {}
-    for i in np.nonzero(ends)[0]:
-        o_ix, minute = int(owner_sorted[i]), int(minute_sorted[i])
-        key = minutes_base3(minute * 60000)
-        d = out.setdefault(o_ix, {})
-        d[key] = to_int32(d.get(key, 0) ^ int(xs[i]))
+    with anatomy.part("tree_fold"):  # of a tiled Receive; a no-op elsewhere
+        owner_sorted = to_host(owner_sorted)
+        minute_sorted = to_host(minute_sorted)
+        ends = to_host(seg_end) & to_host(valid_sorted)
+        xs = to_host(seg_xor)
+        out: Dict[int, Dict[str, int]] = {}
+        for i in np.nonzero(ends)[0]:
+            o_ix, minute = int(owner_sorted[i]), int(minute_sorted[i])
+            key = minutes_base3(minute * 60000)
+            d = out.setdefault(o_ix, {})
+            d[key] = to_int32(d.get(key, 0) ^ int(xs[i]))
     return out
 
 

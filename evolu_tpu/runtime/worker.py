@@ -725,8 +725,11 @@ class DbWorker:
                 update_clock(self.db, clock)
                 self._emit(msg.OnReceive())
 
-        server_tree = merkle_tree_from_string(command.merkle_tree)
-        diff = diff_merkle_trees(server_tree, clock.merkle_tree)
+        with anatomy.part("tree_diff"):
+            server_tree = merkle_tree_from_string(command.merkle_tree)
+            diff = diff_merkle_trees(server_tree, clock.merkle_tree)
+        metrics.inc("evolu_merkle_tree_bytes_total", len(command.merkle_tree),
+                    leg="remote")
         if diff is None:
             return
         # Livelock guard: the same diff twice in a row means the replicas

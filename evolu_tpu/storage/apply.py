@@ -40,7 +40,7 @@ from evolu_tpu.core.merkle import (
 )
 from evolu_tpu.core.timestamp import timestamp_from_string
 from evolu_tpu.core.types import CrdtMessage
-from evolu_tpu.obs import ledger
+from evolu_tpu.obs import anatomy, ledger, metrics
 from evolu_tpu.storage.sqlite import PySqliteDatabase, quote_ident
 
 
@@ -56,6 +56,18 @@ _INSERT_MESSAGE = (
     'INSERT INTO "__message" ("timestamp", "table", "row", "column", "value") '
     "VALUES (?, ?, ?, ?, ?) ON CONFLICT DO NOTHING"
 )
+
+
+def _fold_tree(merkle_tree: dict, deltas: dict) -> dict:
+    """A planned batch's per-minute deltas into the client's tree: ONE
+    copy of `apply_prefix_xors` for every apply route, timed as the
+    second half of a tiled Receive's `recv_tree_fold` (the first is the
+    delta decode in `ops.merkle_ops`) and counted."""
+    with anatomy.part("tree_fold"):
+        tree = apply_prefix_xors(merkle_tree, deltas)
+    metrics.inc_many((("evolu_merkle_fold_minutes_total", len(deltas), {}),
+                      ("evolu_merkle_fold_calls_total", 1, {})))
+    return tree
 
 
 def _upsert_sql(table: str, column: str) -> str:
@@ -287,7 +299,6 @@ def _apply_in_txn(db, merkle_tree, messages, planner, changes=None,
     identical either way (test-pinned)."""
     from evolu_tpu.core.packed import PackedReceive
     from evolu_tpu.core.crdt_types import load_schema
-    from evolu_tpu.obs import metrics
     from evolu_tpu.storage.changes import record_batch
 
     if entry is None:
@@ -327,7 +338,7 @@ def _apply_in_txn(db, merkle_tree, messages, planner, changes=None,
                 entry.count(ledger.APPLY_INSERTED, n_win)
                 entry.count(ledger.APPLY_LOSING, n_xor - n_win)
                 entry.count(ledger.APPLY_DUPLICATE, n - n_xor)
-                return apply_prefix_xors(merkle_tree, deltas)
+                return _fold_tree(merkle_tree, deltas)
         # The packed batch bounced (non-canonical shape, small batch,
         # hot-owner route, or a backend without the cell apply):
         # materialize and take the object path below.
@@ -433,7 +444,7 @@ def _apply_messages_in_txn(db, merkle_tree, messages, planner, changes=None,
     entry.count(ledger.APPLY_DUPLICATE, len(messages) - n_xor)
 
     # One sparse-tree pass (pure, cannot fail after commit).
-    return apply_prefix_xors(merkle_tree, deltas)
+    return _fold_tree(merkle_tree, deltas)
 
 
 def apply_messages_log_only(
@@ -484,7 +495,7 @@ def apply_messages_log_only(
             entry.count(ledger.APPLY_LOSING, n_xor - len(upserts))
             entry.count(ledger.APPLY_DUPLICATE, len(messages) - n_xor)
             entry.count(ledger.APPLY_DEFERRED_MAT, len(messages))
-            tree = apply_prefix_xors(merkle_tree, deltas)
+            tree = _fold_tree(merkle_tree, deltas)
         entry.commit()
         return tree
     except BaseException:

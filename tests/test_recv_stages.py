@@ -12,6 +12,14 @@ registry's lock; with annotations on each is one `evolu/<name>` profiler
 annotation and with them off none is constructed; a Send, which runs
 the same planner, records none of them; and none of it changes a byte
 of the end state.
+
+ISSUE 33 adds four children inside those tiles (`anatomy.part`):
+`recv_tree_load` in `recv_clock`, `recv_tree_fold` in `recv_apply`,
+`recv_tree_store` and `recv_tree_diff` in `recv_commit`. Each is observed
+once a `Receive`, in the same `observe_many`, lies inside its parent,
+and leaves the six tiles summing to `recv_handle`; the fold's counters
+equal the batch's distinct minutes; and both new cells of the benchmark
+rehearse `correct` with the new data files.
 """
 
 import itertools
@@ -38,6 +46,9 @@ pytestmark = pytest.mark.skipif(
 TILES = ("recv_clock", "recv_plan_host", "recv_device_call", "recv_pull",
          "recv_apply", "recv_commit")
 ON_WORKER = TILES + ("recv_handle",)
+# child of a tile -> the tile it lies inside
+TREE = {"recv_tree_load": "recv_clock", "recv_tree_fold": "recv_apply",
+        "recv_tree_store": "recv_commit", "recv_tree_diff": "recv_commit"}
 NOW = 1_700_010_000_000
 
 
@@ -51,12 +62,12 @@ def wires():
 class Device:
     """One restoring device: a fresh database and worker."""
 
-    def __init__(self):
+    def __init__(self, now: int = NOW):
         self.outputs = []
         self.db = native.open_database(backend="native")
         self.worker = DbWorker(self.db, Config(backend="tpu"),
                                on_output=self.outputs.append,
-                               now=itertools.count(NOW, 1000).__next__)
+                               now=itertools.count(now, 1000).__next__)
         self.worker.start(gen_client.MNEMONIC)
         self.worker.post(rmsg.UpdateDbSchema(tuple(
             TableDefinition.of(t, cols) for t, cols in gen_client.TABLES)))
@@ -142,7 +153,7 @@ def test_one_registry_acquisition_a_receive(device, wires, monkeypatch):
         worker = device.worker._thread.ident
         on_worker = [c for c in calls if c[1] == worker]
         assert [c[0] for c in on_worker] == ["observe_many"], on_worker
-        assert sorted(on_worker[0][2]) == sorted(ON_WORKER)
+        assert sorted(on_worker[0][2]) == sorted(ON_WORKER + tuple(TREE))
         decrypt = [c for c in calls if c[1] != worker]
         assert decrypt and all(c[2] == ["recv_decrypt"] for c in decrypt)
         assert all(c[1] == threading.get_ident() for c in decrypt)
@@ -183,10 +194,17 @@ def test_every_stage_is_one_annotation_on_its_thread(device, wires):
     # wave lies inside recv_pull.
     order = [(kind, name[len("evolu/"):]) for kind, name, tid in events
              if tid == worker and name.startswith("evolu/")]
+    # ... and the tree's parts inside their tiles (the fold twice: the
+    # delta decode in the planner, the fold after the SQLite apply).
+    inside = {"recv_clock": ["recv_tree_load"], "recv_pull": ["pull_wave"],
+              "recv_apply": ["recv_tree_fold"] * 2,
+              "recv_commit": ["recv_tree_store", "recv_tree_diff"]}
     one = [("open", "recv_handle")]
     for s in TILES:
-        one += [("open", s)] + ([("open", "pull_wave"), ("close", "pull_wave")]
-                                if s == "recv_pull" else []) + [("close", s)]
+        one += [("open", s)]
+        for child in inside.get(s, []):
+            one += [("open", child), ("close", child)]
+        one += [("close", s)]
     one += [("close", "recv_handle")]
     assert order == one * len(wires)
     # Annotations off: the stand-in (or any class) is never constructed.
@@ -223,6 +241,106 @@ def test_end_state_identical_with_metrics_disabled(wires):
     assert {s: _hist(s)[1] - counts[s] for s in ON_WORKER} == dict.fromkeys(ON_WORKER, 4)
 
 
+@pytest.mark.parametrize("child", sorted(TREE))
+def test_tree_part_once_a_receive_inside_its_tile(device, wires, child):
+    parent = TREE[child]
+    for k, wire in enumerate(wires):
+        before = {s: _hist(s) for s in ON_WORKER + (child,)}
+        device.receive(wire)
+        ms = {}
+        for s, (sum0, count0) in before.items():
+            sum1, count1 = _hist(s)
+            assert count1 - count0 == 1, (k, s)
+            ms[s] = sum1 - sum0
+        assert 0 < ms[child] <= ms[parent], (k, ms)
+        tiled = sum(ms[s] for s in TILES)  # the parts are inside, not beside
+        assert 0.95 * ms["recv_handle"] <= tiled <= ms["recv_handle"], (k, ms)
+
+
+def test_tree_parts_sum_inside_their_tile_and_are_no_ops_elsewhere(device, wires):
+    before = {s: _hist(s) for s in tuple(TREE) + TILES}
+    device.receive(wires[0])
+    ms = {s: _hist(s)[0] - before[s][0] for s in before}
+    assert ms["recv_tree_store"] + ms["recv_tree_diff"] <= ms["recv_commit"]
+    # Outside a tiled command a part is one shared no-op context, and a
+    # Send (read_clock, the fold, update_clock) observes none.
+    assert anatomy.part("tree_fold") is anatomy.part("tree_load")
+    with anatomy.part("tree_fold"):
+        pass
+    counts = {s: _hist(s)[1] for s in TREE}
+    device.worker.post(rmsg.Send((rmsg.NewCrdtMessage("todo", "row1", "title", "mine"),)))
+    device.worker.flush()
+    assert {s: _hist(s)[1] for s in TREE} == counts
+
+
+@pytest.mark.parametrize("shape", ["one-minute", "months"])
+def test_fold_counters_equal_the_batches_distinct_minutes(shape):
+    from perf import gen_history
+
+    messages = (gen_client.build_messages(4000, 33, 50, 8) if shape == "one-minute" else
+                gen_history.build_messages(4000, 33, 50, 8, 30, 3, 30))
+    wires = gen_client.build_responses(messages, 4, gen_client.MNEMONIC)
+    dev = Device(now=NOW + 366 * 86_400_000)  # after the months' last message
+    try:
+        for wire, batch in zip(wires, gen_client.split_responses(messages, 4)):
+            read = {name: metrics.get_counter(name) for name in (
+                "evolu_merkle_fold_minutes_total", "evolu_merkle_fold_calls_total")}
+            legs = {leg: metrics.get_counter("evolu_merkle_tree_bytes_total", leg=leg)
+                    for leg in ("load", "store", "remote")}
+            loaded = dev.db.exec_sql_query('SELECT "merkleTree" FROM "__clock"')[0]["merkleTree"]
+            dev.receive(wire)
+            stored = dev.db.exec_sql_query('SELECT "merkleTree" FROM "__clock"')[0]["merkleTree"]
+            assert metrics.get_counter("evolu_merkle_fold_calls_total") \
+                - read["evolu_merkle_fold_calls_total"] == 1
+            assert metrics.get_counter("evolu_merkle_fold_minutes_total") \
+                - read["evolu_merkle_fold_minutes_total"] \
+                == len({m.timestamp[:16] for m in batch})
+            # The relay's tree in the response is the client's after it.
+            want = {"load": len(loaded), "store": len(stored), "remote": len(stored)}
+            assert {leg: metrics.get_counter("evolu_merkle_tree_bytes_total", leg=leg)
+                    - legs[leg] for leg in legs} == want
+        assert not [o for o in dev.outputs if isinstance(o, rmsg.OnError)]
+        assert (len(stored) < 1000) == (shape == "one-minute")
+    finally:
+        dev.close()
+
+
+@pytest.mark.parametrize("cell", ["client-todo-months.restore", "client-todo.restore"])
+def test_rehearsal_of_the_client_cells_ends_correct_with_the_tree_metrics(cell):
+    """`perf/run.py --rehearse --trace 1` (which runs `perf/selfcheck.py`
+    first) on the CPU: control flow, counts and `correct`, no device
+    number. Both client cells print the six tree metrics; the new one
+    also its own `recv_handle_ms` and `window_compiles`."""
+    import json
+    import os
+    import subprocess
+    import sys
+
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    env = {k: v for k, v in os.environ.items() if k not in ("JAX_PLATFORMS", "XLA_FLAGS")}
+    done = subprocess.run(
+        [sys.executable, os.path.join(root, "perf", "run.py"), "--workload", cell,
+         "--seed", str(2**31 + 33), "--seconds", "2", "--trace", "1", "--rehearse"],
+        capture_output=True, text=True, timeout=600, env=env, cwd=root)
+    assert done.returncode == 0, done.stderr[-2000:]
+    line = json.loads(done.stdout.strip().splitlines()[-1])
+    assert line["correct"] is True and line["failed"] == 0 and line["attempted"] > 0
+    assert line["device"]["platform"] == "cpu"
+    got = line["metrics"]
+    tree = {"recv_tree_load_ms", "recv_tree_fold_ms", "recv_tree_store_ms",
+            "recv_tree_diff_ms", "tree_minutes_receive", "tree_kb_receive"}
+    assert tree <= set(got)
+    if cell == "client-todo-months.restore":
+        assert {"recv_handle_ms.months", "window_compiles.months"} <= set(got)
+        assert got["window_compiles.months"]["value"] == 0
+        assert got["tree_minutes_receive"]["value"] == 30 * 3 * 30 / 4  # 675 a response
+        parts = sum(got[f"recv_tree_{p}_ms"]["value"] for p in ("load", "fold", "store", "diff"))
+        assert parts < got["recv_handle_ms.months"]["value"]
+    else:
+        assert "recv_handle_ms.months" not in got
+        assert got["tree_minutes_receive"]["value"] == 1
+
+
 def test_perf_selfcheck_reads_every_client_layer_file():
     import os
     import subprocess
@@ -237,3 +355,6 @@ def test_perf_selfcheck_reads_every_client_layer_file():
     assert {f"{s}_ms.json" for s in ON_WORKER} | {
         "decrypt_us_msg.json", "winner_cache_hit_share.json",
         "window_compiles.client.json"} <= listed
+    assert {f"{s}_ms.json" for s in TREE} | {
+        "tree_minutes_receive.json", "tree_kb_receive.json",
+        "recv_handle_ms.months.json", "window_compiles.months.json"} <= listed
