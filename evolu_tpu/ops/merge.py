@@ -712,13 +712,13 @@ def _run_full_plan(cell_ids, k1, k2, ex_k1, ex_k2, n: int):
     sequence lives here. → (xor_mask, upsert_mask, deltas), masks in
     batch order, length n. Callers hold the x64 scope and have already
     verified the canonical-case invariant."""
-    from evolu_tpu.ops.merkle_ops import decode_owner_minute_deltas
+    from evolu_tpu.ops.merkle_ops import decode_minute_delta_arrays
     from evolu_tpu.ops.scatter_merge import scatter_table_for
 
     # Admission + table sizing in one pre-pad pass (pad rows use the
     # dump slot, never the table).
     table_size = scatter_table_for(cell_ids, k1, k2)
-    (cell_ids, k1, k2, ex_k1, ex_k2), size = pad_columns(
+    (cell_ids, k1, k2, ex_k1, ex_k2), _size = pad_columns(
         [cell_ids, k1, k2, ex_k1, ex_k2], n
     )
     anatomy.seam("device_call")
@@ -736,9 +736,7 @@ def _run_full_plan(cell_ids, k1, k2, ex_k1, ex_k2, n: int):
         )
     xor_s, upsert_s, i_s, minute_sorted, seg_end, seg_xor, valid = pull_plan_outputs(outs)
     xor_mask, upsert_mask = unpermute_masks(xor_s, upsert_s, i_s)
-    deltas = decode_owner_minute_deltas(
-        np.zeros(size, np.int32), minute_sorted, seg_end, seg_xor, valid
-    ).get(0, {})
+    deltas = decode_minute_delta_arrays(minute_sorted, seg_end, seg_xor, valid)
     return xor_mask[:n], upsert_mask[:n], deltas
 
 

@@ -27,7 +27,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from evolu_tpu.core.merkle import minutes_base3
+from evolu_tpu.core.merkle import MinuteDeltas, minutes_base3
 from evolu_tpu.core.murmur import to_int32
 from evolu_tpu.obs import anatomy
 from evolu_tpu.ops import to_host, with_x64
@@ -221,6 +221,29 @@ def decode_owner_minute_deltas(
             d = out.setdefault(o_ix, {})
             d[key] = to_int32(d.get(key, 0) ^ int(xs[i]))
     return out
+
+
+def decode_minute_delta_arrays(
+    minute_sorted, seg_end, seg_xor, valid_sorted
+) -> MinuteDeltas:
+    """Host side of a ONE-owner plan (the client's): the same segments
+    as `decode_owner_minute_deltas` reads, to sorted distinct minutes
+    and their int32 deltas for `core.merkle.fold_minute_deltas`, in
+    numpy alone: no key string and no Python step a minute. Tile-local
+    grouping hands a minute that spans tiles over once a tile, so the
+    partials are sorted by minute and XOR-combined (exact, as there)."""
+    with anatomy.part("tree_fold"):  # of a tiled Receive; a no-op elsewhere
+        ends = to_host(seg_end) & to_host(valid_sorted)
+        minutes = to_host(minute_sorted)[ends].astype(np.int64)
+        xs = to_host(seg_xor)[ends].astype(np.uint32, copy=False)
+        if len(minutes) > 1 and not bool((minutes[1:] > minutes[:-1]).all()):
+            order = np.argsort(minutes, kind="stable")
+            minutes, xs = minutes[order], xs[order]
+            first = np.flatnonzero(
+                np.concatenate(([True], minutes[1:] != minutes[:-1]))
+            )
+            minutes, xs = minutes[first], np.bitwise_xor.reduceat(xs, first)
+        return MinuteDeltas(minutes, xs.view(np.int32))
 
 
 def minute_deltas_core(millis, counter, node, xor_mask):

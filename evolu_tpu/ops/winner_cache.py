@@ -72,7 +72,7 @@ from evolu_tpu.ops.merge import (
     unpermute_masks,
 )
 from evolu_tpu.obs import anatomy, metrics
-from evolu_tpu.ops.merkle_ops import decode_owner_minute_deltas, owner_minute_segments
+from evolu_tpu.ops.merkle_ops import decode_minute_delta_arrays, owner_minute_segments
 from evolu_tpu.utils.log import span
 
 Cell = Tuple[str, str, str]
@@ -595,9 +595,7 @@ class DeviceWinnerCache:
             pull_plan_outputs(outs)
         )
         xor_mask, upsert_mask = unpermute_masks(xor_s, upsert_s, i_s)
-        deltas = decode_owner_minute_deltas(
-            np.zeros(size, np.int32), minute_sorted, seg_end, seg_xor, valid
-        ).get(0, {})
+        deltas = decode_minute_delta_arrays(minute_sorted, seg_end, seg_xor, valid)
         return xor_mask[:n], upsert_mask[:n], deltas
 
     @with_x64
@@ -967,9 +965,7 @@ class MeshShardedWinnerCache(DeviceWinnerCache):
         xor_flat, upsert_flat = unpermute_masks(
             xor_s, upsert_s, i_s, block_size=size
         )
-        deltas = decode_owner_minute_deltas(
-            np.zeros(total, np.int32), minute_sorted, seg_end, seg_xor, valid
-        ).get(0, {})
+        deltas = decode_minute_delta_arrays(minute_sorted, seg_end, seg_xor, valid)
         return xor_flat[dest], upsert_flat[dest], deltas
 
 

@@ -22,7 +22,7 @@ import threading
 import time
 from typing import Callable, Dict, List, Optional, Sequence
 
-from evolu_tpu.core.merkle import diff_merkle_trees, merkle_tree_from_string, merkle_tree_to_string
+from evolu_tpu.core.merkle import diff_merkle_trees, merkle_tree_from_string
 from evolu_tpu.core.timestamp import (
     receive_timestamps_batch,
     receive_timestamps_batch_packed,
@@ -45,7 +45,7 @@ from evolu_tpu.storage.apply import (
 )
 from evolu_tpu.storage.changes import ChangedSet
 from evolu_tpu.storage.deps import query_dependencies
-from evolu_tpu.storage.clock import read_clock, update_clock
+from evolu_tpu.storage.clock import TreeText, read_clock, tree_text, update_clock
 from evolu_tpu.storage.schema import delete_all_tables, init_db_model, update_db_schema
 from evolu_tpu.storage.sqlite import PySqliteDatabase
 from evolu_tpu.sync.protocol import assert_wire_encodable
@@ -247,6 +247,11 @@ class DbWorker:
         self._change_log: List[tuple] = []
         self._change_seq: int = 0
         self._planner = select_planner(self.config, self.db)
+        # The clock's tree and its JSON text, as last read or written
+        # (storage/clock.py): spares the parse of an unchanged `__clock`,
+        # the second dump of a tree just stored, and the parse of a
+        # relay's tree that is the client's own, byte for byte.
+        self._tree_text = TreeText()
         self._staged_effects: List = []
         self._staged_cache: Dict[str, List[dict]] = {}
         self._staged_raw: Dict[str, tuple] = {}
@@ -587,7 +592,7 @@ class DbWorker:
             "client.mutate", attrs={"messages": len(command.messages)}
         )
         with mspan, trace.use(mspan.context):
-            clock = read_clock(self.db)
+            clock = read_clock(self.db, self._tree_text)
             t = clock.timestamp
             now = self.now()
             stamped: List[CrdtMessage] = []
@@ -600,12 +605,12 @@ class DbWorker:
                                   planner=self._planner,
                                   changes=self._staged_changes_or_none())
             next_clock = CrdtClock(t, tree)
-            update_clock(self.db, next_clock)
+            tree_string = update_clock(self.db, next_clock, self._tree_text)
             self._push(
                 msg.SyncRequestInput(
                     messages=tuple(stamped),
                     clock_timestamp=timestamp_to_string(t),
-                    merkle_tree=merkle_tree_to_string(tree),
+                    merkle_tree=tree_string,
                     owner=self.owner,
                     trace=mspan.context,
                 )
@@ -614,7 +619,7 @@ class DbWorker:
 
     def _receive(self, command: msg.Receive) -> None:
         """receive.ts:144-199: merge remote messages, then anti-entropy."""
-        clock = read_clock(self.db)
+        clock = read_clock(self.db, self._tree_text)
         if len(command.messages):
             # HLC merge folded over every remote timestamp
             # (receive.ts:45-66) — the reduced vectorized fold, with one
@@ -696,7 +701,7 @@ class DbWorker:
                     # chunks committed instead of them staying hidden
                     # until some later command emits.
                     nonlocal receive_staged
-                    update_clock(self.db, CrdtClock(t, tree_so_far))
+                    update_clock(self.db, CrdtClock(t, tree_so_far), self._tree_text)
                     if not receive_staged:
                         receive_staged = True
                         self._emit(msg.OnReceive())
@@ -711,7 +716,7 @@ class DbWorker:
                 anatomy.seam("commit")
                 if deferred:
                     tree = self._apply_deferred(tree, deferred)
-                    update_clock(self.db, CrdtClock(t, tree))
+                    update_clock(self.db, CrdtClock(t, tree), self._tree_text)
                 clock = CrdtClock(t, tree)
             else:
                 tree = apply_messages(
@@ -722,14 +727,24 @@ class DbWorker:
                 if deferred:
                     tree = self._apply_deferred(tree, deferred)
                 clock = CrdtClock(t, tree)
-                update_clock(self.db, clock)
+                update_clock(self.db, clock, self._tree_text)
                 self._emit(msg.OnReceive())
 
         with anatomy.part("tree_diff"):
-            server_tree = merkle_tree_from_string(command.merkle_tree)
-            diff = diff_merkle_trees(server_tree, clock.merkle_tree)
-        metrics.inc("evolu_merkle_tree_bytes_total", len(command.merkle_tree),
-                    leg="remote")
+            # Equal texts are equal trees: nothing to parse, no diff.
+            own_text = self._tree_text.text_of(clock.merkle_tree)
+            same = own_text is not None and own_text == command.merkle_tree
+            if same:
+                diff = None
+            else:
+                server_tree = merkle_tree_from_string(command.merkle_tree)
+                diff = diff_merkle_trees(server_tree, clock.merkle_tree)
+        metrics.inc_many((
+            ("evolu_merkle_tree_bytes_total", len(command.merkle_tree), {"leg": "remote"}),
+            ("evolu_merkle_tree_text_checks_total", int(own_text is not None),
+             {"leg": "remote"}),
+            ("evolu_merkle_tree_text_hits_total", int(same), {"leg": "remote"}),
+        ))
         if diff is None:
             return
         # Livelock guard: the same diff twice in a row means the replicas
@@ -751,7 +766,7 @@ class DbWorker:
             msg.SyncRequestInput(
                 messages=resend,
                 clock_timestamp=timestamp_to_string(clock.timestamp),
-                merkle_tree=merkle_tree_to_string(clock.merkle_tree),
+                merkle_tree=tree_text(clock.merkle_tree, self._tree_text),
                 owner=self.owner,
                 previous_diff=diff,
             )
@@ -1130,12 +1145,12 @@ class DbWorker:
             self._query(command.queries, gated=False)
         if self.sync_lock.is_pending_or_held():
             return
-        clock = read_clock(self.db)
+        clock = read_clock(self.db, self._tree_text)
         self._push(
             msg.SyncRequestInput(
                 messages=(),
                 clock_timestamp=timestamp_to_string(clock.timestamp),
-                merkle_tree=merkle_tree_to_string(clock.merkle_tree),
+                merkle_tree=tree_text(clock.merkle_tree, self._tree_text),
                 owner=self.owner,
             )
         )

@@ -34,7 +34,9 @@ from __future__ import annotations
 from typing import Dict, Iterable, List, Optional, Sequence, Tuple
 
 from evolu_tpu.core.merkle import (
-    apply_prefix_xors,
+    MinuteDeltas,
+    fold_key_deltas,
+    fold_minute_deltas,
     insert_into_merkle_tree,
     minute_deltas_host,
 )
@@ -58,15 +60,22 @@ _INSERT_MESSAGE = (
 )
 
 
-def _fold_tree(merkle_tree: dict, deltas: dict) -> dict:
+def _fold_tree(merkle_tree: dict, deltas) -> dict:
     """A planned batch's per-minute deltas into the client's tree: ONE
-    copy of `apply_prefix_xors` for every apply route, timed as the
-    second half of a tiled Receive's `recv_tree_fold` (the first is the
-    delta decode in `ops.merkle_ops`) and counted."""
+    fold for every apply route, timed as the second half of a tiled
+    Receive's `recv_tree_fold` (the first is the delta decode in
+    `ops.merkle_ops`) and counted. The device planners hand over
+    `MinuteDeltas` (sorted minutes, folded a distinct node at a time),
+    the host routes a {base3-minute-key: delta} dict (a path a minute);
+    either way a tree that came in key order leaves in key order."""
     with anatomy.part("tree_fold"):
-        tree = apply_prefix_xors(merkle_tree, deltas)
+        if isinstance(deltas, MinuteDeltas):
+            tree, nodes = fold_minute_deltas(merkle_tree, deltas)
+        else:
+            tree, nodes = fold_key_deltas(merkle_tree, deltas)
     metrics.inc_many((("evolu_merkle_fold_minutes_total", len(deltas), {}),
-                      ("evolu_merkle_fold_calls_total", 1, {})))
+                      ("evolu_merkle_fold_calls_total", 1, {}),
+                      ("evolu_merkle_fold_nodes_total", nodes, {})))
     return tree
 
 

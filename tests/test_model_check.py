@@ -373,11 +373,11 @@ def _run_crash_restart_episode(tmp_path, seed, crash_at):
         vic.update_db_schema(SCHEMA)
         calls = {"n": 0}
 
-        def crashing_update(db, clock):
+        def crashing_update(db, clock, *slot):
             calls["n"] += 1
             if calls["n"] == crash_at:
                 raise RuntimeError("injected crash: died before clock persist")
-            return real_update(db, clock)
+            return real_update(db, clock, *slot)
 
         worker_mod.update_clock = crashing_update
         errors = []

@@ -20,6 +20,13 @@ once a `Receive`, in the same `observe_many`, lies inside its parent,
 and leaves the six tiles summing to `recv_handle`; the fold's counters
 equal the batch's distinct minutes; and both new cells of the benchmark
 rehearse `correct` with the new data files.
+
+ISSUE 34 keeps the four spans where they are: load and diff time the
+text comparison on a hit and the parse (and the walk) on a miss, the
+fold its numpy decode and the fold per distinct node, the store the
+dump and the `UPDATE`. Three counters say how often the new path
+engages: `evolu_merkle_fold_nodes_total` and
+`evolu_merkle_tree_text_checks_total` / `_hits_total{leg}`.
 """
 
 import itertools
@@ -305,6 +312,91 @@ def test_fold_counters_equal_the_batches_distinct_minutes(shape):
         dev.close()
 
 
+@pytest.mark.parametrize("shape", ["one-minute", "months"])
+def test_tree_counters_say_how_often_the_new_path_engages(shape):
+    from evolu_tpu.core import merkle
+    from evolu_tpu.core.timestamp import timestamp_from_string
+    from perf import gen_history
+
+    messages = (gen_client.build_messages(4000, 34, 50, 8) if shape == "one-minute" else
+                gen_history.build_messages(4000, 34, 50, 8, 30, 3, 30))
+    wires = gen_client.build_responses(messages, 4, gen_client.MNEMONIC)
+    text = {(kind, leg): lambda kind=kind, leg=leg: metrics.get_counter(
+        f"evolu_merkle_tree_text_{kind}_total", leg=leg)
+        for kind in ("checks", "hits") for leg in ("load", "remote")}
+    dev = Device(now=NOW + 366 * 86_400_000)
+    try:
+        for k, (wire, batch) in enumerate(zip(wires, gen_client.split_responses(messages, 4))):
+            nodes0 = metrics.get_counter("evolu_merkle_fold_nodes_total")
+            text0 = {key: read() for key, read in text.items()}
+            dev.receive(wire)
+            keys = {merkle.minutes_base3(timestamp_from_string(m.timestamp).millis)
+                    for m in batch}
+            if len(keys) >= merkle.LEVEL_PASS_MIN_MINUTES:  # each distinct node once
+                want = 1 + len({key[:i] for key in keys for i in range(1, 17)})
+                assert want < 17 * len(keys) / 4
+            else:  # a root and a path a minute
+                want = 17 * len(keys)
+            assert metrics.get_counter("evolu_merkle_fold_nodes_total") - nodes0 == want
+            # Every Receive compares both texts; only a restore's first
+            # load (`{}` against an empty slot) has to parse.
+            assert {key: read() - text0[key] for key, read in text.items()} == {
+                ("checks", "load"): 1, ("hits", "load"): int(k > 0),
+                ("checks", "remote"): 1, ("hits", "remote"): 1}
+        assert not [o for o in dev.outputs if isinstance(o, rmsg.OnError)]
+    finally:
+        dev.close()
+
+
+def test_load_and_diff_compare_on_a_hit_and_parse_inside_their_span_on_a_miss(
+        device, wires, monkeypatch):
+    """The four spans keep their extents: what is inside `recv_tree_load`
+    and `recv_tree_diff` is the comparison, or the parse (and the walk)
+    where it misses."""
+    import evolu_tpu.runtime.worker as worker_mod
+    from evolu_tpu.storage import clock as clock_mod
+
+    events = []
+
+    class Recording:
+        def __init__(self, name):
+            self.name = name
+
+        def __enter__(self):
+            if self.name.startswith("evolu/recv_tree_"):
+                events.append("<" + self.name[len("evolu/recv_tree_"):])
+            return self
+
+        def __exit__(self, *exc):
+            if self.name.startswith("evolu/recv_tree_"):
+                events.append(self.name[len("evolu/recv_tree_"):] + ">")
+
+    def spy(module, name, label):
+        real = getattr(module, name)
+        monkeypatch.setattr(module, name, lambda *a: events.append(label) or real(*a))
+
+    spy(clock_mod, "ordered_tree_from_string", "parse-own")
+    spy(clock_mod, "merkle_tree_to_string", "dump")
+    spy(worker_mod, "merkle_tree_from_string", "parse-relay")
+    spy(worker_mod, "diff_merkle_trees", "walk")
+    monkeypatch.setattr(log_mod, "_trace_annotation_cls", Recording)
+    hit = ["<load", "load>", "<fold", "fold>", "<fold", "fold>",
+           "<store", "dump", "store>", "<diff", "diff>"]
+    device.receive(wires[0])
+    assert events == ["<load", "parse-own"] + hit[1:]
+    del events[:]
+    device.receive(wires[1])
+    assert events == hit
+    # A relay that is ahead (no messages, the tree of the next response):
+    # the texts differ, so the relay's is parsed and walked, inside the span.
+    _packed, ahead = native_crypto.decrypt_response_columns(wires[2], gen_client.MNEMONIC)
+    del events[:]
+    device.worker.post(rmsg.Receive((), ahead, None))
+    device.worker.flush()
+    assert events == ["<load", "load>", "<diff", "parse-relay", "walk", "diff>"]
+    assert not [o for o in device.outputs if isinstance(o, rmsg.OnError)]
+
+
 @pytest.mark.parametrize("cell", ["client-todo-months.restore", "client-todo.restore"])
 def test_rehearsal_of_the_client_cells_ends_correct_with_the_tree_metrics(cell):
     """`perf/run.py --rehearse --trace 1` (which runs `perf/selfcheck.py`
@@ -328,8 +420,10 @@ def test_rehearsal_of_the_client_cells_ends_correct_with_the_tree_metrics(cell):
     assert line["device"]["platform"] == "cpu"
     got = line["metrics"]
     tree = {"recv_tree_load_ms", "recv_tree_fold_ms", "recv_tree_store_ms",
-            "recv_tree_diff_ms", "tree_minutes_receive", "tree_kb_receive"}
+            "recv_tree_diff_ms", "tree_minutes_receive", "tree_kb_receive",
+            "tree_nodes_receive", "tree_text_hit_share"}
     assert tree <= set(got)
+    assert got["tree_text_hit_share"]["value"] == 87.5  # 7 of a restore's 8 comparisons
     if cell == "client-todo-months.restore":
         assert {"recv_handle_ms.months", "window_compiles.months"} <= set(got)
         assert got["window_compiles.months"]["value"] == 0
@@ -339,6 +433,7 @@ def test_rehearsal_of_the_client_cells_ends_correct_with_the_tree_metrics(cell):
     else:
         assert "recv_handle_ms.months" not in got
         assert got["tree_minutes_receive"]["value"] == 1
+        assert got["tree_nodes_receive"]["value"] == 17
 
 
 def test_perf_selfcheck_reads_every_client_layer_file():
@@ -357,4 +452,5 @@ def test_perf_selfcheck_reads_every_client_layer_file():
         "window_compiles.client.json"} <= listed
     assert {f"{s}_ms.json" for s in TREE} | {
         "tree_minutes_receive.json", "tree_kb_receive.json",
+        "tree_nodes_receive.json", "tree_text_hit_share.json",
         "recv_handle_ms.months.json", "window_compiles.months.json"} <= listed

@@ -347,3 +347,78 @@ def test_truncated_variants_nest_structurally():
     # And each stage genuinely adds primitives.
     for prev, cur in zip(multisets, multisets[1:]):
         assert cur - prev
+
+
+# --- the client tree's counters (ISSUE 34) ---
+
+
+@pytest.mark.parametrize("route", ["arrays", "keys"])
+def test_fold_counts_the_nodes_it_copied_in_the_acquisition_it_had(route, monkeypatch):
+    """`evolu_merkle_fold_nodes_total` rides `_fold_tree`'s one
+    `inc_many` with the minutes and the calls, on both routes."""
+    import numpy as np
+
+    from evolu_tpu.core.merkle import MinuteDeltas, minutes_base3
+    from evolu_tpu.storage.apply import _fold_tree
+
+    posts = []
+    real = metrics.inc_many
+    monkeypatch.setattr(metrics, "inc_many", lambda items: posts.append(1) or real(items))
+    minutes = np.arange(28_333_334, 28_333_334 + 40, dtype=np.int64)  # 2023, 16-digit keys
+    deltas = MinuteDeltas(minutes, np.arange(40, dtype=np.int32) + 1)
+    keys = {minutes_base3(int(m) * 60_000) for m in minutes}
+    if route == "keys":
+        deltas, want = dict(deltas), 17 * 40  # a root and a path a minute
+    else:
+        want = 1 + len({k[:i] for k in keys for i in range(1, 17)})  # each distinct node
+    _fold_tree({}, deltas)
+    assert posts == [1]
+    assert metrics.get_counter("evolu_merkle_fold_nodes_total") == want
+    assert metrics.get_counter("evolu_merkle_fold_minutes_total") == 40
+    assert metrics.get_counter("evolu_merkle_fold_calls_total") == 1
+
+
+def test_clock_text_checks_and_hits_by_leg_and_only_with_a_slot():
+    """`evolu_merkle_tree_text_checks_total` / `_hits_total{leg=load}`:
+    a comparison is counted where there is a slot to compare with, a hit
+    where it spared the parse; the bytes counter counts the text either
+    way. A rollback can only miss."""
+    from evolu_tpu.core.merkle import OrderedTree, fold_key_deltas
+    from evolu_tpu.core.types import CrdtClock
+    from evolu_tpu.storage.clock import TreeText, read_clock, tree_text, update_clock
+    from evolu_tpu.storage.native import open_database
+    from evolu_tpu.storage.schema import init_db_model
+
+    def counts():
+        return tuple(metrics.get_counter(f"evolu_merkle_tree_text_{kind}_total", leg="load")
+                     for kind in ("checks", "hits"))
+
+    db = open_database(backend="python")
+    try:
+        init_db_model(db, "zoo zoo zoo zoo zoo zoo zoo zoo zoo zoo zoo wrong")
+        clock = read_clock(db)  # no slot: as before, nothing compared
+        assert counts() == (0, 0) and isinstance(clock.merkle_tree, OrderedTree)
+        slot = TreeText()
+        clock = read_clock(db, slot)
+        assert counts() == (1, 0)  # compared with an empty slot, parsed, remembered
+        assert read_clock(db, slot).merkle_tree is clock.merkle_tree
+        assert counts() == (2, 1)
+        tree, _ = fold_key_deltas(clock.merkle_tree, {"1220221222001120": 5})
+        with pytest.raises(RuntimeError):
+            with db.transaction():
+                text = update_clock(db, CrdtClock(clock.timestamp, tree), slot)
+                assert slot.text_of(tree) is text and tree_text(tree, slot) is text
+                raise RuntimeError("after update_clock")
+        again = read_clock(db, slot)  # `__clock` rolled back: the slot's text is not its text
+        assert counts() == (3, 1) and again.merkle_tree == {} and slot.text == "{}"
+        text = update_clock(db, CrdtClock(clock.timestamp, tree), slot)
+        assert read_clock(db, slot).merkle_tree is tree and counts() == (4, 2)
+        # An unmarked tree is stored in key order as ever and never remembered.
+        plain = {"hash": 5, "1": {"hash": 5}}
+        assert update_clock(db, CrdtClock(clock.timestamp, plain), slot) == '{"1":{"hash":5},"hash":5}'
+        assert slot.text is None and slot.text_of(plain) is None
+        assert metrics.get_counter("evolu_merkle_tree_bytes_total", leg="load") > 0
+        assert metrics.get_counter("evolu_merkle_tree_bytes_total", leg="store") \
+            == 2 * len(text) + len('{"1":{"hash":5},"hash":5}')
+    finally:
+        db.close()
