@@ -656,16 +656,19 @@ def mesh_phase(seed: int, compiles: CompileLog, n_messages: int = 1_000_000,
                owners: int = 1_000, n_devices: int = 4) -> dict:
     import numpy as np
 
-    from benchmarks.config3_server_reconcile import build_requests
     from evolu_tpu.core.merkle import (
         apply_prefix_xors, merkle_tree_from_string, merkle_tree_to_string)
     from evolu_tpu.ops.host_parse import parse_timestamp_strings
     from evolu_tpu.parallel.mesh import MeshContext, create_mesh
     from evolu_tpu.server import engine as eng
-    from evolu_tpu.server.relay import ShardedRelayStore
+    from evolu_tpu.server.store import ShardedRelayStore
+    from perf import gen
 
     mark = compiles.mark()
-    requests = build_requests(n=n_messages, owners=owners, seed=seed)
+    # The benchmark's own log (the cell relay-mesh4.backfill feeds the
+    # same requests to the same engine, pass by pass).
+    requests = gen.build_requests(
+        n_messages, owners, seed, gen.ciphertext_pool(min(8192, n_messages)))
     n_msgs = sum(len(r.messages) for r in requests)
     ctx = MeshContext(create_mesh(n_devices))
     assert ctx.n_shards == n_devices, f"mesh has {ctx.n_shards} devices"

@@ -196,7 +196,7 @@ class MetricsRegistry:
         with self._lock:
             self._observe_locked(name, value, buckets, exemplar, key)
 
-    def observe_many(self, items) -> None:
+    def observe_many(self, items, buckets=None, also_inc=()) -> None:
         """`observe(name, value, **labels)` for each (name, value,
         labels-dict) of `items`, under ONE acquisition of the registry
         lock. For a hot path that closes several intervals at once (a
@@ -204,13 +204,21 @@ class MetricsRegistry:
         close): with the interpreter lock contended, a thread that is
         switched out while it holds this lock stalls every other
         thread's next metric, so the number of acquisitions on such a
-        path costs more than their microseconds."""
+        path costs more than their microseconds. `buckets` is
+        `observe`'s, for every family this call is the first to
+        observe; `also_inc` are `inc_many` items that the same event
+        moves (a mesh dispatch's rows beside its per-device
+        histograms), posted under the same acquisition."""
         if not self.enabled:
             return
         keyed = [(name, value, _label_key(labels)) for name, value, labels in items]
+        counted = [(name, value, _label_key(labels))
+                   for name, value, labels in also_inc if value]
         with self._lock:
             for name, value, key in keyed:
-                self._observe_locked(name, value, None, None, key)
+                self._observe_locked(name, value, buckets, None, key)
+            for name, value, key in counted:
+                self._inc_locked(name, value, key)
 
     def _observe_locked(self, name, value, buckets, exemplar, key) -> None:
         edges = self._buckets.get(name)

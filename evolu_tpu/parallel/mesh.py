@@ -130,16 +130,23 @@ class MeshContext:
 
     def record_occupancy(self, loads: Sequence[int], shard_size: int) -> None:
         """Per-device batch-occupancy / padding-waste telemetry for one
-        sharded dispatch (`evolu_mesh_*`, docs/OBSERVABILITY.md)."""
+        sharded dispatch (`evolu_mesh_*`, docs/OBSERVABILITY.md): two
+        observations a device, and the dispatch's rows and slots
+        (devices x bucket) as counters, so that occupancy over any
+        window is a ratio of two deltas. One acquisition of the
+        registry's lock a dispatch."""
         from evolu_tpu.obs import metrics
 
+        observations = []
         for load in loads:
-            metrics.observe("evolu_mesh_shard_rows", load,
-                            buckets=metrics.COUNT_BUCKETS)
-            metrics.observe("evolu_mesh_padding_waste_rows",
-                            max(shard_size - load, 0),
-                            buckets=metrics.COUNT_BUCKETS)
-        metrics.inc("evolu_mesh_dispatches_total")
+            observations.append(("evolu_mesh_shard_rows", load, {}))
+            observations.append(("evolu_mesh_padding_waste_rows",
+                                 max(shard_size - load, 0), {}))
+        metrics.observe_many(observations, buckets=metrics.COUNT_BUCKETS, also_inc=(
+            ("evolu_mesh_dispatches_total", 1, {}),
+            ("evolu_mesh_rows_total", sum(loads), {}),
+            ("evolu_mesh_slot_rows_total", len(loads) * shard_size, {}),
+        ))
 
     def record_xdev_reduce(self, kind: str) -> None:
         """Count one cross-device reduction (the digest XOR all-reduce

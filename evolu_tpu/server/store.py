@@ -339,9 +339,10 @@ class ShardedRelayStore:
     its own single-writer — the storage twin of the owners-over-mesh
     device sharding (owners are independent, SURVEY.md §2.15), and the
     way past SQLite's one-writer throughput wall: the batch reconciler
-    lands a pass on every shard in one native call, which runs the
-    shards on threads of its own when the pass is large
-    (`storage/native.py`, the shard-set calls).
+    lands a pass on every shard in one native call, the shards one
+    after the other on the caller's thread (`storage/native.py`, the
+    shard-set calls; a thread a shard lost at every size on the chip's
+    host, native/evolu_host.cpp).
 
     Same public surface as RelayStore; userId routes to a shard by a
     stable hash. Per-request semantics are unchanged — a request only

@@ -342,6 +342,77 @@ def test_mesh_obs_family_and_stats_section():
         store.close()
 
 
+def test_occupancy_counters_move_by_rows_and_slots_under_one_lock(monkeypatch):
+    """A sharded dispatch's occupancy as a ratio of two counter deltas
+    (ISSUE 35): `evolu_mesh_rows_total` moves by the rows laid out,
+    `evolu_mesh_slot_rows_total` by devices x bucket, beside one
+    observation a device in each histogram and one dispatch, and
+    `record_occupancy` takes the registry's lock once for all of it."""
+    import threading
+
+    ctx = MeshContext(n_devices=4)
+
+    def reading():
+        shard_rows = metrics.registry.get_histogram("evolu_mesh_shard_rows")
+        waste = metrics.registry.get_histogram("evolu_mesh_padding_waste_rows")
+        return {
+            "rows": metrics.get_counter("evolu_mesh_rows_total"),
+            "slots": metrics.get_counter("evolu_mesh_slot_rows_total"),
+            "dispatches": metrics.get_counter("evolu_mesh_dispatches_total"),
+            "shard_rows": (shard_rows[2], shard_rows[3]) if shard_rows else (0.0, 0),
+            "waste": (waste[2], waste[3]) if waste else (0.0, 0),
+        }
+
+    class CountingLock:
+        def __init__(self):
+            self.lock, self.taken = threading.Lock(), 0
+
+        def __enter__(self):
+            self.taken += 1
+            return self.lock.__enter__()
+
+        def __exit__(self, *exc):
+            return self.lock.__exit__(*exc)
+
+    before = reading()
+    counting = CountingLock()
+    monkeypatch.setattr(metrics.registry, "_lock", counting)
+    ctx.record_occupancy([61_700, 63_300, 62_500, 0], 65_536)
+    assert counting.taken == 1
+    monkeypatch.undo()
+    after = reading()
+    assert after["rows"] - before["rows"] == 187_500
+    assert after["slots"] - before["slots"] == 4 * 65_536
+    assert after["dispatches"] - before["dispatches"] == 1
+    assert after["shard_rows"][1] - before["shard_rows"][1] == 4
+    assert after["shard_rows"][0] - before["shard_rows"][0] == 187_500
+    assert after["waste"][1] - before["waste"][1] == 4
+    assert after["waste"][0] - before["waste"][0] == 4 * 65_536 - 187_500
+    # row counts, not milliseconds: the family keeps the count buckets
+    # whichever call observed it first
+    assert metrics.registry.get_histogram("evolu_mesh_shard_rows")[0] == metrics.COUNT_BUCKETS
+
+    # An engine pass on the mesh posts exactly that once a dispatch.
+    store = ShardedRelayStore(shards=2)
+    from evolu_tpu.server.engine import BatchReconciler
+
+    engine = BatchReconciler(store, mesh_ctx=ctx)
+    try:
+        requests = [protocol.SyncRequest(_msgs(f"{i + 1:016x}", 0, 5 + i), f"occ-u{i:02d}",
+                                         f"{i + 1:016x}", "{}") for i in range(12)]
+        before = reading()
+        engine.reconcile(requests)
+        after = reading()
+    finally:
+        engine.close()
+        store.close()
+    rows = sum(len(r.messages) for r in requests)
+    assert after["dispatches"] - before["dispatches"] == 1
+    assert after["rows"] - before["rows"] == rows
+    slots = after["slots"] - before["slots"]
+    assert slots % 4 == 0 and slots >= rows and (slots // 4) & (slots // 4 - 1) == 0
+
+
 def test_non_canonical_batch_bounces_before_side_effect_on_sharded_path():
     """The r5 contract, kept on the sharded path: a non-canonical
     timestamp width never enters a packed sharded batch — it dispatches
