@@ -176,6 +176,88 @@ def parse_packed_timestamps(
     return millis, counter, node
 
 
+_PACK_LANE = False  # resolved lazily: False = untried, None = unavailable
+
+
+def _pack_lane():
+    """The CPython-ABI request pack → (`eh_pack_requests`,
+    `eh_pack_scratch_words`) through `open_gil_held`'s second handle on
+    libevolu_host.so (the walk over the message objects needs the GIL;
+    `storage/native.py`'s handle drops it), or None: then the caller's
+    Python body is the path."""
+    global _PACK_LANE
+    if _PACK_LANE is not False:
+        return _PACK_LANE
+    _PACK_LANE = None
+    import ctypes as c
+
+    from evolu_tpu.storage.native import load_library
+    from evolu_tpu.utils.native_loader import open_gil_held
+
+    plib = open_gil_held("libevolu_host.so", "eh_py_abi_probe") if load_library() else None
+    if plib is not None:  # the probe is there, so the two are
+        words = plib.eh_pack_scratch_words
+        words.restype = c.c_int64
+        words.argtypes = [c.c_int64, c.c_int64]
+        pack = plib.eh_pack_requests
+        pack.restype = c.c_int
+        pack.argtypes = [
+            c.py_object, c.c_int64, c.c_void_p, c.c_void_p, c.c_void_p,
+            c.c_int64, c.c_void_p, c.c_void_p, c.c_void_p, c.c_int64,
+            c.py_object,
+        ]
+        _PACK_LANE = (pack, words)
+    return _PACK_LANE
+
+
+def pack_requests(groups: list, owners: Sequence[int],
+                  shard_groups: Sequence[int], scratch=None):
+    """One native walk over a relay pass's messages: the in-batch dedup
+    on (timestamp, owner) and the packed buffers of every shard.
+
+    `groups` lists the requests' `messages` sequences (of objects with
+    a `timestamp` str and a `content` bytes), shard after shard;
+    `owners[g]` is group g's owner as a dense id (equal ids, one
+    owner); shard s takes the next `shard_groups[s]` groups. `scratch`
+    is what an earlier call returned (the table and the row index the
+    walk needs: kept by the caller so that a pass touches no fresh
+    pages for them), or None.
+
+    → (kept, lens, buffers, scratch): rows of each group that survived
+    the dedup (first occurrence in walk order), the kept rows' content
+    lengths (int32, all shards back to back; the caller slices), and
+    for every shard with a kept row, in shard order, two `bytes`:
+    rows x 46 timestamp bytes and the packed contents. None where the
+    lane is unavailable or declines the batch (a timestamp that is not
+    an exact 46-character ASCII `str`, a content that is not exact
+    `bytes`, any CPython error): the caller's Python body then packs
+    the batch and raises what it raises."""
+    lane = _pack_lane()
+    if lane is None:
+        return None
+    pack, scratch_words = lane
+    n_groups = len(groups)
+    sizes = np.fromiter(map(len, groups), np.int64, count=n_groups)
+    owner_ids = np.fromiter(owners, np.int32, count=n_groups)
+    per_shard = np.fromiter(shard_groups, np.int64, count=len(shard_groups))
+    n_rows = int(sizes.sum())
+    if scratch is None or len(scratch) < scratch_words(n_rows, len(per_shard)):
+        # By the power of two above, so that passes of about one size
+        # share one allocation.
+        scratch = np.empty(
+            scratch_words(1 << max(n_rows - 1, 0).bit_length(), len(per_shard)),
+            np.uint64)
+    kept = np.empty(n_groups, np.int64)
+    lens = np.empty(n_rows, np.int32)
+    buffers: list = []
+    rc = pack(groups, n_groups, sizes.ctypes.data, owner_ids.ctypes.data,
+              per_shard.ctypes.data, len(per_shard), kept.ctypes.data,
+              lens.ctypes.data, scratch.ctypes.data, len(scratch), buffers)
+    if rc != 0:
+        return None
+    return kept, lens, buffers, scratch
+
+
 def intern_cells(
     tables: Sequence[str], rows: Sequence[str], columns: Sequence[str]
 ) -> Tuple[np.ndarray, List[Tuple[str, str, str]]]:

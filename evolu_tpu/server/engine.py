@@ -38,7 +38,11 @@ from evolu_tpu.core.merkle import (
 )
 from evolu_tpu.ops import bucket_size, start_host_transfer, to_host_many, with_x64
 from evolu_tpu.ops.encode import timestamp_hashes
-from evolu_tpu.ops.host_parse import parse_packed_timestamps, parse_timestamp_strings
+from evolu_tpu.ops.host_parse import (
+    pack_requests,
+    parse_packed_timestamps,
+    parse_timestamp_strings,
+)
 from evolu_tpu.ops.merkle_ops import decode_owner_minute_deltas, owner_minute_segments
 from evolu_tpu.parallel.mesh import (
     OWNERS_AXIS,
@@ -589,6 +593,17 @@ def _ledger_count_pass(requests, inserted_by_owner) -> None:
         ledger.count(ledger.STORE_DUPLICATE, total - ins, owner=o)
 
 
+def _count_pass(route: str, st) -> None:
+    """One landing pass: the route it lands by (`_route`) and which
+    body packed it in `start_batch` (`_pack_batch`), in one acquisition
+    of the registry's lock. A pass that raised before it landed counts
+    in neither."""
+    metrics.inc_many((
+        ("evolu_engine_store_passes_total", 1, {"path": route}),
+        ("evolu_engine_pack_total", 1, {"path": st["pack_path"]}),
+    ))
+
+
 def _fold_trees(deltas_by_owner, stored, tree_rows, shard_index, trees, strings) -> None:
     """Fold each owner's pass deltas onto its stored tree TEXT
     (`_store_pass`'s `stored`): the folded tree into `trees`, its dump
@@ -614,6 +629,43 @@ def _pack_rows(ts_list, contents):
     ts_packed = "".join(ts_list).encode("ascii")
     lens = np.fromiter(map(len, contents), np.int32, count=n)
     return ts_packed, b"".join(contents), lens
+
+
+def _pack_shards_python(per_shard) -> Dict[int, tuple]:
+    """The per-message half of `_pack_batch` in Python: the canonical
+    body, which `pack_requests`' native walk reproduces byte for byte
+    and demotes to for anything but exact 46-character ASCII `str`
+    timestamps and exact `bytes` contents, so that every such batch
+    raises here what it always raised. `per_shard[si]`: the requests of
+    shard si in request order. → {si: (owners, kept rows of each, packed
+    timestamps, packed contents, content lengths)} for the shards with
+    a row, in shard order; a request whose rows the dedup all dropped
+    gives no group."""
+    seen: set = set()
+    shard_data: Dict[int, tuple] = {}
+    for si, reqs in enumerate(per_shard):
+        gu: List[str] = []
+        gc: List[int] = []
+        ts_list: List[str] = []
+        contents: List[bytes] = []
+        for r in reqs:
+            # In-batch dedup up front: correction logic needs
+            # was_new==False to mean exactly "already in the
+            # store". Same-user rows stay in request order, so the
+            # kept occurrence matches the row the PK would keep.
+            kept = [
+                m for m in r.messages
+                if (m.timestamp, r.user_id) not in seen
+                and not seen.add((m.timestamp, r.user_id))
+            ]
+            if kept:
+                gu.append(r.user_id)
+                gc.append(len(kept))
+                ts_list.extend(m.timestamp for m in kept)
+                contents.extend(m.content for m in kept)
+        if ts_list:
+            shard_data[si] = (gu, gc, *_pack_rows(ts_list, contents))
+    return shard_data
 
 
 class _PackedRows:
@@ -664,6 +716,9 @@ class BatchReconciler:
         self.mesh = mesh or create_mesh()
         self.write_behind = write_behind
         self._pull_pool = None
+        # `pack_requests`' dedup table and row index, kept from pass to
+        # pass so that a pass's pack touches no fresh pages for them.
+        self._pack_scratch = None
 
     def _new_messages(
         self, requests: Sequence[protocol.SyncRequest]
@@ -840,7 +895,7 @@ class BatchReconciler:
         and `deltas_dispatch`'s `pass_layout` + `pass_device_call`."""
         with anatomy.stage("device_dispatch") as whole, \
                 anatomy.stage("pass_pack") as tile:
-            live, shard_data, packed, shard_offsets, merged, off = \
+            live, shard_data, packed, shard_offsets, merged, off, pack_path = \
                 self._pack_batch(requests)
             whole.rows = tile.rows = off
             # Parse AFTER every shard packed (it needs only the packed
@@ -866,66 +921,89 @@ class BatchReconciler:
         return {
             "requests": requests, "live": live, "shard_data": shard_data,
             "dev": dev_state, "packed": packed, "n_total": off,
-            "shard_offsets": shard_offsets,
+            "shard_offsets": shard_offsets, "pack_path": pack_path,
         }
 
     def _pack_batch(self, requests):
-        """The `pass_pack` leg of `start_batch`: shard grouping, in-batch
-        dedup, list building, `_pack_rows`, owner index arrays. →
+        """The `pass_pack` leg of `start_batch`: shard grouping, then
+        what is per message (the in-batch dedup, the packed buffers of
+        every shard) in one native walk, `_pack_shards_native`, or,
+        where that declines the batch, in `_pack_shards_python`, which
+        owns the error surface; then the owner index arrays. →
         (live shard ids, per-shard packed data, `_PackedRows`, shard row
-        offsets, owner → row indices, row count)."""
+        offsets, owner → row indices, row count, which of the two
+        packed: `evolu_engine_pack_total`'s `path`)."""
         stores, shard_index = self._shards()
         per_shard: List[List[protocol.SyncRequest]] = [[] for _ in stores]
         for r in requests:
             per_shard[shard_index(r.user_id)].append(r)
+        path = "native"
+        shard_data = self._pack_shards_native(per_shard)
+        if shard_data is None:
+            path = "python"
+            shard_data = _pack_shards_python(per_shard)
 
-        seen: set = set()
-        shard_data: Dict[int, tuple] = {}
         buffers: List[bytes] = []
         offsets: List[int] = []
         owner_rows: Dict[str, List[np.ndarray]] = {}
-        live: List[int] = []
+        # One arange a pass, sliced a request: `np.arange` drops the
+        # interpreter lock whatever its length, and on the dispatcher
+        # thread every drop is a turn lost to the handler threads.
+        rows = np.arange(sum(len(d[4]) for d in shard_data.values()))
         off = 0
-        for si, reqs in enumerate(per_shard):
-            gu: List[str] = []
-            gc: List[int] = []
-            ts_list: List[str] = []
-            contents: List[bytes] = []
-            for r in reqs:
-                # In-batch dedup up front: correction logic needs
-                # was_new==False to mean exactly "already in the
-                # store". Same-user rows stay in request order, so the
-                # kept occurrence matches the row the PK would keep.
-                kept = [
-                    m for m in r.messages
-                    if (m.timestamp, r.user_id) not in seen
-                    and not seen.add((m.timestamp, r.user_id))
-                ]
-                if kept:
-                    gu.append(r.user_id)
-                    gc.append(len(kept))
-                    ts_list.extend(m.timestamp for m in kept)
-                    contents.extend(m.content for m in kept)
-            n = len(ts_list)
-            if n == 0:
-                continue
-            live.append(si)
-            ts_packed, content_packed, lens = _pack_rows(ts_list, contents)
-            pos = 0
+        for gu, gc, ts_packed, _cp, lens in shard_data.values():
+            pos = off
             for u, k in zip(gu, gc):
-                if k:
-                    owner_rows.setdefault(u, []).append(np.arange(pos, pos + k) + off)
+                owner_rows.setdefault(u, []).append(rows[pos:pos + k])
                 pos += k
             buffers.append(ts_packed)
             offsets.append(off)
-            shard_data[si] = (gu, gc, ts_packed, content_packed, lens)
-            off += n
+            off += len(lens)
         merged = {
             u: (v[0] if len(v) == 1 else np.concatenate(v))
             for u, v in owner_rows.items()
         }
+        live = list(shard_data)
         return (live, shard_data, _PackedRows(buffers, offsets),
-                dict(zip(live, offsets)), merged, off)
+                dict(zip(live, offsets)), merged, off, path)
+
+    def _pack_shards_native(self, per_shard) -> Optional[Dict[int, tuple]]:
+        """`_pack_shards_python`'s result from `pack_requests`' one
+        walk (ops/host_parse.py), or None where it declines. What is
+        per request stays here: the groups in shard order, an owner's
+        dense id, and the (owner, kept rows) lists of each shard."""
+        groups: List[Sequence] = []
+        users: List[str] = []
+        owner_ids: Dict[str, int] = {}
+        shard_groups: List[int] = []
+        for reqs in per_shard:
+            before = len(groups)
+            for r in reqs:
+                if r.messages:
+                    groups.append(r.messages)
+                    users.append(r.user_id)
+                    owner_ids.setdefault(r.user_id, len(owner_ids))
+            shard_groups.append(len(groups) - before)
+        packed = pack_requests(
+            groups, map(owner_ids.__getitem__, users), shard_groups,
+            self._pack_scratch)
+        if packed is None:
+            return None
+        kept, lens, buffers, self._pack_scratch = packed
+        kept = kept.tolist()
+        buffers = iter(buffers)
+        shard_data: Dict[int, tuple] = {}
+        g = row = 0
+        for si, n_groups in enumerate(shard_groups):
+            gu = [u for u, k in zip(users[g:g + n_groups], kept[g:g + n_groups]) if k]
+            gc = [k for k in kept[g:g + n_groups] if k]
+            g += n_groups
+            n = sum(gc)
+            if n:
+                shard_data[si] = (gu, gc, next(buffers), next(buffers),
+                                  lens[row:row + n])
+                row += n
+        return shard_data
 
     def finish_batch(self, st, wire: bool = False, respond_stage=None) -> List:
         """Land batch k (`_land`) and answer it — while batch k+1 flies
@@ -947,7 +1025,7 @@ class BatchReconciler:
         commit per shard in a second (`_store_pass`), then the ledger
         count. `respond_stage`: see `finish_batch`."""
         stores, shard_index = self._shards()
-        metrics.inc("evolu_engine_store_passes_total", path="stream")
+        _count_pass("stream", st)
         live, shard_data = st["live"], st["shard_data"]
         trees: Dict[str, dict] = {}
         strings: Dict[str, str] = {}
@@ -1229,7 +1307,7 @@ class BatchReconciler:
         live, shard_data = st["live"], st["shard_data"]
         trees: Dict[str, dict] = {}
         strings: Dict[str, str] = {}
-        metrics.inc("evolu_engine_store_passes_total", path="write_behind")
+        _count_pass("write_behind", st)
         if not live:
             if respond_stage is not None:
                 respond_stage.start()

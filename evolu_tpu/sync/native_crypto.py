@@ -300,27 +300,18 @@ def _py_push_fn():
     _PY_PUSH = None
     if load_library() is None or not _AEAD_NATIVE:
         return None
-    import os
+    from evolu_tpu.utils.native_loader import open_gil_held
 
-    from evolu_tpu.utils.native_loader import NATIVE_DIR
-
-    try:
+    plib = open_gil_held("libevolu_crypto.so", "ehc_py_abi_probe")
+    fn = getattr(plib, "ehc_aead_encrypt_push_py", None)  # None: a stale binary
+    if fn is not None:
         c = ctypes
-        plib = c.PyDLL(os.path.join(NATIVE_DIR, "libevolu_crypto.so"))
-        probe = plib.ehc_py_abi_probe
-        probe.restype = c.c_int
-        probe.argtypes = [c.py_object]
-        if probe("x") != 0:
-            return None
-        fn = plib.ehc_aead_encrypt_push_py
         fn.restype = c.c_int
         fn.argtypes = [
             c.py_object, c.c_int64, c.c_char_p, c.c_char_p,
             c.POINTER(c.c_void_p), c.POINTER(c.c_int64),
         ]
         _PY_PUSH = fn
-    except (OSError, AttributeError, ctypes.ArgumentError):
-        _PY_PUSH = None
     return _PY_PUSH
 
 

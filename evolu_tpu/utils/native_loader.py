@@ -83,6 +83,25 @@ def load_native_library(
         return lib
 
 
+def open_gil_held(so_name: str, probe: str) -> Optional[ctypes.PyDLL]:
+    """An already-built library opened again through ctypes.PyDLL — the
+    same mapping, but calls made through this handle KEEP the GIL, which
+    every function that takes Python objects (native/pyabi.h) requires.
+    Only after the library's `probe` (`py_abi_probe` under its prefix)
+    has validated pyabi.h's self-declared object layout against a live
+    str on THIS interpreter: any drift (debug build, free-threading, a
+    future CPython), or a binary without the symbol, gives None and the
+    caller keeps its Python path."""
+    try:
+        plib = ctypes.PyDLL(os.path.join(NATIVE_DIR, so_name))
+        fn = getattr(plib, probe)
+        fn.restype = ctypes.c_int
+        fn.argtypes = [ctypes.py_object]
+        return plib if fn("x") == 0 else None
+    except (OSError, AttributeError):
+        return None
+
+
 def _note_failure(so_name: str, reason: str, error: object) -> None:
     stderr = getattr(error, "stderr", None)
     detail = stderr.decode("utf-8", "replace")[-400:] if stderr else repr(error)

@@ -56,6 +56,7 @@
 #include <unordered_map>
 #include <vector>
 
+#include "pyabi.h"
 #include "wire.h"
 
 // ---- OpenSSL 3 ABI (self-declared; no headers in the image) ----
@@ -829,93 +830,24 @@ int ehc_aead_encrypt_wire_batch(
 
 // ---- CPython ABI fast lane (aead push encode) ----
 //
-// Self-declared like the OpenSSL ABI at the top of this file: the .so
-// is only ever dlopen'd from inside a CPython process, so these
-// symbols resolve from the already-loaded interpreter. The binding
-// side (sync/native_crypto.py) calls through ctypes.PyDLL so the GIL
-// is HELD for the whole call — mandatory for every function below.
+// The ABI declarations, `PyRefs`, `GilScope`, `py_str` and the probe
+// live in pyabi.h, shared with libevolu_host's request pack. The
+// binding side (sync/native_crypto.py) calls through ctypes.PyDLL so
+// the GIL is HELD for the whole call — mandatory for every function
+// below.
 // Why: the Python-side columnar packer costs ~0.9µs/msg (attr access,
 // per-string encode, length arrays — more than the ENTIRE C crypto
 // leg after the S2K removal). Extracting fields here instead reads
 // each str's cached UTF-8 in place (zero-copy for ASCII), turning the
 // residual Python share into ~5 C-API calls per message.
-// Safety: `ehc_py_abi_probe` verifies the assumed PyObject layout
-// (ob_type at offset 8, non-debug non-free-threaded build) against a
-// live str before the lane is enabled; any drift disables it and the
-// blob ABI above stays the path. Exact types only — a str/int
-// subclass or any error demotes the whole batch (return 2) to the
-// Python packer, which owns the canonical error surface.
-
-extern "C" {
-struct PyObj {
-  long long ob_refcnt;  // Py_ssize_t (union in 3.12+, same size/offset)
-  void *ob_type;
-};
-PyObj *PySequence_GetItem(PyObj *, long long);
-PyObj *PyObject_GetAttr(PyObj *, PyObj *);
-PyObj *PyUnicode_FromString(const char *);
-const char *PyUnicode_AsUTF8AndSize(PyObj *, long long *);
-long long PyLong_AsLongLong(PyObj *);
-double PyFloat_AsDouble(PyObj *);
-void Py_DecRef(PyObj *);
-PyObj *PyErr_Occurred(void);
-void PyErr_Clear(void);
-void *PyEval_SaveThread(void);
-void PyEval_RestoreThread(void *);
-extern char PyUnicode_Type, PyLong_Type, PyFloat_Type, PyBool_Type;
-extern char _Py_NoneStruct;
-}
-
-namespace {
-
-struct PyRefs {
-  std::vector<PyObj *> refs;
-  ~PyRefs() {
-    for (PyObj *o : refs) Py_DecRef(o);
-  }
-  PyObj *keep(PyObj *o) {
-    if (o) refs.push_back(o);
-    return o;
-  }
-};
-
-// Drop the GIL for a pure-C region (the seal loop touches no Python
-// state — only Row fields and the strs' cached UTF-8 buffers, pinned
-// alive by PyRefs; str is immutable, so concurrent threads can't
-// move the bytes out from under us). Scoped so EVERY exit path —
-// including the error returns inside the loop — restores the GIL
-// before PyRefs' Py_DecRefs run (reverse destruction order).
-struct GilScope {
-  void *tstate;
-  GilScope() : tstate(PyEval_SaveThread()) {}
-  ~GilScope() { PyEval_RestoreThread(tstate); }
-};
-
-// Exact-str extraction: → utf8 pointer + BYTE length (the interned
-// rep CPython caches on the object — no copy for compact ASCII).
-inline bool py_str(PyObj *o, const char **s, long long *n) {
-  if (!o || o->ob_type != static_cast<void *>(&PyUnicode_Type)) return false;
-  *s = PyUnicode_AsUTF8AndSize(o, n);
-  if (!*s) { PyErr_Clear(); return false; }  // lone surrogates etc.
-  return true;
-}
-
-}  // namespace
+// Safety: `ehc_py_abi_probe` gates the lane; exact types only — a
+// str/int subclass or any error demotes the whole batch (return 2) to
+// the Python packer, which owns the canonical error surface.
 
 extern "C" {
 
-// Layout sanity gate for the self-declared CPython ABI: called with a
-// known one-char str; any mismatch (debug build, free-threaded
-// layout, future drift) returns nonzero and the binding never uses
-// the lane. MUST be called via PyDLL (GIL held).
-int ehc_py_abi_probe(PyObj *sample) {
-  if (!sample || sample->ob_type != static_cast<void *>(&PyUnicode_Type))
-    return 1;
-  long long n = 0;
-  const char *s = PyUnicode_AsUTF8AndSize(sample, &n);
-  if (!s) { PyErr_Clear(); return 2; }
-  return (n == 1 && s[0] == 'x') ? 0 : 3;
-}
+// pyabi.h's layout gate. MUST be called via PyDLL (GIL held).
+int ehc_py_abi_probe(PyObj *sample) { return py_abi_probe(sample); }
 
 // aead-batch-v1 push leg over the message OBJECTS: extraction +
 // content assembly + seal in one GIL-held call. `messages` is the

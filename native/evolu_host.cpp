@@ -26,6 +26,7 @@
 // 4:blob} — no string round-trip for numerics, preserving SQLite
 // storage classes byte-for-byte vs the Python backend.
 
+#include <algorithm>
 #include <cstdint>
 #include <cstdlib>
 #include <cstring>
@@ -33,6 +34,7 @@
 #include <string>
 #include <vector>
 
+#include "pyabi.h"
 #include "wire.h"
 
 extern "C" {
@@ -672,6 +674,270 @@ int eh_parse_timestamps(const char *ts_packed, int64_t n, int64_t *out_millis,
     }
     out_node[i] = node;
     out_case_ok[i] = canonical ? 1 : 0;
+  }
+  return 0;
+}
+
+// --- the relay pass's request pack (CPython ABI, pyabi.h) ---
+//
+// `BatchReconciler._pack_batch`'s per-message half in one walk: the
+// in-batch dedup on (timestamp, owner), and per shard the packed
+// 46-byte timestamps, the packed contents and their lengths — the
+// buffers eh_parse_timestamps and eh_relay_insert_packed_shards read.
+// The Python body took ~1.1 us a message for this (a set probe and a
+// tuple, two list.extends of generators, two map(len)s, two joins);
+// here a message is an item read, two attribute reads, a hash probe
+// and two copies. Called through ctypes.PyDLL (ops/host_parse.py):
+// the GIL is HELD for the whole call, which touches Python objects
+// from its first line to its last.
+//
+// `groups` is a sequence of the requests' `messages` sequences, shard
+// after shard in the order the caller will store them; group g has
+// group_sizes[g] messages of owner group_owner[g] (a dense id: equal
+// ids are one owner), and shard s takes the next shard_groups[s]
+// groups. Dedup is `_pack_batch`'s: one table over the whole batch,
+// rows in walk order, the first occurrence kept — the row the primary
+// key would keep — and the same timestamp under two owners both kept.
+//
+// Out: out_kept[g] rows of group g survived; out_lens holds the kept
+// rows' content lengths, all shards back to back (capacity: the sum of
+// group_sizes); for every shard with a kept row, in shard order, `out`
+// (a list) gains two `bytes`: rows x 46 timestamp bytes and the
+// contents. Those and `scratch` (eh_pack_scratch_words(...) words, the
+// caller's, reusable from pass to pass) are all the memory the call
+// uses: it calls no malloc of its own, so it grows no thread arena.
+//
+// Returns 0; 2 = demotion: a timestamp that is not an exact str of 46
+// ASCII bytes, a content that is not exact bytes, or any CPython error
+// (cleared) — the caller runs the Python body, which owns the error
+// surface; 1 = bad arguments or no memory (the caller demotes too).
+// On a non-zero return `out` may hold buffers of earlier shards: drop
+// it.
+
+namespace {
+
+constexpr int64_t kTsWidth = 46;
+
+inline uint64_t load64(const char *p) {
+  uint64_t v;
+  memcpy(&v, p, 8);
+  return v;
+}
+
+// 64 bits over the 46 bytes and the owner. A batch's timestamps share
+// their first words and differ in the last, so every word is folded
+// through a multiply; low bits index the table, high bits tag a slot.
+inline uint64_t pack_key_hash(const char *t, uint32_t owner) {
+  uint64_t h = (uint64_t(owner) + 1) * 0x9E3779B97F4A7C15ull;
+  for (int i = 0; i < 40; i += 8) {
+    h = (h ^ load64(t + i)) * 0xFF51AFD7ED558CCDull;
+    h ^= h >> 32;
+  }
+  h = (h ^ load64(t + kTsWidth - 8)) * 0xC4CEB9FE1A85EC53ull;
+  return h ^ (h >> 29);
+}
+
+int64_t pack_table_slots(int64_t n_rows) {
+  int64_t slots = 16;
+  while (slots < 2 * n_rows) slots <<= 1;
+  return slots;
+}
+
+// The messages the walk has in hand: their objects and timestamp strs
+// (owned references, released at scope exit), the strs' bytes, the key
+// hashes.
+struct PackBlock {
+  static constexpr int64_t kRows = 32;
+  PyObj *msg[kRows], *ts[kRows];
+  const char *text[kRows];
+  uint64_t hash[kRows];
+  int n = 0;
+  ~PackBlock() {
+    for (int j = 0; j < n; ++j) {
+      Py_DecRef(msg[j]);
+      if (ts[j]) Py_DecRef(ts[j]);
+    }
+  }
+};
+
+// Owned references in caller-provided storage, released at scope exit.
+struct HeldRefs {
+  PyObj **items;
+  int64_t n = 0;
+  explicit HeldRefs(PyObj **storage) : items(storage) {}
+  ~HeldRefs() { release(); }
+  void release() {
+    for (int64_t i = 0; i < n; ++i)
+      if (items[i]) Py_DecRef(items[i]);
+    n = 0;
+  }
+};
+
+}  // namespace
+
+// Words of `scratch` eh_pack_requests needs for n_rows messages over
+// n_shards shards: the dedup table, a kept row's timestamp pointer,
+// content object and owner, a shard's two buffers.
+int64_t eh_pack_scratch_words(int64_t n_rows, int64_t n_shards) {
+  if (n_rows < 0 || n_shards < 0) return -1;
+  return pack_table_slots(n_rows) + 2 * n_rows + (n_rows + 1) / 2 + 2 * n_shards;
+}
+
+int eh_py_abi_probe(PyObj *sample) { return py_abi_probe(sample); }
+
+int eh_pack_requests(PyObj *groups, int64_t n_groups, const int64_t *group_sizes,
+                     const int32_t *group_owner, const int64_t *shard_groups,
+                     int64_t n_shards, int64_t *out_kept, int32_t *out_lens,
+                     uint64_t *scratch, int64_t scratch_words, PyObj *out) {
+  if (!groups || !out || n_groups < 0 || n_shards < 0) return 1;
+  int64_t n_rows = 0;
+  for (int64_t g = 0; g < n_groups; ++g) {
+    if (group_sizes[g] < 0) return 1;
+    n_rows += group_sizes[g];
+  }
+  for (int64_t s = 0; s < n_shards; ++s)
+    if (shard_groups[s] < 0) return 1;
+  // A slot holds a row's index + 1 in its low 32 bits.
+  if (n_rows >= INT32_MAX || sum_of(shard_groups, n_shards) != n_groups ||
+      scratch_words < eh_pack_scratch_words(n_rows, n_shards))
+    return 1;
+  const int64_t slots = pack_table_slots(n_rows);
+  const uint64_t mask = uint64_t(slots) - 1;
+  uint64_t *table = scratch;
+  uint64_t *rest = scratch + slots;
+  const char **row_ts = reinterpret_cast<const char **>(rest);
+  PyObj **row_content = reinterpret_cast<PyObj **>(rest + n_rows);
+  PyObj **shard_bufs = reinterpret_cast<PyObj **>(rest + 2 * n_rows);  // ts, content a shard
+  int32_t *row_owner = reinterpret_cast<int32_t *>(rest + 2 * n_rows + 2 * n_shards);
+  memset(table, 0, size_t(slots) * sizeof(uint64_t));
+
+  PyRef a_ts(PyUnicode_InternFromString("timestamp"));
+  PyRef a_content(PyUnicode_InternFromString("content"));
+  if (!a_ts.o || !a_content.o) { PyErr_Clear(); return 1; }
+
+  HeldRefs bufs(shard_bufs);       // finished shards' buffers
+  HeldRefs contents(row_content);  // the running shard's kept contents
+  int64_t row = 0, g = 0;          // kept rows, groups so far
+  for (int64_t s = 0; s < n_shards; ++s) {
+    const int64_t g_end = g + shard_groups[s];
+    const int64_t capacity = sum_of(group_sizes + g, shard_groups[s]);
+    PyObj *&ts_buf = shard_bufs[2 * s];
+    PyObj *&content_buf = shard_bufs[2 * s + 1];
+    ts_buf = content_buf = nullptr;
+    bufs.n = 2 * s + 2;
+    if (capacity == 0) {
+      for (; g < g_end; ++g) out_kept[g] = 0;
+      continue;
+    }
+    // Sized for no duplicate; cut to the kept rows after the walk (a
+    // copy, only where the dedup dropped a row of this shard).
+    ts_buf = PyBytes_FromStringAndSize(nullptr, capacity * kTsWidth);
+    if (!ts_buf) { PyErr_Clear(); return 1; }
+    char *ts_dst = PyBytes_AsString(ts_buf);
+    const int64_t shard_row0 = row;
+    int64_t content_bytes = 0;
+    for (; g < g_end; ++g) {
+      PyRef msgs(PySequence_GetItem(groups, g));
+      if (!msgs.o) { PyErr_Clear(); return 2; }
+      const uint32_t owner = uint32_t(group_owner[g]);
+      const int64_t group_row0 = row;
+      // A block of messages at a time, in four sweeps, so that the
+      // cache misses of a block overlap instead of queueing: a
+      // message, its timestamp str and its table slot are wherever
+      // the allocator and the hash put them.
+      for (int64_t i0 = 0; i0 < group_sizes[g]; i0 += PackBlock::kRows) {
+        PackBlock b;
+        const int64_t i1 = std::min(i0 + PackBlock::kRows, group_sizes[g]);
+        for (int64_t i = i0; i < i1; ++i) {
+          PyObj *m = PySequence_GetItem(msgs.o, i);
+          if (!m) { PyErr_Clear(); return 2; }
+          b.msg[b.n] = m;
+          b.ts[b.n++] = nullptr;
+          // the object's head and the words before it, where CPython
+          // keeps an instance's attribute storage: a hint, never read
+          __builtin_prefetch(reinterpret_cast<const char *>(m) - 24);
+          __builtin_prefetch(reinterpret_cast<const char *>(m) + 8);
+        }
+        for (int j = 0; j < b.n; ++j) {
+          PyObj *ts = b.ts[j] = PyObject_GetAttr(b.msg[j], a_ts.o);
+          if (!ts) { PyErr_Clear(); return 2; }
+          __builtin_prefetch(ts);
+          __builtin_prefetch(reinterpret_cast<const char *>(ts) + 64);
+        }
+        for (int j = 0; j < b.n; ++j) {
+          const char *t;
+          long long t_len;
+          if (!py_str(b.ts[j], &t, &t_len) || t_len != kTsWidth) return 2;
+          uint64_t high = 0;
+          for (int w = 0; w < 40; w += 8) high |= load64(t + w);
+          if ((high | load64(t + kTsWidth - 8)) & 0x8080808080808080ull) return 2;
+          b.text[j] = t;
+          b.hash[j] = pack_key_hash(t, owner);
+          __builtin_prefetch(&table[b.hash[j] & mask]);
+        }
+        for (int j = 0; j < b.n; ++j) {
+          const char *t = b.text[j];
+          const uint64_t tag = b.hash[j] & 0xFFFFFFFF00000000ull;
+          uint64_t slot = b.hash[j] & mask;
+          bool duplicate = false;
+          for (uint64_t e; (e = table[slot]) != 0; slot = (slot + 1) & mask) {
+            if ((e & 0xFFFFFFFF00000000ull) != tag) continue;
+            const int64_t k = int64_t(e & 0xFFFFFFFFull) - 1;
+            if (uint32_t(row_owner[k]) == owner && memcmp(row_ts[k], t, kTsWidth) == 0) {
+              duplicate = true;
+              break;
+            }
+          }
+          if (duplicate) continue;
+          PyObj *c = PyObject_GetAttr(b.msg[j], a_content.o);
+          if (!c) { PyErr_Clear(); return 2; }
+          row_content[contents.n++] = c;
+          const long long c_len = py_exact(c, PyBytes_Type) ? PyBytes_Size(c) : -1;
+          if (c_len < 0 || c_len > INT32_MAX) return 2;
+          char *dst = ts_dst + (row - shard_row0) * kTsWidth;
+          memcpy(dst, t, kTsWidth);
+          row_ts[row] = dst;
+          row_owner[row] = int32_t(owner);
+          out_lens[row] = int32_t(c_len);
+          content_bytes += c_len;
+          table[slot] = tag | uint64_t(row + 1);
+          ++row;
+        }
+      }
+      out_kept[g] = row - group_row0;
+    }
+    const int64_t kept = row - shard_row0;
+    if (kept == 0) continue;
+    content_buf = PyBytes_FromStringAndSize(nullptr, content_bytes);
+    if (!content_buf) { PyErr_Clear(); return 1; }
+    char *dst = PyBytes_AsString(content_buf);
+    for (int64_t k = 0; k < kept; ++k) {
+      memcpy(dst, PyBytes_AsString(row_content[k]), size_t(out_lens[shard_row0 + k]));
+      dst += out_lens[shard_row0 + k];
+    }
+    contents.release();
+  }
+  // Every probe is done: nothing reads a shard's timestamp buffer
+  // through row_ts any more, so one the dedup left short may move.
+  g = 0;
+  for (int64_t s = 0; s < n_shards; ++s) {
+    int64_t kept = 0, capacity = 0;
+    for (const int64_t g_end = g + shard_groups[s]; g < g_end; ++g) {
+      kept += out_kept[g];
+      capacity += group_sizes[g];
+    }
+    if (kept == 0) continue;
+    PyObj *&ts_buf = shard_bufs[2 * s];
+    if (kept < capacity) {
+      PyObj *cut = PyBytes_FromStringAndSize(PyBytes_AsString(ts_buf), kept * kTsWidth);
+      if (!cut) { PyErr_Clear(); return 1; }
+      Py_DecRef(ts_buf);
+      ts_buf = cut;
+    }
+    if (PyList_Append(out, ts_buf) != 0 || PyList_Append(out, shard_bufs[2 * s + 1]) != 0) {
+      PyErr_Clear();
+      return 1;
+    }
   }
   return 0;
 }

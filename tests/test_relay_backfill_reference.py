@@ -275,8 +275,10 @@ def test_rehearsal_of_the_cell_ends_correct_with_the_mesh_metrics():
               ("pack", "parse", "layout", "device_call", "insert", "pull_wait", "tree")}
     assert stages | {"window_compiles.mesh4", "backfill_p50_s", "mesh_occupancy_share",
                      "mesh_rows_device_pass", "mesh_xdev_reduce_pass",
-                     "mesh_upload_kb_pass", "mesh_pull_kb_pass"} == set(got)
+                     "mesh_upload_kb_pass", "mesh_pull_kb_pass",
+                     "pack_native_share"} == set(got)
     assert got["window_compiles.mesh4"] == 0
+    assert got["pack_native_share"] == 100  # every pass packed by the native walk (PR 36)
     assert got["mesh_xdev_reduce_pass"] == 1  # the digest's all-reduce; no owner is split
     assert got["mesh_rows_device_pass"] == 6000 / 2 / 4  # 2 passes over 4 devices
     # 16 B a slot of 4 devices x the fullest device's bucket
