@@ -335,22 +335,29 @@ class _StageAccountant:
         return st
 
     def record(self, stage: str, seconds: float, rows: int = 0,
-               nbytes: int = 0, shard: Optional[int] = None) -> None:
+               nbytes: int = 0, shard: Optional[int] = None,
+               wait: Optional[float] = None) -> None:
         if not metrics.registry.enabled:
             return
         ms = seconds * 1e3
-        metrics.observe("evolu_stage_ms", ms, stage=stage)
+        labels = {"stage": stage}
+        observed = [("evolu_stage_ms", ms, labels)]
+        if wait is not None:
+            # What `stage` measured of it off the CPU (its docstring).
+            observed.append(("evolu_stage_wait_ms", wait * 1e3, labels))
         if shard is not None:
             # Per-shard split of a stage that runs concurrently per
             # shard (the write-behind drain): shard labels are bounded
             # by store topology, far inside the 512-per-family cap.
-            metrics.observe("evolu_stage_shard_ms", ms, stage=stage,
-                            shard=str(shard))
-        metrics.inc("evolu_stage_seconds_total", seconds, stage=stage)
-        if rows:
-            metrics.inc("evolu_stage_rows_total", rows, stage=stage)
-        if nbytes:
-            metrics.inc("evolu_stage_bytes_total", nbytes, stage=stage)
+            observed.append(("evolu_stage_shard_ms", ms,
+                             {"stage": stage, "shard": str(shard)}))
+        # One acquisition of the registry's lock for the histograms and
+        # the totals: eleven stages a served pass record here, on the
+        # thread that is the relay's bottleneck.
+        metrics.observe_many(observed, also_inc=(
+            ("evolu_stage_seconds_total", seconds, labels),
+            ("evolu_stage_rows_total", rows, labels),
+            ("evolu_stage_bytes_total", nbytes, labels)))
         floor = floor_ms(stage, rows=rows, nbytes=nbytes)
         with self._lock:
             st = self._stage_state(stage)
@@ -471,6 +478,16 @@ def record_stage(stage: str, seconds: float, rows: int = 0,
     _acct.record(stage, seconds, rows=rows, nbytes=nbytes, shard=shard)
 
 
+def _edge(cpu: bool):
+    """This instant on the calling thread, as (wall, cpu):
+    `time.perf_counter` and, where the kind of stage asks for it,
+    `time.thread_time`. None with the registry disabled: no clock is
+    read and the interval goes nowhere."""
+    if metrics.registry.enabled:
+        return time.perf_counter(), (time.thread_time() if cpu else None)
+    return None
+
+
 class stage:
     """The ONE stage primitive: time an interval on the thread that
     does the work and record it where `record_stage` does.
@@ -488,58 +505,86 @@ class stage:
     sampled `obs.trace` context (read at `start`) it lands in the
     distributed trace too, as `log.span` does, so `GET /trace/<id>`
     shows the same names. Annotations off: one `is None` test beyond
-    `record_stage`; registry disabled: `record_stage` is one attribute
-    read.
+    `record_stage`; registry disabled: the annotation and no clock.
 
-    `then(name)` is a SEAM: one clock read closes the running stage and
-    opens the next under the new name, so consecutive stages tile their
-    parent with no gap and no overlap (the engine pass's `pass_*`
-    children). `start`/`stop` are the explicit edges for an interval
-    that opens in one function and closes in another on the SAME
-    thread (`pass_respond`: engine → scheduler); `stop` on a stage that
-    is not running is a no-op, so a `finally` may always call it.
-    Runtime seam names passed here are NOT `STAGES` entries (that is
-    the ablation registry the baseline digest pins); an unregistered
-    name is unpriced and never flagged."""
+    `time.thread_time` is read at the same edges, and the interval's
+    `wait` = wall − this thread's own CPU time (user and system) is
+    recorded beside it as `evolu_stage_wait_ms{stage}`: the time the
+    thread was not executing inside the stage. On a thread that shares
+    the interpreter with others that is the wait for the interpreter
+    lock; around a device call or a pull it also holds what the runtime
+    made the caller wait for; a page fault is system CPU and never
+    wait. The difference is SIGNED: where the kernel counts a thread's
+    CPU time in ticks longer than the stage (10 ms on the chip's host)
+    one observation is −tick or +wall and only the mean over many is
+    the wait, so a clamp at 0 would bias it. The read is a system call
+    with the interpreter lock held (0.4 us on a plain kernel; 6-12 us
+    alone on the chip's host, and a served relay lost 0.1 % of its
+    rate for each read a pass there), so `cpu=False` is for a stage
+    whose wait nothing reads: a parent that its tiles cover, a leg that
+    fires per request on many threads, a part that only adds up.
 
-    __slots__ = ("name", "rows", "nbytes", "seconds", "_t0",
-                 "_annotation", "_ctx")
+    `then(name)` is a SEAM: one read of each clock closes the running
+    stage and opens the next under the new name, so consecutive stages
+    tile their parent with no gap and no overlap, in wall time and in
+    CPU time (the engine pass's `pass_*` children). `start`/`stop` are
+    the explicit edges for an interval that opens in one function and
+    closes in another on the SAME thread (`pass_respond`: engine →
+    scheduler); `stop` on a stage that is not running is a no-op, so a
+    `finally` may always call it. Runtime seam names passed here are
+    NOT `STAGES` entries (that is the ablation registry the baseline
+    digest pins); an unregistered name is unpriced and never
+    flagged."""
 
-    def __init__(self, name: str, rows: int = 0, nbytes: int = 0):
+    __slots__ = ("name", "rows", "nbytes", "cpu", "seconds", "wait", "_at",
+                 "_running", "_annotation", "_ctx")
+
+    def __init__(self, name: str, rows: int = 0, nbytes: int = 0,
+                 cpu: bool = True):
         self.name = name
         self.rows = rows
         self.nbytes = nbytes
+        self.cpu = cpu  # read time.thread_time at the edges
         self.seconds = 0.0  # of the last closed interval
-        self._t0: Optional[float] = None
+        self.wait: Optional[float] = None  # None: no CPU clock was read
+        self._running = False
 
     def start(self) -> "stage":
         self._annotation = _log.open_annotation(self.name, "evolu/")
+        return self._open(_edge(self.cpu))
+
+    def _open(self, at) -> "stage":
         self._ctx = trace.current()
-        self._t0 = time.perf_counter()
+        self._at = at
+        self._running = True
         return self
 
     def stop(self) -> None:
-        if self._t0 is not None:
-            self._close(time.perf_counter())
-            self._t0 = None
+        if self._running:
+            self._close(_edge(self.cpu))
 
     def then(self, name: str, rows: int = 0, nbytes: int = 0) -> None:
-        now = time.perf_counter()
-        if self._t0 is not None:
-            self._close(now)
+        at = _edge(self.cpu)  # the seam is ONE instant: no gap between tiles
+        if self._running:
+            self._close(at)
         self.name, self.rows, self.nbytes = name, rows, nbytes
-        self.start()
-        self._t0 = now  # the seam is ONE instant: no gap between tiles
+        self._annotation = _log.open_annotation(name, "evolu/")
+        self._open(at)
 
-    def _close(self, now: float) -> None:
-        self.seconds = now - self._t0
+    def _close(self, at) -> None:
+        self._running = False
         _log.close_annotation(self._annotation)
-        self._record(self.seconds)
+        if at is not None and self._at is not None:
+            self.seconds = at[0] - self._at[0]
+            if self.cpu:
+                self.wait = self.seconds - (at[1] - self._at[1])
+            self._record(self.seconds, self.wait)
 
-    def _record(self, seconds: float) -> None:
+    def _record(self, seconds: float, wait: Optional[float]) -> None:
         """Where a closed interval goes. A subclass that fires per
         request overrides this with a plain histogram observation."""
-        _acct.record(self.name, seconds, rows=self.rows, nbytes=self.nbytes)
+        _acct.record(self.name, seconds, rows=self.rows,
+                     nbytes=self.nbytes, wait=wait)
         if self._ctx is not None:
             trace.record_span(self.name, self._ctx, time.time() - seconds,
                               seconds * 1e3)
@@ -552,9 +597,10 @@ class stage:
 
 class batched_stage(stage):
     """`stage` for a hot path that closes several intervals a unit of
-    work: its clock and `evolu/<name>` annotation, but each closed
-    interval is kept in `closed` as a plain `family{stage=<name>}`
-    histogram observation, for the owner to post in ONE
+    work: its clocks and `evolu/<name>` annotation, but each closed
+    interval is kept in `closed` as plain histogram observations,
+    `family{stage=<name>}` and (where the CPU clock is read) its wait
+    under the family's `_wait_ms` name, for the owner to post in ONE
     `metrics.observe_many` when the unit ends. It skips the
     accountant's totals, fit and gauges and the trace ring, and takes
     the registry lock once a unit, not five times an interval."""
@@ -562,12 +608,16 @@ class batched_stage(stage):
     __slots__ = ("closed",)
     family = "evolu_stage_ms"
 
-    def __init__(self, name: str, closed: Optional[list] = None):
-        super().__init__(name)
+    def __init__(self, name: str, closed: Optional[list] = None,
+                 cpu: bool = True):
+        super().__init__(name, cpu=cpu)
         self.closed = [] if closed is None else closed
 
-    def _record(self, seconds: float) -> None:
-        self.closed.append((self.family, seconds * 1e3, {"stage": self.name}))
+    def _record(self, seconds: float, wait: Optional[float]) -> None:
+        labels = {"stage": self.name}
+        self.closed.append((self.family, seconds * 1e3, labels))
+        if wait is not None:
+            self.closed.append((self.family[:-2] + "wait_ms", wait * 1e3, labels))
 
 
 class _part(batched_stage):
@@ -578,10 +628,10 @@ class _part(batched_stage):
     __slots__ = ("ms",)
 
     def __init__(self, name: str):
-        super().__init__(name)
+        super().__init__(name, cpu=False)  # its intervals only add up: no wait
         self.ms = 0.0
 
-    def _record(self, seconds: float) -> None:
+    def _record(self, seconds: float, wait: Optional[float]) -> None:
         self.ms += seconds * 1e3
 
 

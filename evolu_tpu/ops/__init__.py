@@ -101,7 +101,7 @@ def to_host_many(*xs):
     # the pulling thread's line of the profiler trace); where the
     # device has a recorded pull bandwidth law, the over-floor flag
     # fires when a wave runs slower than FLOOR_FACTOR× it.
-    with _anatomy.stage("pull_wave") as wave:
+    with _anatomy.stage("pull_wave", cpu=False) as wave:  # nothing reads its wait
         out = tuple(to_host(x) for x in start_host_transfer(*xs))
         wave.nbytes = sum(int(getattr(a, "nbytes", 0)) for a in out)
     if _metrics.registry.enabled:

@@ -893,7 +893,7 @@ class BatchReconciler:
         seam, each recorded once per pass: `pass_pack` (`_pack_batch`),
         `pass_parse` (`parse_packed_timestamps` of every live shard),
         and `deltas_dispatch`'s `pass_layout` + `pass_device_call`."""
-        with anatomy.stage("device_dispatch") as whole, \
+        with anatomy.stage("device_dispatch", cpu=False) as whole, \
                 anatomy.stage("pass_pack") as tile:
             live, shard_data, packed, shard_offsets, merged, off, pack_path = \
                 self._pack_batch(requests)
@@ -1047,7 +1047,7 @@ class BatchReconciler:
         # to_host_many on the pull thread — shares are over summed stage
         # walls, and the two legs overlap (docs/OBSERVABILITY.md).
         rows = st["n_total"]
-        with anatomy.stage("host_apply", rows=rows), \
+        with anatomy.stage("host_apply", rows=rows, cpu=False), \
                 span("kernel:merkle", "reconcile_stream_finish",
                      owners=len({r.user_id for r in st["requests"]}),
                      n=rows, shards=len(live)), \

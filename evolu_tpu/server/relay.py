@@ -284,11 +284,69 @@ class _round_leg(anatomy.batched_stage):
     handler to post in ONE `metrics.observe_many` at the end of the
     round — these fire per request on 25 threads, so they skip the
     stage accountant's fit and gauges (and the trace ring, which
-    already holds relay.sync/relay.respond) and take the registry lock
-    once a round."""
+    already holds relay.sync/relay.respond), take the registry lock
+    once a round, and read no CPU clock (`anatomy.stage`: five system
+    calls a round under the interpreter lock)."""
 
     __slots__ = ()
     family = "evolu_relay_stage_ms"
+
+    def __init__(self, name: str):
+        super().__init__(name, cpu=False)
+
+    def since(self, name: str, instant: float) -> None:
+        """`name`: from `instant` (a `perf_counter` reading of ANOTHER
+        thread) to where this running leg began. It crosses threads, so
+        it is an observation beside the legs: no annotation."""
+        if self._at is not None:  # None: the registry is disabled
+            self.closed.append((self.family, (self._at[0] - instant) * 1e3,
+                                {"stage": name}))
+
+
+# The connection's leg on a threaded-tier handler thread: `conn_head`,
+# running from the thread's first instruction, with `conn_spawn` already
+# in its `closed`. do_POST takes it and makes it the round's.
+_conn = threading.local()
+_ACCEPTOR = "evolu_relay_acceptor_seconds_total"
+_HANDLER = "evolu_relay_handler_seconds_total"
+_IDLE, _BUSY, _WALL, _CPU = ({"state": s} for s in ("idle", "busy", "wall", "cpu"))
+# Connections between two posts of the acceptor's seconds, and between
+# two handler threads that are timed: on these threads a registry
+# acquisition a connection cost the served relay over 1 % of its rate
+# each, and a read of the CPU clock is a system call (my chip runs,
+# PR 37, PERF.md §6).
+_POST_EVERY = 32
+
+
+class _accept_extent(anatomy.stage):
+    """One connection on the acceptor thread, `accept()` to
+    `Thread.start()` returning, as the dispatcher keeps its own time:
+    `evolu_relay_acceptor_seconds_total{state}`, `busy` this extent,
+    `idle` everything since the extent before (in `select`), so idle +
+    busy is the thread's wall time; `cpu` the thread's own CPU seconds
+    (`time.thread_time` counts from the thread's start, and `select`
+    burns none). Summed on the server object, which only this thread
+    writes, and posted in one `inc_many` every `_POST_EVERY`
+    connections."""
+
+    __slots__ = ("server",)
+
+    def __init__(self, server: "_RelayHTTPServer"):
+        super().__init__("accept", cpu=False)
+        self.server = server
+
+    def _record(self, seconds: float, wait) -> None:
+        opened, server = self._at[0], self.server
+        server.idle_s += opened - server.busy_end
+        server.busy_s += seconds
+        server.busy_end = opened + seconds
+        if server.accepted % _POST_EVERY == 0:
+            cpu = time.thread_time()
+            metrics.inc_many(((_ACCEPTOR, server.idle_s, _IDLE),
+                              (_ACCEPTOR, server.busy_s, _BUSY),
+                              (_ACCEPTOR, cpu - server.cpu_posted, _CPU)))
+            server.idle_s = server.busy_s = 0.0
+            server.cpu_posted = cpu
 
 
 class _Handler(BaseHTTPRequestHandler):
@@ -617,7 +675,6 @@ class _Handler(BaseHTTPRequestHandler):
             # fat-fingered ms=3600000 cannot park a handler for an hour.
             ms = min(max(ms, 10.0), 30_000.0)
             if not _PROFILE_LOCK.acquire(blocking=False):
-                metrics.inc("evolu_relay_profile_busy_total")
                 self.send_error(429, "a profile capture is already running")
                 return
             try:
@@ -711,7 +768,13 @@ class _Handler(BaseHTTPRequestHandler):
         # side of a 200 round; the client's median minus it is what the
         # client, the TCP connect and the accept queue cost.
         t0 = time.perf_counter()
-        leg = _round_leg("read_decode").start()
+        leg = getattr(_conn, "leg", None)
+        if leg is None:  # event-loop tier, or a handler driven directly
+            leg = _round_leg("read_decode").start()
+        else:  # the threaded tier's: `conn_head` ends where the round begins
+            _conn.leg = None
+            leg.then("read_decode")
+        before_round = len(leg.closed)  # conn_spawn and conn_head
         answered = False
         try:
             answered = self._sync_round(t0, leg)
@@ -720,6 +783,8 @@ class _Handler(BaseHTTPRequestHandler):
             if answered:
                 leg.closed.append(("evolu_relay_round_ms",
                                    (time.perf_counter() - t0) * 1e3, {}))
+            else:  # the connection's legs count the rounds round_ms counts
+                del leg.closed[:before_round]
             metrics.observe_many(leg.closed)
 
     def _sync_round(self, t0: float, leg: _round_leg) -> bool:
@@ -1136,6 +1201,52 @@ class _RelayHTTPServer(ThreadingHTTPServer):
     # (examples/server-nodejs/fly.toml); socketserver's default listen
     # backlog of 5 resets simultaneous connects well below that.
     request_queue_size = 128
+
+    # What a round costs before do_POST (docs/OBSERVABILITY.md, "Is the
+    # acceptor a second one?"): the acceptor's extent a connection, then
+    # `conn_spawn` (accept() returned → the handler thread's first
+    # instruction; it crosses threads, so an observation and no
+    # annotation), then `conn_head` (→ do_POST: handler set-up, request
+    # line, headers); and of one handler thread in `_POST_EVERY`, its
+    # whole life: evolu_relay_handler_seconds_total{state=wall|cpu},
+    # first instruction → shutdown_request returning, `cpu` ONE read of
+    # the CPU clock at its end (the thread is born for the connection).
+
+    def serve_forever(self, poll_interval: float = 0.5) -> None:
+        self.busy_end = time.perf_counter()  # of the acceptor's last extent
+        self.accepted, self.idle_s, self.busy_s = 0, 0.0, 0.0
+        self.cpu_posted = time.thread_time()
+        super().serve_forever(poll_interval)
+
+    def _handle_request_noblock(self) -> None:
+        with _accept_extent(self):
+            super()._handle_request_noblock()
+
+    def process_request(self, request, client_address) -> None:
+        # ThreadingMixIn's, with the instant accept() returned and
+        # whether this thread is timed as the thread's arguments (daemon
+        # threads, as ThreadingHTTPServer sets: server_close has none to
+        # join).
+        self.accepted += 1
+        threading.Thread(
+            target=self.process_request_thread, daemon=True,
+            args=(request, client_address, time.perf_counter(),
+                  self.accepted % _POST_EVERY == 0)).start()
+
+    def process_request_thread(self, request, client_address,
+                               accepted: float, timed: bool) -> None:
+        leg = _conn.leg = _round_leg("conn_head").start()
+        leg.since("conn_spawn", accepted)
+        born = time.perf_counter()
+        try:
+            super().process_request_thread(request, client_address)
+        finally:
+            _conn.leg = None
+            leg.stop()  # running only if no POST / took it
+            if timed:
+                metrics.inc_many((
+                    (_HANDLER, time.perf_counter() - born, _WALL),
+                    (_HANDLER, time.thread_time(), _CPU)))
 
 
 class RelayServer:

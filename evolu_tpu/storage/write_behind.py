@@ -659,8 +659,7 @@ class WriteBehindQueue:
             except BaseException as te:  # noqa: BLE001
                 self._log.close()
                 self._log = None
-                self._log_poisoned = True
-                metrics.inc("evolu_wb_log_poisoned_total")
+                self._log_poisoned = True  # /health fails, /stats says so
                 log("storage", "write-behind log unrecoverable; "
                     "admission refused until restart", error=repr(te))
             self._log_bytes = start

@@ -613,11 +613,15 @@ def test_late_declaration_folds_predeclaration_ops():
     update_db_schema(late, [TableDefinition.of("metrics", ("name", "clicks", "tags"))])
     apply_messages(late, create_initial_merkle_tree(), ops)
     assert _app_value(late, "clicks") == 7  # LWW winner, pre-upgrade
+    folds = metrics.get_counter("evolu_crdt_predeclaration_folds_total")
     update_db_schema(late, [SCHEMA_DEF])  # the upgrade declares the types
+    # the declaration folded the column logs it found: three ops
+    assert metrics.get_counter("evolu_crdt_predeclaration_folds_total") == folds + 3
 
     # Replica E: declared first, then synced.
     early = _mk_db()
     apply_messages(early, create_initial_merkle_tree(), ops)
+    assert metrics.get_counter("evolu_crdt_predeclaration_folds_total") == folds + 3
 
     for db in (late, early):
         assert _app_value(db, "clicks") == 12, "fold must cover pre-declaration ops"

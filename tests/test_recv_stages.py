@@ -133,21 +133,24 @@ def test_each_stage_once_a_receive_and_the_six_tile_the_handle(device, wires):
 
 
 def test_one_registry_acquisition_a_receive(device, wires, monkeypatch):
-    """The seven worker-thread stages go to the registry in one
-    `observe_many`; no other write names a `recv_*` stage there."""
-    calls = []  # (writer, thread, stages named)
+    """The seven worker-thread stages and their waits (ISSUE 37) go to
+    the registry in one `observe_many`; the caller's `recv_decrypt` in
+    one more, its totals riding with it; no other write names a
+    `recv_*` stage there. The tree's parts record no wait."""
+    calls = []  # (writer, thread, [(family, stage named)])
     real = {w: getattr(metrics, w) for w in ("observe_many", "observe", "inc")}
 
-    def spy_many(items):
-        items = list(items)
-        calls.append(("observe_many", threading.get_ident(),
-                      [labels.get("stage") for _f, _v, labels in items]))
-        real["observe_many"](items)
+    def spy_many(items, also_inc=()):
+        items, also_inc = list(items), list(also_inc)
+        named = [(f, labels.get("stage")) for f, _v, labels in items + also_inc]
+        if any(str(stage).startswith("recv_") for _f, stage in named):
+            calls.append(("observe_many", threading.get_ident(), named))
+        real["observe_many"](items, also_inc=also_inc)
 
     def spy(writer):
         def write(name, *args, **labels):
             if str(labels.get("stage", "")).startswith("recv_"):
-                calls.append((writer, threading.get_ident(), [labels["stage"]]))
+                calls.append((writer, threading.get_ident(), [(name, labels["stage"])]))
             real[writer](name, *args, **labels)
         return write
 
@@ -160,10 +163,44 @@ def test_one_registry_acquisition_a_receive(device, wires, monkeypatch):
         worker = device.worker._thread.ident
         on_worker = [c for c in calls if c[1] == worker]
         assert [c[0] for c in on_worker] == ["observe_many"], on_worker
-        assert sorted(on_worker[0][2]) == sorted(ON_WORKER + tuple(TREE))
-        decrypt = [c for c in calls if c[1] != worker]
-        assert decrypt and all(c[2] == ["recv_decrypt"] for c in decrypt)
-        assert all(c[1] == threading.get_ident() for c in decrypt)
+        assert sorted(on_worker[0][2]) == sorted(
+            [("evolu_stage_ms", s) for s in ON_WORKER + tuple(TREE)]
+            + [("evolu_stage_wait_ms", s) for s in ON_WORKER])
+        (decrypt,) = [c for c in calls if c[1] != worker]
+        assert decrypt[:2] == ("observe_many", threading.get_ident())
+        assert decrypt[2] == [(f, "recv_decrypt") for f in (
+            "evolu_stage_ms", "evolu_stage_wait_ms", "evolu_stage_seconds_total",
+            "evolu_stage_rows_total", "evolu_stage_bytes_total")]
+
+
+def test_each_tile_waits_no_longer_than_it_lasts(device, wires):
+    """`evolu_stage_wait_ms{stage=recv_*}`: one observation a `Receive`
+    beside each tile's `evolu_stage_ms`, wait <= the tile and (on this
+    machine's nanosecond CPU clock) not under 0 by more than the two
+    clocks' jitter; the tiles' waits add up to no more than the
+    handle's, which is the thread's whole time off the CPU; a part has
+    none."""
+    def reading():
+        return {s: (_hist(s), _wait(s)) for s in ON_WORKER + tuple(TREE)}
+
+    def _wait(stage):
+        h = metrics.registry.get_histogram("evolu_stage_wait_ms", stage=stage)
+        return (h[2], h[3]) if h else (0.0, 0)
+
+    for wire in wires:
+        before = reading()
+        device.receive(wire)
+        after = reading()
+        ms, wait = {}, {}
+        for s in ON_WORKER:
+            assert after[s][1][1] - before[s][1][1] == 1, s
+            ms[s] = after[s][0][0] - before[s][0][0]
+            wait[s] = after[s][1][0] - before[s][1][0]
+            assert -0.05 <= wait[s] <= ms[s], (s, wait[s], ms[s])
+        # one clock reading a seam, so CPU time tiles as wall time does
+        assert sum(wait[s] for s in TILES) <= wait["recv_handle"] + 0.05 * ms["recv_handle"]
+        for child in TREE:
+            assert after[child][1] == (0.0, 0)
 
 
 def test_every_stage_is_one_annotation_on_its_thread(device, wires):

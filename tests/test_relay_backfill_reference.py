@@ -276,7 +276,10 @@ def test_rehearsal_of_the_cell_ends_correct_with_the_mesh_metrics():
     assert stages | {"window_compiles.mesh4", "backfill_p50_s", "mesh_occupancy_share",
                      "mesh_rows_device_pass", "mesh_xdev_reduce_pass",
                      "mesh_upload_kb_pass", "mesh_pull_kb_pass",
-                     "pack_native_share"} == set(got)
+                     "pack_native_share", "pass_insert_wait_ms.mesh4"} == set(got)
+    # the control of PR 37: one thread works alone, so the insert's wall
+    # time is nearly all its own CPU time (signed: jitter may read below 0)
+    assert got["pass_insert_wait_ms.mesh4"] <= got["pass_insert_ms.mesh4"]
     assert got["window_compiles.mesh4"] == 0
     assert got["pack_native_share"] == 100  # every pass packed by the native walk (PR 36)
     assert got["mesh_xdev_reduce_pass"] == 1  # the digest's all-reduce; no owner is split

@@ -308,7 +308,9 @@ def test_scoped_tree_cache_coherent_by_construction():
         store.add_messages("u1", _emsgs(NODE_A, 0, 8))
         clause = protocol.ScopeClause(BASE, (), ())
         full = store.get_merkle_tree_string("u1")
+        misses = metrics.get_counter("evolu_scope_tree_cache_misses_total")
         t1, r1 = server_scope.scoped_tree_for(store, "u1", NODE_B, clause, full)
+        assert metrics.get_counter("evolu_scope_tree_cache_misses_total") == misses + 1
         hits = metrics.get_counter("evolu_scope_tree_cache_hits_total")
         t2, r2 = server_scope.scoped_tree_for(store, "u1", NODE_B, clause, full)
         assert (t2, r2) == (t1, r1)
@@ -319,6 +321,17 @@ def test_scoped_tree_cache_coherent_by_construction():
         assert full2 != full
         t3, _r3 = server_scope.scoped_tree_for(store, "u1", NODE_B, clause, full2)
         assert t3 != t1
+        # ... which reads as a miss; misses track the owner's write rate.
+        assert metrics.get_counter("evolu_scope_tree_cache_misses_total") == misses + 2
+        assert metrics.get_counter("evolu_scope_tree_cache_hits_total") == hits + 1
+        # LRU past the cap: the oldest entry goes, and is counted.
+        evictions = metrics.get_counter("evolu_scope_tree_cache_evictions_total")
+        lru = server_scope._ScopedTreeCache(cap=1)
+        lru.put(("u1", NODE_A), full, t1, r1)
+        lru.put(("u1", NODE_B), full, t1, r1)
+        assert lru.get(("u1", NODE_A), full) is None
+        assert lru.get(("u1", NODE_B), full) == (t1, r1)
+        assert metrics.get_counter("evolu_scope_tree_cache_evictions_total") == evictions + 1
     finally:
         store.close()
 

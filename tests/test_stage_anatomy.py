@@ -266,6 +266,192 @@ def test_stage_lands_in_the_ambient_trace_and_costs_nothing_disabled():
     assert "pass_insert" not in anatomy.stages_payload()["stages"]
 
 
+# --- a stage's CPU time beside its wall time (ISSUE 37) ---
+
+
+class _Clocks:
+    """Stands in for the `time` module inside obs.anatomy: every read
+    is counted and advances its clock by a fixed step."""
+
+    def __init__(self, wall_step=0.010, cpu_step=0.004):
+        self.steps = {"perf_counter": wall_step, "thread_time": cpu_step}
+        self.now = {"perf_counter": 100.0, "thread_time": 5.0}
+        self.reads = {"perf_counter": 0, "thread_time": 0}
+
+    def _read(self, clock):
+        self.reads[clock] += 1
+        self.now[clock] += self.steps[clock]
+        return self.now[clock]
+
+    def perf_counter(self):
+        return self._read("perf_counter")
+
+    def thread_time(self):
+        return self._read("thread_time")
+
+    def time(self):
+        return 1_700_000_000.0
+
+
+def _ms(family, stage):
+    h = metrics.registry.get_histogram(family, stage=stage)
+    return (h[2], h[3]) if h else (0.0, 0)  # (sum, count)
+
+
+def test_a_stage_that_sleeps_then_spins_waits_for_the_sleep():
+    """wait = wall - this thread's CPU: the sleep is wait, the spin is
+    not; loose enough for a busy machine, where a descheduled spin only
+    adds to both sides of `wait <= wall`."""
+    import time
+
+    with anatomy.stage("sleep_spin") as st:
+        time.sleep(0.08)
+        spun = time.thread_time() + 0.02
+        while time.thread_time() < spun:
+            pass
+    assert 0.0 <= st.wait <= st.seconds
+    assert st.wait >= 0.75 * 0.08
+    assert st.seconds - st.wait >= 0.019  # the spin's CPU is never wait
+    assert _ms("evolu_stage_ms", "sleep_spin") == (pytest.approx(st.seconds * 1e3), 1)
+    assert _ms("evolu_stage_wait_ms", "sleep_spin") == (pytest.approx(st.wait * 1e3), 1)
+    # an interval known only afterwards has no CPU reading: no wait
+    anatomy.record_stage("host_apply", 0.010, rows=10, shard=3)
+    assert _ms("evolu_stage_ms", "host_apply")[1] == 1
+    assert _ms("evolu_stage_wait_ms", "host_apply") == (0.0, 0)
+
+
+def test_a_seam_is_one_read_of_each_clock_and_cpu_tiles_as_wall_does(monkeypatch):
+    clocks = _Clocks()
+    monkeypatch.setattr(anatomy, "time", clocks)
+    with anatomy.stage("whole") as whole, anatomy.stage("tile_a") as tile:
+        tile.then("tile_b")
+        tile.then("tile_c")
+    # two starts, two seams, two stops: six instants, one read of each clock each
+    assert clocks.reads == {"perf_counter": 6, "thread_time": 6}
+    tiles = ("tile_a", "tile_b", "tile_c")
+    for s in tiles:  # each tile: 10 ms of wall, 4 ms of CPU
+        assert _ms("evolu_stage_ms", s) == (pytest.approx(10.0), 1)
+        assert _ms("evolu_stage_wait_ms", s) == (pytest.approx(6.0), 1)
+    # no gap at a seam on either clock: the tiles' CPU is the whole's
+    # less its first and last instants, as their wall time is
+    cpu = sum(_ms("evolu_stage_ms", s)[0] - _ms("evolu_stage_wait_ms", s)[0] for s in tiles)
+    assert cpu == pytest.approx(3 * 4.0)
+    assert whole.seconds == pytest.approx(0.050) and whole.wait == pytest.approx(0.030)
+    assert (whole.seconds - whole.wait) * 1e3 == pytest.approx(cpu + 2 * 4.0)
+    # A CPU clock that ticks coarser than the stage lasts (10 ms on the
+    # chip's host) reads more CPU than wall in one interval and none in
+    # the next: the difference is kept SIGNED, so that the mean over
+    # many is the wait; clamped at 0 it would read 1.0 here, not 0.0.
+    monkeypatch.setattr(anatomy, "time", _Clocks(wall_step=0.001, cpu_step=0.002))
+    with anatomy.stage("tick") as st:
+        pass
+    assert st.wait == pytest.approx(-0.001)
+    monkeypatch.setattr(anatomy, "time", _Clocks(wall_step=0.001, cpu_step=0.0))
+    with anatomy.stage("tick"):
+        pass
+    assert _ms("evolu_stage_wait_ms", "tick") == (pytest.approx(0.0, abs=1e-9), 2)
+
+
+def test_a_batched_stage_keeps_its_wait_under_its_familys_name(monkeypatch):
+    clocks = _Clocks()
+    monkeypatch.setattr(anatomy, "time", clocks)
+
+    class leg(anatomy.batched_stage):
+        __slots__ = ()
+        family = "evolu_test_leg_ms"
+
+    tile = leg("first").start()
+    tile.then("second")
+    tile.stop()
+    assert [(f, round(v, 6), labels) for f, v, labels in tile.closed] == [
+        ("evolu_test_leg_ms", 10.0, {"stage": "first"}),
+        ("evolu_test_leg_wait_ms", 6.0, {"stage": "first"}),
+        ("evolu_test_leg_ms", 10.0, {"stage": "second"}),
+        ("evolu_test_leg_wait_ms", 6.0, {"stage": "second"})]
+    with anatomy.tiles("t37_", whole="handle", first="one"):
+        anatomy.seam("two")
+        with anatomy.part("child"):
+            pass
+    for s in ("t37_handle", "t37_one", "t37_two"):
+        assert _ms("evolu_stage_ms", s)[1] == _ms("evolu_stage_wait_ms", s)[1] == 1
+    assert _ms("evolu_stage_ms", "t37_child")[1] == 1
+    # a part records no wait and reads no CPU clock: three instants the
+    # leg, four the tiles (two starts, a seam, two stops share... five)
+    assert _ms("evolu_stage_wait_ms", "t37_child") == (0.0, 0)
+    assert clocks.reads == {"perf_counter": 3 + 5 + 2, "thread_time": 3 + 5}
+
+    tile = leg("first", cpu=False).start()  # a stage whose wait nothing reads
+    tile.then("second")
+    tile.stop()
+    assert [(f, labels) for f, _v, labels in tile.closed] == [
+        ("evolu_test_leg_ms", {"stage": "first"}), ("evolu_test_leg_ms", {"stage": "second"})]
+    assert tile.wait is None and clocks.reads["thread_time"] == 3 + 5
+
+
+def test_a_disabled_registry_reads_no_clock_at_all(monkeypatch):
+    clocks = _Clocks()
+    monkeypatch.setattr(anatomy, "time", clocks)
+    metrics.set_enabled(False)
+    try:
+        with anatomy.stage("pass_pack") as tile:
+            tile.then("pass_parse")
+        leg = anatomy.batched_stage("leg").start()
+        leg.then("next")
+        leg.stop()
+        with anatomy.tiles("t37_", whole="handle", first="one"):
+            anatomy.seam("two")
+            with anatomy.part("child"):
+                pass
+    finally:
+        metrics.set_enabled(True)
+    assert clocks.reads == {"perf_counter": 0, "thread_time": 0}
+    assert leg.closed == [] and tile.seconds == 0.0 and tile.wait is None
+    assert anatomy.stages_payload()["stages"] == {}
+    # enabled between a start and its stop: an interval with one edge goes nowhere
+    metrics.set_enabled(False)
+    half = anatomy.stage("half").start()
+    metrics.set_enabled(True)
+    half.stop()
+    assert "half" not in anatomy.stages_payload()["stages"]
+
+
+class _CountingLock:
+    def __init__(self, lock):
+        self.lock, self.taken = lock, 0
+
+    def __enter__(self):
+        self.taken += 1
+        return self.lock.__enter__()
+
+    def __exit__(self, *exc):
+        return self.lock.__exit__(*exc)
+
+
+def test_a_stage_posts_its_histograms_and_totals_in_one_acquisition(monkeypatch):
+    """`_StageAccountant.record`: `evolu_stage_ms`, its wait, the shard
+    split and the seconds / rows / bytes totals under ONE acquisition
+    of the registry's lock (three to five before ISSUE 37); an unpriced
+    stage's first record sets no gauge, so one is all it takes."""
+    anatomy.set_device_kind("unknown-bench")
+    lock = _CountingLock(metrics.registry._lock)
+    monkeypatch.setattr(metrics.registry, "_lock", lock)
+    anatomy._acct.record("pass_x37", 0.004, rows=7, nbytes=64, shard=2, wait=0.001)
+    assert lock.taken == 1
+    with anatomy.stage("pass_y37", rows=3):
+        pass
+    assert lock.taken == 2
+    monkeypatch.undo()
+    assert _ms("evolu_stage_ms", "pass_x37") == (pytest.approx(4.0), 1)
+    assert _ms("evolu_stage_wait_ms", "pass_x37") == (pytest.approx(1.0), 1)
+    shard = metrics.registry.get_histogram("evolu_stage_shard_ms", stage="pass_x37", shard="2")
+    assert (shard[2], shard[3]) == (pytest.approx(4.0), 1)
+    assert metrics.get_counter("evolu_stage_seconds_total", stage="pass_x37") == pytest.approx(0.004)
+    assert metrics.get_counter("evolu_stage_rows_total", stage="pass_x37") == 7
+    assert metrics.get_counter("evolu_stage_bytes_total", stage="pass_x37") == 64
+    assert metrics.get_counter("evolu_stage_rows_total", stage="pass_y37") == 3
+    assert metrics.get_counter("evolu_stage_bytes_total", stage="pass_y37") == 0
+
+
 def test_kernel_span_folds_into_family():
     anatomy.set_device_kind(anatomy.V5E)
     with span("kernel:merkle", "t", n=1000):
