@@ -21,7 +21,10 @@ ciphertext blobs — the LWW cell merge happens client-side.
 
 from __future__ import annotations
 
+import contextvars
 import functools
+import threading
+from concurrent.futures import ThreadPoolExecutor
 from contextlib import contextmanager
 from typing import Dict, List, Optional, Sequence, Tuple
 
@@ -716,6 +719,7 @@ class BatchReconciler:
         self.mesh = mesh or create_mesh()
         self.write_behind = write_behind
         self._pull_pool = None
+        self._stage_pool = None
         # `pack_requests`' dedup table and row index, kept from pass to
         # pass so that a pass's pack touches no fresh pages for them.
         self._pack_scratch = None
@@ -809,16 +813,25 @@ class BatchReconciler:
 
     def _pull_executor(self):
         if self._pull_pool is None:
-            from concurrent.futures import ThreadPoolExecutor
-
             self._pull_pool = ThreadPoolExecutor(max_workers=1, thread_name_prefix="evolu-pull")
         return self._pull_pool
 
+    def _stage_executor(self):
+        """The ONE helper thread of `reconcile_stream`: it runs
+        `start_batch` of the next pass and nothing else, never two at
+        once, and never touches the store."""
+        if self._stage_pool is None:
+            self._stage_pool = ThreadPoolExecutor(max_workers=1, thread_name_prefix="evolu-stage")
+        return self._stage_pool
+
     @contextmanager
-    def _store_pass(self, stores, live, batches):
+    def _store_pass(self, stores, live, batches, entering=None):
         """The storage leg of ONE pass over the live shards, in two
         native calls (`storage/native.py`, the shard-set calls) and no
-        thread of this process's but the caller's. On entry: BEGIN +
+        thread of this process's but the caller's: `reconcile_stream`'s
+        helper stages the next pass beside the first call (`entering`,
+        handed to it, is what lets the helper go) and never enters
+        here. On entry: BEGIN +
         the packed INSERT OR IGNORE of `batches[i]` on shard `live[i]`
         + the stored trees of every group user, one call. Yields
         (was-new flags by shard id, {owner: stored tree TEXT}, per-shard
@@ -830,7 +843,8 @@ class BatchReconciler:
         dbs = [stores[si].db for si in live]
         flags, stored = relay_insert_packed_shards(
             dbs, batches,
-            also_count=(("evolu_engine_store_calls_total", 1, {"op": "insert"}),))
+            also_count=(("evolu_engine_store_calls_total", 1, {"op": "insert"}),),
+            entering=entering)
         tree_rows: List[List[Tuple[str, str]]] = [[] for _ in stores]
         try:
             yield dict(zip(live, flags)), stored, tree_rows
@@ -867,6 +881,10 @@ class BatchReconciler:
             raise commit_err
 
     def close(self) -> None:
+        # The helper first: a pass it is still staging submits its pull.
+        if self._stage_pool is not None:
+            self._stage_pool.shutdown(wait=True)
+            self._stage_pool = None
         if self._pull_pool is not None:
             self._pull_pool.shutdown(wait=True)
             self._pull_pool = None
@@ -877,9 +895,12 @@ class BatchReconciler:
     # WHOLE batch optimistically (newness is unknown until the insert),
     # and owners that turn out to contain duplicate rows get their
     # deltas recomputed host-side from the new rows only. So the insert
-    # runs while the device computes, and in `reconcile_stream` batch
-    # k+1's transfer + compute run on the device while batch k's C
-    # inserts/trees/commit run on the host (the C calls drop the GIL).
+    # runs while the device computes, and in `reconcile_stream` ALL of
+    # batch k+1's `start_batch` (pack, parse, layout, device call, and
+    # behind them the device's compute and transfer) runs on the helper
+    # thread while batch k's C inserts run on the caller's: the insert
+    # is one native call that holds no interpreter lock, and
+    # `start_batch` never calls SQLite.
 
     def start_batch(self, requests: Sequence[protocol.SyncRequest]):
         """Stage batch k+1: pack per-shard buffers, parse natively,
@@ -1005,25 +1026,30 @@ class BatchReconciler:
                 row += n
         return shard_data
 
-    def finish_batch(self, st, wire: bool = False, respond_stage=None) -> List:
+    def finish_batch(self, st, wire: bool = False, respond_stage=None,
+                     entering=None) -> List:
         """Land batch k (`_land`) and answer it — while batch k+1 flies
-        on the device. `wire=True` answers in BYTES mode
+        on the device, or in `reconcile_stream` is staged on the helper
+        (`entering`: see `_land`). `wire=True` answers in BYTES mode
         (`_respond_wire`) for consumers that only forward protobuf,
         byte-identical to encoding the object responses (test-pinned
         via `_respond_wire`'s own fence). A `respond_stage` (an
         unstarted `anatomy.stage`, the scheduler's `pass_respond`) is
         STARTED where the apply leg ends; the caller, on this thread,
         stops it when its own respond work is done."""
-        trees, strings = self._land(st, respond_stage)
+        trees, strings = self._land(st, respond_stage, entering)
         respond = self._respond_wire if wire else self._respond
         return respond(st["requests"], trees, strings)
 
-    def _land(self, st, respond_stage=None):
+    def _land(self, st, respond_stage=None, entering=None):
         """The landing half of a packed pass → (trees, strings): the C
         inserts of every live shard in one native call,
         duplicate-owner delta recompute, tree updates, one atomic
         commit per shard in a second (`_store_pass`), then the ledger
-        count. `respond_stage`: see `finish_batch`."""
+        count. `respond_stage`: see `finish_batch`. `entering`: called
+        on this thread as the insert call gives up the interpreter lock
+        (`relay_insert_packed_shards`); not called where the pass has
+        no live shard or raises before that."""
         stores, shard_index = self._shards()
         _count_pass("stream", st)
         live, shard_data = st["live"], st["shard_data"]
@@ -1052,7 +1078,8 @@ class BatchReconciler:
                      owners=len({r.user_id for r in st["requests"]}),
                      n=rows, shards=len(live)), \
                 anatomy.stage("pass_insert", rows=rows) as tile:
-            with self._store_pass(stores, live, [shard_data[si] for si in live]) as (
+            with self._store_pass(
+                    stores, live, [shard_data[si] for si in live], entering) as (
                     was_new_by_shard, stored, tree_rows):
                 tile.then("pass_pull_wait")
                 pulled = deltas_pull(st["dev"])
@@ -1129,31 +1156,60 @@ class BatchReconciler:
         self, batches: Sequence[Sequence[protocol.SyncRequest]]
     ) -> List[List[protocol.SyncResponse]]:
         """Software-pipelined reconcile over a stream of request
-        batches: batch k+1's device leg (upload, hash, output transfer)
-        overlaps batch k's host leg (C inserts, trees, commit). End
-        state is identical to sequential `reconcile` calls, which is
-        what any other route than the packed one runs."""
+        batches: while this thread lands batch k (`finish_batch`: C
+        inserts, pull wait, trees, commit, answers), the engine's one
+        helper thread stages batch k+1 (`start_batch`: pack, parse,
+        layout, device call, and behind them the device's hash and the
+        output transfer). The stream is iterated, every SQLite call is
+        made and every answer is built here, in stream order; the first
+        batch is staged here too, so a stream of one starts no thread.
+        At most one batch is staged ahead. `pass_stage_join` is what the
+        landing thread then still waits for the staging. End state is
+        identical to sequential `reconcile` calls, which is what any
+        other route than the packed one runs."""
         if self._route(live=False) != "stream":
             return [self.reconcile(b) for b in batches]
         out: List[List[protocol.SyncResponse]] = []
         prev = None
         for reqs in batches:
+            if prev is None:
+                prev = self.start_batch(reqs)
+                metrics.inc("evolu_engine_stream_staged_total", thread="caller")
+                continue
+            # The helper parks on `go` until the landing has entered its
+            # native insert: let go any earlier it can win the
+            # interpreter lock and hold it through the whole native
+            # pack, in front of the insert.
+            go = threading.Event()
+            staging = self._stage_executor().submit(
+                contextvars.copy_context().run, self._stage_behind, go, reqs)
             try:
-                st = self.start_batch(reqs)
+                out.append(self.finish_batch(prev, entering=go.set))
             except BaseException:
-                # A bad batch k+1 must not drop the already-dispatched
-                # batch k — sequential reconcile would have committed it
-                # before raising; match that contract.
-                if prev is not None:
-                    out.append(self.finish_batch(prev))
-                    prev = None
+                # The landing's exception, not the helper's: wait for
+                # the helper, drop what it staged once its pull is in.
+                go.set()
+                if staging.exception() is None:
+                    dev = staging.result()["dev"]
+                    if dev is not None and dev[3] is not None:
+                        dev[3].exception()  # waits; the pull's own failure is dropped too
                 raise
-            if prev is not None:
-                out.append(self.finish_batch(prev))
-            prev = st
+            go.set()  # a landing without live shards never reached the insert
+            # A bad batch k+1 surfaces here, after batch k has landed
+            # and answered: sequential reconcile would have committed k
+            # before raising.
+            with anatomy.stage("pass_stage_join", cpu=False):
+                prev = staging.result()
+            metrics.inc("evolu_engine_stream_staged_total", thread="helper")
         if prev is not None:
             out.append(self.finish_batch(prev))
         return out
+
+    def _stage_behind(self, go, requests):
+        """The helper's whole job: wait, off the interpreter lock, for
+        the landing thread to let go, then `start_batch`."""
+        go.wait()
+        return self.start_batch(requests)
 
     def _ingest_generic(self, requests, tree_strings=None) -> Dict[str, dict]:
         """Python-backend fallback: temp-table set-diff + bulk SQL."""

@@ -984,7 +984,7 @@ def _handles(dbs):
     return (ctypes.c_void_p * len(dbs))(*[db._db for db in dbs])
 
 
-def relay_insert_packed_shards(dbs, batches, also_count=()):
+def relay_insert_packed_shards(dbs, batches, also_count=(), entering=None):
     """BEGIN + `relay_insert_packed` + the stored trees of the group
     users, on every shard, in ONE native call (which first reserves the
     calling thread's heap once for all of them). `batches[i]` is the
@@ -992,7 +992,11 @@ def relay_insert_packed_shards(dbs, batches, also_count=()):
     group_counts, ts_packed, content_packed, content_lens).
     `also_count`: counter items of the caller's, posted with the
     reservation's in one acquisition of the registry's lock once the
-    call has succeeded. →
+    call has succeeded. `entering`: called with no argument on this
+    thread once every argument is built and every shard's lock is held,
+    as the last Python before the native call drops the interpreter
+    lock: a thread it wakes runs BESIDE the inserts and cannot win the
+    lock in front of them (the numpy sums above drop it). →
     (per-shard was-new bool arrays, {owner: stored merkleTree TEXT} over
     all shards, "{}" for an owner with no stored tree). Every shard is
     left INSIDE its transaction; finish with `relay_commit_shards` or
@@ -1031,7 +1035,7 @@ def relay_insert_packed_shards(dbs, batches, also_count=()):
             db._check_open()
             if db._in_txn:
                 raise UnknownError("begin inside an open transaction")
-        failed = lib.eh_relay_insert_packed_shards(
+        args = (
             k, _handles(dbs), (ctypes.c_char_p * k)(*[db._begin_sql for db in dbs]),
             n_groups.ctypes.data_as(_I64P), user_arr, user_lens.ctypes.data_as(_I32P),
             counts_np.ctypes.data_as(_I64P),
@@ -1042,6 +1046,9 @@ def relay_insert_packed_shards(dbs, batches, also_count=()):
             ctypes.byref(out_trees), ctypes.byref(out_trees_len), err, _ERR_CAP,
             ctypes.byref(reserved),
         )
+        if entering is not None:
+            entering()
+        failed = lib.eh_relay_insert_packed_shards(*args)
         if failed >= 0:  # the C side rolled back whatever it began
             _count_reserved(reserved)
             raise UnknownError(

@@ -1055,10 +1055,24 @@ int eh_relay_insert(sqlite3 *db, int64_t n, const char *const *timestamps,
 // size from 256 to 250,000 rows a call (1,250 rows over 8 shards: 20.4
 // ms against 2.65 ms; 250,000: 3,036 ms against 633 ms; PERF.md §6, PR
 // 27), which is also what the Python thread pool this replaced had
-// cost. Should a host turn up where parallel handles win, run_shards is
-// the one place to start threads; the threading contract above
-// eh_relay_insert_packed holds either way: only the given handles and
-// caller-owned buffers are touched, no globals, no Python API.
+// cost. The cause (PR 38's builder's chip runs; the ledger's PR 38
+// line): the image's libsqlite3 (3.40.1) is built with OMIT_LOOKASIDE
+// and SYSTEM_MALLOC and keeps allocation statistics, so every malloc
+// and free inside a step takes the ONE process-wide
+// SQLITE_MUTEX_STATIC_MEM, 5.4 times an inserted row: 250,000 rows over
+// 8 shards, this loop 593 ms against 1,246 / 1,269 / 1,399 ms in 2 / 4
+// / 8 lanes of threads, each with its heap reserved, at 17x the CPU.
+// With sqlite3_config(SQLITE_CONFIG_MEMSTATUS, 0) before SQLite
+// initialises the same lanes win (534 -> 319 -> 200 ms at 1 / 2 / 8),
+// but that setting is the process's, must precede the first
+// sqlite3_open (and Python's `import sqlite3`), and belongs to whoever
+// owns the process, not to this library. Until a relay owns its
+// process that way, no two threads of it step SQLite at once
+// (`reconcile_stream`'s helper stages the next pass and never comes
+// here), and run_shards is the one place to start threads then; the
+// threading contract above eh_relay_insert_packed holds either way:
+// only the given handles and caller-owned buffers are touched, no
+// globals, no Python API.
 
 namespace {
 

@@ -276,7 +276,10 @@ def test_rehearsal_of_the_cell_ends_correct_with_the_mesh_metrics():
     assert stages | {"window_compiles.mesh4", "backfill_p50_s", "mesh_occupancy_share",
                      "mesh_rows_device_pass", "mesh_xdev_reduce_pass",
                      "mesh_upload_kb_pass", "mesh_pull_kb_pass",
-                     "pack_native_share", "pass_insert_wait_ms.mesh4"} == set(got)
+                     "pack_native_share", "pass_insert_wait_ms.mesh4",
+                     "stream_overlap_share", "pass_stage_join_ms.mesh4"} == set(got)
+    # PR 39: the rehearsal's two passes a backfill, the second staged on the helper
+    assert got["stream_overlap_share"] == 50 and got["pass_stage_join_ms.mesh4"] >= 0
     # the control of PR 37: one thread works alone, so the insert's wall
     # time is nearly all its own CPU time (signed: jitter may read below 0)
     assert got["pass_insert_wait_ms.mesh4"] <= got["pass_insert_ms.mesh4"]
