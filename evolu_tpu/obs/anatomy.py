@@ -620,10 +620,12 @@ class batched_stage(stage):
             self.closed.append((self.family[:-2] + "wait_ms", wait * 1e3, labels))
 
 
-class _part(batched_stage):
-    """A child of a running tile that may open and close several times
-    a unit of work (`part`, below): its intervals add up in `ms` and go
-    to the registry as ONE observation when the unit ends."""
+class summed_stage(batched_stage):
+    """A stage that may open and close several times a unit of work (a
+    `part` of a tile, below; the respond leg's two halves, once a
+    request of a pass): its intervals add up in `ms` and go to the
+    registry as ONE observation, `observation()`, which the owner posts
+    when the unit ends."""
 
     __slots__ = ("ms",)
 
@@ -633,6 +635,9 @@ class _part(batched_stage):
 
     def _record(self, seconds: float, wait: Optional[float]) -> None:
         self.ms += seconds * 1e3
+
+    def observation(self) -> tuple:
+        return (self.family, self.ms, {"stage": self.name})
 
 
 _tiled = threading.local()  # .tiles: the `tiles` open on this thread
@@ -685,8 +690,7 @@ class tiles:
         self._whole.stop()
         _tiled.tiles = self._outer
         closed = self._whole.closed
-        closed.extend((batched_stage.family, p.ms, {"stage": p.name})
-                      for p in self._parts.values())
+        closed.extend(p.observation() for p in self._parts.values())
         metrics.observe_many(closed)
 
 
@@ -708,7 +712,7 @@ def part(name: str):
     name = open_tiles.prefix + name
     child = open_tiles._parts.get(name)
     if child is None:
-        child = open_tiles._parts[name] = _part(name)
+        child = open_tiles._parts[name] = summed_stage(name)
     return child
 
 

@@ -57,7 +57,9 @@ from evolu_tpu.parallel.mesh import (
 )
 from evolu_tpu.obs import anatomy, flight, ledger, metrics
 from evolu_tpu.parallel.reconcile import xor_allreduce
-from evolu_tpu.server.store import ShardedRelayStore, fetch_response_stream
+from evolu_tpu.server.store import (
+    ShardedRelayStore, fetch_response_stream, response_since,
+)
 from evolu_tpu.storage.native import relay_commit_shards, relay_insert_packed_shards
 from evolu_tpu.utils.log import log, span
 from evolu_tpu.sync import protocol
@@ -1534,18 +1536,29 @@ class BatchReconciler:
         tree_strings: Optional[Dict[str, str]] = None,
     ) -> List[bytes]:
         """Bytes-mode twin of `_respond`. The response composition is
-        `store.fetch_response_stream` (ONE copy shared with
-        `RelayStore.sync_wire`) plus the field-2 tree string — the SAME
-        serialized tree `_respond` would carry, so encodings are
-        byte-identical. Requests a shard cannot C-serve (python
-        backend, malformed stored row) degrade to ONE batched
-        object-path respond at their original positions."""
+        `store.fetch_response_stream`'s two halves (`response_since`,
+        then the C fetch; the same rule as `RelayStore.sync_wire`)
+        plus the field-2 tree string — the SAME serialized tree
+        `_respond` would carry, so encodings are byte-identical.
+        Requests a shard cannot C-serve (python backend, malformed
+        stored row) degrade to ONE batched object-path respond at
+        their original positions.
+
+        Two children of `pass_respond` split the per-request loop:
+        `respond_diff` (client-tree parse + diff + the `since` string)
+        and `respond_fetch` (the C fetch), each the SUM over the
+        call's requests and ONE `evolu_stage_ms` observation, posted
+        with what the answers held (`evolu_engine_respond_*`) in one
+        `metrics.observe_many` a call (docs/OBSERVABILITY.md)."""
         from evolu_tpu.core.types import NonCanonicalStoreError
 
         shards, shard_ix = self._shards()
         tree_strings = dict(tree_strings or {})
         out: List[Optional[bytes]] = []
         fallback: List[Tuple[int, protocol.SyncRequest]] = []
+        diffing = anatomy.summed_stage("respond_diff")
+        fetching = anatomy.summed_stage("respond_fetch")
+        held = [0] * len(requests)  # messages in each answer
         for i, r in enumerate(requests):
             if r.scope is not None:
                 # Scoped responds never ride the fused C stream —
@@ -1553,8 +1566,9 @@ class BatchReconciler:
                 # (server/scope.py), ingest already done by the batch.
                 from evolu_tpu.server import scope as scope_mod
 
-                out.append(protocol.encode_sync_response(
-                    scope_mod.scoped_response(self.store, r)))
+                resp = scope_mod.scoped_response(self.store, r)
+                held[i] = len(resp.messages)
+                out.append(protocol.encode_sync_response(resp))
                 continue
             tree, raw = self._resolve_tree(r.user_id, trees, tree_strings)
             # A generic store (no `.db` attribute at all) must degrade
@@ -1564,22 +1578,37 @@ class BatchReconciler:
                 fallback.append((i, r))
                 out.append(None)
                 continue
-            client_tree = merkle_tree_from_string(r.merkle_tree)
-            try:
-                stream = fetch_response_stream(
-                    db, r.user_id, r.node_id, tree, client_tree
-                )
-            except NonCanonicalStoreError:
-                # A malformed stored width degrades this request to the
-                # object path (generic SQL), like sync_wire.
-                fallback.append((i, r))
-                out.append(None)
-                continue
+            with diffing:
+                since = response_since(tree, merkle_tree_from_string(r.merkle_tree))
+            stream = b""
+            if since is not None:
+                try:
+                    with fetching:
+                        stream, held[i] = db.fetch_relay_messages_wire(
+                            r.user_id, since, r.node_id)
+                except NonCanonicalStoreError:
+                    # A malformed stored width degrades this request to
+                    # the object path (generic SQL), like sync_wire.
+                    fallback.append((i, r))
+                    out.append(None)
+                    continue
             out.append(stream + protocol._string(2, raw))
         if fallback:
             resps = self._respond([r for _i, r in fallback], trees, tree_strings)
             for (i, _r), resp in zip(fallback, resps):
+                held[i] = len(resp.messages)
                 out[i] = protocol.encode_sync_response(resp)
+        with_messages = sum(1 for n in held if n)
+        metrics.observe_many(
+            (diffing.observation(), fetching.observation()),
+            also_inc=(
+                ("evolu_engine_respond_requests_total",
+                 len(out) - with_messages, {"answer": "empty"}),
+                ("evolu_engine_respond_requests_total",
+                 with_messages, {"answer": "messages"}),
+                ("evolu_engine_respond_messages_total", sum(held), {}),
+                ("evolu_engine_respond_bytes_total", sum(map(len, out)), {}),
+            ))
         return out
 
 

@@ -92,12 +92,13 @@ class _Pending:
     open; a handler-thread write acked mid-batch would be rolled back
     with a poisoned batch)."""
 
-    __slots__ = ("request", "single", "t_enqueue", "t_wall", "t_done", "ctx",
-                 "done", "response", "error")
+    __slots__ = ("request", "single", "kept", "t_enqueue", "t_wall", "t_done",
+                 "ctx", "done", "response", "error")
 
     def __init__(self, request: protocol.SyncRequest, single: bool = False):
         self.request = request
         self.single = single
+        self.kept = 0  # batch closes that left it queued (_close_batch)
         self.t_enqueue = time.monotonic()
         self.t_wall = time.time()
         # The submitting handler thread's ambient trace context — the
@@ -189,6 +190,10 @@ class SyncScheduler:
         self._engine_broken: Optional[BaseException] = None
         self._cv = threading.Condition()
         self._queue: List[_Pending] = []
+        # What the last _close_batch kept back, {reason: requests}:
+        # written under the lock and posted by the same dispatcher
+        # thread with that batch's queue waits (_record_queue_waits).
+        self._kept: dict = {}
         self._stopping = False
         self._stopped = threading.Event()
         self._thread = threading.Thread(
@@ -301,22 +306,46 @@ class SyncScheduler:
         sequential server's would), and once anything of an owner is
         kept back (same-owner duplicate, single, or capacity), every
         later request of that owner is kept too — per-owner FIFO is
-        never reordered."""
+        never reordered.
+
+        Every request a close keeps counts once in `self._kept` under
+        the reason that kept it (`evolu_sched_deferred_total{reason}`:
+        `owner_blocked` behind a kept request of its owner,
+        `same_owner` an owner already in the batch, `single` a
+        non-batchable request or the queue behind the one dispatched
+        alone, `capacity`) and once in its own `kept`
+        (`evolu_sched_passes_waited`, observed at the close that takes
+        it). Counting decides nothing: what is kept is what was."""
+        kept = self._kept = {}
         if self._queue[0].single:
-            return [self._queue.pop(0)]
+            head = self._queue.pop(0)
+            for p in self._queue:
+                p.kept += 1
+            if self._queue:
+                kept["single"] = len(self._queue)
+            return [head]
         batch: List[_Pending] = []
         owners: set = set()
         keep: List[_Pending] = []
         blocked: set = set()
         for p in self._queue:
             uid = p.request.user_id
-            if (p.single or uid in owners or uid in blocked
-                    or len(batch) >= self.max_batch):
-                blocked.add(uid)
-                keep.append(p)
+            if uid in blocked:
+                reason = "owner_blocked"
+            elif p.single:
+                reason = "single"
+            elif uid in owners:
+                reason = "same_owner"
+            elif len(batch) >= self.max_batch:
+                reason = "capacity"
             else:
                 owners.add(uid)
                 batch.append(p)
+                continue
+            blocked.add(uid)
+            keep.append(p)
+            p.kept += 1
+            kept[reason] = kept.get(reason, 0) + 1
         # Anything kept is seen by the next loop iteration's queue
         # check — no new arrival needed to wake the dispatcher.
         self._queue = keep
@@ -327,11 +356,18 @@ class SyncScheduler:
         it), measured against ONE dispatch instant: the
         `evolu_sched_queue_wait_ms` histogram for every request, and a
         `sched.queue` span under the request's own trace where it has
-        one — one leg of the queue-wait / engine-time / respond split."""
+        one — one leg of the queue-wait / engine-time / respond split.
+        Under the same acquisition of the registry's lock: how many
+        closes had kept each request (`evolu_sched_passes_waited`) and
+        what this close kept (`evolu_sched_deferred_total{reason}`)."""
         t_dispatch = time.monotonic()
         waits = [(t_dispatch - p.t_enqueue) * 1e3 for p in batch]
+        kept, self._kept = self._kept, {}  # posted once, by its own close
         metrics.observe_many(
-            [("evolu_sched_queue_wait_ms", w, {}) for w in waits])
+            [("evolu_sched_queue_wait_ms", w, {}) for w in waits]
+            + [("evolu_sched_passes_waited", p.kept, {}) for p in batch],
+            also_inc=[("evolu_sched_deferred_total", n, {"reason": reason})
+                      for reason, n in kept.items()])
         for p, wait_ms in zip(batch, waits):
             if p.ctx is not None:
                 trace.record_span("sched.queue", p.ctx, p.t_wall, wait_ms)

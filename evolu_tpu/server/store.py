@@ -83,19 +83,29 @@ def _ledger_store_apply(user_id, new_flags) -> None:
                  owner=user_id)
 
 
-def fetch_response_stream(db, user_id, node_id, server_tree, client_tree) -> bytes:
-    """The C-served SyncResponse `messages` stream for one request:
-    tree diff → since timestamp → `eh_get_messages_wire`. b"" when the
-    trees agree; raises NonCanonicalStoreError for a malformed stored
-    row (callers degrade that request to the object path). ONE copy of
-    this byte-format-coupled composition, shared by
-    `RelayStore.sync_wire` and `BatchReconciler._respond_wire` — the
-    serve rule must never drift between them (byte-identity with the
-    object path is test-pinned at both call sites)."""
+def response_since(server_tree, client_tree) -> Optional[str]:
+    """The serve rule's first half: the trees' diff as the `since`
+    timestamp string `eh_get_messages_wire` compares stored rows
+    with, or None where the trees agree (nothing to fetch)."""
     diff = diff_merkle_trees(server_tree, client_tree)
     if diff is None:
+        return None
+    return timestamp_to_string(create_sync_timestamp(diff))
+
+
+def fetch_response_stream(db, user_id, node_id, server_tree, client_tree) -> bytes:
+    """The C-served SyncResponse `messages` stream for one request:
+    tree diff → since timestamp (`response_since`) →
+    `eh_get_messages_wire`. b"" when the trees agree; raises
+    NonCanonicalStoreError for a malformed stored row (callers degrade
+    that request to the object path). The serve rule must never drift
+    between `RelayStore.sync_wire`, `_respond_deferred` and
+    `BatchReconciler._respond_wire`, which times and counts the two
+    halves itself (byte-identity with the object path is test-pinned
+    at every call site)."""
+    since = response_since(server_tree, client_tree)
+    if since is None:
         return b""
-    since = timestamp_to_string(create_sync_timestamp(diff))
     stream, _n = db.fetch_relay_messages_wire(user_id, since, node_id)
     return stream
 

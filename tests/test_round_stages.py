@@ -428,27 +428,58 @@ def test_every_stage_is_one_annotation_on_its_thread(server):
 
 
 def _serve_script(enabled: bool):
-    """One deterministic, sequential script of pushes and pulls against a
-    fresh batching relay → (every response's bytes, the store's dump)."""
+    """One deterministic script of pushes and pulls against a fresh
+    batching relay → (every response's bytes, the store's dump): a
+    sequential part, then (ISSUE 40) ONE mixed batch, four requests 50 ms
+    apart inside a 0.4 s coalescing window: a push, a pull of the same
+    owner from another device (kept for the next pass: it must see the
+    push), a pull with messages and a push of other owners."""
+    from evolu_tpu.server.scheduler import SyncScheduler
+
     metrics.set_enabled(enabled)
-    srv = RelayServer(ShardedRelayStore(shards=2), batching=True).start()
+    store = ShardedRelayStore(shards=2)
+    srv = RelayServer(store, scheduler=SyncScheduler(store, max_wait_s=0.4)).start()
     try:
         out = [_post(srv.url, _body(owner, k, 30))
                for k in range(3) for owner in range(3)]
         for owner in range(3):  # another device of each owner pulls it all
             out.append(_post(srv.url, protocol.encode_sync_request(
                 protocol.SyncRequest((), f"owner-{owner}", "f" * 16, "{}"))))
-        return out, relay_store_dump(srv.store)
+        mixed = [_body(0, 3, 30),
+                 protocol.encode_sync_request(
+                     protocol.SyncRequest((), "owner-0", "e" * 16, "{}")),
+                 protocol.encode_sync_request(
+                     protocol.SyncRequest((), "owner-1", "e" * 16, "{}")),
+                 _body(2, 3, 30)]
+        answers = [None] * len(mixed)
+
+        def send(i):
+            answers[i] = _post(srv.url, mixed[i])
+
+        threads = [threading.Thread(target=send, args=(i,)) for i in range(len(mixed))]
+        for t in threads:
+            t.start()
+            time.sleep(0.05)
+        for t in threads:
+            t.join(timeout=120)
+        return out + answers, relay_store_dump(srv.store)
     finally:
         srv.stop()
         metrics.set_enabled(True)
 
 
 def test_responses_and_store_identical_with_metrics_disabled():
-    on, off = _serve_script(True), _serve_script(False)
-    assert on[0] == off[0] and len(on[0]) == 12 and all(on[0])
+    deferred0 = metrics.get_counter("evolu_sched_deferred_total", reason="same_owner")
+    on = _serve_script(True)
+    assert metrics.get_counter(
+        "evolu_sched_deferred_total", reason="same_owner") == deferred0 + 1
+    off = _serve_script(False)
+    assert on[0] == off[0] and len(on[0]) == 16 and all(on[0])
     assert on[1] == off[1]
-    assert sum(len(messages) for messages, _trees in on[1]) == 3 * 3 * 30
+    assert sum(len(messages) for messages, _trees in on[1]) == (3 * 3 + 2) * 30
+    # the mixed batch: the same owner's pull saw the push queued before it
+    pulled = [len(protocol.decode_sync_response(a).messages) for a in on[0][12:]]
+    assert pulled == [0, 120, 90, 0]  # "{}" trees: everything but the node's own
 
 
 def test_perf_selfcheck_reads_every_new_layer_file():
