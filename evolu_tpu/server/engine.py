@@ -39,7 +39,7 @@ from evolu_tpu.core.merkle import (
     merkle_tree_from_string,
     merkle_tree_to_string,
 )
-from evolu_tpu.ops import bucket_size, start_host_transfer, to_host_many, with_x64
+from evolu_tpu.ops import bucket_size, to_host_many, with_x64
 from evolu_tpu.ops.encode import timestamp_hashes
 from evolu_tpu.ops.host_parse import (
     pack_requests,
@@ -179,22 +179,6 @@ def _merkle_shard_kernel_compact(k1, node, owner_ix, cap):
     return _compact_segments_tail(owner_ix, millis, counter, node, valid, cap)
 
 
-@functools.lru_cache(maxsize=None)
-def _compiled_merkle_kernel_compact(mesh: Mesh, cap: int):
-    spec = P(OWNERS_AXIS)
-    fn = jax.jit(
-        shard_map(
-            functools.partial(_merkle_shard_kernel_compact, cap=cap),
-            mesh=mesh,
-            in_specs=(spec,) * 3,
-            out_specs=(spec, spec, spec, P()),
-            check_vma=False,
-        )
-    )
-    _JIT_KERNELS.append(fn)
-    return fn
-
-
 # Owner field bits in the delta-compact upload's owner|counter column.
 # Owner 0xFFFF is the padding sentinel, so ≤ 65534 distinct owners per
 # dispatch ride the 16-byte/row path; bigger batches (or millis spans
@@ -222,20 +206,74 @@ def _merkle_shard_kernel_compact_delta(dmillis, ownctr, node, base, cap):
     return _compact_segments_tail(owner_ix, millis, counter, node, valid, cap)
 
 
+def _pack_outputs(packed, xors, seg_count, digest):
+    """One u64 array a device for the four outputs of the compact tail:
+    `packed` [cap], `xors` two a word (the first half in the low
+    halves, the second in the high: contiguous slices on both sides),
+    then seg_count | digest << 32. `_unpack_outputs` is its host
+    inverse."""
+    half = xors.shape[0] // 2
+    lo, hi = xors[:half].astype(jnp.uint64), xors[half:].astype(jnp.uint64)
+    last = seg_count.astype(jnp.uint64) | (digest.astype(jnp.uint64) << jnp.uint64(32))
+    return jnp.concatenate([packed, lo | (hi << jnp.uint64(32)), last.reshape(1)])
+
+
+def _halves(words):
+    """The low and the high u32 half of every u64 word."""
+    return words.astype(jnp.uint32), (words >> jnp.uint64(32)).astype(jnp.uint32)
+
+
+def _packed_kernel_delta(buf, cap):
+    """`_merkle_shard_kernel_compact_delta` behind ONE upload a device:
+    2S + 1 words, dmillis | ownctr << 32 a row, then `node`, then
+    `base` (in every device's tail, so it needs no replicated twin)."""
+    s = (buf.shape[0] - 1) // 2
+    dmillis, ownctr = _halves(buf[:s])
+    return _pack_outputs(*_merkle_shard_kernel_compact_delta(
+        dmillis, ownctr, buf[s:2 * s], buf[2 * s:].astype(jnp.int64), cap))
+
+
+def _packed_kernel_full(buf, cap):
+    """`_merkle_shard_kernel_compact` behind one upload a device: 2.5 S
+    words, `k1`, `node`, then `oix` two a word (first half of the rows
+    in the low halves)."""
+    s = buf.shape[0] * 2 // 5
+    oix = jax.lax.bitcast_convert_type(
+        jnp.concatenate(_halves(buf[2 * s:])), jnp.int32)
+    return _pack_outputs(*_merkle_shard_kernel_compact(buf[:s], buf[s:2 * s], oix, cap))
+
+
 @functools.lru_cache(maxsize=None)
-def _compiled_merkle_kernel_compact_delta(mesh: Mesh, cap: int):
-    spec = P(OWNERS_AXIS)
+def _compiled_packed_kernel(mesh: Mesh, cap: int, delta: bool):
+    """The program of one relay pass: one host buffer in (a NUMPY array
+    handed to the call, whose C++ path uploads it: no Python
+    `device_put`), one device array out."""
+    shd = sharding(mesh)
     fn = jax.jit(
         shard_map(
-            functools.partial(_merkle_shard_kernel_compact_delta, cap=cap),
+            functools.partial(_packed_kernel_delta if delta else _packed_kernel_full,
+                              cap=cap),
             mesh=mesh,
-            in_specs=(spec, spec, spec, P()),
-            out_specs=(spec, spec, spec, P()),
+            in_specs=P(OWNERS_AXIS),
+            out_specs=P(OWNERS_AXIS),
             check_vma=False,
-        )
+        ),
+        in_shardings=shd,
+        out_shardings=shd,
     )
     _JIT_KERNELS.append(fn)
     return fn
+
+
+def _unpack_outputs(out, n_devices: int, cap: int):
+    """→ (packed [n, cap], xors [n, cap], counts [n], digest) of the
+    pulled `_pack_outputs` rows."""
+    rows = out.reshape(n_devices, -1)
+    x32 = rows[:, cap:cap + cap // 2].view(np.uint32)
+    xors = np.concatenate((x32[:, 0::2], x32[:, 1::2]), axis=1)
+    last = rows[:, -1]
+    return (rows[:, :cap], xors, (last & np.uint64(0xFFFFFFFF)).astype(np.int64),
+            int(last[0] >> np.uint64(32)))
 
 
 @with_x64
@@ -349,29 +387,21 @@ def deltas_dispatch(
     )
     if layout is None:
         return (deltas, digest, good, None, None)
-    k1, node, oix, cap, upload, tile.rows = layout
+    buf, k1, oix, cap, delta, tile.rows = layout
     tile.then("pass_device_call", rows=tile.rows)
-    shd = sharding(mesh)
-    if upload is not None:
-        dmillis, ownctr, base = upload
-        metrics.inc("evolu_engine_compact_upload_bytes_total",
-                    16 * len(oix), variant="delta")
-        args = [put_sharded(a, shd) for a in (dmillis, ownctr, node)]
-        base_arr = jax.device_put(
-            np.array([base], np.int64),
-            jax.sharding.NamedSharding(mesh, P()),
-        )
-        outs = start_host_transfer(
-            *_compiled_merkle_kernel_compact_delta(mesh, cap)(*args, base_arr)
-        )
-    else:
-        metrics.inc("evolu_engine_compact_upload_bytes_total",
-                    20 * len(oix), variant="full")
-        args = [put_sharded(a, shd) for a in (k1, node, oix)]
-        outs = start_host_transfer(*_compiled_merkle_kernel_compact(mesh, cap)(*args))
+    out = _compiled_packed_kernel(mesh, cap, delta)(buf)
+    out.copy_to_host_async()
+    metrics.inc_many((
+        ("evolu_engine_compact_upload_bytes_total", buf.nbytes,
+         {"variant": "delta" if delta else "full"}),
+        ("evolu_engine_device_dispatches_total", 1, {}),
+        ("evolu_engine_device_transfers_total", 1, {"dir": "up"}),
+        ("evolu_engine_device_transfers_total", 1, {"dir": "down"}),
+    ))
+    outs = (out,)
     if pull_pool is not None:
-        outs = pull_pool.submit(to_host_many, *outs)
-    return (deltas, digest, good, outs, (k1, node, oix, mesh, cap))
+        outs = pull_pool.submit(to_host_many, out)
+    return (deltas, digest, good, outs, (buf, k1, oix, mesh, cap))
 
 
 def _deltas_layout(mesh, owner_index, all_m, all_c, all_n, case_ok,
@@ -380,9 +410,10 @@ def _deltas_layout(mesh, owner_index, all_m, all_c, all_n, case_ok,
     owners fold on the host here), owner units, shard assignment,
     bucket padding, key packing. → (deltas, digest, good, layout);
     `layout` is None where no owner is left for the device, else
-    (k1, node, oix, cap, upload, rows) with `upload` = (dmillis,
-    ownctr, base) for the 16 B/row delta kernel or None for the
-    full-key one, and `rows` the real (unpadded) row count."""
+    (buf, k1, oix, cap, delta, rows): `buf` the pass's ONE upload
+    (`_packed_kernel_delta`'s words where `delta`, 16 B a row, else
+    `_packed_kernel_full`'s, 20 B), `k1` and `oix` the host's copies
+    for the overflow re-run, `rows` the real (unpadded) row count."""
     owners = list(owner_index)
     deltas: Dict[str, Dict[str, int]] = {o: {} for o in owners}
     digest = 0
@@ -426,7 +457,6 @@ def _deltas_layout(mesh, owner_index, all_m, all_c, all_n, case_ok,
         shards = assign_owners_to_shards(unit_sizes, mesh.devices.size)
     loads = [sum(len(units[u]) for u in s) for s in shards]
     shard_size = bucket_size(max(max(loads, default=0), 1))
-    total = mesh.devices.size * shard_size
     if ctx is not None:
         ctx.record_occupancy(loads, shard_size)
         # The in-kernel XOR all-reduce of the batch digest is one
@@ -442,25 +472,30 @@ def _deltas_layout(mesh, owner_index, all_m, all_c, all_n, case_ok,
             if len(devs) > 1:
                 ctx.record_xdev_reduce("owner_delta_partials")
 
-    # Transfer-lean upload: 20 bytes/row — packed HLC key (millis<<16 |
-    # counter), node, and int32 owner with -1 marking padding (the
-    # timestamp columns are rebuilt on device from the packed key).
-    k1 = np.zeros(total, np.uint64)
-    node = np.zeros(total, np.uint64)
-    oix = np.full(total, -1, np.int32)
-    pos_by_shard = [si * shard_size for si in range(len(shards))]
+    # The columns of the transfer-lean upload, a row a device: packed
+    # HLC key (millis<<16 | counter), node, and int32 owner with -1
+    # marking padding (the timestamp columns are rebuilt on device from
+    # the packed key). `node` is written where it is uploaded from:
+    # words [S, 2S) of each device's row of the pass's one buffer.
+    n_dev = len(shards)
+    buf = np.zeros((n_dev, 2 * shard_size + 1), np.uint64)
+    k1 = np.zeros((n_dev, shard_size), np.uint64)
+    node = buf[:, shard_size:2 * shard_size]
+    oix = np.full((n_dev, shard_size), -1, np.int32)
+    pos_by_shard = [0] * n_dev
     shard_of_unit = {u: si for si, shard in enumerate(shards) for u in shard}
     for u, ix in units.items():
         n = len(ix)
         si = shard_of_unit[u]
         pos = pos_by_shard[si]
         sl = slice(pos, pos + n)
-        k1[sl] = (all_m[ix].astype(np.uint64) << np.uint64(16)) | all_c[ix].astype(
+        k1[si, sl] = (all_m[ix].astype(np.uint64) << np.uint64(16)) | all_c[ix].astype(
             np.uint64
         )
-        node[sl] = all_n[ix]
-        oix[sl] = owner_ix[u[0]]
+        node[si, sl] = all_n[ix]
+        oix[si, sl] = owner_ix[u[0]]
         pos_by_shard[si] = pos + n
+    k1, oix = k1.reshape(-1), oix.reshape(-1)
 
     cap = bucket_size(max(shard_size // 8, 64))
     real = oix >= 0
@@ -483,7 +518,6 @@ def _deltas_layout(mesh, owner_index, all_m, all_c, all_n, case_ok,
         and max_real < (1 << 47)  # wrapped pre-1970 lands near 2^48
         and len(good) < _DELTA_PAD_OWNER
     )
-    upload = None
     if use_delta:
         dmillis = np.where(real, millis - base, 0).astype(np.uint32)
         ownctr = np.where(
@@ -492,8 +526,25 @@ def _deltas_layout(mesh, owner_index, all_m, all_c, all_n, case_ok,
             | (k1 & np.uint64(0xFFFF)).astype(np.uint32),
             np.uint32(_DELTA_PAD_OWNER << 16),
         )
-        upload = (dmillis, ownctr, base)
-    return deltas, digest, good, (k1, node, oix, cap, upload, n_good_rows)
+        halves = buf[:, :shard_size].view(np.uint32)
+        halves[:, 0::2] = dmillis.reshape(n_dev, shard_size)
+        halves[:, 1::2] = ownctr.reshape(n_dev, shard_size)
+        buf[:, -1] = base
+    else:
+        buf = _full_key_upload(buf, k1, oix)
+    return deltas, digest, good, (buf.reshape(-1), k1, oix, cap, use_delta, n_good_rows)
+
+
+def _full_key_upload(buf, k1, oix):
+    """`_packed_kernel_full`'s words from a delta-sized buffer that
+    holds `node`: the rare variant pays the copy."""
+    n_dev, s = buf.shape[0], buf.shape[1] // 2
+    full = np.empty((n_dev, 2 * s + s // 2), np.uint64)
+    full[:, :s] = k1.reshape(n_dev, s)
+    full[:, s:2 * s] = buf[:, s:2 * s]
+    halves, oix = full[:, 2 * s:].view(np.uint32), oix.reshape(n_dev, s)
+    halves[:, 0::2], halves[:, 1::2] = oix[:, :s // 2], oix[:, s // 2:]
+    return full
 
 
 def deltas_finish(state) -> Tuple[Dict[str, Dict[str, int]], int]:
@@ -530,15 +581,17 @@ def deltas_decode(state, pulled) -> Tuple[Dict[str, Dict[str, int]], int]:
     deltas, digest, good, _outs, extra = state
     if pulled is None:
         return deltas, digest
-    packed, xors, counts, dev_digest = pulled
-    k1, node, oix, mesh, cap = extra
-    counts = np.asarray(counts)
+    buf, k1, oix, mesh, cap = extra
+    n_dev = mesh.devices.size
+    packed, xors, counts, dev_digest = _unpack_outputs(pulled[0], n_dev, cap)
     if (counts > cap).any():
         log("kernel:merkle", "segment compaction overflow: full-width pull",
             cap=cap, max_count=int(counts.max()))
         millis = (k1 >> np.uint64(16)).astype(np.int64)
         counter = (k1 & np.uint64(0xFFFF)).astype(np.int32)
         valid = oix >= 0
+        shard_size = len(oix) // n_dev
+        node = buf.reshape(n_dev, -1)[:, shard_size:2 * shard_size].reshape(-1)
         shd = sharding(mesh)
         args = [
             put_sharded(a, shd)
@@ -557,14 +610,9 @@ def deltas_decode(state, pulled) -> Tuple[Dict[str, Dict[str, int]], int]:
 
         by_ix: Dict[int, Dict[str, int]] = {}
         key_cache: Dict[int, str] = {}
-        packed = np.asarray(packed)
-        xors = np.asarray(xors)
-        for si in range(len(counts)):
+        for si in range(n_dev):
             c = int(counts[si])
-            base = si * cap
-            for p, x in zip(
-                packed[base : base + c].tolist(), xors[base : base + c].tolist()
-            ):
+            for p, x in zip(packed[si, :c].tolist(), xors[si, :c].tolist()):
                 o_ix = p >> 32
                 minute = p & 0xFFFFFFFF
                 if minute >= 1 << 31:  # undo the uint32 bit carriage of

@@ -248,6 +248,7 @@ class SyncScheduler:
         # long; cpu/busy well under 1: the dispatcher waits — for the
         # interpreter lock, SQLite's shard threads or the device.
         t_idle = time.perf_counter()
+        t_free = 0.0  # time.monotonic() at the previous pass's end
         try:
             while True:
                 with self._cv:
@@ -255,14 +256,19 @@ class SyncScheduler:
                         self._cv.wait()
                     if not self._queue:
                         return  # stopping + drained
-                    # Deadline from the OLDEST pending's enqueue time:
-                    # requests that piled up during the previous engine
-                    # pass close a batch immediately — the pass itself
-                    # is the coalescing window under load; max_wait_s
-                    # only delays a lone request on an idle queue.
-                    # stop() waives the wait so the drain runs at full
-                    # batch size without deadline stalls.
-                    deadline = self._queue[0].t_enqueue + self.max_wait_s
+                    # A batch stays open max_wait_s from the moment the
+                    # dispatcher could first have taken it: the LATER of
+                    # its oldest request's enqueue and the previous
+                    # pass's end. While a pass took 20-30 ms it was the
+                    # coalescing window itself; at 6-14 ms (ISSUE 41) a
+                    # dispatcher that closes at once what piled up
+                    # behind it runs passes of three requests back to
+                    # back and keeps the interpreter lock from the
+                    # handlers and the acceptor: 7 % fewer rounds a
+                    # second than before the pass got cheaper (PERF.md
+                    # §6, PR 41). stop() waives the wait so the drain
+                    # runs at full batch size without deadline stalls.
+                    deadline = max(self._queue[0].t_enqueue, t_free) + self.max_wait_s
                     while len(self._queue) < self.max_batch and not self._stopping:
                         remaining = deadline - time.monotonic()
                         if remaining <= 0:
@@ -285,7 +291,7 @@ class SyncScheduler:
                             now - t_busy, state="busy")
                 metrics.inc("evolu_sched_dispatcher_seconds_total",
                             time.thread_time() - cpu_busy, state="cpu")
-                t_idle = now
+                t_idle, t_free = now, time.monotonic()
         finally:
             # If the loop died abnormally (BaseException out of
             # _run_batch — e.g. KeyboardInterrupt mid-pass), blocked

@@ -766,7 +766,6 @@ def test_delta_compact_transfer_matches_full_key_kernel():
     from evolu_tpu.obs import metrics
     from evolu_tpu.ops import to_host_many, with_x64
     from evolu_tpu.ops.host_parse import parse_timestamp_strings
-    from evolu_tpu.parallel.mesh import put_sharded, sharding
     from evolu_tpu.server import engine
 
     base = 1_700_000_000_000
@@ -774,13 +773,17 @@ def test_delta_compact_transfer_matches_full_key_kernel():
 
     @with_x64
     def full_key(cols):
-        """The full-key kernel on the layout `_deltas_layout` returns."""
+        """The full-key program on the layout `_deltas_layout` returns
+        (its words rebuilt by `_full_key_upload` where the layout
+        admitted the delta variant)."""
         deltas, digest, good, layout = engine._deltas_layout(mesh, *cols, None)
-        k1, node, oix, cap, upload, _rows = layout
-        outs = engine._compiled_merkle_kernel_compact(mesh, cap)(
-            *[put_sharded(a, sharding(mesh)) for a in (k1, node, oix)])
-        state = (deltas, digest, good, None, (k1, node, oix, mesh, cap))
-        return engine.deltas_decode(state, to_host_many(*outs)), upload is not None
+        buf, k1, oix, cap, delta, _rows = layout
+        if delta:
+            buf = engine._full_key_upload(
+                buf.reshape(mesh.devices.size, -1), k1, oix).reshape(-1)
+        out = engine._compiled_packed_kernel(mesh, cap, False)(buf)
+        state = (deltas, digest, good, None, (buf, k1, oix, mesh, cap))
+        return engine.deltas_decode(state, to_host_many(out)), delta
 
     def uploaded(variant):
         return metrics.get_counter(

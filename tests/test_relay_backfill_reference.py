@@ -209,7 +209,8 @@ def test_restore_loop_counts_whole_backfills_and_the_check_holds_them(mesh4, mon
         outcome["window_compiles"] = 0
         assert driver.check(state, outcome) is True
         assert outcome["upload_variant"] == "variant=delta"  # 16 B a slot
-        assert outcome["upload_bytes_pass"] % (4 * 16) == 0
+        # PR 41: one buffer a pass, `base` (8 B) in each of the four devices' tails
+        assert (outcome["upload_bytes_pass"] - 4 * 8) % (4 * 16) == 0
 
         # A check that would pass anything decides nothing: a tree that
         # is not the request's, a lost row, a dispatch that was not counted.
@@ -277,7 +278,9 @@ def test_rehearsal_of_the_cell_ends_correct_with_the_mesh_metrics():
                      "mesh_rows_device_pass", "mesh_xdev_reduce_pass",
                      "mesh_upload_kb_pass", "mesh_pull_kb_pass",
                      "pack_native_share", "pass_insert_wait_ms.mesh4",
-                     "stream_overlap_share", "pass_stage_join_ms.mesh4"} == set(got)
+                     "stream_overlap_share", "pass_stage_join_ms.mesh4",
+                     "device_transfers_pass.mesh4"} == set(got)
+    assert got["device_transfers_pass.mesh4"] == 2.0  # PR 41: one buffer up, one back
     # PR 39: the rehearsal's two passes a backfill, the second staged on the helper
     assert got["stream_overlap_share"] == 50 and got["pass_stage_join_ms.mesh4"] >= 0
     # the control of PR 37: one thread works alone, so the insert's wall
@@ -287,8 +290,9 @@ def test_rehearsal_of_the_cell_ends_correct_with_the_mesh_metrics():
     assert got["pack_native_share"] == 100  # every pass packed by the native walk (PR 36)
     assert got["mesh_xdev_reduce_pass"] == 1  # the digest's all-reduce; no owner is split
     assert got["mesh_rows_device_pass"] == 6000 / 2 / 4  # 2 passes over 4 devices
-    # 16 B a slot of 4 devices x the fullest device's bucket
-    slots = got["mesh_upload_kb_pass"] * 1024 / 16
+    # 16 B a slot of 4 devices x the fullest device's bucket, and 8 B of
+    # `base` a device
+    slots = (got["mesh_upload_kb_pass"] * 1024 - 4 * 8) / 16
     assert slots % 4 == 0 and got["mesh_occupancy_share"] == pytest.approx(3000 / slots * 100)
     other = [json.loads(l) for l in done.stdout.strip().splitlines()[:-1]]
     e2e = next(l["metrics"] for l in other if l.get("info") == "the other set")

@@ -457,7 +457,8 @@ def test_rehearsal_of_the_cell_ends_correct_with_every_new_metric():
     assert line["correct"] is True and line["failed"] == 0 and line["attempted"] > 0
     assert line["device"]["platform"] == "cpu"
     got = {k: v["value"] for k, v in line["metrics"].items()}
-    assert set(got) == set(NEW_METRICS) | set(TWINS)
+    assert set(got) == set(NEW_METRICS) | set(TWINS) | {"device_transfers_pass"}
+    assert got["device_transfers_pass"] == 2.0  # PR 41: one buffer up, one back
     assert got["window_compiles.skewed"] == 0
     assert 0 < got["pull_answer_share"] <= 100 and got["respond_msgs_request"] > 0
     assert 0 <= got["sched_deferred_share"] <= 100 and got["sched_passes_waited"] >= 0

@@ -210,6 +210,49 @@ def test_duplicate_owner_in_one_batch_keeps_sequential_semantics():
     ], "the deferred pull must see the push's rows"
 
 
+def test_batch_window_counts_from_the_previous_pass_end():
+    """PR 41: requests that piled up behind a pass do not close a batch
+    the moment it ends: the batch stays open max_wait_s from the LATER
+    of its oldest request's enqueue and the previous pass's end, so one
+    that arrives inside that window rides the same pass. (While a pass
+    was 20-30 ms it was the coalescing window itself; at 6 ms the
+    dispatcher ran passes of three requests back to back.)"""
+    from evolu_tpu.server.engine import BatchReconciler
+
+    store = ShardedRelayStore(shards=2)
+    eng = BatchReconciler(store)
+    orig, sizes = eng.run_batch_wire, []
+
+    def slow_run(reqs, *stage):
+        sizes.append(len(reqs))
+        time.sleep(0.6)  # longer than max_wait_s: the old rule closed at once
+        return orig(reqs, *stage)
+
+    def request(i):
+        node = f"{i + 0x70:016x}"
+        return protocol.SyncRequest(_msgs(node, 0, 2), f"win{i}", node, "{}")
+
+    sched = SyncScheduler(store, engine=eng, max_batch=8, max_wait_s=0.5)
+    threads = [threading.Thread(target=sched.submit, args=(request(i),)) for i in range(3)]
+    try:
+        sched.submit(request(9))      # compiles the bucket's kernel
+        eng.run_batch_wire = slow_run
+        time.sleep(0.55)              # the warm-up's own window is over
+        threads[0].start()            # pass 1: alone, closes at 0.5 s, runs to ~1.1 s
+        time.sleep(0.7)
+        threads[1].start()            # queued behind pass 1
+        time.sleep(0.65)              # 1.35 s: pass 1 over, the window open to ~1.6 s
+        threads[2].start()            # the older rule's pass 2 left at 1.2 s without it
+        for t in threads:
+            t.join(30)
+            assert not t.is_alive()
+    finally:
+        sched.stop()
+        eng.close()
+        store.close()
+    assert sizes == [1, 2]
+
+
 def test_queue_full_returns_503_with_retry_after():
     store = ShardedRelayStore(shards=2)
     sched = SyncScheduler(store, max_queue=0, retry_after_s=3)

@@ -122,12 +122,16 @@ def _merkle_case(variant, mesh):
         return engine._compiled_merkle_kernel(mesh), _sharded(
             mesh, ((N,), jnp.int64), ((N,), jnp.int32), ((N,), jnp.uint64),
             ((N,), jnp.bool_), ((N,), jnp.int64))
-    if variant == "compact":
-        return engine._compiled_merkle_kernel_compact(mesh, cap), _sharded(
-            mesh, ((N,), jnp.uint64), ((N,), jnp.uint64), ((N,), jnp.int32))
-    base = jax.ShapeDtypeStruct((1,), jnp.int64, sharding=NamedSharding(mesh, P()))
-    return engine._compiled_merkle_kernel_compact_delta(mesh, cap), _sharded(
-        mesh, ((N,), jnp.uint32), ((N,), jnp.uint32), ((N,), jnp.uint64)) + [base]
+    delta = variant == "compact_delta"
+    return engine._compiled_packed_kernel(mesh, cap, delta), _packed_upload(
+        mesh, shard_size, delta)
+
+
+def _packed_upload(mesh, shard_size, delta):
+    """The ONE u64 buffer a pass uploads: 2S + 1 words a device for the
+    delta variant, 2.5 S for the full-key one."""
+    words = 2 * shard_size + 1 if delta else 2 * shard_size + shard_size // 2
+    return _sharded(mesh, ((mesh.devices.size * words,), jnp.uint64))
 
 
 def _check_merkle_kernel(topo, variant, n_devices):
@@ -211,12 +215,15 @@ def _slow_cases(topo):
             four, reconcile._shard_kernel), _sharded(four, *shard_args), True),
         # relay phase, one chip: the streamed 250k-row batches
         "merkle_compact_delta@2^18x1": (
-            engine._compiled_merkle_kernel_compact_delta(one, stream_cap),
-            _sharded(one, ((stream,), jnp.uint32), ((stream,), jnp.uint32),
-                     _u64(stream))
-            + [jax.ShapeDtypeStruct((1,), jnp.int64,
-                                    sharding=NamedSharding(one, P()))],
-            True),
+            engine._compiled_packed_kernel(one, stream_cap, True),
+            _packed_upload(one, stream, True), True),
+        # the served pass's smallest bucket, and a backfill's on four chips
+        "merkle_compact_delta@64x1": (
+            engine._compiled_packed_kernel(one, 64, True),
+            _packed_upload(one, 64, True), False),
+        "merkle_compact_delta@4x65536": (
+            engine._compiled_packed_kernel(four, 1 << 13, True),
+            _packed_upload(four, 1 << 16, True), True),
         # --chips 4: the mesh-sharded winner cache
         "sharded_plan@4x(cap2^12,2^13)": (
             winner_cache._sharded_plan_kernel(four),
@@ -235,6 +242,7 @@ def _slow_cases(topo):
 @pytest.mark.parametrize("case", [
     "plan_full@2^15", "cached_plan@cap2^15,2^15", "seed@cap2^15,2^13",
     "shard_kernel@2^20x1", "shard_kernel@2^20x4", "merkle_compact_delta@2^18x1",
+    "merkle_compact_delta@64x1", "merkle_compact_delta@4x65536",
     "sharded_plan@4x(cap2^12,2^13)", "sharded_seed@4x(cap2^12,2^11)",
 ])
 def test_planner_programs_compile_for_v5e(topo, tpu_scan_route, case):
