@@ -14,11 +14,12 @@ and "0"/"1"/"2" (child dicts). Matches the reference JSON wire shape
 
 The client's tree is a year's (tens of thousands of minutes, over a
 megabyte of JSON) and crosses four legs a `Receive`: load, fold, store,
-diff. It keeps its canonical form from one leg to the next
-(`OrderedTree`: a root whose whole tree is in JS property order, so the
-store dumps it as it is) and `fold_minute_deltas` copies each distinct
-node of a batch once. The relay's trees are a few minutes an owner and
-many a pass: they stay plain dicts on `apply_prefix_xors`.
+diff. It stays its text from one leg to the next (`MerkleText`): the
+fold is one native pass from the old text to the new
+(`fold_minute_deltas`), the store writes the text, and the diff compares
+texts and parses only where they differ. The relay's trees are a few
+minutes an owner and many a pass: they stay plain dicts on
+`apply_prefix_xors`.
 """
 
 from __future__ import annotations
@@ -131,26 +132,13 @@ def apply_prefix_xors(tree: dict, prefix_xors: dict) -> dict:
     return new_tree
 
 
-class OrderedTree(dict):
-    """A ROOT whose whole tree is known to be in JS property order
-    ("0","1","2" ascending, then "hash") and to hold no other key, so
-    `merkle_tree_to_string` dumps it without the `_ordered` rebuild.
-    Only this module's constructors make one: `fold_minute_deltas` /
-    `fold_key_deltas` from a marked or empty root, and
-    `ordered_tree_from_string` after one `_ordered` pass. Every other
-    dict (a test's literal, the relay's trees) is unmarked and ordered
-    at every dump, as before. Trees are never mutated in place."""
-
-    __slots__ = ()
-
-
 class MinuteDeltas(Mapping):
     """A batch's per-minute XOR deltas as the device hands them over:
     `minutes` sorted distinct JS-int32 minutes (int64 array) and
     `deltas` their signed int32 XOR deltas. `fold_minute_deltas` folds
     the arrays; as a Mapping it reads as the {base3-minute-key: delta}
     dict of `apply_prefix_xors`, rendered on first use only (tests, the
-    per-minute routes)."""
+    dict route)."""
 
     __slots__ = ("minutes", "deltas", "_keyed")
 
@@ -177,147 +165,121 @@ class MinuteDeltas(Mapping):
         return self._by_key()[key]
 
 
-# The level pass below needs every key to have 16 digits: minutes in
-# [3**15, 3**16), i.e. 1997-04-13 to 2051-11-04.
-_FULL_KEY_MINUTES = (3 ** (MERKLE_KEY_LENGTH - 1), 3 ** MERKLE_KEY_LENGTH)
+class MerkleText(Mapping):
+    """The client's tree as its text: what `merkle_tree_to_string` wrote
+    (`__clock.merkleTree`, or a fold's result), held as it is. The text
+    is what the store writes and the wire carries, and the folds read
+    and write text, so nothing parses it unless a reader asks for a
+    node: as a Mapping it reads as the parsed tree, parsed on first use
+    only (the diff walk, the sequential route's inserts, tests)."""
 
-# Fewer minutes than this take the per-minute loop. Measured on the
-# chip machine's host (PR 34, call 1; consecutive minutes onto a year's
-# tree, best of 200): the level pass costs 205-242 us whatever the batch
-# up to 64 minutes (17 floor-divides, boundary scans, reduceats and
-# tolists), the per-minute loop 23 us for one minute and about 14.5 us
-# a minute after it: 206 us at 14 minutes, 233 at 16, 453 at 32.
-LEVEL_PASS_MIN_MINUTES = 16
+    __slots__ = ("text", "_tree")
 
-_CHILD_KEYS = ("0", "1", "2")
+    def __init__(self, text: str):
+        self.text = text
+        self._tree = None
 
+    def _parsed(self) -> dict:
+        if self._tree is None:
+            self._tree = json.loads(self.text)
+        return self._tree
 
-def _in_key_order(tree: dict) -> bool:
-    """Known to be, not assumed: marked, or empty."""
-    return not tree or isinstance(tree, OrderedTree)
+    def __len__(self) -> int:
+        return len(self._parsed())
 
+    def __iter__(self):
+        return iter(self._parsed())
 
-def _xor32(h, d: int) -> int:
-    """`_xor` where `d` is already a signed int32: two signed int32s XOR
-    to one, so only a foreign stored hash takes the coercion."""
-    if h is None:
-        return d
-    x = (h or 0) ^ d
-    return x if -0x80000000 <= x <= 0x7FFFFFFF else to_int32(x)
+    def __getitem__(self, key):
+        return self._parsed()[key]
 
 
-def _fold_levels(tree: dict, minutes, deltas) -> Tuple[dict, int]:
-    """The level pass: `minutes` sorted, distinct, all 16-digit. At
-    level L (0 = root, 16 = minute) a node is a run of minutes that
-    share `minute // 3**(16-L)`, and its delta is the XOR of the run.
-    One descent then visits each distinct node once and copies it once,
-    children before "hash". → (root dict, nodes copied)."""
+_TEXT_FOLD = False  # resolved lazily: False = untried, None = unavailable
+
+
+def _text_fold_lane():
+    """`eh_fold_tree_text` through `open_gil_held`'s handle on
+    libevolu_host.so (it reads and makes a str), or None: then every
+    fold takes the dict route."""
+    global _TEXT_FOLD
+    if _TEXT_FOLD is False:
+        _TEXT_FOLD = None
+        import ctypes as c
+
+        from evolu_tpu.storage.native import load_library
+        from evolu_tpu.utils.native_loader import open_gil_held
+
+        plib = open_gil_held("libevolu_host.so", "eh_py_abi_probe") if load_library() else None
+        if plib is not None:
+            fold = plib.eh_fold_tree_text
+            fold.restype = c.c_int
+            fold.argtypes = [c.py_object, c.c_void_p, c.c_void_p, c.c_int64,
+                             c.POINTER(c.c_int64), c.py_object]
+            _TEXT_FOLD = fold
+    return _TEXT_FOLD
+
+
+def _fold_text(text: str, minutes, deltas) -> Optional[Tuple[MerkleText, int]]:
+    """The text route: one native pass from the old text to the new,
+    or None where the pass refuses (or is unavailable)."""
+    import ctypes
     import numpy as np
 
-    depth = MERKLE_KEY_LENGTH
-    # Bottom up: level L's prefixes are level L+1's // 3, and its runs
-    # are unions of level L+1's runs.
-    prefix = minutes
-    starts = np.arange(len(minutes))
-    digits = [None] * (depth + 1)  # per level: each node's key digit
-    node_delta = [None] * (depth + 1)  # per level: each node's delta
-    first_child = [None] * depth  # per level: node i's children are [fc[i], fc[i+1])
-    nodes = 0
-    for level in range(depth, -1, -1):
-        if level < depth:
-            parent = prefix // 3
-            run = np.flatnonzero(parent[1:] != parent[:-1]) + 1
-            child_first = np.concatenate(([0], run))  # into level+1's nodes
-            first_child[level] = np.append(child_first, len(prefix)).tolist()
-            starts = starts[child_first]
-            prefix = parent[child_first]
-        digits[level] = [_CHILD_KEYS[k] for k in (prefix % 3).tolist()]
-        node_delta[level] = np.bitwise_xor.reduceat(deltas, starts).tolist()
-        nodes += len(starts)
-
-    def visit(level: int, i: int, old) -> dict:
-        new = {}
-        if level < depth:
-            lo, hi = first_child[level][i], first_child[level][i + 1]
-            below, digs = level + 1, digits[level + 1]
-        else:  # a minute: no child of the batch's, but after 2051 a
-            lo = hi = 0  # stored minute is also a prefix of 17-digit keys
-        if old:
-            for k in _CHILD_KEYS:
-                if lo < hi and digs[lo] == k:
-                    new[k] = visit(below, lo, old.get(k))
-                    lo += 1
-                elif k in old:
-                    new[k] = old[k]
-        else:
-            for j in range(lo, hi):
-                new[digs[j]] = visit(below, j, None)
-        new["hash"] = _xor32(old.get("hash") if old else None, node_delta[level][i])
-        return new
-
-    return visit(0, 0, tree), nodes
+    fold = _text_fold_lane()
+    if fold is None or len(minutes) != len(deltas):
+        return None
+    minutes = np.ascontiguousarray(minutes, np.int64)
+    deltas = np.ascontiguousarray(deltas, np.int32)
+    nodes = ctypes.c_int64(0)
+    out: list = []
+    if fold(text, minutes.ctypes.data, deltas.ctypes.data, len(minutes),
+            ctypes.byref(nodes), out) != 0:
+        return None
+    return MerkleText(out[0]), nodes.value
 
 
-def _put_ordered(parent, c, child, h: int) -> dict:
-    """A copy of `parent` (or a new node where it is None or empty) with
-    `h` XORed into its hash and, unless `c` is None, `child` at key `c`;
-    keys in JS property order."""
-    new = {}
-    for k in _CHILD_KEYS:
-        if k == c:
-            new[k] = child
-        elif parent and k in parent:
-            new[k] = parent[k]
-    new["hash"] = _xor(parent.get("hash") if parent else None, h)
-    return new
+def _distinct_nodes(keys) -> int:
+    """The root and each distinct prefix of the keys."""
+    return len({key[:i] for key in keys for i in range(len(key) + 1)})
 
 
-def fold_key_deltas(tree: dict, prefix_xors: Mapping) -> Tuple[dict, int]:
-    """`apply_prefix_xors` for the client's tree: the same fold, one
-    path a minute, but a marked (or empty) root stays marked, because
-    every node it copies is written children first, "hash" last. An
-    unmarked root, or a key with another character than 0, 1, 2 (a
-    negative minute's "-", which `_ordered` would drop at the dump),
-    takes `apply_prefix_xors` itself and comes back unmarked.
-    → (tree, nodes copied)."""
-    nodes = sum(len(key) + 1 for key in prefix_xors)
-    if not _in_key_order(tree) or any(key.strip("012") for key in prefix_xors):
-        return apply_prefix_xors(tree, prefix_xors), nodes
-    root = tree
-    for key, h in prefix_xors.items():
-        path = []  # the old node at each prefix of the key, root first
-        node = root
-        for c in key:
-            path.append(node)
-            node = node.get(c) if node else None
-        new = _put_ordered(node, None, None, h)
-        for c, parent in zip(reversed(key), reversed(path)):
-            new = _put_ordered(parent, c, new, h)
-        root = new
-    return OrderedTree(root), nodes
+def _fold_parsed(tree: Mapping, prefix_xors: Mapping) -> Tuple[dict, int]:
+    """The dict route: `apply_prefix_xors` on the parsed tree, for a
+    tree that is not text, or a text or batch the native pass refuses."""
+    base = tree._parsed() if isinstance(tree, MerkleText) else tree
+    return apply_prefix_xors(base, prefix_xors), _distinct_nodes(prefix_xors)
 
 
-def fold_minute_deltas(tree: dict, batch: MinuteDeltas) -> Tuple[dict, int]:
+def fold_minute_deltas(tree: Mapping, batch: MinuteDeltas) -> Tuple[Mapping, int]:
     """Fold a batch's sorted distinct minutes into the client's tree,
-    equivalent to `insert_into_merkle_tree` over its timestamps. The
-    input is not mutated and untouched subtrees stay the same objects.
-    Adapts to what the batch shows: the level pass where every key has
-    16 digits and there are enough minutes to pay numpy's fixed cost,
-    else the per-minute loop. A zero delta still materializes its path.
-    → (tree, nodes copied): marked `OrderedTree` from a marked or empty
-    root, a plain dict otherwise."""
-    n = len(batch)
-    if n == 0:
-        return tree, 0
-    lo, hi = _FULL_KEY_MINUTES
-    if (
-        n < LEVEL_PASS_MIN_MINUTES
-        or not _in_key_order(tree)
-        or not (lo <= batch.minutes[0] and batch.minutes[-1] < hi)
-    ):
-        return fold_key_deltas(tree, batch)
-    root, nodes = _fold_levels(tree, batch.minutes, batch.deltas)
-    return OrderedTree(root), nodes
+    equivalent to `insert_into_merkle_tree` over its timestamps; a zero
+    delta still materializes its path. The input is not mutated.
+    A `MerkleText` folds as its text (`_fold_text`) into a new
+    `MerkleText`; where the native pass refuses the text or the batch
+    (not canonical, a minute without a 16-digit key), and for any other
+    tree, the dict route gives a dict with the same text.
+    → (tree, nodes written: the root and each distinct prefix)."""
+    if isinstance(tree, MerkleText):
+        folded = _fold_text(tree.text, batch.minutes, batch.deltas)
+        if folded is not None:
+            return folded
+    return _fold_parsed(tree, batch)
+
+
+def fold_key_deltas(tree: Mapping, prefix_xors: Mapping) -> Tuple[Mapping, int]:
+    """`fold_minute_deltas` for the host and mutate routes'
+    {base3-minute-key: delta} dict: where every key has 16 digits 0-2,
+    as sorted minutes; otherwise (a short or long key, a negative
+    minute's "-") the dict route."""
+    if isinstance(tree, MerkleText) and all(
+            len(key) == MERKLE_KEY_LENGTH and not key.strip("012") for key in prefix_xors):
+        import numpy as np
+
+        pairs = sorted((int(key, 3), d) for key, d in prefix_xors.items())
+        minutes = np.fromiter((m for m, _ in pairs), np.int64, count=len(pairs))
+        deltas = np.fromiter((d for _, d in pairs), np.int32, count=len(pairs))
+        return fold_minute_deltas(tree, MinuteDeltas(minutes, deltas))
+    return _fold_parsed(tree, prefix_xors)
 
 
 def _child_keys(tree: dict):
@@ -369,24 +331,17 @@ def _ordered(tree: dict) -> dict:
     return out
 
 
-def merkle_tree_to_string(tree: dict) -> str:
+def merkle_tree_to_string(tree: Mapping) -> str:
     """types.ts:80-81 — JSON with JS property order and no whitespace.
-    An `OrderedTree` is in that order already; any other dict is
-    rebuilt in it first."""
-    if not isinstance(tree, OrderedTree):
-        tree = _ordered(tree)
-    # Either way the tree was built by this module's own recursion, so
-    # it has no cycle for the encoder to look for (a third of its time).
-    return json.dumps(tree, separators=(",", ":"), check_circular=False)
+    A `MerkleText` is that text already; any other tree is rebuilt in
+    that order and dumped."""
+    if isinstance(tree, MerkleText):
+        return tree.text
+    # `_ordered` built the dict by its own recursion, so it has no cycle
+    # for the encoder to look for (a third of its time).
+    return json.dumps(_ordered(tree), separators=(",", ":"), check_circular=False)
 
 
 def merkle_tree_from_string(s: str) -> dict:
     """types.ts:83-84."""
     return json.loads(s)
-
-
-def ordered_tree_from_string(s: str) -> OrderedTree:
-    """`merkle_tree_from_string` for a tree that will be folded and
-    stored again (the client's `__clock`): ordered once here, whoever
-    wrote the text, and marked."""
-    return OrderedTree(_ordered(json.loads(s)))

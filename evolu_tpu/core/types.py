@@ -10,7 +10,7 @@ orders all writes (types.ts:90-99).
 from __future__ import annotations
 
 from dataclasses import dataclass
-from typing import Optional, Union
+from typing import Mapping, Optional, Union
 
 CrdtValue = Union[None, str, int, float]
 
@@ -59,7 +59,7 @@ class CrdtClock:
     """Per-replica clock state persisted in __clock (types.ts:101-104)."""
 
     timestamp: Timestamp
-    merkle_tree: dict
+    merkle_tree: Mapping  # a dict, or core.merkle.MerkleText
 
 
 # --- Errors (types.ts:315-399). Raised as exceptions; the runtime

@@ -22,7 +22,12 @@ import threading
 import time
 from typing import Callable, Dict, List, Optional, Sequence
 
-from evolu_tpu.core.merkle import diff_merkle_trees, merkle_tree_from_string
+from evolu_tpu.core.merkle import (
+    MerkleText,
+    diff_merkle_trees,
+    merkle_tree_from_string,
+    merkle_tree_to_string,
+)
 from evolu_tpu.core.timestamp import (
     receive_timestamps_batch,
     receive_timestamps_batch_packed,
@@ -45,7 +50,7 @@ from evolu_tpu.storage.apply import (
 )
 from evolu_tpu.storage.changes import ChangedSet
 from evolu_tpu.storage.deps import query_dependencies
-from evolu_tpu.storage.clock import TreeText, read_clock, tree_text, update_clock
+from evolu_tpu.storage.clock import read_clock, update_clock
 from evolu_tpu.storage.schema import delete_all_tables, init_db_model, update_db_schema
 from evolu_tpu.storage.sqlite import PySqliteDatabase
 from evolu_tpu.sync.protocol import assert_wire_encodable
@@ -247,11 +252,6 @@ class DbWorker:
         self._change_log: List[tuple] = []
         self._change_seq: int = 0
         self._planner = select_planner(self.config, self.db)
-        # The clock's tree and its JSON text, as last read or written
-        # (storage/clock.py): spares the parse of an unchanged `__clock`,
-        # the second dump of a tree just stored, and the parse of a
-        # relay's tree that is the client's own, byte for byte.
-        self._tree_text = TreeText()
         self._staged_effects: List = []
         self._staged_cache: Dict[str, List[dict]] = {}
         self._staged_raw: Dict[str, tuple] = {}
@@ -592,7 +592,7 @@ class DbWorker:
             "client.mutate", attrs={"messages": len(command.messages)}
         )
         with mspan, trace.use(mspan.context):
-            clock = read_clock(self.db, self._tree_text)
+            clock = read_clock(self.db)
             t = clock.timestamp
             now = self.now()
             stamped: List[CrdtMessage] = []
@@ -605,7 +605,7 @@ class DbWorker:
                                   planner=self._planner,
                                   changes=self._staged_changes_or_none())
             next_clock = CrdtClock(t, tree)
-            tree_string = update_clock(self.db, next_clock, self._tree_text)
+            tree_string = update_clock(self.db, next_clock)
             self._push(
                 msg.SyncRequestInput(
                     messages=tuple(stamped),
@@ -619,7 +619,7 @@ class DbWorker:
 
     def _receive(self, command: msg.Receive) -> None:
         """receive.ts:144-199: merge remote messages, then anti-entropy."""
-        clock = read_clock(self.db, self._tree_text)
+        clock = read_clock(self.db)
         if len(command.messages):
             # HLC merge folded over every remote timestamp
             # (receive.ts:45-66) — the reduced vectorized fold, with one
@@ -701,7 +701,7 @@ class DbWorker:
                     # chunks committed instead of them staying hidden
                     # until some later command emits.
                     nonlocal receive_staged
-                    update_clock(self.db, CrdtClock(t, tree_so_far), self._tree_text)
+                    update_clock(self.db, CrdtClock(t, tree_so_far))
                     if not receive_staged:
                         receive_staged = True
                         self._emit(msg.OnReceive())
@@ -716,7 +716,7 @@ class DbWorker:
                 anatomy.seam("commit")
                 if deferred:
                     tree = self._apply_deferred(tree, deferred)
-                    update_clock(self.db, CrdtClock(t, tree), self._tree_text)
+                    update_clock(self.db, CrdtClock(t, tree))
                 clock = CrdtClock(t, tree)
             else:
                 tree = apply_messages(
@@ -727,18 +727,19 @@ class DbWorker:
                 if deferred:
                     tree = self._apply_deferred(tree, deferred)
                 clock = CrdtClock(t, tree)
-                update_clock(self.db, clock, self._tree_text)
+                update_clock(self.db, clock)
                 self._emit(msg.OnReceive())
 
         with anatomy.part("tree_diff"):
             # Equal texts are equal trees: nothing to parse, no diff.
-            own_text = self._tree_text.text_of(clock.merkle_tree)
+            tree = clock.merkle_tree
+            own_text = tree.text if isinstance(tree, MerkleText) else None
             same = own_text is not None and own_text == command.merkle_tree
             if same:
                 diff = None
             else:
                 server_tree = merkle_tree_from_string(command.merkle_tree)
-                diff = diff_merkle_trees(server_tree, clock.merkle_tree)
+                diff = diff_merkle_trees(server_tree, tree)
         metrics.inc_many((
             ("evolu_merkle_tree_bytes_total", len(command.merkle_tree), {"leg": "remote"}),
             ("evolu_merkle_tree_text_checks_total", int(own_text is not None),
@@ -766,7 +767,7 @@ class DbWorker:
             msg.SyncRequestInput(
                 messages=resend,
                 clock_timestamp=timestamp_to_string(clock.timestamp),
-                merkle_tree=tree_text(clock.merkle_tree, self._tree_text),
+                merkle_tree=merkle_tree_to_string(clock.merkle_tree),
                 owner=self.owner,
                 previous_diff=diff,
             )
@@ -1145,12 +1146,12 @@ class DbWorker:
             self._query(command.queries, gated=False)
         if self.sync_lock.is_pending_or_held():
             return
-        clock = read_clock(self.db, self._tree_text)
+        clock = read_clock(self.db)
         self._push(
             msg.SyncRequestInput(
                 messages=(),
                 clock_timestamp=timestamp_to_string(clock.timestamp),
-                merkle_tree=tree_text(clock.merkle_tree, self._tree_text),
+                merkle_tree=merkle_tree_to_string(clock.merkle_tree),
                 owner=self.owner,
             )
         )

@@ -34,6 +34,7 @@ from __future__ import annotations
 from typing import Dict, Iterable, List, Optional, Sequence, Tuple
 
 from evolu_tpu.core.merkle import (
+    MerkleText,
     MinuteDeltas,
     fold_key_deltas,
     fold_minute_deltas,
@@ -65,17 +66,20 @@ def _fold_tree(merkle_tree: dict, deltas) -> dict:
     fold for every apply route, timed as the second half of a tiled
     Receive's `recv_tree_fold` (the first is the delta decode in
     `ops.merkle_ops`) and counted. The device planners hand over
-    `MinuteDeltas` (sorted minutes, folded a distinct node at a time),
-    the host routes a {base3-minute-key: delta} dict (a path a minute);
-    either way a tree that came in key order leaves in key order."""
+    `MinuteDeltas` (sorted minutes), the host routes a
+    {base3-minute-key: delta} dict; either way a tree that is text
+    folds as text (`route=text`), and what the native pass refuses, or
+    a tree that is not text, takes the dict route (`route=dict`)."""
     with anatomy.part("tree_fold"):
         if isinstance(deltas, MinuteDeltas):
             tree, nodes = fold_minute_deltas(merkle_tree, deltas)
         else:
             tree, nodes = fold_key_deltas(merkle_tree, deltas)
+    route = "text" if isinstance(tree, MerkleText) else "dict"
     metrics.inc_many((("evolu_merkle_fold_minutes_total", len(deltas), {}),
                       ("evolu_merkle_fold_calls_total", 1, {}),
-                      ("evolu_merkle_fold_nodes_total", nodes, {})))
+                      ("evolu_merkle_fold_nodes_total", nodes, {}),
+                      ("evolu_merkle_fold_route_total", 1, {"route": route})))
     return tree
 
 

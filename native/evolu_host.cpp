@@ -34,6 +34,8 @@
 #include <string>
 #include <vector>
 
+#include <sys/mman.h>
+
 #include "pyabi.h"
 #include "wire.h"
 
@@ -939,6 +941,282 @@ int eh_pack_requests(PyObj *groups, int64_t n_groups, const int64_t *group_sizes
       return 1;
     }
   }
+  return 0;
+}
+
+// --- the client's Merkle tree, folded as its text (CPython ABI, pyabi.h) ---
+//
+// `core/merkle.py::fold_minute_deltas` for a tree held as its text: the
+// canonical upstream JSON (types.ts:80-84; `{"0":…,"1":…,"2":…,"hash":n}`,
+// child keys ascending, then "hash", no whitespace) in, the text of the
+// tree with a batch's per-minute XOR deltas folded in out, byte for byte
+// what `merkle_tree_to_string(apply_prefix_xors(parsed, deltas))` makes.
+// A year's tree is over a megabyte of text and a batch touches a few
+// percent of it: the walk copies every subtree the batch does not enter
+// as one byte range, rewrites the "hash" of each node on the batch's
+// paths, and writes the nodes the tree lacks in key order. No node
+// becomes an object and nothing is serialized again.
+//
+// `minutes` are the batch's n sorted distinct JS-int32 minutes, all with
+// a 16-digit base-3 key ([3^15, 3^16): 1997-04-13 to 2051-11-04), and
+// `deltas` their signed int32 XOR deltas. A node's delta is the XOR of
+// the minutes under it; a hash the text lacks is the delta (JS
+// `undefined ^ d`), and a zero delta still writes its path.
+//
+// Called through ctypes.PyDLL (core/merkle.py): the GIL is held to read
+// `text` and to make the result, and dropped for the walk, which reads
+// only the str's cached UTF-8 bytes (the caller holds the str). The
+// text is written into a buffer the calling thread keeps from call to
+// call (`TextBuffer`).
+//
+// Returns 0: `out` (a list) gains the new text as one str and
+// *out_nodes the nodes written anew (the root and each distinct prefix
+// of the batch's keys). 2 = refusal, and nothing appended: a minute
+// outside the range or out of order, or a text that is not canonical
+// (another key or key order, whitespace, a number that is not a plain
+// int32, a node deeper than any int32 minute's key); the caller then
+// takes the dict route, which writes the same bytes. 1 = bad arguments
+// or a CPython error (cleared).
+
+namespace {
+
+constexpr int kKeyDigits = 16;
+// An int32 minute has at most 20 base-3 digits.
+constexpr int kMaxTreeDepth = 20;
+constexpr int64_t kPow3[kKeyDigits + 1] = {
+    1, 3, 9, 27, 81, 243, 729, 2187, 6561, 19683, 59049, 177147, 531441,
+    1594323, 4782969, 14348907, 43046721};
+constexpr char kHashKey[] = "\"hash\":";  // 7 bytes
+// At most what the fold adds to a node of the batch's paths: a new
+// `,"0":{"hash":-2147483648}` (25 bytes), or a hash rewritten longer.
+constexpr int64_t kNodeGrowth = 32;
+
+// A canonical JSON int32 at s[p] (`-?(0|[1-9][0-9]*)`, no "-0") →
+// the position after it, or -1. `s` is NUL-terminated at n.
+int64_t parse_int32(const char *s, int64_t n, int64_t p, int32_t *out) {
+  bool neg = s[p] == '-';
+  if (neg) ++p;
+  if (s[p] < '0' || s[p] > '9') return -1;
+  if (s[p] == '0') {
+    if (neg || (s[p + 1] >= '0' && s[p + 1] <= '9')) return -1;
+    *out = 0;
+    return p + 1;
+  }
+  int64_t v = 0;
+  int digits = 0;
+  while (p < n && s[p] >= '0' && s[p] <= '9') {
+    if (++digits > 10) return -1;
+    v = v * 10 + (s[p++] - '0');
+  }
+  v = neg ? -v : v;
+  if (v < INT32_MIN || v > INT32_MAX) return -1;
+  *out = int32_t(v);
+  return p;
+}
+
+// JSON.stringify of an int32.
+char *write_int32(char *w, int32_t v) {
+  uint32_t u = v < 0 ? 0u - uint32_t(v) : uint32_t(v);
+  if (v < 0) *w++ = '-';
+  char digits[10];
+  int k = 0;
+  do {
+    digits[k++] = char('0' + u % 10);
+    u /= 10;
+  } while (u);
+  while (k) *w++ = digits[--k];
+  return w;
+}
+
+// A child key `"d":` (d in 0-2) at s[p] → d, else -1.
+inline int child_key(const char *s, int64_t n, int64_t p) {
+  if (n - p < 5 || s[p] != '"' || s[p + 1] < '0' || s[p + 1] > '2' || s[p + 2] != '"' ||
+      s[p + 3] != ':')
+    return -1;
+  return s[p + 1] - '0';
+}
+
+inline bool hash_key(const char *s, int64_t n, int64_t p) {
+  return n - p >= 8 && memcmp(s + p, kHashKey, 7) == 0;
+}
+
+// The end (one past its '}') of the canonical node that starts at s[p],
+// at depth `depth`, or -1. Returns where it ends, so that no caller
+// scans a subtree twice.
+int64_t skip_node(const char *s, int64_t n, int64_t p, int depth) {
+  if (depth > kMaxTreeDepth || s[p] != '{') return -1;
+  if (s[++p] == '}') return p + 1;
+  for (int last = -1;;) {
+    int d = child_key(s, n, p);
+    if (d > last) {
+      last = d;
+      if ((p = skip_node(s, n, p + 4, depth + 1)) < 0) return -1;
+    } else if (d < 0 && hash_key(s, n, p)) {
+      int32_t h;
+      p = parse_int32(s, n, p + 7, &h);
+      return p >= 0 && s[p] == '}' ? p + 1 : -1;
+    } else {
+      return -1;
+    }
+    if (s[p] == '}') return p + 1;
+    if (s[p++] != ',') return -1;
+  }
+}
+
+struct TextFold {
+  const char *s;  // the old text, NUL-terminated at n
+  int64_t n;
+  const int64_t *minutes;
+  const int32_t *deltas;
+  char *w;  // the write cursor
+  int64_t nodes = 0;
+};
+
+// Writes the node at depth `level` whose subtree holds the batch's
+// minutes [lo, hi): the old text's node at s[p] with them folded in, or
+// (p < 0) a new one. → the end of the old node (0 for a new one), or -1
+// to refuse; *delta ← the XOR of the minutes' deltas.
+int64_t fold_node(TextFold &f, int64_t p, int level, int64_t lo, int64_t hi, int32_t *delta) {
+  ++f.nodes;
+  // Child d holds the minutes [at[d], at[d + 1]); a minute has none.
+  int64_t at[4] = {lo, lo, lo, lo};
+  if (level < kKeyDigits) {
+    const int64_t unit = kPow3[kKeyDigits - 1 - level];
+    int64_t i = lo;
+    for (int d = 0; d < 3; ++d) {
+      while (i < hi && f.minutes[i] / unit % 3 == d) ++i;
+      at[d + 1] = i;
+    }
+  }
+  int32_t x = level < kKeyDigits ? 0 : f.deltas[lo];
+  int next = 0;  // batch children below `next` are written
+  bool first = true;
+  auto comma = [&] {
+    if (!first) *f.w++ = ',';
+    first = false;
+  };
+  auto new_children_below = [&](int end) {
+    for (; next < end; ++next) {
+      if (at[next] == at[next + 1]) continue;
+      comma();
+      memcpy(f.w, "\"0\":", 4);
+      f.w[1] = char('0' + next);
+      f.w += 4;
+      int32_t d;
+      fold_node(f, -1, level + 1, at[next], at[next + 1], &d);
+      x ^= d;
+    }
+  };
+  *f.w++ = '{';
+  bool had_hash = false;
+  int32_t old_hash = 0;
+  if (p >= 0) {
+    const char *s = f.s;
+    const int64_t n = f.n;
+    if (s[p] != '{') return -1;
+    if (s[++p] != '}') {
+      for (int last = -1;;) {
+        int d = child_key(s, n, p);
+        if (d > last) {
+          last = d;
+          new_children_below(d);
+          comma();
+          memcpy(f.w, s + p, 4);
+          f.w += 4;
+          if (at[d] < at[d + 1]) {
+            int32_t cd;
+            if ((p = fold_node(f, p + 4, level + 1, at[d], at[d + 1], &cd)) < 0) return -1;
+            x ^= cd;
+          } else {
+            int64_t end = skip_node(s, n, p + 4, level + 1);
+            if (end < 0) return -1;
+            memcpy(f.w, s + p + 4, size_t(end - p - 4));
+            f.w += end - p - 4;
+            p = end;
+          }
+          next = d + 1;
+        } else if (d < 0 && hash_key(s, n, p)) {
+          if ((p = parse_int32(s, n, p + 7, &old_hash)) < 0 || s[p] != '}') return -1;
+          had_hash = true;
+          break;
+        } else {
+          return -1;
+        }
+        if (s[p] == '}') break;
+        if (s[p++] != ',') return -1;
+      }
+    }
+    ++p;  // past the node's '}'
+  }
+  new_children_below(3);
+  comma();
+  memcpy(f.w, kHashKey, 7);
+  f.w = write_int32(f.w + 7, had_hash ? old_hash ^ x : x);
+  *f.w++ = '}';
+  *delta = x;
+  return p < 0 ? 0 : p;
+}
+
+// The walk's output buffer: the calling thread's, kept from call to
+// call and mapped outside malloc, so that only a thread's first fold
+// touches fresh pages and the allocator's thresholds never see it.
+struct TextBuffer {
+  char *data = nullptr;
+  size_t size = 0;
+  ~TextBuffer() {
+    if (data) munmap(data, size);
+  }
+  bool reserve(size_t want) {
+    if (want <= size) return true;
+    if (data) munmap(data, size);
+    size = (want + 0xFFFFF) & ~size_t(0xFFFFF);  // whole MiB
+    void *p = mmap(nullptr, size, PROT_READ | PROT_WRITE, MAP_PRIVATE | MAP_ANONYMOUS, -1, 0);
+    data = p == MAP_FAILED ? nullptr : static_cast<char *>(p);
+    if (!data) size = 0;
+    return data != nullptr;
+  }
+};
+
+}  // namespace
+
+int eh_fold_tree_text(PyObj *text, const int64_t *minutes, const int32_t *deltas, int64_t n,
+                      int64_t *out_nodes, PyObj *out) {
+  if (!out || n < 0 || (n > 0 && (!minutes || !deltas))) return 1;
+  const char *s;
+  long long len;
+  if (!py_str(text, &s, &len)) return 1;
+  *out_nodes = 0;
+  for (int64_t i = 0; i < n; ++i)
+    if (minutes[i] < kPow3[kKeyDigits - 1] || minutes[i] >= kPow3[kKeyDigits] ||
+        (i > 0 && minutes[i] <= minutes[i - 1]))
+      return 2;
+  if (n == 0) {  // the tree as it is, once it is known to be canonical
+    if (len == 0 || skip_node(s, len, 0, 0) != len) return 2;
+    return PyList_Append(out, text) == 0 ? 0 : (PyErr_Clear(), 1);
+  }
+  // The paths' nodes: the root and at most 16 a minute.
+  static thread_local TextBuffer buffer;
+  if (!buffer.reserve(size_t(len + (kKeyDigits * n + 1) * kNodeGrowth + 16))) return 1;
+  TextFold f{s, len, minutes, deltas, buffer.data};
+  int64_t end;
+  {
+    GilScope unlocked;
+    int32_t root_delta;
+    end = len == 0 ? -1 : fold_node(f, 0, 0, 0, n, &root_delta);
+  }
+  if (end != len) return 2;
+  PyObj *folded = PyUnicode_FromStringAndSize(buffer.data, f.w - buffer.data);
+  if (!folded) {
+    PyErr_Clear();
+    return 1;
+  }
+  int rc = PyList_Append(out, folded);
+  Py_DecRef(folded);
+  if (rc != 0) {
+    PyErr_Clear();
+    return 1;
+  }
+  *out_nodes = f.nodes;
   return 0;
 }
 

@@ -25,6 +25,7 @@ struct PyObj {
 PyObj *PySequence_GetItem(PyObj *, long long);
 PyObj *PyObject_GetAttr(PyObj *, PyObj *);
 PyObj *PyUnicode_FromString(const char *);
+PyObj *PyUnicode_FromStringAndSize(const char *, long long);
 // Interned: the identity a type's attribute cache and an instance's keys
 // compare first, where a fresh str walks the MRO and compares bytes.
 PyObj *PyUnicode_InternFromString(const char *);

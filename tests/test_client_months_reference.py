@@ -8,12 +8,14 @@ the reference's, which is also the relay's in that response: the client
 never asks to sync again. The one-minute shape is `client-todo`'s own
 generator: the same code, a tree of one leaf.
 
-ISSUE 34: the worker keeps the clock's tree beside its text between
-commands and compares texts before it parses. Held here, through a
+The worker compares texts before it parses. Held here, through a
 `DbWorker`: a relay tree that differs in one minute still takes the
-parse and the walk; a rollback, a reset or a restore of the owner can
-only miss (no stale tree is ever folded into); a `Send` pushes the text
-`update_clock` wrote.
+parse and the walk; after a rollback, a reset or a restore of the owner
+the next fold starts from what `__clock` holds; a `Send` pushes the text
+`update_clock` wrote. The tree is carried as its text (`MerkleText`)
+and every fold goes text to text
+(`evolu_merkle_fold_route_total{route=text}`), over a year's history
+too, and on a `Send`; nothing is dumped.
 """
 
 import itertools
@@ -38,6 +40,7 @@ needs_native = pytest.mark.skipif(
 
 MESSAGES, ROWS, NODES, RESPONSES = 4000, 50, 8, 4
 DAYS, SESSIONS, SESSION_MINUTES = 30, 3, 30
+YEAR = 365  # days: the configuration's history, at this test's message count
 NOW, STEP = 1_731_622_400_000, 1000  # the configuration's: after the history's end
 
 
@@ -54,16 +57,18 @@ def _history(shape: str, seed: int) -> list:
     if shape == "one-minute":
         return gen_client.build_messages(MESSAGES, seed, ROWS, NODES)
     return gen_history.build_messages(MESSAGES, seed, ROWS, NODES,
-                                      DAYS, SESSIONS, SESSION_MINUTES)
+                                      YEAR if shape == "year" else DAYS,
+                                      SESSIONS, SESSION_MINUTES)
 
 
 @needs_native
-@pytest.mark.parametrize("shape", ["one-minute", "months"])
+@pytest.mark.parametrize("shape", ["one-minute", "months", "year"])
 @pytest.mark.parametrize("seed", [5, 2**31 + 33])
 def test_worker_equals_the_reference_after_each_receive(seed, shape):
     messages = _history(shape, seed)
     wires = gen_history.build_responses(messages, RESPONSES, gen_history.MNEMONIC)
     minutes0 = metrics.get_counter("evolu_merkle_fold_minutes_total")
+    routes0 = _routes()
     outputs, syncs = [], []
     db = native.open_database(backend="native")
     worker = DbWorker(db, Config(backend="tpu"), on_output=outputs.append,
@@ -100,13 +105,20 @@ def test_worker_equals_the_reference_after_each_receive(seed, shape):
     distinct = sum(len({m.timestamp[:16] for m in b}) for b in batches)
     assert metrics.get_counter("evolu_merkle_fold_minutes_total") - minutes0 == distinct
     assert distinct == RESPONSES if shape == "one-minute" else distinct > 2000
+    # Every fold went text to text: one a Receive, none by the dict route.
+    assert _routes() == {"text": routes0["text"] + RESPONSES, "dict": routes0["dict"]}
+
+
+def _routes():
+    return {route: metrics.get_counter("evolu_merkle_fold_route_total", route=route)
+            for route in ("text", "dict")}
 
 
 class _Restoring:
-    """A worker on the months history beside its reference twin."""
+    """A worker on the months (or a year's) history beside its reference twin."""
 
-    def __init__(self, seed: int = 5):
-        self.messages = _history("months", seed)
+    def __init__(self, seed: int = 5, shape: str = "months"):
+        self.messages = _history(shape, seed)
         self.batches = gen_history.split_responses(self.messages, RESPONSES)
         self.wires = gen_history.build_responses(self.messages, RESPONSES, gen_history.MNEMONIC)
         self.outputs, self.syncs = [], []
@@ -181,10 +193,8 @@ def test_equal_texts_spare_both_parses_and_a_differing_minute_takes_the_walk(res
     before = _text_counts()
     r.receive(0, r.relay_tree(0))
     assert r.stored() == reference.tree_to_string(r.twin.tree) and r.syncs == []
-    # The first load compared "{}" with an empty slot and parsed; the
-    # relay's text was the client's own.
-    assert _moved(before) == {("checks", "load"): 1, ("checks", "remote"): 1,
-                              ("hits", "remote"): 1}
+    # The relay's text was the client's own; the load compared nothing.
+    assert _moved(before) == {("checks", "remote"): 1, ("hits", "remote"): 1}
     # The relay is one minute ahead: the comparison misses, the tree is
     # parsed and walked, and the request carries that minute and the
     # client's own text.
@@ -193,8 +203,7 @@ def test_equal_texts_spare_both_parses_and_a_differing_minute_takes_the_walk(res
     ahead, minute = _one_minute_ahead(tree1)
     r.receive(1, ahead)
     assert r.errors() == [] and r.stored() == tree1
-    assert _moved(before) == {("checks", "load"): 1, ("hits", "load"): 1,
-                              ("checks", "remote"): 1}
+    assert _moved(before) == {("checks", "remote"): 1}
     (request,) = r.syncs
     assert request.previous_diff == minute and request.merkle_tree == tree1
     assert [m.timestamp for m in request.messages] == sorted(
@@ -210,17 +219,16 @@ def test_a_rolled_back_receive_leaves_no_stale_tree(restoring):
     tree1 = r.relay_tree(1)
     ahead, minute = _one_minute_ahead(tree1)
     # The same diff twice: SyncError AFTER update_clock wrote the new
-    # tree, so the whole Receive rolls back and the slot is ahead of
-    # `__clock`.
+    # tree, so the whole Receive rolls back.
     r.receive(1, ahead, previous_diff=minute)
     assert [type(e).__name__ for e in r.errors()] == ["SyncError"]
     assert r.stored() == tree0
     before = _text_counts()
     r.receive(1, tree1)
-    # The load compared, missed and parsed `__clock`; folding batch 1
-    # into the remembered tree instead would XOR it in twice.
-    assert _moved(before) == {("checks", "load"): 1, ("checks", "remote"): 1,
-                              ("hits", "remote"): 1}
+    # The fold started from `__clock` as it was rolled back to; folding
+    # batch 1 into the tree the rolled-back Receive made would XOR it in
+    # twice.
+    assert _moved(before) == {("checks", "remote"): 1, ("hits", "remote"): 1}
     assert r.stored() == tree1 and r.syncs == []
     assert len(r.errors()) == 1
 
@@ -247,33 +255,52 @@ def test_a_reset_or_restored_owner_starts_from_its_own_clock(restoring, wipe):
     before = _text_counts()
     r.receive(0, tree0)
     assert r.errors() == [] and r.stored() == tree0 and r.syncs == []
-    assert _moved(before) == {("checks", "load"): 1, ("checks", "remote"): 1,
-                              ("hits", "remote"): 1}
+    assert _moved(before) == {("checks", "remote"): 1, ("hits", "remote"): 1}
+
+
+@pytest.fixture
+def restored_year():
+    r = _Restoring(shape="year")
+    for k in range(RESPONSES):
+        r.receive(k, r.relay_tree(k))
+    yield r
+    r.close()
 
 
 @needs_native
-def test_a_send_pushes_the_text_update_clock_wrote(restoring, monkeypatch):
-    from evolu_tpu.storage import clock as clock_mod
+def test_a_send_pushes_the_text_update_clock_wrote(restored_year, monkeypatch):
+    import json
 
-    r = restoring
-    r.receive(0, r.relay_tree(0))
-    dumps = []
-    real = clock_mod.merkle_tree_to_string
-    monkeypatch.setattr(clock_mod, "merkle_tree_to_string",
-                        lambda tree: dumps.append(1) or real(tree))
+    from evolu_tpu.core.merkle import (
+        insert_into_merkle_tree, merkle_tree_from_string, merkle_tree_to_string)
+    from evolu_tpu.core.timestamp import timestamp_from_string
+
+    r = restored_year  # a year's tree: thousands of minutes
+    tree0 = r.stored()
+    assert len(tree0) > 100_000 and r.errors() == [] and r.syncs == []
+    calls = []
+    for name in ("dumps", "loads"):
+        real = getattr(json, name)
+        monkeypatch.setattr(json, name, lambda *a, real=real, name=name, **k:
+                            calls.append(name) or real(*a, **k))
+    routes0 = _routes()
     r.worker.post(rmsg.Send((rmsg.NewCrdtMessage("todo", "row1", "title", "mine"),)))
     r.worker.flush()
     assert r.errors() == []
     (request,) = r.syncs
     assert request.merkle_tree == r.stored() and len(request.messages) == 1
-    assert dumps == [1]  # serialized once, for the UPDATE; the push reuses it
-    # A Sync of the unchanged clock sends the remembered text: no dump, no parse.
+    assert calls == []  # the mutate folded text to text: no parse, no dump
+    assert _routes() == {"text": routes0["text"] + 1, "dict": routes0["dict"]}
+    monkeypatch.undo()
+    want = insert_into_merkle_tree(timestamp_from_string(request.messages[0].timestamp),
+                                   merkle_tree_from_string(tree0))
+    assert r.stored() == merkle_tree_to_string(want)
+    # A Sync of the unchanged clock sends `__clock`'s text as it is.
     before = _text_counts()
     r.worker.post(rmsg.Sync())
     r.worker.flush()
     assert r.syncs[-1].merkle_tree == r.stored() and r.syncs[-1].messages == ()
-    assert dumps == [1]
-    assert _moved(before) == {("checks", "load"): 1, ("hits", "load"): 1}
+    assert _moved(before) == {}
 
 
 @pytest.mark.parametrize("seed", [5, 2**31 + 33])

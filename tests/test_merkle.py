@@ -126,25 +126,25 @@ def test_apply_prefix_xors_equivalence():
     assert batched == seq
 
 
-# --- the client's fold: a distinct node once, key order kept (ISSUE 34) ---
+# --- the client's fold: text to text, the dict route beside it ---
 
 import copy  # noqa: E402
+import functools  # noqa: E402
 
 import numpy as np  # noqa: E402
 import pytest  # noqa: E402
 
-from evolu_tpu.core import merkle  # noqa: E402
 from evolu_tpu.core.merkle import (  # noqa: E402
+    MerkleText,
     MinuteDeltas,
-    OrderedTree,
     fold_key_deltas,
     fold_minute_deltas,
-    ordered_tree_from_string,
 )
 from evolu_tpu.ops.merkle_ops import decode_minute_delta_arrays  # noqa: E402
 
 MINUTE = 60_000
 Y2023 = 1_700_000_040_000  # minute-aligned, a 16-digit key
+FULL_KEYS = (3 ** 15 * MINUTE, 3 ** 16 * MINUTE)  # 1997-04-13 .. 2051-11-04
 
 
 def _js_minute(millis: int) -> int:
@@ -158,10 +158,10 @@ def _stamps(minutes, rng, per_minute=(1, 3)):
             for m in minutes for _ in range(rng.randint(*per_minute))]
 
 
-def _sessions(days, rng):
+def _sessions(days, rng, first_day=0):
     """`days` days of three 30-minute sessions: days x 90 distinct minutes."""
     return [Y2023 + (d * 1440 + s * 480 + start + k) * MINUTE
-            for d in range(days) for s in range(3)
+            for d in range(first_day, first_day + days) for s in range(3)
             for start in [rng.randrange(440)] for k in range(30)]
 
 
@@ -186,63 +186,142 @@ def _keyed(batch):
     return deltas
 
 
+def _fuzz_tree(rng):
+    """A random tree in canonical form, as any writer of `__clock` could
+    leave it: 16-digit minutes and a few 17-digit ones below them, hashes
+    anywhere in int32, some nodes without a hash, some empty children."""
+    lo, hi = FULL_KEYS
+    base = rng.randrange(lo, hi - 400_000 * MINUTE) // MINUTE
+    keys = {minutes_base3((base + rng.randrange(400_000)) * MINUTE): rng.choice(
+        [0, -2 ** 31, 2 ** 31 - 1, rng.randrange(-2 ** 31, 2 ** 31)]) for _ in range(rng.randrange(1, 400))}
+    tree = apply_prefix_xors({}, keys)
+    leaves = sorted(keys)
+    for key in rng.sample(leaves, min(3, len(leaves))):  # a 17-digit key below a 16-digit one
+        node = tree
+        for c in key:
+            node = node[c]
+        node[rng.choice("012")] = {"hash": rng.randrange(-2 ** 31, 2 ** 31)}
+
+    def walk(node, depth):
+        if depth and rng.random() < 0.05:
+            node.pop("hash", None)
+        for c in "012":
+            if c in node:
+                walk(node[c], depth + 1)
+            elif depth < 16 and rng.random() < 0.01:
+                node[c] = {}
+    walk(tree, 0)
+    return tree, base
+
+
 def _fold_case(name):
-    """→ (initial tree, batches of Timestamps, the result is marked)."""
+    """→ (initial tree, its text as the text route starts from it,
+    batches of Timestamps, whether the text route's first fold is
+    refused: the text is not canonical or a minute has no 16-digit key)."""
     rng = random.Random(34)
+    if name == "empty-batch":
+        tree = apply_prefix_xors({}, _keyed(_stamps(_sessions(1, rng), rng)))
+        return tree, merkle_tree_to_string(tree), [[]], False
     if name == "year-in-four-batches":
         minutes = _sessions(100, rng)  # 9,000 minutes
         assert len(set(minutes)) >= 8000
         stamps = _stamps(minutes, rng)
         q = len(stamps) // 4
-        return {}, [stamps[:q], stamps[q:2 * q], stamps[2 * q:3 * q], stamps[3 * q:]], True
+        return {}, "{}", [stamps[:q], stamps[q:2 * q], stamps[2 * q:3 * q], stamps[3 * q:]], False
+    if name == "8212-minutes-onto-a-year":  # a restore's last Receive in client-todo-months
+        minutes = _sessions(365, rng)
+        q = len(minutes) * 3 // 4
+        tree = {}
+        for t in _stamps(minutes[:q], rng, (1, 1)):
+            tree = insert_into_merkle_tree(t, tree)
+        batch = _stamps(minutes[q - 2:], rng, (1, 1))
+        assert len({minutes_base3(t.millis) for t in batch}) >= 8212
+        return tree, merkle_tree_to_string(tree), [batch], False
     if name == "one-minute":
-        return {}, [_stamps([Y2023], rng, (3, 3))], True
+        return {}, "{}", [_stamps([Y2023], rng, (3, 3))], False
     if name == "straddle":  # the last minute of a batch is the first of the next
         minutes = _sessions(1, rng)
         a, b = _stamps(minutes[:46], rng, (2, 2)), _stamps(minutes[45:], rng, (2, 2))
-        return {}, [a, b], True
-    if name == "zero-delta-few":  # the per-minute route
+        return {}, "{}", [a, b], False
+    if name == "zero-delta-one-minute":
         t = Timestamp(Y2023 + 5, 1, "0000000000000001")
-        return {}, [[t, t]], True
-    if name == "zero-delta-many":  # the level pass
+        return {}, "{}", [[t, t]], False
+    if name == "zero-delta-among-many":
         t = Timestamp(Y2023 + 77 * MINUTE + 5, 1, "0000000000000001")
-        return {}, [_stamps(_sessions(1, rng), rng) + [t, t]], True
-    if name == "short-and-long-keys":  # 0, under 3**15, at and over 3**16: the per-minute route
-        lo, hi = 3 ** 15 * MINUTE, 3 ** 16 * MINUTE
+        return {}, "{}", [_stamps(_sessions(1, rng), rng) + [t, t]], False
+    if name == "hash-of-minus-2-to-the-31":
+        stamps = _stamps(_sessions(1, rng), rng)
+        tree = apply_prefix_xors({}, {minutes_base3(stamps[0].millis): -2 ** 31,
+                                      minutes_base3(Y2023 + 3 * 1440 * MINUTE): -2 ** 31})
+        assert '"hash":-2147483648' in merkle_tree_to_string(tree)
+        return tree, merkle_tree_to_string(tree), [stamps, _stamps([Y2023 + 3 * 1440 * MINUTE], rng)], False
+    if name == "pre-1997-and-post-2051-minutes":  # 0, under 3**15, at and over 3**16
+        lo, hi = FULL_KEYS
         minutes = [0, 5 * MINUTE, lo - MINUTE, lo, hi - MINUTE, hi, hi + MINUTE]
-        return {}, [_stamps(minutes, rng), _stamps(minutes + _sessions(1, rng), rng)], True
-    if name == "negative-minutes":  # a "-" key: `_ordered` drops it at the dump, so never marked
+        return {}, "{}", [_stamps(minutes, rng), _stamps(minutes + _sessions(1, rng), rng)], True
+    if name == "negative-minutes":  # a "-" key: `_ordered` drops it at the dump
         minutes = [-5 * MINUTE, 2 ** 31 * MINUTE + 2 * MINUTE, Y2023]
-        return {}, [_stamps(minutes, rng), _stamps(minutes + _sessions(1, rng), rng)], False
-    if name == "hand-built-wrong-order":  # "hash" before the children, as insert writes it
-        first = _stamps(_sessions(2, rng), rng)
+        return {}, "{}", [_stamps(minutes, rng), _stamps(minutes + _sessions(1, rng), rng)], True
+    if name in ("text-hash-first", "text-with-whitespace", "text-with-a-dash-key"):
         tree = {}
-        for t in first:
-            tree = insert_into_merkle_tree(t, tree)
-        assert list(tree)[0] == "hash" and not isinstance(tree, OrderedTree)
-        return tree, [_stamps(_sessions(3, rng), rng), _stamps([Y2023], rng)], False
+        for t in _stamps(_sessions(2, rng), rng):
+            tree = insert_into_merkle_tree(t, tree)  # "hash" before the children
+        assert list(tree)[0] == "hash"
+        if name == "text-hash-first":
+            text = json.dumps(tree, separators=(",", ":"))
+        elif name == "text-with-whitespace":
+            text = json.dumps(json.loads(merkle_tree_to_string(tree)))
+        else:
+            tree = {"-": {"hash": 5}, **json.loads(merkle_tree_to_string(tree))}
+            text = json.dumps(tree, separators=(",", ":"))
+        return tree, text, [_stamps(_sessions(3, rng), rng), _stamps([Y2023], rng)], True
+    if name.startswith("fuzz-"):
+        rng = random.Random(int(name[len("fuzz-"):]))
+        tree, base = _fuzz_tree(rng)
+        batches = [_stamps([(base + rng.randrange(400_000)) * MINUTE
+                            for _ in range(rng.randrange(0, 300))], rng) for _ in range(3)]
+        return tree, merkle_tree_to_string(tree), batches, False
     raise AssertionError(name)
 
 
-FOLD_CASES = ["year-in-four-batches", "one-minute", "straddle", "zero-delta-few",
-              "zero-delta-many", "short-and-long-keys", "negative-minutes",
-              "hand-built-wrong-order"]
+FOLD_CASES = ["empty-batch", "year-in-four-batches", "8212-minutes-onto-a-year", "one-minute",
+              "straddle", "zero-delta-one-minute", "zero-delta-among-many",
+              "hash-of-minus-2-to-the-31", "pre-1997-and-post-2051-minutes", "negative-minutes",
+              "text-hash-first", "text-with-whitespace", "text-with-a-dash-key",
+              "fuzz-1", "fuzz-2", "fuzz-3"]
 
 
+@functools.lru_cache(maxsize=None)
+def _insert_texts(case):
+    """`merkle_tree_to_string` of `insert_into_merkle_tree` folded over
+    each batch in turn: the reference, after every batch."""
+    tree, _text, batches, _refused = _fold_case(case)
+    texts = []
+    for batch in batches:
+        for t in batch:
+            tree = insert_into_merkle_tree(t, tree)
+        texts.append(merkle_tree_to_string(tree))
+    return texts
+
+
+@pytest.mark.parametrize("start", ["text", "dict"])
 @pytest.mark.parametrize("route", ["arrays", "keys"])
 @pytest.mark.parametrize("case", FOLD_CASES)
-def test_ordered_fold_equals_insert_text_for_text(case, route):
+def test_ordered_fold_equals_insert_text_for_text(case, route, start):
     """`fold_minute_deltas` over the decoded segments (and `fold_key_deltas`
     over the host routes' dict) against `insert_into_merkle_tree` folded
     over the same timestamps, after every batch, through
-    `merkle_tree_to_string`; a marked result dumps straight to that text."""
-    tree, batches, marked = _fold_case(case)
+    `merkle_tree_to_string`, from the tree's text (the text route: the
+    native pass, or the dict route where it refuses) and from the tree
+    as a dict (the dict route). The nodes counted are the batch's
+    distinct nodes, whichever route wrote them."""
+    tree, text, batches, refused = _fold_case(case)
+    if start == "text":
+        tree = MerkleText(text)
     rng = random.Random(7)
-    want = tree
-    for batch in batches:
-        for t in batch:
-            want = insert_into_merkle_tree(t, want)
-        before = copy.deepcopy(tree)
+    for batch, want in zip(batches, _insert_texts(case)):
+        as_text = isinstance(tree, MerkleText)
+        before = tree.text if as_text else copy.deepcopy(tree)
         if route == "arrays":
             deltas = decode_minute_delta_arrays(*_segments(batch, rng))
             assert isinstance(deltas, MinuteDeltas) and deltas == _keyed(batch)
@@ -250,15 +329,15 @@ def test_ordered_fold_equals_insert_text_for_text(case, route):
             folded, nodes = fold_minute_deltas(tree, deltas)
         else:
             folded, nodes = fold_key_deltas(tree, _keyed(batch))
-        assert tree == before  # the input is not mutated
+        assert (tree.text if as_text else tree) == before  # not mutated
         tree = folded
-        text = merkle_tree_to_string(want)
-        assert merkle_tree_to_string(tree) == text
-        assert isinstance(tree, OrderedTree) == marked
-        if marked:
-            assert json.dumps(tree, separators=(",", ":")) == text
-        assert nodes >= len({minutes_base3(t.millis) for t in batch}) + 16
-    if case == "zero-delta-few":
+        assert merkle_tree_to_string(tree) == want
+        assert isinstance(tree, MerkleText) == (start == "text" and not refused)
+        if isinstance(tree, MerkleText):
+            assert json.loads(tree.text) == json.loads(want)
+        keys = {minutes_base3(t.millis) for t in batch}
+        assert nodes == len({key[:i] for key in keys for i in range(len(key) + 1)})
+    if case.startswith("zero-delta-one"):
         node = tree  # the path is there, every hash on it 0
         for c in minutes_base3(Y2023):
             assert node["hash"] == 0
@@ -266,79 +345,62 @@ def test_ordered_fold_equals_insert_text_for_text(case, route):
         assert node == {"hash": 0}
 
 
-def test_level_pass_copies_each_distinct_node_once_and_shares_the_rest():
-    rng = random.Random(3)
-    first, second = _sessions(40, rng)[:1800], [Y2023 + (400 * 1440 + k) * MINUTE for k in range(60)]
-    base, _ = fold_minute_deltas({}, decode_minute_delta_arrays(*_segments(_stamps(first, rng), rng)))
-    batch = _stamps(second, rng)
-    deltas = decode_minute_delta_arrays(*_segments(batch, rng))
-    assert len(deltas) >= merkle.LEVEL_PASS_MIN_MINUTES
-    snapshot = copy.deepcopy(base)
-    tree, nodes = fold_minute_deltas(base, deltas)
-    assert base == snapshot and tree is not base
-    # One copy a distinct node: the root and every distinct prefix of the keys.
-    keys = {minutes_base3(t.millis) for t in batch}
-    assert nodes == 1 + len({k[:i] for k in keys for i in range(1, 17)})
-    assert nodes < 17 * len(keys) / 4  # where the per-minute loop copies 17 a minute
-    # Subtrees the batch does not enter are the same objects, not copies.
-    touched = {k[:i] for k in keys for i in range(17)}
-    shared = 0
-
-    def walk(old, new, prefix):
-        nonlocal shared
-        for c in "012":
-            if c in old:
-                if prefix + c in touched:
-                    assert new[c] is not old[c]
-                    walk(old[c], new[c], prefix + c)
-                else:
-                    assert new[c] is old[c]
-                    shared += 1
-    walk(base, tree, "")
-    assert shared > 0
-
-
-def test_a_few_minutes_take_the_per_minute_loop_and_many_the_level_pass(monkeypatch):
-    calls = []
-    real = merkle._fold_levels
-    monkeypatch.setattr(merkle, "_fold_levels", lambda *a: calls.append(len(a[1])) or real(*a))
-    few = MinuteDeltas(np.arange(3, dtype=np.int64) + Y2023 // MINUTE, np.array([1, 2, 3], np.int32))
-    n = merkle.LEVEL_PASS_MIN_MINUTES
-    many = MinuteDeltas(np.arange(n, dtype=np.int64) + Y2023 // MINUTE, np.arange(n, dtype=np.int32))
-    early = MinuteDeltas(np.arange(n, dtype=np.int64) + 3 ** 15 - 1, np.arange(n, dtype=np.int32))
-    tree, nodes = fold_minute_deltas({}, few)
-    assert calls == [] and nodes == 3 * 17
-    tree, _ = fold_minute_deltas(tree, many)
-    assert calls == [n]
-    tree, _ = fold_minute_deltas(tree, early)  # one 15-digit key among them
-    assert calls == [n]
-    assert isinstance(tree, OrderedTree)
-    assert fold_minute_deltas(tree, MinuteDeltas(few.minutes[:0], few.deltas[:0])) == (tree, 0)
-
-
-def test_a_marked_root_never_reaches_ordered_and_an_unmarked_one_always_does(monkeypatch):
+def test_a_text_is_parsed_only_when_a_node_is_read(monkeypatch):
+    """`MerkleText` reads as the parsed tree, parsed once on first use;
+    a fold, `merkle_tree_to_string` and the text itself never parse."""
     rng = random.Random(9)
     stamps = _stamps(_sessions(2, rng), rng)
-    marked, _ = fold_minute_deltas({}, decode_minute_delta_arrays(*_segments(stamps, rng)))
     plain = {}
     for t in stamps:
         plain = insert_into_merkle_tree(t, plain)
     text = merkle_tree_to_string(plain)
-    seen = []
-    real = merkle._ordered
-    monkeypatch.setattr(merkle, "_ordered", lambda t: seen.append(t) or real(t))
-    assert merkle_tree_to_string(marked) == text and seen == []
-    assert merkle_tree_to_string(plain) == text and seen[0] is plain
-    # A dict that merely looks ordered is not trusted: only the marker is.
-    del seen[:]
-    assert merkle_tree_to_string(dict(marked)) == text and len(seen) > 0
-    # A foreign text is ordered once at the parse, then marked.
-    foreign = json.dumps(plain, separators=(",", ":"))  # "hash" first: not JS order
-    assert foreign != text
-    del seen[:]
-    parsed = ordered_tree_from_string(foreign)
-    assert isinstance(parsed, OrderedTree) and len(seen) > 0
-    del seen[:]
-    assert merkle_tree_to_string(parsed) == text and seen == []
-    # apply_prefix_xors keeps its contract: plain in, plain out, whatever comes in.
-    assert not isinstance(apply_prefix_xors(marked, {}), OrderedTree)
+    parses = []
+    real = json.loads
+    monkeypatch.setattr(json, "loads", lambda s, *a, **k: parses.append(len(s)) or real(s, *a, **k))
+    tree, _ = fold_key_deltas(MerkleText("{}"), _keyed(stamps))
+    assert isinstance(tree, MerkleText) and tree.text == text
+    assert merkle_tree_to_string(tree) is tree.text and parses == []
+    assert tree == plain and tree["hash"] == plain["hash"] and parses == [len(text)]
+    assert diff_merkle_trees(plain, tree) is None and sorted(tree) == sorted(plain)
+    assert parses == [len(text)]
+    # apply_prefix_xors keeps its contract: a dict out, whatever comes in.
+    assert type(apply_prefix_xors(tree, {})) is dict
+
+
+_Y2023_MINUTE = Y2023 // MINUTE
+_DEEP = '{"0":' * 21 + '{"hash":1}' + "}" * 21
+
+
+@pytest.mark.parametrize("text, minutes", [
+    ('{"hash":1,"0":{"hash":1}}', [_Y2023_MINUTE]),  # "hash" before a child
+    ('{"0": {"hash":1},"hash":1}', [_Y2023_MINUTE]),  # whitespace
+    ('{"hash":1} ', [_Y2023_MINUTE]),  # trailing whitespace
+    ('{"-":{"hash":1},"hash":1}', [_Y2023_MINUTE]),  # a negative minute's key
+    ('{"3":{"hash":1},"hash":1}', [_Y2023_MINUTE]),  # not a base-3 digit
+    ('{"1":{"hash":1},"0":{"hash":1}}', [_Y2023_MINUTE]),  # children out of order
+    ('{"0":{"hash":1},"0":{"hash":2}}', [_Y2023_MINUTE]),  # a child twice
+    ('{"hash":2147483648}', [_Y2023_MINUTE]),  # over int32
+    ('{"hash":-2147483649}', [_Y2023_MINUTE]),  # under int32
+    ('{"hash":-0}', [_Y2023_MINUTE]),  # not how JSON.stringify writes 0
+    (_DEEP, [_Y2023_MINUTE]),  # deeper than any int32 minute's key
+    ('{}', [3 ** 15 - 1]),  # before 1997-04-13: a 15-digit key
+    ('{}', [3 ** 16]),  # from 2051-11-04: a 17-digit key
+    ('{}', [_Y2023_MINUTE + 1, _Y2023_MINUTE]),  # minutes out of order
+], ids=["hash-first", "whitespace", "trailing-space", "dash-key", "digit-3", "order", "twice",
+        "over-int32", "under-int32", "minus-zero", "too-deep", "pre-1997", "post-2051", "unsorted"])
+def test_the_native_pass_refuses_what_it_does_not_recognise(text, minutes):
+    """Every refusal of `eh_fold_tree_text`: the pass returns no text, and
+    the fold takes the dict route, which writes what
+    `apply_prefix_xors` on the parsed text writes."""
+    from evolu_tpu.core.merkle import _fold_text
+    from evolu_tpu.storage import native
+
+    if not native.native_available():
+        pytest.skip("the text route needs the native library")
+    batch = MinuteDeltas(np.array(minutes, np.int64), np.arange(1, len(minutes) + 1, dtype=np.int32))
+    assert _fold_text(text, batch.minutes, batch.deltas) is None
+    folded, nodes = fold_minute_deltas(MerkleText(text), batch)
+    assert type(folded) is dict
+    assert merkle_tree_to_string(folded) == merkle_tree_to_string(
+        apply_prefix_xors(json.loads(text), dict(batch)))
+    assert nodes == len({key[:i] for key in batch for i in range(len(key) + 1)})

@@ -27,6 +27,12 @@ fold its numpy decode and the fold per distinct node, the store the
 dump and the `UPDATE`. Three counters say how often the new path
 engages: `evolu_merkle_fold_nodes_total` and
 `evolu_merkle_tree_text_checks_total` / `_hits_total{leg}`.
+
+The tree is carried as its text (`MerkleText`): the load reads
+`__clock` and parses nothing, the fold is one native pass from the old
+text to the new (`evolu_merkle_fold_route_total{route=text}`, a fold a
+`Receive`), the store writes that text, and the diff compares texts and
+parses only where they differ.
 """
 
 import itertools
@@ -361,6 +367,8 @@ def test_tree_counters_say_how_often_the_new_path_engages(shape):
     text = {(kind, leg): lambda kind=kind, leg=leg: metrics.get_counter(
         f"evolu_merkle_tree_text_{kind}_total", leg=leg)
         for kind in ("checks", "hits") for leg in ("load", "remote")}
+    text[("route", "text")] = lambda: metrics.get_counter(
+        "evolu_merkle_fold_route_total", route="text")
     dev = Device(now=NOW + 366 * 86_400_000)
     try:
         for k, (wire, batch) in enumerate(zip(wires, gen_client.split_responses(messages, 4))):
@@ -369,17 +377,15 @@ def test_tree_counters_say_how_often_the_new_path_engages(shape):
             dev.receive(wire)
             keys = {merkle.minutes_base3(timestamp_from_string(m.timestamp).millis)
                     for m in batch}
-            if len(keys) >= merkle.LEVEL_PASS_MIN_MINUTES:  # each distinct node once
-                want = 1 + len({key[:i] for key in keys for i in range(1, 17)})
-                assert want < 17 * len(keys) / 4
-            else:  # a root and a path a minute
-                want = 17 * len(keys)
+            # Each distinct node once, written by the text fold.
+            want = 1 + len({key[:i] for key in keys for i in range(1, 17)})
+            assert want < 17 * len(keys) / 4 or len(keys) == 1
             assert metrics.get_counter("evolu_merkle_fold_nodes_total") - nodes0 == want
-            # Every Receive compares both texts; only a restore's first
-            # load (`{}` against an empty slot) has to parse.
+            # The load compares nothing (it parses nothing); every Receive
+            # compares its text with the relay's, and folds as text.
             assert {key: read() - text0[key] for key, read in text.items()} == {
-                ("checks", "load"): 1, ("hits", "load"): int(k > 0),
-                ("checks", "remote"): 1, ("hits", "remote"): 1}
+                ("checks", "load"): 0, ("hits", "load"): 0,
+                ("checks", "remote"): 1, ("hits", "remote"): 1, ("route", "text"): 1}
         assert not [o for o in dev.outputs if isinstance(o, rmsg.OnError)]
     finally:
         dev.close()
@@ -387,11 +393,14 @@ def test_tree_counters_say_how_often_the_new_path_engages(shape):
 
 def test_load_and_diff_compare_on_a_hit_and_parse_inside_their_span_on_a_miss(
         device, wires, monkeypatch):
-    """The four spans keep their extents: what is inside `recv_tree_load`
-    and `recv_tree_diff` is the comparison, or the parse (and the walk)
-    where it misses."""
+    """The four spans keep their extents: nothing in `recv_tree_load` or
+    `recv_tree_store` parses or dumps the tree (the text is read and
+    written as it is), and what is inside `recv_tree_diff` is the
+    comparison, or the parse of both trees (and the walk) where it
+    misses."""
+    import json
+
     import evolu_tpu.runtime.worker as worker_mod
-    from evolu_tpu.storage import clock as clock_mod
 
     events = []
 
@@ -412,15 +421,15 @@ def test_load_and_diff_compare_on_a_hit_and_parse_inside_their_span_on_a_miss(
         real = getattr(module, name)
         monkeypatch.setattr(module, name, lambda *a: events.append(label) or real(*a))
 
-    spy(clock_mod, "ordered_tree_from_string", "parse-own")
-    spy(clock_mod, "merkle_tree_to_string", "dump")
+    spy(json, "loads", "parse")
+    spy(json, "dumps", "dump")
     spy(worker_mod, "merkle_tree_from_string", "parse-relay")
     spy(worker_mod, "diff_merkle_trees", "walk")
     monkeypatch.setattr(log_mod, "_trace_annotation_cls", Recording)
     hit = ["<load", "load>", "<fold", "fold>", "<fold", "fold>",
-           "<store", "dump", "store>", "<diff", "diff>"]
+           "<store", "store>", "<diff", "diff>"]
     device.receive(wires[0])
-    assert events == ["<load", "parse-own"] + hit[1:]
+    assert events == hit
     del events[:]
     device.receive(wires[1])
     assert events == hit
@@ -430,7 +439,8 @@ def test_load_and_diff_compare_on_a_hit_and_parse_inside_their_span_on_a_miss(
     del events[:]
     device.worker.post(rmsg.Receive((), ahead, None))
     device.worker.flush()
-    assert events == ["<load", "load>", "<diff", "parse-relay", "walk", "diff>"]
+    assert events == ["<load", "load>", "<diff", "parse-relay", "parse", "walk", "parse",
+                      "diff>"]
     assert not [o for o in device.outputs if isinstance(o, rmsg.OnError)]
 
 
@@ -438,8 +448,8 @@ def test_load_and_diff_compare_on_a_hit_and_parse_inside_their_span_on_a_miss(
 def test_rehearsal_of_the_client_cells_ends_correct_with_the_tree_metrics(cell):
     """`perf/run.py --rehearse --trace 1` (which runs `perf/selfcheck.py`
     first) on the CPU: control flow, counts and `correct`, no device
-    number. Both client cells print the six tree metrics; the new one
-    also its own `recv_handle_ms` and `window_compiles`."""
+    number. Both client cells print the tree metrics; the new one also
+    its own `recv_handle_ms` and `window_compiles`."""
     import json
     import os
     import subprocess
@@ -458,9 +468,10 @@ def test_rehearsal_of_the_client_cells_ends_correct_with_the_tree_metrics(cell):
     got = line["metrics"]
     tree = {"recv_tree_load_ms", "recv_tree_fold_ms", "recv_tree_store_ms",
             "recv_tree_diff_ms", "tree_minutes_receive", "tree_kb_receive",
-            "tree_nodes_receive", "tree_text_hit_share"}
+            "tree_nodes_receive", "tree_text_hit_share", "tree_text_fold_share"}
     assert tree <= set(got)
-    assert got["tree_text_hit_share"]["value"] == 87.5  # 7 of a restore's 8 comparisons
+    assert got["tree_text_hit_share"]["value"] == 100.0  # the relay's text is the client's
+    assert got["tree_text_fold_share"]["value"] == 100.0  # every fold text to text
     if cell == "client-todo-months.restore":
         assert {"recv_handle_ms.months", "window_compiles.months"} <= set(got)
         assert got["window_compiles.months"]["value"] == 0
@@ -489,5 +500,5 @@ def test_perf_selfcheck_reads_every_client_layer_file():
         "window_compiles.client.json"} <= listed
     assert {f"{s}_ms.json" for s in TREE} | {
         "tree_minutes_receive.json", "tree_kb_receive.json",
-        "tree_nodes_receive.json", "tree_text_hit_share.json",
+        "tree_nodes_receive.json", "tree_text_hit_share.json", "tree_text_fold_share.json",
         "recv_handle_ms.months.json", "window_compiles.months.json"} <= listed
