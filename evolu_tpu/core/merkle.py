@@ -18,8 +18,9 @@ diff. It stays its text from one leg to the next (`MerkleText`): the
 fold is one native pass from the old text to the new
 (`fold_minute_deltas`), the store writes the text, and the diff compares
 texts and parses only where they differ. The relay's trees are a few
-minutes an owner and many a pass: they stay plain dicts on
-`apply_prefix_xors`.
+minutes an owner and many a pass: a pass folds every owner's text in
+one native call (`fold_texts`), and a reader parses a tree only where
+the texts it compares differ.
 """
 
 from __future__ import annotations
@@ -166,22 +167,26 @@ class MinuteDeltas(Mapping):
 
 
 class MerkleText(Mapping):
-    """The client's tree as its text: what `merkle_tree_to_string` wrote
-    (`__clock.merkleTree`, or a fold's result), held as it is. The text
+    """A tree as its text: what `merkle_tree_to_string` wrote (the
+    client's `__clock.merkleTree`, the relay's `merkleTree` row, or a
+    fold's result), held as it is. The text
     is what the store writes and the wire carries, and the folds read
     and write text, so nothing parses it unless a reader asks for a
     node: as a Mapping it reads as the parsed tree, parsed on first use
-    only (the diff walk, the sequential route's inserts, tests)."""
+    only (the diff walk, the sequential route's inserts, tests).
+    `parse`, where given, makes that same tree more cheaply than a parse
+    of the text (a fold of a tree already parsed), on first use too."""
 
-    __slots__ = ("text", "_tree")
+    __slots__ = ("text", "_tree", "_parse")
 
-    def __init__(self, text: str):
+    def __init__(self, text: str, parse=None):
         self.text = text
         self._tree = None
+        self._parse = parse
 
     def _parsed(self) -> dict:
         if self._tree is None:
-            self._tree = json.loads(self.text)
+            self._tree = json.loads(self.text) if self._parse is None else self._parse()
         return self._tree
 
     def __len__(self) -> int:
@@ -198,9 +203,9 @@ _TEXT_FOLD = False  # resolved lazily: False = untried, None = unavailable
 
 
 def _text_fold_lane():
-    """`eh_fold_tree_text` through `open_gil_held`'s handle on
-    libevolu_host.so (it reads and makes a str), or None: then every
-    fold takes the dict route."""
+    """(`eh_fold_tree_text`, `eh_fold_tree_texts`) through
+    `open_gil_held`'s handle on libevolu_host.so (they read and make
+    str), or None: then every fold takes the dict route."""
     global _TEXT_FOLD
     if _TEXT_FOLD is False:
         _TEXT_FOLD = None
@@ -211,11 +216,15 @@ def _text_fold_lane():
 
         plib = open_gil_held("libevolu_host.so", "eh_py_abi_probe") if load_library() else None
         if plib is not None:
+            i64p = c.POINTER(c.c_int64)
             fold = plib.eh_fold_tree_text
             fold.restype = c.c_int
-            fold.argtypes = [c.py_object, c.c_void_p, c.c_void_p, c.c_int64,
-                             c.POINTER(c.c_int64), c.py_object]
-            _TEXT_FOLD = fold
+            fold.argtypes = [c.py_object, c.c_void_p, c.c_void_p, c.c_int64, i64p, c.py_object]
+            many = plib.eh_fold_tree_texts
+            many.restype = c.c_int
+            many.argtypes = [c.py_object, c.c_int64, c.c_void_p, c.c_void_p, c.c_void_p,
+                             c.c_void_p, c.py_object]
+            _TEXT_FOLD = (fold, many)
     return _TEXT_FOLD
 
 
@@ -225,17 +234,57 @@ def _fold_text(text: str, minutes, deltas) -> Optional[Tuple[MerkleText, int]]:
     import ctypes
     import numpy as np
 
-    fold = _text_fold_lane()
-    if fold is None or len(minutes) != len(deltas):
+    lane = _text_fold_lane()
+    if lane is None or len(minutes) != len(deltas):
         return None
     minutes = np.ascontiguousarray(minutes, np.int64)
     deltas = np.ascontiguousarray(deltas, np.int32)
     nodes = ctypes.c_int64(0)
     out: list = []
-    if fold(text, minutes.ctypes.data, deltas.ctypes.data, len(minutes),
-            ctypes.byref(nodes), out) != 0:
+    if lane[0](text, minutes.ctypes.data, deltas.ctypes.data, len(minutes),
+               ctypes.byref(nodes), out) != 0:
         return None
     return MerkleText(out[0]), nodes.value
+
+
+def fold_texts(texts, starts, minutes, deltas) -> Optional[list]:
+    """The text route of many trees in one native pass, which drops the
+    interpreter lock once for all of them: tree j is `texts[j]` with the
+    sorted distinct minutes `minutes[starts[j]:starts[j + 1]]` and their
+    int32 `deltas` folded in (`starts`: len(texts) + 1 offsets from 0).
+    → the new texts, None for a tree the pass refuses (a text that is
+    not canonical, a minute without a 16-digit key: the dict route
+    writes the same bytes); or None where the pass is unavailable."""
+    import numpy as np
+
+    lane = _text_fold_lane()
+    if lane is None:
+        return None
+    starts = np.ascontiguousarray(starts, np.int64)
+    minutes = np.ascontiguousarray(minutes, np.int64)
+    deltas = np.ascontiguousarray(deltas, np.int32)
+    if len(starts) != len(texts) + 1 or len(minutes) != len(deltas) or (
+            len(texts) and starts[-1] != len(minutes)):
+        return None
+    nodes = np.empty(len(texts), np.int64)
+    out: list = []
+    if lane[1](texts, len(texts), starts.ctypes.data, minutes.ctypes.data,
+               deltas.ctypes.data, nodes.ctypes.data, out) != 0:
+        return None
+    return out
+
+
+def key_minutes(prefix_xors: Mapping):
+    """A {base3-minute-key: delta} dict as (sorted minutes int64, their
+    int32 deltas) for the text route, or None where a key has not 16
+    digits 0-2 (a short or long key, a negative minute's "-")."""
+    import numpy as np
+
+    if not all(len(key) == MERKLE_KEY_LENGTH and not key.strip("012") for key in prefix_xors):
+        return None
+    pairs = sorted((int(key, 3), d) for key, d in prefix_xors.items())
+    return (np.fromiter((m for m, _ in pairs), np.int64, count=len(pairs)),
+            np.fromiter((d for _, d in pairs), np.int32, count=len(pairs)))
 
 
 def _distinct_nodes(keys) -> int:
@@ -269,16 +318,11 @@ def fold_minute_deltas(tree: Mapping, batch: MinuteDeltas) -> Tuple[Mapping, int
 def fold_key_deltas(tree: Mapping, prefix_xors: Mapping) -> Tuple[Mapping, int]:
     """`fold_minute_deltas` for the host and mutate routes'
     {base3-minute-key: delta} dict: where every key has 16 digits 0-2,
-    as sorted minutes; otherwise (a short or long key, a negative
-    minute's "-") the dict route."""
-    if isinstance(tree, MerkleText) and all(
-            len(key) == MERKLE_KEY_LENGTH and not key.strip("012") for key in prefix_xors):
-        import numpy as np
-
-        pairs = sorted((int(key, 3), d) for key, d in prefix_xors.items())
-        minutes = np.fromiter((m for m, _ in pairs), np.int64, count=len(pairs))
-        deltas = np.fromiter((d for _, d in pairs), np.int32, count=len(pairs))
-        return fold_minute_deltas(tree, MinuteDeltas(minutes, deltas))
+    as sorted minutes (`key_minutes`); otherwise the dict route."""
+    if isinstance(tree, MerkleText):
+        keyed = key_minutes(prefix_xors)
+        if keyed is not None:
+            return fold_minute_deltas(tree, MinuteDeltas(*keyed))
     return _fold_parsed(tree, prefix_xors)
 
 

@@ -28,6 +28,7 @@ from __future__ import annotations
 import contextvars
 import functools
 import threading
+from collections.abc import Mapping
 from concurrent.futures import ThreadPoolExecutor
 from contextlib import contextmanager
 from typing import Dict, List, Optional, Sequence, Tuple
@@ -39,7 +40,11 @@ from jax import shard_map
 from jax.sharding import Mesh, PartitionSpec as P
 
 from evolu_tpu.core.merkle import (
+    MerkleText,
+    MinuteDeltas,
     apply_prefix_xors,
+    fold_texts,
+    key_minutes,
     merkle_tree_from_string,
     merkle_tree_to_string,
 )
@@ -576,18 +581,119 @@ def deltas_pull(state):
     return to_host_many(*outs)
 
 
+class PassDeltas(Mapping):
+    """{owner: its minute deltas} of one pass, held as the device's
+    compacted outputs give them and never as a dict a segment: one run
+    an owner (`run_ix`: its index in `good`; `starts`: len(runs) + 1
+    offsets) of `minutes` (JS-int32, int64) and `deltas` (int32), sorted
+    by owner and then by minute as uint32, each (owner, minute) once;
+    beside them `host`, {owner: {base3-minute-key: delta}} for the
+    owners folded on the host (a non-canonical hex case, the full-width
+    route, rows already stored: `_recompute_duplicate_owners` sets
+    them), which wins over a run. `_fold_trees` hands the runs to one
+    native pass (`text_runs`); as a Mapping over every owner of the
+    pass, in pass order, an owner reads as a `MinuteDeltas` view of its
+    run (keys rendered on first use) or its host dict."""
+
+    def __init__(self, owners, host, good=(), runs=None):
+        self.owners = owners
+        self.host = host
+        self.good = good
+        empty = np.zeros(0, np.int64)
+        self.run_ix, self.starts, self.minutes, self.deltas = runs or (
+            empty, np.zeros(1, np.int64), empty, np.zeros(0, np.int32))
+        self._run_of = None
+
+    def __iter__(self):
+        return iter(self.owners)
+
+    def __len__(self) -> int:
+        return len(self.owners)
+
+    def __getitem__(self, owner):
+        if owner in self.host:
+            return self.host[owner]
+        if self._run_of is None:
+            self._run_of = dict.fromkeys(self.owners)
+            self._run_of.update(
+                (self.good[ix], j) for j, ix in enumerate(self.run_ix.tolist()))
+        j = self._run_of[owner]
+        if j is None:
+            return {}
+        a, b = self.starts[j], self.starts[j + 1]
+        return MinuteDeltas(self.minutes[a:b], self.deltas[a:b])
+
+    def __setitem__(self, owner, deltas) -> None:
+        self.host[owner] = deltas
+
+    def text_runs(self):
+        """The owners the text route may take, with their minutes: →
+        (owners, starts, minutes, deltas) in `fold_texts`' form. Every
+        device run whose owner has no host dict, then each non-empty
+        host dict whose keys all have 16 digits (`key_minutes`); an
+        owner left out folds by the dict route."""
+        if not self.host:
+            return [self.good[ix] for ix in self.run_ix.tolist()], \
+                self.starts, self.minutes, self.deltas
+        owners, parts = [], []
+        for j, ix in enumerate(self.run_ix.tolist()):
+            if self.good[ix] not in self.host:
+                a, b = self.starts[j], self.starts[j + 1]
+                owners.append(self.good[ix])
+                parts.append((self.minutes[a:b], self.deltas[a:b]))
+        for o, d in self.host.items():
+            keyed = key_minutes(d) if d else None
+            if keyed is not None:
+                owners.append(o)
+                parts.append(keyed)
+        lens = [len(m) for m, _d in parts]
+        starts = np.zeros(len(parts) + 1, np.int64)
+        np.cumsum(lens, out=starts[1:])
+        if not parts:
+            return owners, starts, self.minutes[:0], self.deltas[:0]
+        return (owners, starts, np.concatenate([m for m, _d in parts]),
+                np.concatenate([d for _m, d in parts]))
+
+
+def _device_runs(packed, xors, counts):
+    """Every device's compacted segments as one set of runs, for
+    `PassDeltas`: concatenated, sorted by (owner, minute) and XOR-merged
+    where an owner's rows were split over devices. → (run_ix, starts,
+    minutes, deltas)."""
+    keys = np.concatenate([packed[si, :c] for si, c in enumerate(counts.tolist())])
+    xs = np.concatenate([xors[si, :c] for si, c in enumerate(counts.tolist())]).view(np.int32)
+    if len(keys) > 1 and not (keys[1:] > keys[:-1]).all():
+        order = np.argsort(keys, kind="stable")
+        keys, xs = keys[order], xs[order]
+        first = np.flatnonzero(np.concatenate(([True], keys[1:] != keys[:-1])))
+        keys, xs = keys[first], np.bitwise_xor.reduceat(xs, first)
+    owner = (keys >> np.uint64(32)).astype(np.int64)
+    minutes = (keys & np.uint64(0xFFFFFFFF)).astype(np.int64)
+    # undo the uint32 bit carriage of the JS |0-wrapped int32 minute
+    minutes[minutes >= 1 << 31] -= 1 << 32
+    starts = np.flatnonzero(np.concatenate(([True], owner[1:] != owner[:-1], [True])))
+    if not len(keys):
+        starts = starts[:1]
+    return owner[starts[:-1]], starts.astype(np.int64), minutes, xs
+
+
 @with_x64
-def deltas_decode(state, pulled) -> Tuple[Dict[str, Dict[str, int]], int]:
-    """Decode the pulled per-(owner, minute) deltas. If any shard
+def deltas_decode(state, pulled) -> Tuple[PassDeltas, int]:
+    """Decode the pulled per-(owner, minute) deltas into a `PassDeltas`:
+    the compacted outputs as numpy runs (`_device_runs`). If any shard
     produced more segments than the compaction cap, re-run the
-    full-width kernel and decode every row (rare: means distinct
-    (owner, minute) pairs exceed an eighth of the shard's rows)."""
+    full-width kernel and decode every row into host dicts (rare: means
+    distinct (owner, minute) pairs exceed an eighth of the shard's
+    rows)."""
     deltas, digest, good, _outs, extra = state
+    good_set = set(good)
+    host = {o: d for o, d in deltas.items() if o not in good_set}
     if pulled is None:
-        return deltas, digest
+        return PassDeltas(list(deltas), host), digest
     buf, k1, oix, mesh, cap = extra
     n_dev = mesh.devices.size
     packed, xors, counts, dev_digest = _unpack_outputs(pulled[0], n_dev, cap)
+    runs = None
     if (counts > cap).any():
         log("kernel:merkle", "segment compaction overflow: full-width pull",
             cap=cap, max_count=int(counts.max()))
@@ -608,27 +714,10 @@ def deltas_decode(state, pulled) -> Tuple[Dict[str, Dict[str, int]], int]:
         by_ix = decode_owner_minute_deltas(
             owner_sorted, minute_sorted, seg_end, seg_xor, valid_sorted
         )
+        host.update((good[o_ix], d) for o_ix, d in by_ix.items())
     else:
-        from evolu_tpu.core.merkle import minutes_base3
-        from evolu_tpu.core.murmur import to_int32
-
-        by_ix: Dict[int, Dict[str, int]] = {}
-        key_cache: Dict[int, str] = {}
-        for si in range(n_dev):
-            c = int(counts[si])
-            for p, x in zip(packed[si, :c].tolist(), xors[si, :c].tolist()):
-                o_ix = p >> 32
-                minute = p & 0xFFFFFFFF
-                if minute >= 1 << 31:  # undo the uint32 bit carriage of
-                    minute -= 1 << 32  # the JS |0-wrapped int32 minute
-                key = key_cache.get(minute)
-                if key is None:
-                    key = key_cache[minute] = minutes_base3(minute * 60000)
-                d = by_ix.setdefault(o_ix, {})
-                d[key] = to_int32(d.get(key, 0) ^ int(x))
-    for o_ix, d in by_ix.items():
-        deltas[good[o_ix]] = d
-    return deltas, digest ^ int(dev_digest)
+        runs = _device_runs(packed, xors, counts)
+    return PassDeltas(list(deltas), host, good, runs), digest ^ int(dev_digest)
 
 
 def _ledger_count_pass(requests, inserted_by_owner) -> None:
@@ -650,6 +739,16 @@ def _ledger_count_pass(requests, inserted_by_owner) -> None:
         ledger.count(ledger.STORE_DUPLICATE, total - ins, owner=o)
 
 
+def _group_sums(was_new, counts) -> np.ndarray:
+    """The was-new flags of a shard's consecutive groups (`counts` rows
+    each, none empty) summed, in one numpy call."""
+    if not len(counts):
+        return np.zeros(0, np.int64)
+    starts = np.zeros(len(counts), np.int64)
+    np.cumsum(counts[:-1], out=starts[1:])
+    return np.add.reduceat(np.asarray(was_new), starts, dtype=np.int64)
+
+
 def _count_pass(route: str, st) -> None:
     """One landing pass: the route it lands by (`_route`) and which
     body packed it in `start_batch` (`_pack_batch`), in one acquisition
@@ -661,17 +760,42 @@ def _count_pass(route: str, st) -> None:
     ))
 
 
-def _fold_trees(deltas_by_owner, stored, tree_rows, shard_index, trees, strings) -> None:
+def _fold_all(deltas_by_key: PassDeltas, stored) -> Tuple[Dict, int]:
+    """Fold each key's deltas onto its tree TEXT `stored[key]`: every
+    key `text_runs` gives spliced in ONE native pass (`fold_texts`) into
+    a `MerkleText`; the dict route (`apply_prefix_xors` on the parsed
+    text, then its dump) for a key the pass refuses or never gets (a
+    text that is not canonical, a minute without a 16-digit key). → ({key:
+    (tree, text)} in key order, keys with an empty delta left out; how
+    many took the text route)."""
+    keys, starts, minutes, deltas = deltas_by_key.text_runs()
+    texts = fold_texts([stored[k] for k in keys], starts, minutes, deltas) if keys else None
+    made = {k: t for k, t in zip(keys, texts or ()) if t is not None}
+    out = {}
+    for k in deltas_by_key:
+        text = made.get(k)
+        if text is not None:
+            out[k] = (MerkleText(text), text)
+        elif deltas_by_key[k]:
+            tree = apply_prefix_xors(merkle_tree_from_string(stored[k]), deltas_by_key[k])
+            out[k] = (tree, merkle_tree_to_string(tree))
+    return out, len(made)
+
+
+def _fold_trees(deltas_by_owner, stored, tree_rows, shard_index, trees, strings) -> tuple:
     """Fold each owner's pass deltas onto its stored tree TEXT
-    (`_store_pass`'s `stored`): the folded tree into `trees`, its dump
-    into `strings` and, as the (owner, TEXT) row `_store_pass` upserts,
-    into the owner's shard's list of `tree_rows`."""
-    for o, deltas in deltas_by_owner.items():
-        if not deltas:
-            continue
-        trees[o] = apply_prefix_xors(merkle_tree_from_string(stored[o]), deltas)
-        strings[o] = merkle_tree_to_string(trees[o])
-        tree_rows[shard_index(o)].append((o, strings[o]))
+    (`_store_pass`'s `stored`; `_fold_all`, one native pass for them
+    all): the folded tree into `trees` (a `MerkleText` where the pass
+    spliced it), its text into `strings` and, as the (owner, TEXT) row
+    `_store_pass` upserts, into the owner's shard's list of
+    `tree_rows`, in pass order. → the `evolu_engine_tree_fold_total`
+    items, one owner a fold."""
+    folded, by_text = _fold_all(deltas_by_owner, stored)
+    for o, (tree, text) in folded.items():
+        trees[o], strings[o] = tree, text
+        tree_rows[shard_index(o)].append((o, text))
+    return (("evolu_engine_tree_fold_total", by_text, {"route": "text"}),
+            ("evolu_engine_tree_fold_total", len(folded) - by_text, {"route": "dict"}))
 
 
 def _owner_runs(requests) -> Dict[str, List[int]]:
@@ -712,18 +836,26 @@ def _tree_prefixes(requests, runs, new_rows, stored, trees, strings) -> Dict[int
     request that carries it. Request i's tree is the owner's stored tree
     (`stored`: its text before the pass) with the new rows of requests
     <= i folded in (`minute_deltas_host`, the fold
-    `_recompute_duplicate_owners` runs); the last request's is the
-    pass's own (`trees`, `strings`), which the device's delta made. The
-    rows to withhold from request i's answer are the new rows of the
-    requests after it, sorted and packed 46 bytes a row for
-    `eh_get_messages_wire`. An owner whose requests inserted nothing
-    needs none of this: every one of them sees the stored tree."""
+    `_recompute_duplicate_owners` runs, once a request; XOR commutes, so
+    the minute deltas of requests <= i XOR-merged are one fold of the
+    stored text, and every request's of every owner is one `_fold_all`:
+    one native pass); the last request's is the pass's own (`trees`,
+    `strings`), which the device's delta made. The rows to withhold
+    from request i's answer are the new rows of the requests after it,
+    sorted and packed 46 bytes a row for `eh_get_messages_wire`. An
+    owner whose requests inserted nothing needs none of this: every one
+    of them sees the stored tree. A diff that reads request i's tree
+    parses the owner's stored text, once an owner, and folds the dict
+    (`MerkleText`'s `parse`), not request i's own text."""
     from evolu_tpu.core.merkle import minute_deltas_host
 
     out: Dict[int, tuple] = {}
+    folds = []  # (request index, owner, its delta dict, rows to withhold)
+    bases: Dict[str, MerkleText] = {}  # each owner's stored tree
     for u, new in new_rows.items():
         if not new:
             continue
+        bases[u] = MerkleText(stored[u])
         unclaimed = set(new)
         firsts = []  # each request's new rows, in the owner's order
         for i in runs[u]:
@@ -738,14 +870,24 @@ def _tree_prefixes(requests, runs, new_rows, stored, trees, strings) -> Dict[int
             withhold.append("".join(sorted(later)).encode("ascii"))
             later.extend(mine)
         withhold.reverse()
-        tree, raw = merkle_tree_from_string(stored[u]), stored[u]
+        so_far: Dict[str, int] = {}  # the new rows of requests <= i, by minute
         for i, mine, held_back in zip(runs[u], firsts, withhold):
             if i == runs[u][-1]:
-                tree, raw = trees[u], strings[u]
-            elif mine:
-                tree = apply_prefix_xors(tree, minute_deltas_host(mine)[0])
-                raw = merkle_tree_to_string(tree)
-            out[i] = (tree, raw, held_back)
+                out[i] = (trees[u], strings[u], held_back)
+                continue
+            for key, d in minute_deltas_host(mine)[0].items():
+                so_far[key] = so_far.get(key, 0) ^ d
+            if so_far:
+                folds.append((i, u, dict(so_far), held_back))
+            else:
+                out[i] = (bases[u], stored[u], held_back)
+    jobs = PassDeltas(list(range(len(folds))), {j: f[2] for j, f in enumerate(folds)})
+    folded, _by_text = _fold_all(jobs, [stored[u] for _i, u, _d, _h in folds])
+    for j, (i, u, deltas, held_back) in enumerate(folds):
+        tree, text = folded[j]
+        if isinstance(tree, MerkleText):
+            tree = MerkleText(text, functools.partial(apply_prefix_xors, bases[u], deltas))
+        out[i] = (tree, text, held_back)
     return out
 
 
@@ -967,7 +1109,7 @@ class BatchReconciler:
         return self._stage_pool
 
     @contextmanager
-    def _store_pass(self, stores, live, batches, entering=None):
+    def _store_pass(self, stores, live, batches, entering=None, also_count=()):
         """The storage leg of ONE pass over the live shards, in two
         native calls (`storage/native.py`, the shard-set calls) and no
         thread of this process's but the caller's: `reconcile_stream`'s
@@ -981,7 +1123,10 @@ class BatchReconciler:
         exit: those rows upserted and every shard committed, one call.
         Insert and upsert of a shard share a transaction, so rows never
         outrun their tree; an exception anywhere, in the body too,
-        rolls back EVERY live shard before it propagates."""
+        rolls back EVERY live shard before it propagates. `also_count`:
+        counter items the body may add to, posted with the commit's
+        count, in one acquisition of the registry's lock, once every
+        shard committed."""
         dbs = [stores[si].db for si in live]
         flags, stored = relay_insert_packed_shards(
             dbs, batches,
@@ -995,7 +1140,8 @@ class BatchReconciler:
             for db in dbs:
                 db.rollback()
             raise
-        metrics.inc("evolu_engine_store_calls_total", op="commit")
+        metrics.inc_many((("evolu_engine_store_calls_total", 1, {"op": "commit"}),
+                          *also_count))
 
     @contextmanager
     def _shard_transactions(self, stores, live):
@@ -1186,7 +1332,8 @@ class BatchReconciler:
     def _land(self, st, respond_stage=None, entering=None):
         """The landing half of a packed pass → (trees, strings, prefix):
         the C inserts of every live shard in one native call,
-        duplicate-owner delta recompute, tree updates, the per-request
+        duplicate-owner delta recompute, the tree folds as text in one
+        native pass (`_fold_trees`), the per-request
         trees of an owner with several requests (`_tree_prefixes`), one
         atomic commit per shard in a second (`_store_pass`), then the
         ledger count. `respond_stage`: see `finish_batch`. `entering`: called
@@ -1196,7 +1343,7 @@ class BatchReconciler:
         stores, shard_index = self._shards()
         _count_pass("stream", st)
         live, shard_data = st["live"], st["shard_data"]
-        trees: Dict[str, dict] = {}
+        trees: Dict[str, Mapping] = {}
         strings: Dict[str, str] = {}
         prefix: Dict[int, tuple] = {}
         if not live:
@@ -1212,8 +1359,8 @@ class BatchReconciler:
         # pass_insert (`_store_pass`'s first native call: BEGIN, the C
         # inserts, the stored trees), pass_pull_wait (only the blocking
         # wait for the device's outputs), pass_tree (decode, duplicate
-        # recompute, tree folds, and the second native call: merkleTree
-        # upsert + COMMIT). The pull itself records under pull_wave from
+        # recompute, the tree folds as text, and the second native call:
+        # merkleTree upsert + COMMIT). The pull itself records under pull_wave from
         # to_host_many on the pull thread — shares are over summed stage
         # walls, and the two legs overlap (docs/OBSERVABILITY.md).
         rows = st["n_total"]
@@ -1222,9 +1369,10 @@ class BatchReconciler:
                      owners=len({r.user_id for r in st["requests"]}),
                      n=rows, shards=len(live)), \
                 anatomy.stage("pass_insert", rows=rows) as tile:
+            folds: List[tuple] = []
             with self._store_pass(
-                    stores, live, [shard_data[si] for si in live], entering) as (
-                    was_new_by_shard, stored, tree_rows):
+                    stores, live, [shard_data[si] for si in live], entering,
+                    also_count=folds) as (was_new_by_shard, stored, tree_rows):
                 tile.then("pass_pull_wait")
                 pulled = deltas_pull(st["dev"])
                 tile.then("pass_tree", rows=rows)
@@ -1232,7 +1380,8 @@ class BatchReconciler:
                 self._recompute_duplicate_owners(
                     st, was_new_by_shard, deltas_by_owner
                 )
-                _fold_trees(deltas_by_owner, stored, tree_rows, shard_index, trees, strings)
+                folds.extend(_fold_trees(
+                    deltas_by_owner, stored, tree_rows, shard_index, trees, strings))
                 runs = _owner_runs(st["requests"])
                 if runs:
                     with anatomy.stage("tree_prefix", cpu=False):
@@ -1248,13 +1397,8 @@ class BatchReconciler:
         ins_by_owner: Dict[str, int] = {}
         for si in live:
             gu, gc, _tsp, _cp, _lens = shard_data[si]
-            was_new = was_new_by_shard[si]
-            pos = 0
-            for u, k in zip(gu, gc):
-                ins_by_owner[u] = ins_by_owner.get(u, 0) + int(
-                    np.asarray(was_new[pos : pos + k]).sum()
-                )
-                pos += k
+            for u, n in zip(gu, _group_sums(was_new_by_shard[si], gc).tolist()):
+                ins_by_owner[u] = ins_by_owner.get(u, 0) + n
         _ledger_count_pass(st["requests"], ins_by_owner)
         return trees, strings, prefix
 
@@ -1272,16 +1416,12 @@ class BatchReconciler:
         packed = st["packed"]
         offsets = st["shard_offsets"]
         # Pass 1 (steady state exits here): which owners have ANY
-        # duplicate row? One cheap .all() per group, no allocations.
+        # duplicate row? One sum a shard's groups.
         affected: set = set()
         for si in st["live"]:
             gu, gc, _tsp, _cp, _lens = st["shard_data"][si]
-            was_new = was_new_by_shard[si]
-            pos = 0
-            for u, k in zip(gu, gc):
-                if not was_new[pos : pos + k].all():
-                    affected.add(u)
-                pos += k
+            affected.update(u for u, n, k in zip(
+                gu, _group_sums(was_new_by_shard[si], gc).tolist(), gc) if n < k)
         if not affected:
             return
         # Pass 2: an affected owner needs ALL its new rows (it may span
@@ -1409,8 +1549,9 @@ class BatchReconciler:
     def _resolve_tree(self, user_id: str, trees, tree_strings):
         """Tree + serialized string for one owner, reusing the ingest's
         caches; owners not in `trees` (no new rows this batch — the
-        cold-sync shape) read the STORED string verbatim and parse it
-        once for the diff, never re-dumping (the parse→re-dump
+        cold-sync shape) read the STORED string verbatim as a
+        `MerkleText`, parsed only where a diff walks it, never re-dumped
+        (the parse→re-dump
         round-trip, ~1.25 ms per realistic owner tree, was the measured
         respond wall at 1k divergent owners — docs/BENCHMARKS.md r4).
         Mutates both caches; ONE copy shared by `_respond` and
@@ -1420,7 +1561,7 @@ class BatchReconciler:
         if tree is None:
             if hasattr(self.store, "get_merkle_tree_string"):
                 raw = self.store.get_merkle_tree_string(user_id)
-                tree = merkle_tree_from_string(raw)
+                tree = MerkleText(raw)
             else:
                 tree = self.store.get_merkle_tree(user_id)
                 raw = merkle_tree_to_string(tree)
@@ -1458,8 +1599,10 @@ class BatchReconciler:
             else:
                 tree, ts = self._resolve_tree(r.user_id, trees, tree_strings)
                 withhold = b""
-            client_tree = merkle_tree_from_string(r.merkle_tree)
-            messages = self.store.get_messages(r.user_id, r.node_id, tree, client_tree)
+            # Equal texts are equal trees: nothing to send, nothing to
+            # parse (`_respond_wire`'s rule).
+            messages = () if r.merkle_tree == ts else self.store.get_messages(
+                r.user_id, r.node_id, tree, merkle_tree_from_string(r.merkle_tree))
             if withhold:
                 later = {withhold[j:j + 46].decode("ascii")
                          for j in range(0, len(withhold), 46)}
@@ -1705,7 +1848,8 @@ class BatchReconciler:
         their original positions.
 
         Two children of `pass_respond` split the per-request loop:
-        `respond_diff` (client-tree parse + diff + the `since` string)
+        `respond_diff` (the texts compared; where they differ, the
+        client-tree parse + diff + the `since` string)
         and `respond_fetch` (the C fetch), each the SUM over the
         call's requests and ONE `evolu_stage_ms` observation, posted
         with what the answers held (`evolu_engine_respond_*`) in one
@@ -1751,7 +1895,10 @@ class BatchReconciler:
                 out.append(None)
                 continue
             with diffing:
-                since = response_since(tree, merkle_tree_from_string(r.merkle_tree))
+                # Equal texts are equal trees (`since` None), so only
+                # texts that differ are parsed and diffed.
+                since = None if r.merkle_tree == raw else response_since(
+                    tree, merkle_tree_from_string(r.merkle_tree))
             stream = b""
             if since is not None:
                 try:

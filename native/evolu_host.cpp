@@ -1177,6 +1177,50 @@ struct TextBuffer {
   }
 };
 
+thread_local TextBuffer text_buffer;
+
+// What one fold may write: the old text, and the paths' nodes (the root
+// and at most 16 a minute).
+inline size_t fold_bound(int64_t len, int64_t n) {
+  return size_t(len + (kKeyDigits * n + 1) * kNodeGrowth + 16);
+}
+
+// Sorted, distinct, and each with a 16-digit key.
+bool foldable_minutes(const int64_t *minutes, int64_t n) {
+  for (int64_t i = 0; i < n; ++i)
+    if (minutes[i] < kPow3[kKeyDigits - 1] || minutes[i] >= kPow3[kKeyDigits] ||
+        (i > 0 && minutes[i] <= minutes[i - 1]))
+      return false;
+  return true;
+}
+
+// One tree's fold, into f.w: true where the old text is canonical and
+// the walk consumed all of it. n > 0; the minutes are foldable.
+bool fold_text(TextFold &f, int64_t n) {
+  int32_t root_delta;
+  return f.n != 0 && fold_node(f, 0, 0, 0, n, &root_delta) == f.n;
+}
+
+// Appends `text` as it is, once it is known to be canonical (a fold of
+// no minute): 0, 2 = not canonical, 1 = a CPython error (cleared).
+int append_unfolded(PyObj *text, const char *s, int64_t len, PyObj *out) {
+  if (len == 0 || skip_node(s, len, 0, 0) != len) return 2;
+  return PyList_Append(out, text) == 0 ? 0 : (PyErr_Clear(), 1);
+}
+
+// Appends s[0, len) as a new str: false on a CPython error (cleared).
+bool append_text(const char *s, int64_t len, PyObj *out) {
+  PyObj *folded = PyUnicode_FromStringAndSize(s, len);
+  if (!folded) {
+    PyErr_Clear();
+    return false;
+  }
+  int rc = PyList_Append(out, folded);
+  Py_DecRef(folded);
+  if (rc != 0) PyErr_Clear();
+  return rc == 0;
+}
+
 }  // namespace
 
 int eh_fold_tree_text(PyObj *text, const int64_t *minutes, const int32_t *deltas, int64_t n,
@@ -1186,37 +1230,98 @@ int eh_fold_tree_text(PyObj *text, const int64_t *minutes, const int32_t *deltas
   long long len;
   if (!py_str(text, &s, &len)) return 1;
   *out_nodes = 0;
-  for (int64_t i = 0; i < n; ++i)
-    if (minutes[i] < kPow3[kKeyDigits - 1] || minutes[i] >= kPow3[kKeyDigits] ||
-        (i > 0 && minutes[i] <= minutes[i - 1]))
-      return 2;
-  if (n == 0) {  // the tree as it is, once it is known to be canonical
-    if (len == 0 || skip_node(s, len, 0, 0) != len) return 2;
-    return PyList_Append(out, text) == 0 ? 0 : (PyErr_Clear(), 1);
-  }
-  // The paths' nodes: the root and at most 16 a minute.
-  static thread_local TextBuffer buffer;
-  if (!buffer.reserve(size_t(len + (kKeyDigits * n + 1) * kNodeGrowth + 16))) return 1;
-  TextFold f{s, len, minutes, deltas, buffer.data};
-  int64_t end;
+  if (!foldable_minutes(minutes, n)) return 2;
+  if (n == 0) return append_unfolded(text, s, len, out);
+  if (!text_buffer.reserve(fold_bound(len, n))) return 1;
+  TextFold f{s, len, minutes, deltas, text_buffer.data};
+  bool folded;
   {
     GilScope unlocked;
-    int32_t root_delta;
-    end = len == 0 ? -1 : fold_node(f, 0, 0, 0, n, &root_delta);
+    folded = fold_text(f, n);
   }
-  if (end != len) return 2;
-  PyObj *folded = PyUnicode_FromStringAndSize(buffer.data, f.w - buffer.data);
-  if (!folded) {
-    PyErr_Clear();
-    return 1;
-  }
-  int rc = PyList_Append(out, folded);
-  Py_DecRef(folded);
-  if (rc != 0) {
-    PyErr_Clear();
-    return 1;
-  }
+  if (!folded) return 2;
+  if (!append_text(text_buffer.data, f.w - text_buffer.data, out)) return 1;
   *out_nodes = f.nodes;
+  return 0;
+}
+
+// The relay's pass: every owner's tree of the pass folded in ONE call,
+// each exactly as `eh_fold_tree_text` folds it. `texts` is a sequence
+// of n_trees stored texts; tree j takes the minutes and deltas
+// [starts[j], starts[j + 1]) (starts has n_trees + 1 entries, the first
+// 0). The GIL is dropped once for the whole walk, never once a tree: on
+// the served relay every drop hands the lock to the handler threads.
+//
+// Returns 0: `out` (a list) gains one entry a tree, in order: the new
+// text, or None where the fold refuses that tree (see above: the caller
+// folds it by the dict route), and out_nodes[j] the nodes its fold wrote
+// (-1 where refused). 1 = bad arguments or a CPython error (cleared);
+// `out` may then hold part of the entries.
+int eh_fold_tree_texts(PyObj *texts, int64_t n_trees, const int64_t *starts,
+                       const int64_t *minutes, const int32_t *deltas, int64_t *out_nodes,
+                       PyObj *out) {
+  if (!texts || !out || n_trees < 0 || !starts || starts[0] != 0) return 1;
+  for (int64_t j = 0; j < n_trees; ++j)
+    if (starts[j + 1] < starts[j]) return 1;
+  if (starts[n_trees] > 0 && (!minutes || !deltas)) return 1;
+  PyRefs refs;
+  std::vector<const char *> s(size_t(n_trees), nullptr);
+  std::vector<int64_t> len(size_t(n_trees), 0);
+  size_t need = 0;
+  for (int64_t j = 0; j < n_trees; ++j) {
+    PyObj *text = refs.keep(PySequence_GetItem(texts, j));
+    if (!text) {
+      PyErr_Clear();
+      return 1;
+    }
+    long long n;
+    if (py_str(text, &s[j], &n)) {  // else refused: not an exact str
+      len[j] = n;
+      need += fold_bound(n, starts[j + 1] - starts[j]);
+    } else {
+      s[j] = nullptr;
+    }
+  }
+  if (!text_buffer.reserve(need + 1)) return 1;
+  // Each tree's [from, to) of the buffer, to = -1 where refused.
+  std::vector<int64_t> from(static_cast<size_t>(n_trees)), to(static_cast<size_t>(n_trees));
+  {
+    GilScope unlocked;
+    char *w = text_buffer.data;
+    for (int64_t j = 0; j < n_trees; ++j) {
+      const int64_t lo = starts[j], n = starts[j + 1] - lo;
+      from[j] = w - text_buffer.data;
+      to[j] = -1;
+      out_nodes[j] = -1;
+      if (!s[j] || !foldable_minutes(minutes + lo, n)) continue;
+      if (n == 0) {  // appended as it is below, once known canonical
+        if (len[j] != 0 && skip_node(s[j], len[j], 0, 0) == len[j]) {
+          to[j] = from[j];
+          out_nodes[j] = 0;
+        }
+        continue;
+      }
+      TextFold f{s[j], len[j], minutes + lo, deltas + lo, w};
+      if (fold_text(f, n)) {
+        w = f.w;
+        to[j] = w - text_buffer.data;
+        out_nodes[j] = f.nodes;
+      }
+    }
+  }
+  for (int64_t j = 0; j < n_trees; ++j) {
+    bool ok;
+    if (to[j] < 0) {
+      ok = PyList_Append(out, reinterpret_cast<PyObj *>(&_Py_NoneStruct)) == 0;
+      if (!ok) PyErr_Clear();
+    } else if (starts[j + 1] == starts[j]) {
+      ok = PyList_Append(out, refs.refs[size_t(j)]) == 0;
+      if (!ok) PyErr_Clear();
+    } else {
+      ok = append_text(text_buffer.data + from[j], to[j] - from[j], out);
+    }
+    if (!ok) return 1;
+  }
   return 0;
 }
 

@@ -344,7 +344,8 @@ def test_rehearsal_of_the_cell_ends_correct_with_the_restored_65th_device():
     assert line["correct"] is True and line["failed"] == 0 and line["attempted"] > 0
     assert line["device"]["platform"] == "cpu"
     got = {k: v["value"] for k, v in line["metrics"].items()}
-    assert set(got) == set(NEW_METRICS) | set(TWINS)
+    assert set(got) == set(NEW_METRICS) | set(TWINS) | {"relay_tree_text_share"}
+    assert got["relay_tree_text_share"] == 100  # every owner's tree folded as text
     assert got["window_compiles.contention"] == 0
     assert got["sched_batch_requests.contention"] > 1 and got["coalesced_share"] > 0
     assert got["respond_withheld_rows"] > 0 and got["tree_prefix_ms"] > 0

@@ -279,8 +279,9 @@ def test_rehearsal_of_the_cell_ends_correct_with_the_mesh_metrics():
                      "mesh_upload_kb_pass", "mesh_pull_kb_pass",
                      "pack_native_share", "pass_insert_wait_ms.mesh4",
                      "stream_overlap_share", "pass_stage_join_ms.mesh4",
-                     "device_transfers_pass.mesh4"} == set(got)
+                     "device_transfers_pass.mesh4", "relay_tree_text_share"} == set(got)
     assert got["device_transfers_pass.mesh4"] == 2.0  # PR 41: one buffer up, one back
+    assert got["relay_tree_text_share"] == 100  # every owner's tree folded as text
     # PR 39: the rehearsal's two passes a backfill, the second staged on the helper
     assert got["stream_overlap_share"] == 50 and got["pass_stage_join_ms.mesh4"] >= 0
     # the control of PR 37: one thread works alone, so the insert's wall

@@ -463,8 +463,10 @@ def test_rehearsal_of_the_cell_ends_correct_with_every_new_metric():
     assert line["correct"] is True and line["failed"] == 0 and line["attempted"] > 0
     assert line["device"]["platform"] == "cpu"
     got = {k: v["value"] for k, v in line["metrics"].items()}
-    assert set(got) == set(NEW_METRICS) | set(TWINS) | {"device_transfers_pass"}
+    assert set(got) == set(NEW_METRICS) | set(TWINS) | {
+        "device_transfers_pass", "relay_tree_text_share"}
     assert got["device_transfers_pass"] == 2.0  # PR 41: one buffer up, one back
+    assert got["relay_tree_text_share"] == 100  # every owner's tree folded as text
     assert got["window_compiles.skewed"] == 0
     assert 0 < got["pull_answer_share"] <= 100 and got["respond_msgs_request"] > 0
     assert 0 <= got["sched_deferred_share"] <= 100 and got["sched_passes_waited"] >= 0
