@@ -94,11 +94,10 @@ def ledger_sentinel_sequence():
 
 
 def anatomy_sequence():
-    """The stage-anatomy accounting ONE config-2 engine pass performs
-    (ISSUE 16): the three runtime seam records (device dispatch / pull
-    wave / host apply, each pricing a floor + feeding the decayed fit +
-    share gauges) plus the two kernel-span folds the pass's spans
-    trigger. Deliberately a superset of the steady state."""
+    """The stage records ONE config-2 engine pass posts: the three
+    runtime seam records (device dispatch / pull wave / host apply)
+    plus the two kernel-span folds the pass's spans trigger.
+    Deliberately a superset of the steady state."""
     anatomy.record_stage("device_dispatch", 0.115, rows=1_000_000)
     anatomy.record_stage("pull_wave", 2.8, nbytes=48_000_000)
     anatomy.record_stage("host_apply", 1.4, rows=1_000_000)
@@ -164,7 +163,6 @@ def main():
     logger.clear()
     instr_ms = measure_instrumentation_ms()
     ledger_ms = measure_ledger_sentinel_ms()
-    anatomy.set_device_kind(anatomy.V5E)  # priced floors = the expensive path
     anatomy_ms = measure_anatomy_ms()
     batch_ms = measure_reconcile_batch_ms()
     print(json.dumps({

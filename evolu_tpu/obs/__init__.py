@@ -22,12 +22,11 @@ links, `GET /trace/<id>` span trees, and a Chrome-trace export
 (ISSUE 10 — one mutation followed client → relay → batch → engine →
 replica).
 
-`obs.anatomy` — the stage-anatomy plane (ISSUE 16): a declarative
-registry over the fused reconcile pipeline with machine-readable cost
-laws and roofline-priced floors, the `evolu_stage_*` metrics family
-(per-batch dispatch/pull/apply shares, fixed-RTT-vs-slope fits,
-over-floor flags), and the registry `benchmarks/stage_anatomy.py`
-builds its self-ablating timed variants from.
+`obs.anatomy` — the stage primitive: `stage` times an interval on the
+thread that does the work and posts the `evolu_stage_*` histograms and
+totals in one registry acquisition (plus a profiler annotation and a
+distributed-trace span); GET /stats reads its `stages` section from
+the `evolu_stage_ms` histogram.
 
 This package MUST NOT import jax (directly or transitively): metrics
 and spans record host-side Python values the hot paths already hold —

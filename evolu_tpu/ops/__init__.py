@@ -96,11 +96,9 @@ def to_host_many(*xs):
     from evolu_tpu.obs import anatomy as _anatomy
     from evolu_tpu.obs import metrics as _metrics
 
-    # Stage-anatomy fold (ISSUE 16): every wave is one pull_wave stage
-    # record (and, with annotations on, one `evolu/pull_wave` event on
-    # the pulling thread's line of the profiler trace); where the
-    # device has a recorded pull bandwidth law, the over-floor flag
-    # fires when a wave runs slower than FLOOR_FACTOR× it.
+    # Every wave is one pull_wave stage record (and, with annotations
+    # on, one `evolu/pull_wave` event on the pulling thread's line of
+    # the profiler trace).
     with _anatomy.stage("pull_wave", cpu=False) as wave:  # nothing reads its wait
         out = tuple(to_host(x) for x in start_host_transfer(*xs))
         wave.nbytes = sum(int(getattr(a, "nbytes", 0)) for a in out)

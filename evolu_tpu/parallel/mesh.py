@@ -24,10 +24,9 @@ def create_mesh(n_devices: Optional[int] = None, devices=None) -> Mesh:
         devices = jax.devices()
     if n_devices is not None:
         devices = devices[:n_devices]
-    # Push the device kind into the jax-free stage-anatomy plane
-    # (ISSUE 16): obs/anatomy.py prices floors from COST_LAWS keyed by
-    # `device_kind` but must never import jax itself, so the one place
-    # that already holds a device tells it.
+    # Push the device kind into the jax-free obs/anatomy.py (a label of
+    # GET /stats and of a captured profile), which must never import
+    # jax itself: the one place that already holds a device tells it.
     from evolu_tpu.obs import anatomy
 
     anatomy.set_device_kind(devices[0].device_kind)

@@ -1194,14 +1194,11 @@ class BatchReconciler:
         """Stage batch k+1: pack per-shard buffers, parse natively,
         dispatch the device hash of ALL rows, START the async output
         transfer. No database access happens here. The whole seam is
-        one `device_dispatch` stage record (obs.anatomy): the fixed
-        per-dispatch cost separates from the per-row slope in the stage
-        fit, and where the device has a priced pipeline floor a
-        dispatch above FLOOR_FACTOR× it flags
-        evolu_stage_over_floor_total. Four children tile it seam to
-        seam, each recorded once per pass: `pass_pack` (`_pack_batch`),
-        `pass_parse` (`parse_packed_timestamps` of every live shard),
-        and `deltas_dispatch`'s `pass_layout` + `pass_device_call`."""
+        one `device_dispatch` stage record (obs.anatomy). Four children
+        tile it seam to seam, each recorded once per pass: `pass_pack`
+        (`_pack_batch`), `pass_parse` (`parse_packed_timestamps` of
+        every live shard), and `deltas_dispatch`'s `pass_layout` +
+        `pass_device_call`."""
         with anatomy.stage("device_dispatch", cpu=False) as whole, \
                 anatomy.stage("pass_pack") as tile:
             live, shard_data, packed, shard_offsets, merged, off, pack_path = \

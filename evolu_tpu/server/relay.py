@@ -158,8 +158,7 @@ def relay_stats_payload(store, replication=None, fleet=None,
         payload["push"] = push_hub.stats_payload()
     if conn_tier is not None:
         payload["conn"] = conn_tier.stats_payload()
-    # Stage-anatomy section (ISSUE 16): per-stage counts/EWMA/fit/
-    # floor/over-floor plus the dispatch/pull/apply runtime shares.
+    # Per-stage count / total / mean of the evolu_stage_ms histogram.
     payload["stages"] = anatomy.stages_payload()
     return payload
 
@@ -283,9 +282,9 @@ class _round_leg(anatomy.batched_stage):
     `evolu_relay_stage_ms{stage=…}` observation in `closed` for the
     handler to post in ONE `metrics.observe_many` at the end of the
     round — these fire per request on 25 threads, so they skip the
-    stage accountant's fit and gauges (and the trace ring, which
-    already holds relay.sync/relay.respond), take the registry lock
-    once a round, and read no CPU clock (`anatomy.stage`: five system
+    stage totals (and the trace ring, which already holds
+    relay.sync/relay.respond), take the registry lock once a round,
+    and read no CPU clock (`anatomy.stage`: five system
     calls a round under the interpreter lock)."""
 
     __slots__ = ()

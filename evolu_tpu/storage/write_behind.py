@@ -1087,9 +1087,8 @@ class WriteBehindQueue:
                             shard=str(pick), exemplar=dspan.trace_id)
             # The host_apply stage seam, per shard: in deferred mode
             # the drain IS engine.finish_batch's btree+tree leg, so
-            # the stage anatomy (obs/anatomy.py) prices it here —
-            # against the same 720k rows/s/core law — instead of
-            # inside the serving pass it left.
+            # it is recorded here instead of inside the serving pass
+            # it left.
             anatomy.record_stage("host_apply", dt, rows=rows, shard=pick)
 
     # -- materialization --

@@ -200,11 +200,10 @@ class Logger:
             flight, metrics, trace, anatomy = _obs()
             metrics.observe("evolu_kernel_span_ms", ms, target=target)
             if target.startswith("kernel:"):
-                # Stage-anatomy fold (ISSUE 16): kernel spans become
-                # evolu_stage_* series keyed by their target, with the
-                # span's n= field as the row count so the per-stage fit
-                # separates fixed RTT from slope. Bounded label set —
-                # targets come from TARGETS, never request data.
+                # Kernel spans become evolu_stage_* series keyed by
+                # their target, with the span's n= field as the row
+                # count. Bounded label set — targets come from
+                # TARGETS, never request data.
                 anatomy.record_span(target, ms, rows=fields.get("n", 0))
             flight.recorder.record_event(ev)
             tctx = trace.current()
@@ -269,11 +268,10 @@ class Logger:
             self._ring.clear()
             self._durations.clear()
         if globals().get("logger") is self:
-            flight, metrics, trace, anatomy = _obs()
-            metrics.reset()
+            flight, metrics, trace = _obs()[:3]
+            metrics.reset()  # the stage payload is a view of it
             flight.recorder.clear()
             trace.recorder.clear()
-            anatomy.reset()
 
 
 # Module-level default, mirroring the reference's module singleton. The

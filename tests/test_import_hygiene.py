@@ -77,20 +77,19 @@ def test_obs_package_never_imports_jax():
     assert "CLEAN" in proc.stdout, "evolu_tpu.obs transitively imported jax"
 
 
-def test_anatomy_module_never_imports_jax_and_prices_without_a_backend():
-    """ISSUE 16's explicit pin for the stage-anatomy module ALONE:
-    importing, setting the device kind, pricing floors, recording stages,
-    fingerprinting the registry, and rendering the /stats payload must
-    never pull jax into the process — the plane runs on relays that
-    serve pure-host workloads and must stay jax-free (the device kind is
-    PUSHED in from parallel/mesh.py on jax-side paths)."""
+def test_anatomy_module_never_imports_jax_and_records_without_a_backend():
+    """The explicit pin for the stage module ALONE: importing, setting
+    the device kind, recording stages and rendering the /stats payload
+    must never pull jax into the process — stages are recorded on relays
+    that serve pure-host workloads and must stay jax-free (the device
+    kind is PUSHED in from parallel/mesh.py on jax-side paths)."""
     script = (
-        "import sys; from evolu_tpu.obs import anatomy; "
-        "anatomy.set_device_kind(anatomy.V5E); "
-        "assert anatomy.floor_ms('key_sort', rows=1_000_000) > 0; "
-        "anatomy.record_stage('host_apply', 0.01, rows=7200); "
-        "assert len(anatomy.registry_digest()) == 8; "
+        "import sys; from evolu_tpu.obs import anatomy\n"
+        "anatomy.set_device_kind('TPU v5 lite')\n"
+        "anatomy.record_stage('host_apply', 0.01, rows=7200)\n"
+        "with anatomy.stage('pass_pack'): pass\n"
         "p = anatomy.stages_payload(); "
+        "assert p['stages']['pass_pack']['count'] == 1; "
         "assert p['stages']['host_apply']['count'] == 1; "
         "print('JAX_LOADED' if 'jax' in sys.modules else 'CLEAN')"
     )

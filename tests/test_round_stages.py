@@ -171,6 +171,26 @@ def test_pass_stages_tile_the_pass(server):
     assert sum(seconds[s] for s in APPLY_CHILDREN) <= seconds["host_apply"]
 
 
+# Every stage one served pass records, in the order the pass reaches them.
+SERVED_STAGES = (("device_dispatch",) + DISPATCH_CHILDREN + ("host_apply",)
+                 + PASS_STAGES[4:] + ("pull_wave", "kernel:merkle"))
+
+
+@pytest.mark.parametrize("name", SERVED_STAGES)
+def test_stats_stage_is_the_registrys_histogram(server, name):
+    """An operator sees over HTTP exactly what the benchmark reads:
+    `GET /stats` → `stages[name]` is the `evolu_stage_ms{stage=name}`
+    histogram's count, and its `total_ms` is 1,000 x
+    `evolu_stage_seconds_total{stage=name}`, which the same record
+    posts in the same call."""
+    import json
+
+    with urllib.request.urlopen(server.url + "/stats", timeout=120) as r:
+        seen = json.loads(r.read())["stages"]["stages"][name]
+    assert 0 < seen["count"] == _hist("evolu_stage_ms", stage=name)[1]
+    assert seen["total_ms"] == pytest.approx(1e3 * _stage_seconds(name), rel=1e-9)
+
+
 @pytest.mark.parametrize("traced", [True, False], ids=["trace-on", "trace-off"])
 def test_round_families_count_every_request(server, traced):
     families = [("evolu_sched_queue_wait_ms", {}), ("evolu_sched_wake_ms", {}),

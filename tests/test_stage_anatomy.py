@@ -1,30 +1,20 @@
-"""Stage-anatomy plane (evolu_tpu/obs/anatomy.py + the ablation
-harness benchmarks/stage_anatomy.py) — registry shape and digest
-stability, floor pricing against the recorded v5e laws, unmeasured laws
-reading "not measured", an unknown device asked for by name raising,
-the evolu_stage_* metrics family
-(histograms/counters/gauges, over-floor flagging past warmup, the
-decayed slope/fixed fit recovering a synthetic cost law, runtime share
-gauges), kernel-span folding through utils.log.span, the /stats
-payload, and registry↔harness agreement (variant arity, device-stage
-order, truncated-variant structural containment)."""
+"""The stage primitive (evolu_tpu/obs/anatomy.py): the evolu_stage_*
+histograms and totals a record posts in one registry acquisition and
+nothing beside them, seams that tile their parent, a stage's CPU time
+beside its wall time, kernel-span folding through utils.log.span, and
+the /stats payload as a view of the registry."""
 
 import json
-import os
-import sys
 
 import pytest
 
 from evolu_tpu.obs import anatomy, metrics
 from evolu_tpu.utils.log import logger, span
 
-sys.path.insert(0, os.path.join(os.path.dirname(os.path.dirname(
-    os.path.abspath(__file__))), "benchmarks"))
-
 
 @pytest.fixture(autouse=True)
 def _clean_slate():
-    logger.clear()  # resets metrics registry + anatomy accumulators
+    logger.clear()  # resets the metrics registry, which the payload reads
     prev = anatomy.get_device_kind()
     yield
     anatomy.set_device_kind(prev)
@@ -32,180 +22,18 @@ def _clean_slate():
     logger.clear()
 
 
-# --- registry shape + digests ---
-
-
-def test_registry_shape():
-    names = [s.name for s in anatomy.STAGES]
-    assert names == [
-        "key_sort", "plan_compare", "hash_render", "minute_fold",
-        "delta_encode", "pull_wave", "device_dispatch", "host_apply",
-    ]
-    device = [s for s in anatomy.STAGES if s.kind == "device"]
-    assert len(device) == 5
-    # Device stages chain: each stage's inputs come from prior outputs
-    # or the kernel's own input columns.
-    produced = {"cell_id", "k1", "k2", "ex_k1", "ex_k2", "owner_ix"}
-    for s in device:
-        assert set(s.inputs) <= produced, (s.name, s.inputs)
-        produced |= set(s.outputs)
-    # Every price term names a law key some device has measured (a
-    # typo would silently unprice the stage everywhere), and every law
-    # a device records prices some stage.
-    price_keys = {
-        law_key for s in anatomy.STAGES for law_key, unit in s.price
-        if unit != "device_pipeline"
-    }
-    measured = set().union(*anatomy.COST_LAWS.values())
-    assert price_keys == measured
-
-
-def test_registry_digest_is_stable_and_law_sensitive():
-    d1 = anatomy.registry_digest()
-    assert d1 == anatomy.registry_digest()
-    assert len(d1) == 8 and int(d1, 16) >= 0
-    old = anatomy.COST_LAWS[anatomy.V5E]["sort_key_ms_per_1m"]
-    try:
-        anatomy.COST_LAWS[anatomy.V5E]["sort_key_ms_per_1m"] = old * 2
-        assert anatomy.registry_digest() != d1  # re-pricing moves the gate
-    finally:
-        anatomy.COST_LAWS[anatomy.V5E]["sort_key_ms_per_1m"] = old
-
-
-# --- floor pricing ---
-
-
-def test_floor_prices_v5e_laws_exactly():
-    v5e = anatomy.V5E
-    # key_sort at 1M rows = 1.5 (key) + 2 × 0.75 (payloads) = 3.0 ms.
-    assert anatomy.floor_ms("key_sort", rows=1_000_000,
-                            device_kind=v5e) == pytest.approx(3.0)
-    # host_apply is throughput-priced: 720k rows at 720k rows/s = 1 s.
-    assert anatomy.floor_ms("host_apply", rows=720_000,
-                            device_kind=v5e) == pytest.approx(1000.0)
-    # Span targets price as the sum of their mapped stages.
-    merkle = sum(
-        anatomy.floor_ms(s, rows=1_000_000, device_kind=v5e)
-        for s in ("hash_render", "minute_fold", "delta_encode")
-    )
-    assert anatomy.floor_ms("kernel:merkle", rows=1_000_000,
-                            device_kind=v5e) == pytest.approx(merkle)
-
-
-def test_floor_prices_bandwidth_and_fixed_terms_where_measured():
-    # pull_wave is bandwidth-priced: 7650 MB at 7650 MB/s = 1000 ms.
-    assert anatomy.floor_ms("pull_wave", nbytes=7_650_000_000,
-                            device_kind="cpu") == pytest.approx(1000.0)
-    # device_dispatch = fixed intercept + the whole device pipeline.
-    dev_sum = sum(
-        anatomy.floor_ms(s.name, rows=1_000_000, device_kind="cpu")
-        for s in anatomy.STAGES if s.kind == "device"
-    )
-    assert anatomy.floor_ms("device_dispatch", rows=1_000_000,
-                            device_kind="cpu") == pytest.approx(261.0 + dev_sum)
-
-
-def test_unmeasured_v5e_laws_read_not_measured():
-    """No dispatch intercept and no pull bandwidth has been measured on
-    the attached chip: the stages priced by them are unpriced there —
-    None, never a number carried over from another attachment — and so
-    is everything that sums them."""
-    laws = anatomy.COST_LAWS[anatomy.V5E]
-    assert "fixed_dispatch_ms" not in laws and "pull_mb_per_s" not in laws
-    assert anatomy.floor_ms("pull_wave", nbytes=1 << 20,
-                            device_kind=anatomy.V5E) is None
-    assert anatomy.floor_ms("device_dispatch", rows=1 << 20,
-                            device_kind=anatomy.V5E) is None
-    assert anatomy.floor_ms("kernel:reconcile", rows=1 << 20,
-                            device_kind=anatomy.V5E) is None
-    anatomy.set_device_kind(anatomy.V5E)
-    for _ in range(4):  # past warmup: unpriced is never flagged
-        anatomy.record_stage("pull_wave", 10.0, nbytes=1)
-    assert metrics.get_counter("evolu_stage_over_floor_total",
-                               stage="pull_wave") == 0
-    assert metrics.registry.get_gauge("evolu_stage_floor_ms",
-                                      stage="pull_wave") is None
-    assert anatomy.stages_payload()["stages"]["pull_wave"]["floor_ms"] is None
-
-
-def test_unknown_device_by_name_raises_and_unknown_stage_is_unpriced():
-    with pytest.raises(KeyError):
-        anatomy.floor_ms("key_sort", rows=1 << 20, device_kind="riscv")
-    assert anatomy.floor_ms("no_such_stage", rows=1 << 20,
-                            device_kind=anatomy.V5E) is None
-    # The runtime accountant names no device: whatever the process came
-    # up on is recorded unpriced, and compute is never gated.
-    anatomy.set_device_kind("riscv")
-    assert anatomy.floor_ms("key_sort", rows=1 << 20) is None
-    anatomy.record_stage("key_sort", 0.5, rows=1 << 20)
-    assert anatomy.stages_payload()["stages"]["key_sort"]["floor_ms"] is None
-
-
 # --- the evolu_stage_* family ---
 
 
 def test_record_stage_emits_family():
-    anatomy.set_device_kind(anatomy.V5E)
-    anatomy.record_stage("host_apply", 0.010, rows=7200)  # floor = 10 ms
+    anatomy.record_stage("host_apply", 0.010, rows=7200)
     assert metrics.get_counter("evolu_stage_seconds_total",
                                stage="host_apply") == pytest.approx(0.010)
     assert metrics.get_counter("evolu_stage_rows_total",
                                stage="host_apply") == 7200
-    _, _, _, count = metrics.registry.get_histogram("evolu_stage_ms",
-                                                    stage="host_apply")
-    assert count == 1
-    assert metrics.registry.get_gauge(
-        "evolu_stage_floor_ms", stage="host_apply") == pytest.approx(10.0)
-    assert metrics.registry.get_gauge(
-        "evolu_stage_over_floor_ratio", stage="host_apply"
-    ) == pytest.approx(1.0)
-
-
-def test_over_floor_flags_only_past_warmup():
-    anatomy.set_device_kind(anatomy.V5E)
-    # floor = 10 ms; 100 ms is 10× over FLOOR_FACTOR=4.
-    for _ in range(2):  # warmup records never flag (compile time)
-        anatomy.record_stage("host_apply", 0.100, rows=7200)
-    assert metrics.get_counter("evolu_stage_over_floor_total",
-                               stage="host_apply") == 0
-    anatomy.record_stage("host_apply", 0.100, rows=7200)
-    assert metrics.get_counter("evolu_stage_over_floor_total",
-                               stage="host_apply") == 1
-    anatomy.record_stage("host_apply", 0.011, rows=7200)  # healthy: no flag
-    assert metrics.get_counter("evolu_stage_over_floor_total",
-                               stage="host_apply") == 1
-
-
-def test_slope_fit_recovers_synthetic_law():
-    # Synthetic stage law: 5 ms fixed + 2 µs/row. The decayed online
-    # fit must separate intercept from slope (the wall/count trap).
-    anatomy.set_device_kind("unknown-bench")
-    for rows in (1000, 4000, 16000, 2000, 8000, 32000):
-        anatomy.record_stage("device_dispatch", (5.0 + 0.002 * rows) / 1e3,
-                             rows=rows)
-    slope = metrics.registry.get_gauge("evolu_stage_slope_ns_per_row",
-                                       stage="device_dispatch")
-    fixed = metrics.registry.get_gauge("evolu_stage_fixed_ms",
-                                       stage="device_dispatch")
-    assert slope == pytest.approx(2000.0, rel=0.05)  # 2 µs = 2000 ns/row
-    assert fixed == pytest.approx(5.0, rel=0.05)
-
-
-def test_runtime_share_gauges():
-    anatomy.set_device_kind("unknown-bench")
-    anatomy.record_stage("device_dispatch", 0.030, rows=100)
-    anatomy.record_stage("pull_wave", 0.010, nbytes=1000)
-    anatomy.record_stage("host_apply", 0.060, rows=100)
-    total = 0.030 + 0.010 + 0.060
-    assert metrics.registry.get_gauge(
-        "evolu_stage_share", stage="host_apply"
-    ) == pytest.approx(0.060 / total)
-    assert metrics.registry.get_gauge(
-        "evolu_stage_share", stage="pull_wave"
-    ) == pytest.approx(0.010 / total)
-    payload = anatomy.stages_payload()
-    assert payload["stages"]["device_dispatch"]["share"] == pytest.approx(
-        0.030 / total)
+    _, _, total, count = metrics.registry.get_histogram("evolu_stage_ms",
+                                                        stage="host_apply")
+    assert (total, count) == (pytest.approx(10.0), 1)
 
 
 def test_disabled_registry_records_nothing():
@@ -215,13 +43,19 @@ def test_disabled_registry_records_nothing():
     finally:
         metrics.set_enabled(True)
     assert anatomy.stages_payload()["stages"] == {}
+    assert metrics.registry.get_histogram("evolu_stage_ms", stage="host_apply") is None
 
 
-def test_stage_primitive_tiles_its_parent_at_the_seams():
+def test_stage_primitive_tiles_its_parent_at_the_seams(monkeypatch):
     """`stage` is `record_stage` at both edges of an interval; `then`
     is a seam (one clock read closes a tile and opens the next), so the
     children of a parent tile it with no gap and no overlap; `stop` on
-    a stage that is not running, or was never started, is a no-op."""
+    a stage that is not running, or was never started, is a no-op. On
+    a clock that moves one step a read (a power of two, so every sum is
+    exact) the three children are the parent less its first and last
+    instants, to the bit."""
+    step = 2.0 ** -7
+    monkeypatch.setattr(anatomy, "time", _Clocks(wall_step=step, cpu_step=2.0 ** -9))
     with anatomy.stage("device_dispatch", rows=7) as whole, \
             anatomy.stage("pass_pack", rows=7) as tile:
         tile.then("pass_parse")
@@ -233,16 +67,13 @@ def test_stage_primitive_tiles_its_parent_at_the_seams():
     assert [stages[s]["count"] for s in (
         "device_dispatch", "pass_pack", "pass_parse", "pass_layout")] == [1, 1, 1, 1]
     assert "pass_respond" not in stages
-    assert stages["pass_pack"]["floor_ms"] is None  # runtime seams are unpriced
     children = sum(metrics.get_counter("evolu_stage_seconds_total", stage=s)
                    for s in ("pass_pack", "pass_parse", "pass_layout"))
     assert 0 < children <= whole.seconds
-    assert children == pytest.approx(whole.seconds, abs=2e-4)
+    assert children == 3 * step and whole.seconds == 5 * step
+    assert children == whole.seconds - 2 * step  # no gap, no overlap at a seam
     assert metrics.get_counter("evolu_stage_rows_total", stage="pass_pack") == 7
     assert metrics.get_counter("evolu_stage_bytes_total", stage="pass_parse") == 64
-    # The seam names are runtime names, not ablation-registry entries.
-    assert not {"pass_pack", "pass_parse", "pass_layout"} & {
-        s.name for s in anatomy.STAGES}
 
 
 def test_stage_lands_in_the_ambient_trace_and_costs_nothing_disabled():
@@ -428,14 +259,13 @@ class _CountingLock:
 
 
 def test_a_stage_posts_its_histograms_and_totals_in_one_acquisition(monkeypatch):
-    """`_StageAccountant.record`: `evolu_stage_ms`, its wait, the shard
-    split and the seconds / rows / bytes totals under ONE acquisition
-    of the registry's lock (three to five before ISSUE 37); an unpriced
-    stage's first record sets no gauge, so one is all it takes."""
-    anatomy.set_device_kind("unknown-bench")
+    """`anatomy.record_stage`: `evolu_stage_ms`, its wait, the shard
+    split and the seconds / rows / bytes totals under ONE acquisition of
+    the registry's lock, and nothing else."""
     lock = _CountingLock(metrics.registry._lock)
     monkeypatch.setattr(metrics.registry, "_lock", lock)
-    anatomy._acct.record("pass_x37", 0.004, rows=7, nbytes=64, shard=2, wait=0.001)
+    anatomy.record_stage("pass_x37", 0.004, rows=7, nbytes=64, shard=2,
+                         wait=0.001)
     assert lock.taken == 1
     with anatomy.stage("pass_y37", rows=3):
         pass
@@ -452,8 +282,45 @@ def test_a_stage_posts_its_histograms_and_totals_in_one_acquisition(monkeypatch)
     assert metrics.get_counter("evolu_stage_bytes_total", stage="pass_y37") == 0
 
 
+@pytest.mark.parametrize("via", ["stage", "stage.then", "record_stage", "record_span"])
+def test_a_record_posts_once_and_sets_no_gauge(via, monkeypatch):
+    """Every way into the family is ONE `metrics.observe_many` an
+    interval, carrying its histogram(s) and the three totals, and no
+    gauge and no separate counter beside it."""
+    def refuse(*_a, **_k):
+        raise AssertionError("a stage record set a gauge or a lone counter")
+
+    for target in (metrics, metrics.registry):
+        monkeypatch.setattr(target, "set_gauge", refuse)
+        monkeypatch.setattr(target, "inc", refuse)
+    posts = []
+    real = metrics.observe_many
+    monkeypatch.setattr(metrics, "observe_many",
+                        lambda items, **kw: posts.append((list(items), kw)) or real(items, **kw))
+    if via == "stage":
+        with anatomy.stage("seam_a", rows=3, nbytes=8):
+            pass
+    elif via == "stage.then":
+        tile = anatomy.stage("seam_a", rows=3, nbytes=8).start()
+        tile.then("seam_b")
+        assert len(posts) == 1  # the seam closed one interval: one post
+        tile.stop()
+        posts = posts[:1]
+    elif via == "record_stage":
+        anatomy.record_stage("seam_a", 0.002, rows=3, nbytes=8)
+    else:
+        anatomy.record_span("seam_a", 2.0, rows=3)
+    ((items, kw),) = posts
+    families = [f for f, _v, labels in items if labels == {"stage": "seam_a"}]
+    assert families == (["evolu_stage_ms", "evolu_stage_wait_ms"]
+                        if via.startswith("stage") else ["evolu_stage_ms"])
+    assert [(f, labels) for f, _v, labels in kw["also_inc"]] == [
+        (f"evolu_stage_{t}_total", {"stage": "seam_a"}) for t in ("seconds", "rows", "bytes")]
+    assert metrics.get_counter("evolu_stage_rows_total", stage="seam_a") == 3
+    assert metrics.registry._gauges == {}
+
+
 def test_kernel_span_folds_into_family():
-    anatomy.set_device_kind(anatomy.V5E)
     with span("kernel:merkle", "t", n=1000):
         pass
     with span("host:apply", "t"):  # non-kernel spans stay out
@@ -463,76 +330,23 @@ def test_kernel_span_folds_into_family():
     assert "host:apply" not in payload["stages"]
     assert metrics.get_counter("evolu_stage_rows_total",
                                stage="kernel:merkle") == 1000
-    # The span target priced via its mapped stages.
-    assert payload["stages"]["kernel:merkle"]["floor_ms"] == pytest.approx(
-        anatomy.floor_ms("kernel:merkle", rows=1000, device_kind=anatomy.V5E))
+    h = metrics.registry.get_histogram("evolu_stage_ms", stage="kernel:merkle")
+    assert payload["stages"]["kernel:merkle"]["total_ms"] == h[2]
 
 
 def test_stages_payload_shape_and_reset():
-    anatomy.set_device_kind(anatomy.V5E)
+    anatomy.set_device_kind("TPU v5 lite")
     anatomy.record_stage("host_apply", 0.010, rows=7200)
+    anatomy.record_stage("host_apply", 0.030, rows=7200)
     p = anatomy.stages_payload()
-    assert p["device_kind"] == anatomy.V5E
-    assert p["registry_digest"] == anatomy.registry_digest()
-    assert p["floor_factor"] == anatomy.FLOOR_FACTOR
-    st = p["stages"]["host_apply"]
-    assert st["count"] == 1
-    assert st["ewma_ms"] == pytest.approx(10.0)
+    assert set(p) == {"device_kind", "stages"}
+    assert p["device_kind"] == "TPU v5 lite"
+    assert p["stages"]["host_apply"] == {
+        "count": 2, "total_ms": pytest.approx(40.0), "mean_ms": pytest.approx(20.0)}
     json.dumps(p)  # must be JSON-clean for GET /stats
     logger.clear()
     assert anatomy.stages_payload()["stages"] == {}
-    assert anatomy.get_device_kind() == anatomy.V5E  # survives clear
-
-
-# --- registry ↔ ablation-harness agreement ---
-
-
-def test_harness_matches_registry():
-    import stage_anatomy as sa
-
-    assert sa.DEVICE_STAGES == tuple(
-        s.name for s in anatomy.STAGES if s.kind == "device")
-    # Cumulative arity: key_sort 3, +3, +2, +5, +3 = 16.
-    assert [sa.variant_arity(s) for s in sa.DEVICE_STAGES] == [3, 6, 8, 13, 16]
-    assert list(sa.stage_output_indices("hash_render")) == [6, 7]
-    assert list(sa.stage_output_indices("key_sort")) == [0, 1, 2]
-
-
-def test_truncated_variants_nest_structurally():
-    """Each truncated variant's jaxpr primitive multiset must be a
-    sub-multiset of the next one's — ablation only ever REMOVES tail
-    work, so a stage can never change the upstream computation it
-    claims to be measuring."""
-    jax = pytest.importorskip("jax")
-    import numpy as np
-
-    import stage_anatomy as sa
-
-    n = 256
-    probe = (
-        np.full(n, 0x7FFFFFFF, np.int32),
-        np.zeros(n, np.uint64), np.zeros(n, np.uint64),
-        np.zeros(n, np.uint64), np.zeros(n, np.uint64),
-        np.zeros(n, np.int64),
-    )
-    from collections import Counter
-
-    from evolu_tpu.parallel.mesh import create_mesh
-
-    mesh = create_mesh()
-    multisets = []
-    with jax.enable_x64(True):
-        for name in sa.DEVICE_STAGES:
-            loop = sa.make_variant_loop(mesh, 1, sa.build_variant(name))
-            jaxpr = jax.make_jaxpr(loop)(*probe)
-            prims = []
-            sa._collect_prims(jaxpr.jaxpr, prims)
-            multisets.append(Counter(prims))
-    for prev, cur in zip(multisets, multisets[1:]):
-        assert not prev - cur, f"ablation removed upstream work: {prev - cur}"
-    # And each stage genuinely adds primitives.
-    for prev, cur in zip(multisets, multisets[1:]):
-        assert cur - prev
+    assert anatomy.get_device_kind() == "TPU v5 lite"  # survives clear
 
 
 # --- the client tree's counters (ISSUE 34) ---
