@@ -24,7 +24,7 @@ from typing import Callable, Optional
 
 from evolu_tpu.core.timestamp import timestamp_from_string
 from evolu_tpu.core.types import CrdtMessage, UnknownError
-from evolu_tpu.obs import metrics, trace
+from evolu_tpu.obs import anatomy, metrics, trace
 from evolu_tpu.runtime.messages import OnError, SyncRequestInput
 from evolu_tpu.runtime.synclock import SyncLock
 from evolu_tpu.sync import protocol
@@ -511,6 +511,9 @@ class SyncTransport:
                 log("sync:request", "aead downgrade for failover", url=new_url)
 
         followed = False
+        # `sync_post`, on this thread: the body handed to the POST to the
+        # answer's bytes read, retries and a followed redirect included.
+        t_post = time.perf_counter()
         try:
             while True:
                 try:
@@ -576,6 +579,7 @@ class SyncTransport:
                     return None
         except _Abort:
             return None
+        post_s = time.perf_counter() - t_post
         self._note_online()
         if self.push_subscriber is not None:
             # Bind/retarget the push leg with what this round learned:
@@ -656,6 +660,11 @@ class SyncTransport:
                     response = protocol.decode_sync_response(response_bytes)
                     messages = decrypt_messages(response.messages, request.owner.mnemonic)
                     merkle_tree = response.merkle_tree
+            # Its rows are the answer's messages, known only once it is
+            # decoded: the interval is posted here, as the one record a
+            # stage makes.
+            anatomy.record_stage("sync_post", post_s, rows=len(messages),
+                                 nbytes=len(response_bytes))
             metrics.inc("evolu_sync_responses_total")
             metrics.inc("evolu_sync_response_messages_total", len(messages))
             metrics.observe("evolu_sync_response_bytes", len(response_bytes),
