@@ -79,11 +79,13 @@ def _configure(lib: ctypes.CDLL) -> ctypes.CDLL:
     # call's heap reservation held (native/evolu_host.cpp, reserve_heap).
     lib.eh_apply_sequential.argtypes = [
         p, i64, sp, sp, sp, sp, i32p, i64p, dp, sp, i32p, u8p, i64p]
+    # The planned applies pass `out_multi` (int64*) before it: the rows
+    # their `__message` insert stepped through its multi-row statement.
     lib.eh_apply_planned_packed.argtypes = [
-        p, i64, s, i32p, s, i32p, s, i32p, s, i32p, i32p, i64p, dp, s, i32p, u8p, i64p,
+        p, i64, s, i32p, s, i32p, s, i32p, s, i32p, i32p, i64p, dp, s, i32p, u8p, i64p, i64p,
     ]
     lib.eh_apply_planned_cells.argtypes = [
-        p, i64, s, i64, s, i32p, i32p, u8p, i64p, dp, s, i32p, u8p, i64p,
+        p, i64, s, i64, s, i32p, i32p, u8p, i64p, dp, s, i32p, u8p, i64p, i64p,
     ]
     lib.eh_relay_insert.argtypes = [p, i64, sp, sp, sp, i32p, u8p, i64p]
     lib.eh_relay_insert_packed.argtypes = [p, i64, sp, i64p, s, s, i32p, u8p, i64p]
@@ -299,6 +301,17 @@ def _count_reserved(reserved: ctypes.c_int64, also=()) -> None:
         items.append(("evolu_native_heap_reserve_calls_total", 1, {}))
     if items:
         metrics.inc_many(items)
+
+
+def _rows_by_statement(n: int, multi: ctypes.c_int64, rc: int) -> Tuple:
+    """`evolu_apply_rows_total{stmt}` items for one planned apply of n
+    rows that returned `rc`: the rows its `__message` insert stepped
+    through the multi-row statement (`out_multi`) and the rest; none for
+    a call that failed, whose rows the caller rolls back."""
+    if rc != 0:
+        return ()
+    return (("evolu_apply_rows_total", multi.value, {"stmt": "multi"}),
+            ("evolu_apply_rows_total", n - multi.value, {"stmt": "single"}))
 
 
 def _str_array(items: Sequence[str]):
@@ -713,7 +726,7 @@ class CppSqliteDatabase:
         mask_np = np.ascontiguousarray(np.asarray(upsert_mask, dtype=np.uint8))
         if len(mask_np) != n:  # C reads n bytes; a short buffer would be OOB
             raise ValueError(f"upsert_mask length {len(mask_np)} != messages {n}")
-        reserved = ctypes.c_int64()
+        multi, reserved = ctypes.c_int64(), ctypes.c_int64()
         with self._lock:
             self._check_open()
             rc = self._lib.eh_apply_planned_packed(
@@ -725,9 +738,9 @@ class CppSqliteDatabase:
                 dvals.ctypes.data_as(ctypes.POINTER(ctypes.c_double)),
                 val_buf, vlens.ctypes.data_as(i32p),
                 mask_np.ctypes.data_as(ctypes.POINTER(ctypes.c_uint8)),
-                ctypes.byref(reserved),
+                ctypes.byref(multi), ctypes.byref(reserved),
             )
-        _count_reserved(reserved)
+        _count_reserved(reserved, _rows_by_statement(n, multi, rc))
         if rc == 3:
             raise UnknownError("identifier contains NUL")
         if rc != 0:
@@ -758,7 +771,7 @@ class CppSqliteDatabase:
         mask_np = np.ascontiguousarray(np.asarray(upsert_mask, dtype=np.uint8))
         if len(mask_np) != n:  # C reads n bytes; a short buffer would be OOB
             raise ValueError(f"upsert_mask length {len(mask_np)} != rows {n}")
-        reserved = ctypes.c_int64()
+        multi, reserved = ctypes.c_int64(), ctypes.c_int64()
         with self._lock:
             self._check_open()
             rc = self._lib.eh_apply_planned_cells(
@@ -769,9 +782,10 @@ class CppSqliteDatabase:
                 ivals.ctypes.data_as(ctypes.POINTER(ctypes.c_int64)),
                 dvals.ctypes.data_as(ctypes.POINTER(ctypes.c_double)),
                 vblob, vlens.ctypes.data_as(i32p),
-                mask_np.ctypes.data_as(u8p), ctypes.byref(reserved),
+                mask_np.ctypes.data_as(u8p), ctypes.byref(multi),
+                ctypes.byref(reserved),
             )
-        _count_reserved(reserved)
+        _count_reserved(reserved, _rows_by_statement(n, multi, rc))
         if rc == 3:
             raise UnknownError("identifier contains NUL")
         if rc == 2:

@@ -615,6 +615,178 @@ def test_apply_planned_cells_end_state_on_both_sides_of_a_block_and_the_cap(size
     cpp.close(), py.close()
 
 
+
+# --- the multi-row __message insert ---
+#
+# `MessageInserter` in native/evolu_host.cpp: the planned applies bind
+# `__message` rows CHUNK at a time into one multi-row statement, stepped
+# once a chunk, and take the single-row statement for a tail under
+# CHUNK. What is pinned: on both sides of a chunk, into an empty store
+# and into one that holds rows, with timestamps repeated inside the
+# batch and against stored rows, the end state equals the Python
+# backend's down to the rowids and the index's entries, and the rows
+# split between the two statements as `evolu_apply_rows_total` says; a
+# call that fails after chunks were stepped leaves `__message` as it
+# was; the sequential loop still looks each winner up after the
+# previous message's insert.
+
+CHUNK = 64  # kInsertChunk
+ROWS = "evolu_apply_rows_total"
+
+
+def _rows_split():
+    return tuple(metrics.get_counter(ROWS, stmt=s) for s in ("multi", "single"))
+
+
+def _packed_receive(msgs):
+    from evolu_tpu.sync import native_crypto
+    from evolu_tpu.sync.client import encrypt_messages
+
+    mnemonic = "legal winner thank year wave sausage worth useful legal winner thank yellow"
+    wire = protocol.encode_sync_response(
+        protocol.SyncResponse(tuple(encrypt_messages(msgs, mnemonic)), "{}"))
+    return native_crypto.decrypt_response_columns(wire, mnemonic)[0]
+
+
+def _full_dump(db):
+    """`__message` by rowid, the entries of `index__message` in its
+    order with the rowid each points at, the app tables by id (the
+    Python backend upserts in its planner's order, so their rowids are
+    not its to match)."""
+    q = db.exec_sql_query
+    return {
+        "__message": q('SELECT rowid, * FROM "__message" ORDER BY rowid'),
+        "index__message": q('SELECT rowid, "table", "row", "column", "timestamp" '
+                            'FROM "__message" INDEXED BY "index__message" '
+                            'ORDER BY "table", "row", "column", "timestamp"'),
+        **{t: q(f'SELECT * FROM "{t}" ORDER BY "id"') for t in ("todo", "todoCategory")},
+    }
+
+
+def _with_repeats(msgs, stored):
+    """`msgs` with timestamps repeated: every fifth replays the message
+    before it and every eleventh takes its timestamp and cell with
+    another value; with `stored`, every third replays a stored message
+    and every thirteenth takes a stored one's timestamp and cell."""
+    out = list(msgs)
+    for j in range(len(out)):
+        if stored and j % 3 == 0:
+            out[j] = stored[7 * j % len(stored)]
+        elif stored and j % 13 == 6:
+            s = stored[j % len(stored)]
+            out[j] = CrdtMessage(s.timestamp, s.table, s.row, s.column, f"other{j}")
+        elif j % 5 == 2:
+            out[j] = out[j - 1]
+        elif j % 11 == 4:
+            p = out[j - 1]
+            out[j] = CrdtMessage(p.timestamp, p.table, p.row, p.column, f"other{j}")
+    return out
+
+
+@pytest.mark.parametrize("stored", [False, True], ids=["empty", "stored"])
+@pytest.mark.parametrize("n", [1, CHUNK - 1, CHUNK, CHUNK + 1, 3 * CHUNK + 5])
+def test_apply_planned_cells_lands_chunked_rows_as_the_python_backend(n, stored):
+    from evolu_tpu.runtime.worker import select_planner
+    from evolu_tpu.sync import native_crypto
+    from evolu_tpu.utils.config import Config
+
+    if not native_crypto.native_available():
+        pytest.skip("the PackedReceive comes from the native decrypt")
+    held = make_messages(150, seed=48) if stored else []
+    msgs = _with_repeats(make_messages(n, seed=n), held)
+    cpp, py = _client_db("native"), _client_db("python")
+    planner = select_planner(Config(backend="tpu"), cpp)
+    tree_c = apply_messages(cpp, {}, _packed_receive(held), planner=planner) if held else {}
+    tree_p = apply_messages(py, {}, held) if held else {}
+    assert len(_full_dump(cpp)["__message"]) == len({m.timestamp for m in held})
+    packed0 = metrics.get_counter("evolu_apply_batches_total", route="packed")
+    multi0, single0 = _rows_split()
+    tree_c = apply_messages(cpp, tree_c, _packed_receive(msgs), planner=planner)
+    tree_p = apply_messages(py, tree_p, msgs)
+    assert metrics.get_counter("evolu_apply_batches_total", route="packed") == packed0 + 1
+    multi1, single1 = _rows_split()
+    assert (multi1 - multi0, single1 - single0) == (n // CHUNK * CHUNK, n % CHUNK)
+    assert _full_dump(cpp) == _full_dump(py)
+    assert merkle_tree_to_string(tree_c) == merkle_tree_to_string(tree_p)
+    cpp.close(), py.close()
+
+
+def _hand_packed(cells, cell_ids):
+    """A PackedReceive of one text value a row, row i on
+    `cells[cell_ids[i]]` (an index past the cells is kept as given)."""
+    from evolu_tpu.core.packed import PackedReceive
+
+    n = len(cell_ids)
+    enc = [x.encode() for cell in cells for x in cell]
+    vals = [b"v%d" % i for i in range(n)]
+    vlens = np.fromiter(map(len, vals), np.int32, n)
+    return PackedReceive(
+        n, "".join(ts(1_700_000_000_000 + 1000 * i) for i in range(n)).encode(), cells,
+        np.asarray(cell_ids, np.int32), np.full(n, 3, np.uint8), np.zeros(n, np.int64),
+        np.zeros(n, np.float64), vlens, (np.cumsum(vlens) - vlens).astype(np.int64),
+        b"".join(vals), b"".join(enc), np.fromiter(map(len, enc), np.int32, len(enc)))
+
+
+@pytest.mark.parametrize("fault", ["cell_index", "nul_identifier"])
+def test_a_failing_planned_apply_leaves_the_message_table_as_it_was(fault):
+    """Return code 2 (a cell index past the cells, found before any
+    statement steps) and 3 (a NUL in an upserted table's name, met after
+    two chunks were stepped) raise, and the caller's transaction takes
+    every row back; no row is counted."""
+    from evolu_tpu.core.types import UnknownError
+
+    db = _client_db("native")
+    apply_messages(db, {}, make_messages(100, seed=3))
+    before = _full_dump(db)
+    cells = [("todo", "r0", "title"), ("todoCategory", "r1", "title"), ("to\x00do", "r2", "title")]
+    n = 3 * CHUNK + 5
+    ids = [i % 2 for i in range(n)]
+    if fault == "cell_index":
+        ids[-1], want = len(cells), "cell index out of range"
+    else:
+        ids[2 * CHUNK + 3], want = 2, "identifier contains NUL"
+    split = _rows_split()
+    with pytest.raises(UnknownError, match=want):
+        with db.transaction():
+            db.apply_planned_cells(_hand_packed(cells, ids), np.ones(n, np.uint8))
+    assert _full_dump(db) == before
+    assert _rows_split() == split
+    # The same batch with every cell index in range lands whole.
+    ids = [i % 2 for i in range(n)]
+    with db.transaction():
+        db.apply_planned_cells(_hand_packed(cells, ids), np.ones(n, np.uint8))
+    assert len(_full_dump(db)["__message"]) == len(before["__message"]) + n
+    assert _rows_split() == (split[0] + 3 * CHUNK, split[1] + 5)
+    db.close()
+
+
+def test_apply_sequential_looks_each_winner_up_after_the_previous_insert():
+    """`eh_apply_sequential` keeps one insert a message, stepped before
+    the next message's winner lookup. Each cell gets a message, then an
+    older one, then a replay of the first: the older loses to a winner
+    that only this batch stored, and the replay XORs nothing. Over
+    three chunks, where an insert held back for a chunk would not yet be
+    visible to the lookups after it."""
+    msgs = []
+    for c in range(CHUNK):
+        newer = CrdtMessage(ts(1_700_000_000_000 + 1000 * c + 500), "todo", f"r{c}", "title",
+                            f"new{c}")
+        older = CrdtMessage(ts(1_700_000_000_000 + 1000 * c), "todo", f"r{c}", "title",
+                            f"old{c}")
+        msgs += [newer, older, newer]
+    cpp, py = _client_db("native"), _client_db("python")
+    with cpp.transaction():
+        flags = cpp.apply_sequential(msgs)
+    assert flags == [True, True, False] * CHUNK
+    assert [r["title"] for r in cpp.exec_sql_query('SELECT "title" FROM "todo" ORDER BY rowid')] \
+        == [f"new{c}" for c in range(CHUNK)]
+    with py.transaction():
+        apply_messages_sequential(py, {}, msgs)
+    assert _full_dump(cpp) == _full_dump(py)
+    assert len(_full_dump(cpp)["__message"]) == 2 * CHUNK
+    cpp.close(), py.close()
+
+
 SHARDS = 4
 # (rows a shard, content bytes) over 3 live shards of 4: input under one
 # block's worth (16 KiB at 4 a byte), a few blocks, under the cap

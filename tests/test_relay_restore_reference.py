@@ -195,8 +195,10 @@ def test_rehearsal_of_the_cell_ends_correct_with_every_new_metric():
     assert line["correct"] is True and line["failed"] == 0 and line["attempted"] > 0
     assert line["device"]["platform"] == "cpu"
     got = {k: v["value"] for k, v in line["metrics"].items()}
-    assert set(got) == set(NEW_METRICS) | set(TWINS)
+    assert set(got) == set(NEW_METRICS) | set(TWINS) | {"apply_multirow_share"}
     assert got["window_compiles.restore"] == 0
+    # One `Receive` of 2,000 a restore: 31 statements of 64 rows, 16 rows single.
+    assert got["apply_multirow_share"] == pytest.approx(100.0 * 1984 / 2000)
     assert got["respond_fetch_ms.restore"] <= got["pass_respond_ms.restore"]
     assert got["restore_p50_s"] * 1e3 > got["sync_post_ms"] > 0
     e2e = next(l["metrics"] for l in lines if l.get("info") == "the other set")
