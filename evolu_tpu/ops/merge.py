@@ -475,25 +475,63 @@ def select_messages(messages: Sequence[CrdtMessage], mask: np.ndarray) -> List[C
 
 def winner_key_columns(cells, winners: Dict[Tuple[str, str, str], str]):
     """Per-unique-cell stored-winner key columns: → (ex1_u, ex2_u,
-    canonical), zeros where a cell has no stored winner. The ONE
+    canonical), zeros where a cell has no stored winner. With
+    `read_winner_keys` (the same columns read natively) the ONE
     implementation of winner parse/pack/canonical-check — shared by
     `messages_to_columns`, the HBM cache's lazy seeding, and its
     streamed mode, so the canonical-case rule (a golden-parity
     invariant) can never drift between them."""
     from evolu_tpu.ops.host_parse import parse_timestamp_strings
 
-    ex1_u = np.zeros(len(cells), np.uint64)
-    ex2_u = np.zeros(len(cells), np.uint64)
     winner_cids = [i for i, cell in enumerate(cells) if cell in winners]
-    canonical = True
-    if winner_cids:
-        w_millis, w_counter, w_node, w_case_ok = parse_timestamp_strings(
-            [winners[cells[i]] for i in winner_cids], with_case=True
-        )
-        canonical = bool(w_case_ok.all())
-        ex1_u[winner_cids] = pack_ts_key_host(w_millis, w_counter)
-        ex2_u[winner_cids] = w_node
-    return ex1_u, ex2_u, canonical
+    return _key_columns(len(cells), winner_cids, parse_timestamp_strings(
+        [winners[cells[i]] for i in winner_cids], with_case=True
+    ))
+
+
+def _key_columns(n, winner_cids, parsed):
+    """The n cells' key columns from the parsed winners of
+    `winner_cids`; canonical where every winner is."""
+    w_millis, w_counter, w_node, w_case_ok = parsed
+    ex1_u = np.zeros(n, np.uint64)
+    ex2_u = np.zeros(n, np.uint64)
+    ex1_u[winner_cids] = pack_ts_key_host(w_millis, w_counter)
+    ex2_u[winner_cids] = w_node
+    return ex1_u, ex2_u, bool(w_case_ok.all())
+
+
+def read_winner_keys(db, cells, pb=None, ids=None):
+    """`winner_key_columns(cells, fetch_existing_winners(db, cells))`,
+    read in one native call where the backend has one
+    (`fetch_winner_keys`): a probe of the covering index a cell and the
+    46-byte winners parsed by `eh_parse_timestamps`, with no Python
+    object a cell. Where `pb` is given, `cells` are `pb.cells[ids]` and
+    are bound straight from the batch's interned blob; otherwise they
+    are encoded from `cells`. A stored winner of another width or
+    storage class, or one the parse refuses, sends the read the string
+    way, whose result and errors are `winner_key_columns`' own."""
+    from evolu_tpu.core.types import TimestampParseError
+    from evolu_tpu.ops.host_parse import parse_packed_timestamps
+    from evolu_tpu.storage.apply import fetch_existing_winners
+
+    fetch = getattr(db, "fetch_winner_keys", None)
+    if fetch is not None and cells:
+        parsed = None
+        with anatomy.part("winner_read"):
+            if pb is not None:
+                present, slab = fetch(pb.cell_blob, pb.cell_lens, ids)
+            else:
+                from evolu_tpu.storage.native import cell_spans
+
+                present, slab = fetch(*cell_spans(cells))
+            if not (present == 2).any():
+                try:
+                    parsed = parse_packed_timestamps(slab, len(slab) // 46, with_case=True)
+                except TimestampParseError:
+                    pass
+        if parsed is not None:
+            return _key_columns(len(cells), np.flatnonzero(present), parsed)
+    return winner_key_columns(cells, fetch_existing_winners(db, cells))
 
 
 def messages_to_columns(
@@ -673,10 +711,7 @@ def plan_packed_streamed(db, pb, millis, counter, node, cells, touched_ids):
     `cells` are the touched unique cells; `touched_ids` their indices
     into `pb.cells`. None on a non-canonical stored winner (the caller
     materializes to the object path)."""
-    from evolu_tpu.storage.apply import fetch_existing_winners
-
-    winners = fetch_existing_winners(db, cells)
-    ex1_t, ex2_t, canonical = winner_key_columns(cells, winners)
+    ex1_t, ex2_t, canonical = read_winner_keys(db, cells, pb, touched_ids)
     if not canonical:
         return None
     ex1 = np.zeros(len(pb.cells), np.uint64)

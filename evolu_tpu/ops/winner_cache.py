@@ -342,20 +342,19 @@ class DeviceWinnerCache:
             metrics.inc("evolu_winner_cache_grows_total")
             metrics.set_gauge("evolu_winner_cache_capacity_slots", new_cap)
 
-    def _seed_new_cells(self, new_cells: List[Cell]) -> bool:
+    def _seed_new_cells(self, new_cells: List[Cell], pb=None, ids=None) -> bool:
         """Assign slots to first-seen cells (reusing invalidated slots
-        first) and load their winners from SQLite in one batched read.
-        Every assigned slot is written — winner keys for cells with
-        history, zeros for the rest, so a reused slot can never leak a
-        previous cell's stale keys. Returns False when any seed winner
-        is non-canonical (the caller must take the host path; the
-        non-canonical cells stay unassigned)."""
-        from evolu_tpu.ops.merge import winner_key_columns
-        from evolu_tpu.storage.apply import fetch_existing_winners
+        first) and load their winners from SQLite in one batched read
+        (`read_winner_keys`; `pb` / `ids`: the cells are `pb.cells[ids]`,
+        bound from the batch's blob). Every assigned slot is written —
+        winner keys for cells with history, zeros for the rest, so a
+        reused slot can never leak a previous cell's stale keys. Returns
+        False when any seed winner is non-canonical (the caller must
+        take the host path; the non-canonical cells stay unassigned)."""
+        from evolu_tpu.ops.merge import read_winner_keys
 
-        winners = fetch_existing_winners(self._db, new_cells)
         n = len(new_cells)
-        v1, v2, canonical = winner_key_columns(new_cells, winners)
+        v1, v2, canonical = read_winner_keys(self._db, new_cells, pb, ids)
         if not canonical:
             # A stored non-canonical winner cannot live in the
             # numeric cache. Keep every cell of this batch
@@ -634,7 +633,9 @@ class DeviceWinnerCache:
                 return self._plan_packed_streamed(
                     pb, cells, touched_ids, millis, counter, node
                 )
-            if new_cells and not self._seed_new_cells(new_cells):
+            if new_cells and not self._seed_new_cells(
+                new_cells, pb, _new_cell_ids(cells, new_cells, touched_ids)
+            ):
                 # The gate above already took this batch's EWMA sample;
                 # the object-path re-route will re-enter the gate (via
                 # `plan_batch`) for the SAME batch — arm the one-shot
@@ -673,13 +674,9 @@ class DeviceWinnerCache:
         already-parsed columns are reused (`cols=`) so the batch is not
         host-parsed a second time — this IS the hot path while churn
         lasts."""
-        from evolu_tpu.ops.merge import plan_batch_device_full
-        from evolu_tpu.storage.apply import fetch_existing_winners
+        from evolu_tpu.ops.merge import plan_batch_device_full, read_winner_keys
 
-        from evolu_tpu.ops.merge import winner_key_columns
-
-        winners = fetch_existing_winners(self._db, cells)
-        ex1_u, ex2_u, canonical = winner_key_columns(cells, winners)
+        ex1_u, ex2_u, canonical = read_winner_keys(self._db, cells)
         if not canonical:
             # Non-canonical stored winner: host oracle (raw-string
             # order / verbatim hashing), same as the cached route.
@@ -967,6 +964,16 @@ class MeshShardedWinnerCache(DeviceWinnerCache):
         )
         deltas = decode_minute_delta_arrays(minute_sorted, seg_end, seg_xor, valid)
         return xor_flat[dest], upsert_flat[dest], deltas
+
+
+def _new_cell_ids(cells, new_cells, touched_ids):
+    """The batch ids of `new_cells`, which the gate and
+    `_enforce_capacity` leave an ordered subset of `cells` (whose ids
+    are `touched_ids`)."""
+    if len(new_cells) == len(cells):
+        return touched_ids
+    new = set(new_cells)
+    return touched_ids[np.fromiter((c in new for c in cells), bool, len(cells))]
 
 
 def _pad_seed(idx, k1, k2, capacity: int):

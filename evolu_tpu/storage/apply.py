@@ -187,30 +187,33 @@ def apply_messages_sequential(
 def fetch_existing_winners(
     db: PySqliteDatabase, cells: Iterable[Tuple[str, str, str]]
 ) -> Dict[Tuple[str, str, str], str]:
-    """Current winner timestamp per cell, one indexed query per cell batch
-    via a temp table join (uses the (table,row,column,timestamp) covering
-    index, initDbModel.ts:51-56)."""
+    """Current winner timestamp per cell (cells with none left out),
+    through the (table,row,column,timestamp) covering index
+    (initDbModel.ts:51-56). The C++ backend probes it once a cell in
+    one native call (`fetch_winners`), at any count of cells; the
+    stdlib backend, which has no native call, joins the cells as a temp
+    table. Counted in `evolu_winner_read_cells_total{route}` and timed
+    as the `winner_read` part of a tiled Receive."""
     cells = list(cells)
     if not cells:
         return {}
-    if hasattr(db, "fetch_winners") and len(cells) < 4096:
-        # C++ backend: per-cell indexed lookups in one native call —
-        # fastest for small batches; above ~4k cells the single
-        # temp-table GROUP BY join below wins (one scan vs N probes).
-        winners = db.fetch_winners(cells)
-        return {c: w for c, w in zip(cells, winners) if w is not None}
-    with db.transaction():
-        db.exec('CREATE TEMP TABLE IF NOT EXISTS "__cells" ("t" BLOB, "r" BLOB, "c" BLOB)')
-        db.run('DELETE FROM "__cells"')
-        db.run_many('INSERT INTO "__cells" VALUES (?, ?, ?)', cells)
-        rows = db.exec_sql_query(
-            'SELECT m."table" AS t, m."row" AS r, m."column" AS c, '
-            'MAX(m."timestamp") AS w FROM "__message" m '
-            'JOIN "__cells" x ON m."table" = x."t" AND m."row" = x."r" AND m."column" = x."c" '
-            "GROUP BY m.\"table\", m.\"row\", m.\"column\""
-        )
-        db.run('DELETE FROM "__cells"')
-    return {(r["t"], r["r"], r["c"]): r["w"] for r in rows}
+    with anatomy.part("winner_read"):
+        if hasattr(db, "fetch_winners"):
+            winners = db.fetch_winners(cells)
+            return {c: w for c, w in zip(cells, winners) if w is not None}
+        metrics.inc("evolu_winner_read_cells_total", len(cells), route="join")
+        with db.transaction():
+            db.exec('CREATE TEMP TABLE IF NOT EXISTS "__cells" ("t" BLOB, "r" BLOB, "c" BLOB)')
+            db.run('DELETE FROM "__cells"')
+            db.run_many('INSERT INTO "__cells" VALUES (?, ?, ?)', cells)
+            rows = db.exec_sql_query(
+                'SELECT m."table" AS t, m."row" AS r, m."column" AS c, '
+                'MAX(m."timestamp") AS w FROM "__message" m '
+                'JOIN "__cells" x ON m."table" = x."t" AND m."row" = x."r" AND m."column" = x."c" '
+                "GROUP BY m.\"table\", m.\"row\", m.\"column\""
+            )
+            db.run('DELETE FROM "__cells"')
+        return {(r["t"], r["r"], r["c"]): r["w"] for r in rows}
 
 
 def plan_batch(

@@ -74,7 +74,7 @@ def _configure(lib: ctypes.CDLL) -> ctypes.CDLL:
     lib.eh_column_blob.restype = p
     lib.eh_column_blob.argtypes = [p, i]
     lib.eh_column_bytes.argtypes = [p, i]
-    lib.eh_fetch_winners.argtypes = [p, i64, sp, sp, sp, c.c_char_p, i64]
+    lib.eh_fetch_winner_keys.argtypes = [p, i64, i64, s, i32p, i32p, u8p, s]
     # The batched calls end with `out_reserved` (int64*): what the
     # call's heap reservation held (native/evolu_host.cpp, reserve_heap).
     lib.eh_apply_sequential.argtypes = [
@@ -312,6 +312,20 @@ def _rows_by_statement(n: int, multi: ctypes.c_int64, rc: int) -> Tuple:
         return ()
     return (("evolu_apply_rows_total", multi.value, {"stmt": "multi"}),
             ("evolu_apply_rows_total", n - multi.value, {"stmt": "single"}))
+
+
+def cell_spans(cells: Sequence[Tuple[str, str, str]]):
+    """(table, row, column) cells → (blob, lens): their UTF-8 bytes back
+    to back and three byte lengths a cell, the interned-cell layout of a
+    `PackedReceive` that `fetch_winner_keys` binds from. One join and
+    one encode where every identifier is ASCII."""
+    flat = [s for cell in cells for s in cell]
+    text = "".join(flat)
+    blob = text.encode("utf-8")
+    if len(blob) != len(text):
+        flat = [s.encode("utf-8") for s in flat]
+        blob = b"".join(flat)
+    return blob, np.fromiter(map(len, flat), np.int32, len(flat))
 
 
 def _str_array(items: Sequence[str]):
@@ -643,30 +657,67 @@ class CppSqliteDatabase:
 
     # -- native hot paths --
 
+    def fetch_winner_keys(self, cell_blob: bytes, cell_lens, ids=None):
+        """`eh_fetch_winner_keys`: the stored winner of each cell read
+        by one indexed probe, for cells given as spans of an interned
+        blob (`cell_spans`, or a `PackedReceive`'s `cell_blob` /
+        `cell_lens`); `ids` picks the cells read (None: all of them).
+        → (present, slab): `present[i]` is 0 for no winner, 1 for a
+        46-byte text winner, 2 for any other stored form; `slab` holds
+        the 46-byte winners back to back, in cell order, for
+        `eh_parse_timestamps`. Counted in
+        `evolu_winner_read_cells_total{route="native"}`."""
+        lens = np.ascontiguousarray(cell_lens, np.int32)
+        if int(lens.sum()) > len(cell_blob):  # C binds spans of the blob
+            raise ValueError("cell_lens span past the end of cell_blob")
+        k = len(lens) // 3
+        if ids is not None:
+            ids = np.ascontiguousarray(ids, np.int32)
+        n = k if ids is None else len(ids)
+        present = np.zeros(n, np.uint8)
+        slab = ctypes.create_string_buffer(max(n, 1) * 46)
+        i32p = ctypes.POINTER(ctypes.c_int32)
+        with self._lock:
+            self._check_open()
+            rc = self._lib.eh_fetch_winner_keys(
+                self._db, n, k, cell_blob, lens.ctypes.data_as(i32p),
+                None if ids is None else ids.ctypes.data_as(i32p),
+                present.ctypes.data_as(ctypes.POINTER(ctypes.c_uint8)), slab,
+            )
+        if rc == 2:
+            raise UnknownError("fetch_winner_keys: cell index out of range")
+        if rc != 0:
+            raise self._err()
+        metrics.inc("evolu_winner_read_cells_total", n, route="native")
+        return present, ctypes.string_at(slab, 46 * int(np.count_nonzero(present == 1)))
+
     def fetch_winners(
         self, cells: Sequence[Tuple[str, str, str]]
     ) -> List[Optional[str]]:
-        """Winner timestamp per cell (None = no stored winner)."""
-        n = len(cells)
-        if n == 0:
+        """Winner timestamp per cell (None = no stored winner), read by
+        `fetch_winner_keys`; a winner of another width or storage class
+        is read back by the same query on its own, exactly as stored."""
+        from evolu_tpu.storage.apply import _SELECT_WINNER
+
+        if not cells:
             return []
-        cap = 64
-        out = ctypes.create_string_buffer(n * cap)
-        with self._lock:
-            self._check_open()
-            rc = self._lib.eh_fetch_winners(
-                self._db, n,
-                _str_array([c[0] for c in cells]),
-                _str_array([c[1] for c in cells]),
-                _str_array([c[2] for c in cells]),
-                out, cap,
-            )
-        if rc != 0:
-            raise self._err()
+        present, slab = self.fetch_winner_keys(*cell_spans(cells))
+        text = slab.decode("utf-8")
+        if len(text) != len(slab):  # non-ASCII: slice the bytes instead
+            text = [slab[j : j + 46].decode("utf-8")
+                    for j in range(0, len(slab), 46)]
+        else:
+            text = [text[j : j + 46] for j in range(0, len(text), 46)]
+        winners = iter(text)
         res: List[Optional[str]] = []
-        for i in range(n):
-            raw = out.raw[i * cap : (i + 1) * cap].split(b"\0", 1)[0]
-            res.append(raw.decode("utf-8") if raw else None)
+        for cell, p in zip(cells, present.tolist()):
+            if p == 1:
+                res.append(next(winners))
+            elif p == 2:
+                rows = self.exec_sql_query(_SELECT_WINNER, cell)
+                res.append(rows[0]["timestamp"])
+            else:
+                res.append(None)
         return res
 
     def apply_sequential(self, messages) -> List[bool]:

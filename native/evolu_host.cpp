@@ -16,7 +16,7 @@
 // Exported surface (C ABI, driven from Python via ctypes):
 //   eh_open/eh_close/eh_errmsg/eh_exec/eh_changes/eh_total_changes
 //   eh_prepare/eh_finalize/eh_bind_*/eh_step/eh_reset/eh_column_*
-//   eh_fetch_winners   — batched per-cell winner lookup
+//   eh_fetch_winner_keys — batched per-cell winner lookup, packed
 //   eh_apply_sequential — the reference loop (winner check + app-table
 //                         upsert + __message insert), masks out
 //   eh_apply_planned_packed — apply a device-computed plan (upsert mask)
@@ -410,39 +410,60 @@ int eh_column_bytes(sqlite3_stmt *st, int i) { return sqlite3_column_bytes(st, i
 
 // --- hot path 1: batched winner lookup ---
 //
-// For each distinct cell i, writes the current winner timestamp into
-// out[i] (caller-provided buffer of size out_cap, 0-terminated; empty
-// string = no winner). Timestamps are 46 ASCII chars, so out_cap=47.
-int eh_fetch_winners(sqlite3 *db, int64_t n, const char *const *tables,
-                     const char *const *rows, const char *const *cols,
-                     char *out, int64_t out_cap) {
+// The stored winner of n cells, one indexed probe of kSelectWinner a
+// cell (the covering index__message (table, row, column, timestamp)):
+// one prepared statement, identifiers bound by length with no copy, so
+// NUL-bearing identifiers compare exactly. The cells are spans of an
+// interned blob, three lengths a cell as eh_apply_planned_cells takes
+// them: k cells in `cell_blob`/`cell_lens`, the i-th read being cell
+// ids[i] (ids null: cell i, n <= k). out_present[i] is 0 where the cell
+// has no stored winner (no row, or a NULL timestamp: SQLite's legacy
+// non-INTEGER BLOB PRIMARY KEY quirk admits one, and ORDER BY ... DESC
+// puts it last, as MAX ignores it), 1 where the winner is text of 46
+// bytes, and 2 where it is anything else (another width or storage
+// class), which the caller reads the string way. The 46-byte winners
+// are packed in cell order into out_slab (n * 46 bytes at most) for
+// eh_parse_timestamps. Returns 0, 1 on an SQLite error, 2 on an id out
+// of range.
+int eh_fetch_winner_keys(sqlite3 *db, int64_t n, int64_t k, const char *cell_blob,
+                         const int32_t *cell_lens, const int32_t *ids,
+                         uint8_t *out_present, char *out_slab) {
+  std::vector<int64_t> coff(size_t(k) * 3 + 1);
+  int64_t o = 0;
+  for (int64_t j = 0; j < k * 3; ++j) {
+    coff[size_t(j)] = o;
+    o += cell_lens[j];
+  }
+  coff[size_t(k) * 3] = o;
   sqlite3_stmt *st = nullptr;
   if (sqlite3_prepare_v2(db, kSelectWinner, -1, &st, nullptr) != SQLITE_OK) return 1;
+  char *dst = out_slab;
   for (int64_t i = 0; i < n; ++i) {
-    sqlite3_bind_text(st, 1, tables[i], -1, SQLITE_TRANSIENT);
-    sqlite3_bind_text(st, 2, rows[i], -1, SQLITE_TRANSIENT);
-    sqlite3_bind_text(st, 3, cols[i], -1, SQLITE_TRANSIENT);
+    const int64_t cid = ids ? int64_t(ids[i]) : i;
+    if (cid < 0 || cid >= k) {
+      sqlite3_finalize(st);
+      return 2;
+    }
+    for (int f = 0; f < 3; ++f)
+      sqlite3_bind_text(st, f + 1, cell_blob + coff[size_t(cid * 3 + f)],
+                        cell_lens[cid * 3 + f], SQLITE_STATIC);
     int rc = sqlite3_step(st);
-    char *dst = out + i * out_cap;
+    uint8_t present = 0;
     if (rc == SQLITE_ROW) {
-      // NULL is possible despite the PK (SQLite's legacy non-INTEGER
-      // BLOB PRIMARY KEY quirk allows NULL in tampered/corrupt DBs);
-      // treat it as no-winner rather than reading a null pointer.
-      const unsigned char *t = sqlite3_column_text(st, 0);
-      if (t == nullptr) {
-        dst[0] = '\0';
-      } else {
-        std::strncpy(dst, reinterpret_cast<const char *>(t), out_cap - 1);
-        dst[out_cap - 1] = '\0';
+      const int type = sqlite3_column_type(st, 0);
+      if (type == SQLITE_TEXT && sqlite3_column_bytes(st, 0) == 46) {
+        std::memcpy(dst, sqlite3_column_text(st, 0), 46);
+        dst += 46;
+        present = 1;
+      } else if (type != SQLITE_NULL) {
+        present = 2;
       }
-    } else if (rc == SQLITE_DONE) {
-      dst[0] = '\0';
-    } else {
+    } else if (rc != SQLITE_DONE) {
       sqlite3_finalize(st);
       return 1;
     }
+    out_present[i] = present;
     sqlite3_reset(st);
-    sqlite3_clear_bindings(st);
   }
   sqlite3_finalize(st);
   return 0;

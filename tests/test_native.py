@@ -1004,3 +1004,174 @@ def test_a_pass_storage_leg_still_takes_the_registry_lock_once_a_native_call(mon
     finally:
         engine.close()
         store.close()
+
+
+# The stored winners read by one native call (`eh_fetch_winner_keys`):
+# a probe of the covering index a cell, at every count of cells.
+
+_NONCANON = "2023-09-01T10:00:00.000Z-0000-ABCDEF0123456789"
+_INSERT = ('INSERT INTO "__message" ("timestamp", "table", "row", "column", "value") '
+           "VALUES (?, ?, ?, ?, ?) ON CONFLICT DO NOTHING")
+
+
+def _winner_cells(n_cells):
+    """`n_cells` cells, one in four with no stored row, and the rows
+    that store the rest: one to three messages a cell, every seventh
+    cell's last message replayed (the insert's ON CONFLICT is the
+    apply's), cell 2 holding only a NULL timestamp and cell 3 a NULL one
+    besides its messages. Identifiers include a NUL byte and non-ASCII
+    text, which bind by length."""
+    cells = [("todo", f"r{i}", "title") for i in range(n_cells)]
+    cells[5] = ("todo", "r\x005", "title")
+    cells[6] = ("todoCategory", "ré6", "title")
+    rows = []
+    for i, cell in enumerate(cells):
+        if i % 4 == 0:
+            continue
+        if i in (2, 3):
+            rows.append((None, *cell, "ghost"))
+        if i == 2:
+            continue
+        for j in range(1 + i % 3):
+            rows.append((ts(1_700_000_000_000 + 977 * i + 13 * j, j), *cell, f"v{j}"))
+        if i % 7 == 1:
+            rows.append(rows[-1])
+    return cells, rows
+
+
+def _stored_pair(rows):
+    """The same rows in a native store and a stdlib one (whose
+    `fetch_existing_winners` is the temp-table join)."""
+    cpp, py = CppSqliteDatabase(), PySqliteDatabase()
+    for db in (cpp, py):
+        bootstrap(db)
+        with db.transaction():
+            db.run_many(_INSERT, rows)
+    return cpp, py
+
+
+def _cells_pb(cells):
+    from evolu_tpu.core.packed import PackedReceive
+
+    blob, lens = native.cell_spans(cells)
+    return PackedReceive(0, b"", cells, np.zeros(0, np.int32), *([None] * 6), blob, lens)
+
+
+def _same_keys(got, want):
+    assert got[2] == want[2]
+    assert np.array_equal(got[0], want[0]) and np.array_equal(got[1], want[1])
+
+
+@pytest.mark.parametrize("n_cells", [50, 5000])
+def test_native_winner_key_read_equals_the_temp_table_join(n_cells):
+    """Above and below the 4,096 cells where the C++ backend used to
+    switch to the join: the dict, the key columns from the strings, and
+    the key columns straight from the native slab agree key for key
+    with the join, cells with no winner, replays and NULL timestamps
+    included (a NULL-only cell has no winner: MAX ignores it, ORDER BY
+    ... DESC puts it last)."""
+    from evolu_tpu.ops.merge import read_winner_keys, winner_key_columns
+    from evolu_tpu.storage.apply import fetch_existing_winners
+
+    cells, rows = _winner_cells(n_cells)
+    cpp, py = _stored_pair(rows)
+    join = fetch_existing_winners(py, cells)
+    assert join[cells[2]] is None and join[cells[3]] == ts(1_700_000_000_000 + 977 * 3)
+    want = {c: w for c, w in join.items() if w is not None}
+    native0 = metrics.get_counter("evolu_winner_read_cells_total", route="native")
+    assert fetch_existing_winners(cpp, cells) == want
+    assert cpp.fetch_winners(cells) == [want.get(c) for c in cells]
+    keys = winner_key_columns(cells, want)
+    assert keys[2] is True and np.count_nonzero(keys[0]) == len(want)
+    _same_keys(read_winner_keys(cpp, cells), keys)
+    pb = _cells_pb(cells)
+    ids = np.arange(n_cells)[::-1][::3]  # any subset, in any order
+    picked = [cells[i] for i in ids]
+    _same_keys(read_winner_keys(cpp, picked, pb, ids), winner_key_columns(picked, want))
+    assert metrics.get_counter("evolu_winner_read_cells_total", route="native") == \
+        native0 + 3 * n_cells + len(ids)
+    cpp.close(), py.close()
+
+
+def test_a_non_canonical_stored_winner_reads_as_not_canonical():
+    from evolu_tpu.ops.merge import read_winner_keys, winner_key_columns
+    from evolu_tpu.storage.apply import fetch_existing_winners
+
+    cells, rows = _winner_cells(50)
+    rows.append((_NONCANON, *cells[8], "upper"))
+    cpp, py = _stored_pair(rows)
+    join = {c: w for c, w in fetch_existing_winners(py, cells).items() if w is not None}
+    assert join[cells[8]] == _NONCANON and fetch_existing_winners(cpp, cells) == join
+    keys = winner_key_columns(cells, join)
+    assert keys[2] is False
+    _same_keys(read_winner_keys(cpp, cells), keys)
+    _same_keys(read_winner_keys(cpp, cells, _cells_pb(cells), np.arange(50)), keys)
+    cpp.close(), py.close()
+
+
+@pytest.mark.parametrize("stored", ["x" * 45, "2023-09-01T10:00:00.000Z-0000-abcdef0123456789ff",
+                                    "2023-13-01T10:00:00.000Z-0000-abcdef0123456789", 7,
+                                    b"2023-09-01T10:00:00.000Z-0000-abcdef0123456789"],
+                         ids=["narrow", "wide", "malformed", "integer", "blob"])
+def test_a_stored_winner_of_another_form_takes_the_string_path(stored):
+    """The native read reports a winner that is not 46 bytes of text
+    (and the parse refuses a malformed one): the batch takes the string
+    path, so the dict holds the winner exactly as stored and the key
+    read fails exactly as `winner_key_columns` does on the join's dict."""
+    from evolu_tpu.ops.merge import read_winner_keys, winner_key_columns
+    from evolu_tpu.storage.apply import fetch_existing_winners
+
+    cells, rows = _winner_cells(50)
+    rows.append((stored, *cells[8], "odd"))
+    cpp, py = _stored_pair(rows)
+    join = {c: w for c, w in fetch_existing_winners(py, cells).items() if w is not None}
+    assert join[cells[8]] == stored and fetch_existing_winners(cpp, cells) == join
+    present, _slab = cpp.fetch_winner_keys(*native.cell_spans(cells))
+    assert present[8] == (1 if stored == "2023-13-01T10:00:00.000Z-0000-abcdef0123456789" else 2)
+    with pytest.raises(Exception) as want:
+        winner_key_columns(cells, join)
+    for args in ((), (_cells_pb(cells), np.arange(50))):
+        with pytest.raises(want.type, match=str(want.value)):
+            read_winner_keys(cpp, cells, *args)
+    cpp.close(), py.close()
+
+
+@pytest.mark.parametrize("fault", ["id_past_the_cells", "negative_id", "span_past_the_blob"])
+def test_fetch_winner_keys_refuses_what_it_cannot_bind(fault):
+    from evolu_tpu.core.types import UnknownError
+
+    db = CppSqliteDatabase()
+    bootstrap(db)
+    blob, lens = native.cell_spans([("todo", "r1", "title"), ("todo", "r2", "title")])
+    ids = {"id_past_the_cells": [0, 2], "negative_id": [-1]}.get(fault)
+    if fault == "span_past_the_blob":
+        blob = blob[:-1]
+    with pytest.raises(ValueError if ids is None else UnknownError):
+        db.fetch_winner_keys(blob, lens, ids)
+    db.close()
+
+
+def test_fetch_winners_decodes_in_linear_time():
+    """The answer buffer is copied once, not once a cell: 20,000 cells
+    read in well under a second, and in under 30 times what 2,000 take
+    (a copy a cell makes it ~100 times: each copy grows with the cells
+    too). Best of three at each size."""
+    import time
+
+    def best_read(n):
+        cells, rows = _winner_cells(n)
+        db = CppSqliteDatabase()
+        bootstrap(db)
+        with db.transaction():
+            db.run_many(_INSERT, rows)
+        times = []
+        for _ in range(3):
+            t0 = time.perf_counter()
+            winners = db.fetch_winners(cells)
+            times.append(time.perf_counter() - t0)
+        assert sum(w is not None for w in winners) == len({r[1:4] for r in rows if r[0]})
+        db.close()
+        return min(times)
+
+    small, large = best_read(2_000), best_read(20_000)
+    assert large < 1.0 and large < 30 * small, (small, large)

@@ -28,6 +28,10 @@ dump and the `UPDATE`. Three counters say how often the new path
 engages: `evolu_merkle_fold_nodes_total` and
 `evolu_merkle_tree_text_checks_total` / `_hits_total{leg}`.
 
+The stored winners a plan reads from SQLite are a part too,
+`recv_winner_read` inside `recv_plan_host`: observed once in a `Receive`
+that reads any (the streamed plans and the cache's seed), with no wait.
+
 The tree is carried as its text (`MerkleText`): the load reads
 `__clock` and parses nothing, the fold is one native pass from the old
 text to the new (`evolu_merkle_fold_route_total{route=text}`, a fold a
@@ -62,6 +66,10 @@ ON_WORKER = TILES + ("recv_handle",)
 # child of a tile -> the tile it lies inside
 TREE = {"recv_tree_load": "recv_clock", "recv_tree_fold": "recv_apply",
         "recv_tree_store": "recv_commit", "recv_tree_diff": "recv_commit"}
+# ... and every part: each Receive of the fixture reads stored winners
+# (the two streamed plans, the seed of every cell, and the seed of the
+# cells the fourth is the first to touch).
+PARTS = {**TREE, "recv_winner_read": "recv_plan_host"}
 NOW = 1_700_010_000_000
 
 
@@ -170,7 +178,7 @@ def test_one_registry_acquisition_a_receive(device, wires, monkeypatch):
         on_worker = [c for c in calls if c[1] == worker]
         assert [c[0] for c in on_worker] == ["observe_many"], on_worker
         assert sorted(on_worker[0][2]) == sorted(
-            [("evolu_stage_ms", s) for s in ON_WORKER + tuple(TREE)]
+            [("evolu_stage_ms", s) for s in ON_WORKER + tuple(PARTS)]
             + [("evolu_stage_wait_ms", s) for s in ON_WORKER])
         (decrypt,) = [c for c in calls if c[1] != worker]
         assert decrypt[:2] == ("observe_many", threading.get_ident())
@@ -187,7 +195,7 @@ def test_each_tile_waits_no_longer_than_it_lasts(device, wires):
     handle's, which is the thread's whole time off the CPU; a part has
     none."""
     def reading():
-        return {s: (_hist(s), _wait(s)) for s in ON_WORKER + tuple(TREE)}
+        return {s: (_hist(s), _wait(s)) for s in ON_WORKER + tuple(PARTS)}
 
     def _wait(stage):
         h = metrics.registry.get_histogram("evolu_stage_wait_ms", stage=stage)
@@ -205,7 +213,7 @@ def test_each_tile_waits_no_longer_than_it_lasts(device, wires):
             assert -0.05 <= wait[s] <= ms[s], (s, wait[s], ms[s])
         # one clock reading a seam, so CPU time tiles as wall time does
         assert sum(wait[s] for s in TILES) <= wait["recv_handle"] + 0.05 * ms["recv_handle"]
-        for child in TREE:
+        for child in PARTS:
             assert after[child][1] == (0.0, 0)
 
 
@@ -247,6 +255,7 @@ def test_every_stage_is_one_annotation_on_its_thread(device, wires):
     # ... and the tree's parts inside their tiles (the fold twice: the
     # delta decode in the planner, the fold after the SQLite apply).
     inside = {"recv_clock": ["recv_tree_load"], "recv_pull": ["pull_wave"],
+              "recv_plan_host": ["recv_winner_read"],
               "recv_apply": ["recv_tree_fold"] * 2,
               "recv_commit": ["recv_tree_store", "recv_tree_diff"]}
     one = [("open", "recv_handle")]
@@ -291,9 +300,9 @@ def test_end_state_identical_with_metrics_disabled(wires):
     assert {s: _hist(s)[1] - counts[s] for s in ON_WORKER} == dict.fromkeys(ON_WORKER, 4)
 
 
-@pytest.mark.parametrize("child", sorted(TREE))
+@pytest.mark.parametrize("child", sorted(PARTS))
 def test_tree_part_once_a_receive_inside_its_tile(device, wires, child):
-    parent = TREE[child]
+    parent = PARTS[child]
     for k, wire in enumerate(wires):
         before = {s: _hist(s) for s in ON_WORKER + (child,)}
         device.receive(wire)
@@ -472,6 +481,8 @@ def test_rehearsal_of_the_client_cells_ends_correct_with_the_tree_metrics(cell):
     assert tree <= set(got)
     assert got["tree_text_hit_share"]["value"] == 100.0  # the relay's text is the client's
     assert got["tree_text_fold_share"]["value"] == 100.0  # every fold text to text
+    assert got["winner_read_native_share"]["value"] == 100.0  # no temp-table join
+    assert got["recv_winner_read_ms"]["value"] > 0
     if cell == "client-todo-months.restore":
         assert {"recv_handle_ms.months", "window_compiles.months"} <= set(got)
         assert got["window_compiles.months"]["value"] == 0

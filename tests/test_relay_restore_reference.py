@@ -195,7 +195,9 @@ def test_rehearsal_of_the_cell_ends_correct_with_every_new_metric():
     assert line["correct"] is True and line["failed"] == 0 and line["attempted"] > 0
     assert line["device"]["platform"] == "cpu"
     got = {k: v["value"] for k, v in line["metrics"].items()}
-    assert set(got) == set(NEW_METRICS) | set(TWINS) | {"apply_multirow_share"}
+    assert set(got) == set(NEW_METRICS) | set(TWINS) | {
+        "apply_multirow_share", "winner_read_native_share", "recv_winner_read_ms"}
+    assert got["winner_read_native_share"] == 100.0  # every winner read natively
     assert got["window_compiles.restore"] == 0
     # One `Receive` of 2,000 a restore: 31 statements of 64 rows, 16 rows single.
     assert got["apply_multirow_share"] == pytest.approx(100.0 * 1984 / 2000)

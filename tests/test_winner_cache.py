@@ -553,7 +553,7 @@ def test_plan_packed_seed_failure_samples_ewma_once(monkeypatch):
     msgs = tuple(_mk(i) for i in range(40))
     try:
         monkeypatch.setattr(
-            DeviceWinnerCache, "_seed_new_cells", lambda self, cells: False
+            DeviceWinnerCache, "_seed_new_cells", lambda self, cells, pb=None, ids=None: False
         )
         assert cache.plan_packed(_PbStub(msgs)) is None
         assert cache._skip_ewma_once, "bounce did not arm the one-shot skip"
@@ -606,3 +606,105 @@ def test_capacity_cap_drop_and_reseed_eviction():
         assert len(cache._slots) <= 40
     finally:
         db_a.close(), db_b.close()
+
+
+# The stored winners the packed route reads (`read_winner_keys` with the
+# batch's interned blob) against the temp-table join.
+
+_INSERT = ('INSERT INTO "__message" ("timestamp", "table", "row", "column", "value") '
+           "VALUES (?, ?, ?, ?, ?) ON CONFLICT DO NOTHING")
+
+
+def _stored_cells(n_cells, extra=()):
+    """A native store and a stdlib mirror (whose `fetch_existing_winners`
+    is the temp-table join) holding the winners of `n_cells` cells: none
+    for every fourth, one or two messages for the rest, every fifth
+    replaying its last, cell 2 only a NULL timestamp; `extra` rows
+    besides."""
+    from evolu_tpu.storage.sqlite import PySqliteDatabase
+
+    cells = [("todo", f"r{i}", "title") for i in range(n_cells)]
+    rows = []
+    for i, cell in enumerate(cells):
+        if i == 2:
+            rows.append((None, *cell, "ghost"))
+        elif i % 4:
+            rows += [(_mk(3 * i + j).timestamp, *cell, f"v{j}") for j in range(1 + i % 2)]
+            if i % 5 == 1:
+                rows.append(rows[-1])
+    rows += list(extra)
+    db, py = _db(), PySqliteDatabase()
+    init_db_model(py, mnemonic=None)
+    for d in (db, py):
+        with d.transaction():
+            d.run_many(_INSERT, rows)
+    return cells, db, py
+
+
+def _cells_pb(cells, cell_ids):
+    """A PackedReceive of one message a row, row i on `cells[cell_ids[i]]`,
+    with canonical timestamps newer than any stored."""
+    from evolu_tpu.core.packed import PackedReceive
+    from evolu_tpu.storage.native import cell_spans
+
+    n = len(cell_ids)
+    blob, lens = cell_spans(cells)
+    vals = [b"new%d" % i for i in range(n)]
+    vlens = np.fromiter(map(len, vals), np.int32, n)
+    return PackedReceive(
+        n, "".join(_mk(10**6 + i).timestamp for i in range(n)).encode(), cells,
+        np.asarray(cell_ids, np.int32), np.full(n, 3, np.uint8), np.zeros(n, np.int64),
+        np.zeros(n, np.float64), vlens, (np.cumsum(vlens) - vlens).astype(np.int64),
+        b"".join(vals), blob, lens)
+
+
+@pytest.mark.parametrize("n_cells", [50, 5000])
+def test_packed_seeds_read_what_the_join_reads(n_cells):
+    """A third of the cells seeded the string way, then every touched
+    cell through `plan_packed`'s seed read from the batch's blob: each
+    slot holds `winner_key_columns` of the join's dict, at 50 cells and
+    above the 4,096 where the read used to switch to the join."""
+    from evolu_tpu.ops.merge import winner_key_columns
+    from evolu_tpu.ops.winner_cache import _new_cell_ids
+    from evolu_tpu.storage.apply import fetch_existing_winners
+
+    cells, db, py = _stored_cells(n_cells)
+    cache = DeviceWinnerCache(db, capacity=64, adaptive=False)
+    try:
+        join = {c: w for c, w in fetch_existing_winners(py, cells).items() if w is not None}
+        assert cells[2] not in join and len(join) == n_cells * 3 // 4 - 1
+        assert cache._seed_new_cells(cells[::3])
+        pb = _cells_pb(cells, np.arange(n_cells)[::-1])
+        touched_ids, touched = pb.touched_cells()
+        new_cells = [c for c in touched if c not in cache._slots]
+        ids = _new_cell_ids(touched, new_cells, touched_ids)
+        assert [cells[i] for i in ids] == new_cells
+        assert cache._seed_new_cells(new_cells, pb, ids)
+        v1, v2, canonical = winner_key_columns(cells, join)
+        w1, w2 = cache._gather_slot_values(np.array([cache._slots[c] for c in cells]))
+        assert canonical and np.array_equal(w1, v1) and np.array_equal(w2, v2)
+    finally:
+        db.close(), py.close()
+
+
+def test_a_non_canonical_stored_winner_bounces_the_packed_routes_as_before():
+    """Upper-case hex in a stored winner: the cached route's seed and
+    the streamed plan both give the batch back to the object path, with
+    no slot for it, as the string read did."""
+    from evolu_tpu.obs import metrics
+    from evolu_tpu.ops.merge import plan_packed_streamed
+
+    upper = "2023-09-01T10:00:00.000Z-0000-ABCDEF0123456789"
+    cells, db, py = _stored_cells(50, [(upper, "todo", "r8", "title", "U")])
+    cache = DeviceWinnerCache(db, capacity=64, adaptive=False)
+    try:
+        pb = _cells_pb(cells, np.arange(50))
+        bounced = metrics.get_counter("evolu_winner_cache_noncanonical_seeds_total")
+        assert cache.plan_packed(pb) is None
+        assert metrics.get_counter("evolu_winner_cache_noncanonical_seeds_total") == bounced + 1
+        assert cache._skip_ewma_once and not cache._slots
+        millis, counter, node, _ok = pb.parse_timestamps()
+        touched_ids, touched = pb.touched_cells()
+        assert plan_packed_streamed(db, pb, millis, counter, node, touched, touched_ids) is None
+    finally:
+        db.close(), py.close()
